@@ -19,7 +19,6 @@ Public API shape follows the reference (`deepspeed/__init__.py:64,246,269`):
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
-from .utils import jax_compat as _jax_compat  # noqa: F401  (must precede comm)
 from . import comm  # noqa: F401
 from .accelerator import get_accelerator  # noqa: F401
 from .runtime.config import DeeperSpeedConfig  # noqa: F401

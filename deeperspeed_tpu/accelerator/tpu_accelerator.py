@@ -57,7 +57,9 @@ class TpuAccelerator(Accelerator):
         for key, val in _PEAK_TFLOPS.items():
             if key in kind:
                 return val
-        return 275e12  # conservative default (v4-class)
+        raise ValueError(
+            f"no peak FLOP/s known for device kind {kind!r}; add it to "
+            f"_PEAK_TFLOPS with its source")
 
 
 class CpuAccelerator(Accelerator):

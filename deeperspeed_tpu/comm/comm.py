@@ -153,26 +153,25 @@ def init_distributed(dist_backend=None, auto_mpi_discovery=False, timeout=None,
     if world_size < 0:
         world_size = int(os.environ.get("WORLD_SIZE",
                                         os.environ.get("DST_NUM_PROCESSES", -1)))
-    if coord or world_size > 1:
-        try:
-            # NOTE: must not touch jax.default_backend()/jax.devices() here
-            # -- that initializes XLA and forecloses distributed init
-            plats = (jax.config.jax_platforms or "")
-            if plats.split(",")[0] == "cpu":
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            init_kwargs = {}
-            if coord:
-                init_kwargs["coordinator_address"] = coord
-            if world_size > 0:
-                init_kwargs["num_processes"] = world_size
-            if rank >= 0:
-                init_kwargs["process_id"] = rank
-            jax.distributed.initialize(**init_kwargs)
-            logger.info(
-                f"jax.distributed initialized: process {jax.process_index()}/{jax.process_count()}"
-            )
-        except Exception as e:  # already initialized or single-process
-            logger.warning(f"jax.distributed.initialize skipped: {e}")
+    if (coord or world_size > 1) and not jax.distributed.is_initialized():
+        # a rendezvous that was asked for and fails must stop the run: going
+        # on as one process trains a different job than the one launched.
+        # NOTE: must not touch jax.default_backend()/jax.devices() here
+        # -- that initializes XLA and forecloses distributed init
+        plats = (jax.config.jax_platforms or "")
+        if plats.split(",")[0] == "cpu":
+            jax.config.update("jax_cpu_collectives_implementation", "gloo")
+        init_kwargs = {}
+        if coord:
+            init_kwargs["coordinator_address"] = coord
+        if world_size > 0:
+            init_kwargs["num_processes"] = world_size
+        if rank >= 0:
+            init_kwargs["process_id"] = rank
+        jax.distributed.initialize(**init_kwargs)
+        logger.info(
+            f"jax.distributed initialized: process {jax.process_index()}/{jax.process_count()}"
+        )
     _initialized = True
 
 
@@ -343,7 +342,7 @@ def _eager_collective(fn, x, spec=None, out_spec=None, cache_key=None):
     the cached callable is a ``jax.jit``, which retraces per distinct input
     aval on its own.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     mesh = topo.get_mesh().mesh
     in_spec = spec if spec is not None else _infer_spec(x)
@@ -351,14 +350,14 @@ def _eager_collective(fn, x, spec=None, out_spec=None, cache_key=None):
     if cache_key is None:
         return jax.jit(
             shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                      out_specs=out_spec, check_rep=False)
+                      out_specs=out_spec, check_vma=False)
         )(x)
     key = (cache_key, mesh, in_spec, out_spec)
     jitted = _EAGER_CACHE.get(key)
     if jitted is None:
         jitted = jax.jit(
             shard_map(fn, mesh=mesh, in_specs=(in_spec,),
-                      out_specs=out_spec, check_rep=False))
+                      out_specs=out_spec, check_vma=False))
         _EAGER_CACHE[key] = jitted
     return jitted(x)
 

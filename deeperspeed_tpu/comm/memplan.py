@@ -113,7 +113,7 @@ def plan_param_movement(closed_jaxpr, param_indices=None,
     analog).  Inputs with no consumer are skipped (nothing to move).
     """
     import numpy as np
-    from jax import core as jax_core
+    from jax.extend import core as jax_core
 
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     first, last = {}, {}

@@ -38,7 +38,7 @@ import dataclasses
 import math
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 try:  # reorder-safety guard: axis-name tracking is not an ordering effect
     from jax._src.core import NamedAxisEffect
@@ -49,10 +49,10 @@ from ..utils.logging import logger
 from .overlap import bucketize  # noqa: F401  (re-exported for planners)
 
 # primitive name -> wire-model collective kind (telemetry/wire.py convention)
-# (psum2 is psum as re-traced inside check_rep=True shard_map bodies)
+# (psum_invariant is psum as traced inside check_vma=True shard_map bodies)
 COLLECTIVE_PRIMS = {
     "psum": "all_reduce",
-    "psum2": "all_reduce",
+    "psum_invariant": "all_reduce",
     "reduce_scatter": "reduce_scatter",
     "all_gather": "all_gather",
     "all_to_all": "all_to_all",
@@ -565,7 +565,7 @@ class ScheduledStepFn:
 
         def run(*call_args):
             flat = jax.tree_util.tree_leaves(call_args)
-            out_flat = jax_core.eval_jaxpr(
+            out_flat = jax.core.eval_jaxpr(
                 new_closed.jaxpr, new_closed.consts, *flat)
             return jax.tree_util.tree_unflatten(out_tree, out_flat)
 

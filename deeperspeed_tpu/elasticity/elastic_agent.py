@@ -34,6 +34,12 @@ class DSElasticAgent:
     to ``len(jax.devices())``); it is re-queried before every (re)start so a
     shrunk/grown slice gets a compatible batch per the elastic algebra
     (reference ``compute_elastic_config`` driving the v0.1/v0.2 schedules).
+
+    One process for each chip: the default ``world_size_fn`` asks JAX in
+    THIS process, which from then on holds the chip.  That is right for a
+    ``train_fn`` that trains in this process.  A ``train_fn`` that starts
+    worker processes must be given a ``world_size_fn`` that does not touch
+    JAX -- a worker whose parent holds the chip fails or hangs.
     """
 
     def __init__(self, train_fn: Callable, config: dict,

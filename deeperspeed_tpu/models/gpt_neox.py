@@ -22,8 +22,8 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
+from ..parallel.topology import BATCH_AXES
 
-BATCH_AXES = ("dp", "zshard", "ep")  # batch dim sharding (sp shards sequence)
 
 
 def maybe_constrain(x, spec):

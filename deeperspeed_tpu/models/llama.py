@@ -30,9 +30,9 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention.core import dot_product_attention
 from ..ops.transformer.rope import apply_rotary_pos_emb, rotary_tables
+from ..parallel.topology import BATCH_AXES
 from .gpt_neox import ModelLayerNorm, maybe_constrain
 
-BATCH_AXES = ("dp", "zshard", "ep")
 
 
 @dataclasses.dataclass(unsafe_hash=True)

@@ -33,15 +33,11 @@ def _adam_leaf_update_jnp(g, m, v, count, b1, b2, eps):
 
 def _adam_leaf_update(g, m, v, count, b1, b2, eps):
     from ...accelerator import get_accelerator
-    from ...utils.logging import warning_once
 
     if get_accelerator().use_pallas_kernels() and g.size >= 1024:
-        try:
-            from .pallas_adam import fused_adam_kernel
+        from .pallas_adam import fused_adam_kernel
 
-            return fused_adam_kernel(g, m, v, count, b1, b2, eps)
-        except Exception as e:  # pragma: no cover - platform without pallas
-            warning_once(f"pallas fused adam unavailable, using XLA fallback: {e}")
+        return fused_adam_kernel(g, m, v, count, b1, b2, eps)
     return _adam_leaf_update_jnp(g, m, v, count, b1, b2, eps)
 
 

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 
 from ...accelerator import get_accelerator
+from ...parallel.topology import BATCH_AXES, SP_AXIS, TP_AXIS
+from ..pallas_utils import shard_kernel
 
 
 def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rng=None,
@@ -45,7 +47,12 @@ def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_r
         from .flash import flash_attention, flash_attention_supported
 
         if flash_attention_supported(q.shape, q.dtype) and q.shape == k.shape:
-            return flash_attention(q, k, v, causal=causal, scale=scale)
+            # each shard attends over the whole sequence for its own batch
+            # rows and heads (heads over sp is the Ulysses layout)
+            spec = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
+            return shard_kernel(
+                functools.partial(flash_attention, causal=causal, scale=scale),
+                (q, k, v), (spec, spec, spec))
     return _reference_attention(q, k, v, mask=mask, causal=causal, scale=scale,
                                 dropout_rng=dropout_rng, dropout_rate=dropout_rate)
 

@@ -185,6 +185,7 @@ def _spec_decode_reference(q, pool_k, pool_v, block_tables, positions, scale,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "force_kernel"))
+@jax.named_scope("paged_spec_decode_attention")
 def paged_spec_decode_attention(q, pool_k, pool_v, block_tables, positions,
                                 scale=None, force_kernel=False,
                                 k_scale=None, v_scale=None):
@@ -249,6 +250,7 @@ def paged_spec_decode_attention(q, pool_k, pool_v, block_tables, positions,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "force_kernel"))
+@jax.named_scope("paged_decode_attention")
 def paged_decode_attention(q, pool_k, pool_v, block_tables, seq_lens,
                            scale=None, force_kernel=False,
                            k_scale=None, v_scale=None):

@@ -262,9 +262,7 @@ def _params(grid):
     parallel; only the k/q-walk dim carries the scratch accumulator."""
     from jax.experimental.pallas import tpu as pltpu
 
-    # jax renamed TPUCompilerParams -> CompilerParams across releases
-    params_cls = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-    return dict(compiler_params=params_cls(
+    return dict(compiler_params=pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary")))
 
 
@@ -394,6 +392,7 @@ def _mha(q, k, v, causal, scale, block):
     return _mha_fwd(q, k, v, causal, scale, block)[0]
 
 
+@jax.named_scope("flash_attention")
 def _mha_fwd(q, k, v, causal, scale, block):
     s_valid = q.shape[1]
     qp, kp, vp = (_pad_seq(t, block) for t in (q, k, v))
@@ -404,6 +403,7 @@ def _mha_fwd(q, k, v, causal, scale, block):
     return o[:, :s_valid], (qp, kp, vp, o, lse)
 
 
+@jax.named_scope("flash_attention")
 def _mha_bwd(causal, scale, block, res, do):
     qp, kp, vp, o, lse = res
     s_valid = do.shape[1]

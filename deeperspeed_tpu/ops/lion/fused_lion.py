@@ -47,13 +47,9 @@ def fused_lion_kernel(g, m, b1, b2):
 
 def _lion_leaf(g, m, b1, b2):
     from ...accelerator import get_accelerator
-    from ...utils.logging import warning_once
 
     if get_accelerator().use_pallas_kernels() and g.size >= 1024:
-        try:
-            return fused_lion_kernel(g, m, b1, b2)
-        except Exception as e:  # pragma: no cover - platform without pallas
-            warning_once(f"pallas fused lion unavailable, using XLA fallback: {e}")
+        return fused_lion_kernel(g, m, b1, b2)
     return _lion_leaf_jnp(g, m, b1, b2)
 
 

@@ -19,16 +19,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..pallas_utils import NEG_INF, interpret_mode
+from ..pallas_utils import NEG_INF, SUBLANES, interpret_mode, pad_rows
 
 
 def _topk_kernel(x_ref, vals_ref, idx_ref, *, k):
-    work = x_ref[...].astype(jnp.float32)               # [1, V]
+    work = x_ref[...].astype(jnp.float32)               # [SUBLANES, V]
     V = work.shape[1]
     cols = jax.lax.broadcasted_iota(jnp.int32, work.shape, 1)
     vals, idxs = [], []
     for _ in range(k):
-        m = jnp.max(work, axis=1, keepdims=True)        # [1, 1]
+        m = jnp.max(work, axis=1, keepdims=True)        # [SUBLANES, 1]
         # ties resolve to the lowest index, matching lax.top_k
         first = jnp.min(jnp.where(work == m, cols, V), axis=1, keepdims=True)
         vals.append(m)
@@ -39,6 +39,7 @@ def _topk_kernel(x_ref, vals_ref, idx_ref, *, k):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "force_kernel"))
+@jax.named_scope("sorted_topk")
 def sorted_topk(x, k, force_kernel=False):
     """Top-k values (descending) + their indices per row.
 
@@ -51,14 +52,19 @@ def sorted_topk(x, k, force_kernel=False):
     if interpret_mode() and not force_kernel:
         vals, idx = jax.lax.top_k(x.astype(jnp.float32), k)
         return vals, idx.astype(jnp.int32)
+    # one sublane tile of rows per grid step: the TPU lowering takes a row
+    # block only in multiples of 8, and a [1, V] block fills the same vregs
+    # as an [8, V] one.  Rows are independent, so the padding is sliced off.
+    xp = pad_rows(x, SUBLANES)
+    rp = xp.shape[0]
     vals, idx = pl.pallas_call(
         functools.partial(_topk_kernel, k=k),
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, V), lambda r: (r, 0))],
-        out_specs=[pl.BlockSpec((1, k), lambda r: (r, 0)),
-                   pl.BlockSpec((1, k), lambda r: (r, 0))],
-        out_shape=[jax.ShapeDtypeStruct((rows, k), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, k), jnp.int32)],
+        grid=(rp // SUBLANES,),
+        in_specs=[pl.BlockSpec((SUBLANES, V), lambda r: (r, 0))],
+        out_specs=[pl.BlockSpec((SUBLANES, k), lambda r: (r, 0)),
+                   pl.BlockSpec((SUBLANES, k), lambda r: (r, 0))],
+        out_shape=[jax.ShapeDtypeStruct((rp, k), jnp.float32),
+                   jax.ShapeDtypeStruct((rp, k), jnp.int32)],
         interpret=interpret_mode(),
-    )(x)
-    return vals, idx
+    )(xp)
+    return vals[:rows], idx[:rows]

@@ -20,13 +20,30 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ...accelerator import get_accelerator
-from ..pallas_utils import LANES, rowwise_call
+from ...parallel.topology import BATCH_AXES, SP_AXIS
+from ..pallas_utils import LANES, rowwise_call, shard_kernel
 
-BLOCK_ROWS = 256
+MAX_BLOCK_ROWS = 256
+# Share of the 16 MiB scoped VMEM a kernel may fill with its row blocks and
+# their fp32 temporaries; the rest is the compiler's.  Held against the v5e
+# compiler at hidden 1024..8192 (tests/unit/ops/test_tpu_compile.py).
+_VMEM_BUDGET = 14 * 2 ** 20
 
 
 def _supported(hidden):
     return hidden % LANES == 0
+
+
+def _block_rows(hidden, dtype, n_streams, n_temps):
+    """Row-block height that fits VMEM at this width and dtype.
+
+    Each of the ``n_streams`` row-blocked operands is double-buffered in its
+    own dtype and the kernel body keeps ``n_temps`` fp32 copies of a block
+    alive.  A power of two, so the usual batch*seq row counts need no padding
+    copy; never below the bf16 sublane tile."""
+    per_row = hidden * (2 * n_streams * jnp.dtype(dtype).itemsize + 4 * n_temps)
+    fit = max(_VMEM_BUDGET // per_row, 16)
+    return min(MAX_BLOCK_ROWS, 1 << (fit.bit_length() - 1))
 
 
 # --------------------------------------------------------------------- fwd
@@ -68,6 +85,7 @@ def _ln_bwd_kernel(g_ref, x_ref, dy_ref, dx_ref, dg_ref, db_ref, *, eps, rms):
     db_ref[:] += jnp.sum(dy, axis=0, keepdims=True)
 
 
+@jax.named_scope("fused_norm")
 def _ln_fwd_pallas(x2, gamma, beta, eps, rms):
     h = x2.shape[1]
     vec_spec = pl.BlockSpec((1, h), lambda i: (0, 0))
@@ -83,18 +101,20 @@ def _ln_fwd_pallas(x2, gamma, beta, eps, rms):
                 x_ref, g_ref, b_ref, y_ref, **kw), eps=eps, rms=rms)
         extra = (gamma.reshape(1, h), beta.reshape(1, h))
         extra_specs = (vec_spec, vec_spec)
-    (y,) = rowwise_call(kernel, [("row", x2.dtype)], [x2], BLOCK_ROWS,
+    (y,) = rowwise_call(kernel, [("row", x2.dtype)], [x2],
+                        _block_rows(h, x2.dtype, n_streams=2, n_temps=2),
                         extra_in_specs=extra_specs, extra_args=extra)
     return y
 
 
+@jax.named_scope("fused_norm")
 def _ln_bwd_pallas(x2, gamma, dy2, eps, rms):
     h = x2.shape[1]
     vec_spec = pl.BlockSpec((1, h), lambda i: (0, 0))
     dx, dg, db = rowwise_call(
         functools.partial(_ln_bwd_kernel, eps=eps, rms=rms),
         [("row", x2.dtype), ("vec", jnp.float32), ("vec", jnp.float32)],
-        [x2, dy2], BLOCK_ROWS,
+        [x2, dy2], _block_rows(h, x2.dtype, n_streams=3, n_temps=4),
         extra_in_specs=(vec_spec,), extra_args=(gamma.reshape(1, h),))
     return dx, dg, db
 
@@ -156,17 +176,26 @@ def _norm_bwd(eps, rms, use_pallas, res, dy):
 _norm.defvjp(_norm_fwd, _norm_bwd)
 
 
-def layer_norm(x, gamma, beta, eps=1e-5, use_pallas=None):
-    """Fused LayerNorm over the last dim; fp32 statistics."""
+def _dispatch(x, gamma, beta, eps, rms, use_pallas):
     if use_pallas is None:
         use_pallas = (get_accelerator().use_pallas_kernels()
                       and _supported(x.shape[-1]))
-    return _norm(x, gamma, beta, eps, False, bool(use_pallas))
+    if not use_pallas:
+        return _norm(x, gamma, beta, eps, rms, False)
+    # activations [batch, seq, ..., hidden]; gamma/beta whole on every shard
+    lead = (BATCH_AXES, SP_AXIS)[:x.ndim - 1]
+    x_spec = lead + (None,) * (x.ndim - len(lead))
+    vecs = (gamma,) if rms else (gamma, beta)
+    return shard_kernel(
+        lambda x, g, b=None: _norm(x, g, b, eps, rms, True),
+        (x, *vecs), (x_spec, *[(None,)] * len(vecs)))
+
+
+def layer_norm(x, gamma, beta, eps=1e-5, use_pallas=None):
+    """Fused LayerNorm over the last dim; fp32 statistics."""
+    return _dispatch(x, gamma, beta, eps, False, use_pallas)
 
 
 def rms_norm(x, gamma, eps=1e-5, use_pallas=None):
     """Fused RMSNorm over the last dim (reference ``rms_norm.cu``)."""
-    if use_pallas is None:
-        use_pallas = (get_accelerator().use_pallas_kernels()
-                      and _supported(x.shape[-1]))
-    return _norm(x, gamma, None, eps, True, bool(use_pallas))
+    return _dispatch(x, gamma, None, eps, True, use_pallas)

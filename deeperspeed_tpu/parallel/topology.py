@@ -31,6 +31,9 @@ EP_AXIS = "ep"
 SP_AXIS = "sp"
 TP_AXIS = "tp"
 ALL_AXES = (PP_AXIS, DP_AXIS, ZSHARD_AXIS, EP_AXIS, SP_AXIS, TP_AXIS)
+# axes the batch dim is sharded over (ep is extra data parallelism outside
+# MoE blocks; sp shards the sequence dim instead)
+BATCH_AXES = (DP_AXIS, ZSHARD_AXIS, EP_AXIS)
 
 
 class ProcessTopology:

@@ -49,7 +49,7 @@ from .precision import (
 )
 from .zero.sharding import build_sharding_plan
 
-BATCH_AXES = (topo.DP_AXIS, topo.ZSHARD_AXIS, topo.EP_AXIS)
+BATCH_AXES = topo.BATCH_AXES
 
 
 def _is_reduce_plan_leaf(x):
@@ -156,7 +156,8 @@ class DeeperSpeedEngine:
 
         # ---- init params (master copy, fp32 when mixed)
         self._rng = jax.random.PRNGKey(config.seed)
-        master_abstract, self._init_fn = self._make_init(model, model_parameters)
+        master_abstract, self._init_fn, self._init_args = self._make_init(
+            model, model_parameters)
 
         # ---- sharding plan (ZeRO stage -> placement)
         if hasattr(model, "param_specs"):
@@ -972,10 +973,13 @@ class DeeperSpeedEngine:
                 lambda x: jax.ShapeDtypeStruct(x.shape, jnp.float32), model_parameters
             )
 
-            def init_fn():
-                return tree_cast(model_parameters, jnp.float32)
+            # the given params go in as an ARGUMENT of the jitted init: closed
+            # over, they would be baked into the program as constants (a
+            # 410M-param model makes gigabytes of HLO)
+            def init_fn(params):
+                return tree_cast(params, jnp.float32)
 
-            return abstract, init_fn
+            return abstract, init_fn, (model_parameters,)
 
         example = model.example_batch(batch_size=1)
         first = example["input_ids"] if "input_ids" in example else example["x"]
@@ -989,14 +993,16 @@ class DeeperSpeedEngine:
         def init_fn():
             return raw_init(self._rng)
 
-        return abstract, init_fn
+        return abstract, init_fn, ()
 
     def _build_state(self):
         # init on device, then stream offloaded components to pinned host
         # (the SPMD partitioner rejects host-kind out_shardings on the init
         # computation itself)
         master = jax.jit(self._init_fn,
-                         out_shardings=self._master_dev_shardings)()
+                         out_shardings=self._master_dev_shardings)(
+                             *self._init_args)
+        self._init_args = ()  # do not keep the caller's params alive
         if self._host_adam is not None:
             # host-update mode: fp32 masters move to host, moments live in
             # the native optimizer, and the device keeps ONLY the compute-
@@ -1008,7 +1014,7 @@ class DeeperSpeedEngine:
             return {
                 "master_params": compute,
                 "opt_state": None,
-                "step": jnp.zeros((), jnp.int32),
+                "step": jax.device_put(jnp.zeros((), jnp.int32), self._repl),
                 "loss_scale": jax.device_put(
                     init_loss_scale(self.config.fp16), self._repl),
             }
@@ -1028,7 +1034,10 @@ class DeeperSpeedEngine:
         state = {
             "master_params": master,
             "opt_state": opt_state,
-            "step": jnp.zeros((), jnp.int32),
+            # placed like the step's own output: an unplaced scalar has
+            # another type than the mesh-replicated one that comes back, and
+            # the second train_batch would trace and compile all over again
+            "step": jax.device_put(jnp.zeros((), jnp.int32), self._repl),
             "loss_scale": jax.device_put(scale_state, self._repl),
         }
         if getattr(self, "_onebit", False):

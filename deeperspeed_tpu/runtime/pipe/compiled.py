@@ -54,9 +54,12 @@ def make_pipeline_loss_fn(model, mesh, n_micro, compute_dtype=None):
             head_params = cast(head_params)
             # embed table stays fp32: the model's f32 lookup handles dtype
         # stage id comes in as a pp-sharded iota operand rather than
-        # jax.lax.axis_index: under the manual-over-pp / auto-over-rest
-        # shard_map, axis_index lowers to a PartitionId instruction this
-        # jax's SPMD partitioner rejects as ambiguous
+        # jax.lax.axis_index.  That was forced by jaxlib 0.4.37, whose SPMD
+        # partitioner rejected the PartitionId that axis_index lowers to
+        # under a manual-over-pp / auto-over-rest shard_map.  Re-checked on
+        # jax 0.9.0 (PR 24): axis_index now runs there on XLA:CPU and
+        # compiles for a described v5e 2x2 mesh, so the operand is a choice,
+        # not a need; it goes when the executors are merged (ROADMAP D3)
         stage_id = stage_ids[0]
         m, b, s = tokens.shape
         h = model.config.hidden_size

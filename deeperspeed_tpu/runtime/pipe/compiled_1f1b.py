@@ -74,9 +74,9 @@ def make_pipeline_grad_fn(model, mesh, n_micro, compute_dtype=None):
             sp = cast(sp)
             head_params = cast(head_params)
             # embed table stays fp32 (f32 gather/scatter; see _EmbedIn)
-        # pp-sharded iota operand instead of jax.lax.axis_index: axis_index
-        # under the manual-over-pp / auto-over-rest shard_map lowers to a
-        # PartitionId instruction this jax's SPMD partitioner rejects
+        # pp-sharded iota operand instead of jax.lax.axis_index: a jaxlib
+        # 0.4.37 work-around that jax 0.9.0 no longer needs (see the note in
+        # compiled.py); it goes with ROADMAP D3
         stage_id = stage_ids[0]
         is_last = stage_id == S - 1
         is_first = stage_id == 0

@@ -68,7 +68,7 @@ from . import schedule as sched
 from .module import LayerSpec, PipelineModule, TiedLayerSpec
 
 STAGE_AXES = tuple(a for a in topo.ALL_AXES if a != topo.PP_AXIS)
-BATCH_AXES = (topo.DP_AXIS, topo.ZSHARD_AXIS, topo.EP_AXIS)
+BATCH_AXES = topo.BATCH_AXES
 
 
 class _SubmeshTopo:

@@ -123,11 +123,11 @@ def ring_attention_sharded(q, k, v, causal=True, scale=None,
         mesh=mesh.mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
-        # manual over ALL axes, not just sp: a size->1 auto axis next to the
-        # manual ring collectives trips the SPMD partitioner's manual-subgroup
-        # check in this jax (axis_index additionally lowers to an unsupported
-        # PartitionId).  Non-sp axes carry replicated operands here, so
-        # full-manual is semantically identical.
+        # manual over ALL axes, not just sp.  On jax 0.9 this is what a
+        # Pallas kernel in the body needs anyway ("Mosaic kernels cannot be
+        # automatically partitioned" unless every mesh axis is manual; see
+        # ops/pallas_utils.shard_kernel).  Non-sp axes carry replicated
+        # operands here, so full-manual is semantically identical.
         axis_names=set(mesh.mesh.axis_names),
         check_vma=False,
     )

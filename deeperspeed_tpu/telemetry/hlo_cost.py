@@ -55,6 +55,10 @@ def device_peaks(device=None):
     hit = match_device_spec(TPU_PEAK_SPECS, kind)
     if hit:
         return hit[1][0], hit[1][1], kind
+    if getattr(device, "platform", "cpu") != "cpu":
+        raise ValueError(
+            f"no peak spec known for device kind {kind!r}; add it to "
+            f"TPU_PEAK_SPECS with its source")
     return _CPU_PEAK[0], _CPU_PEAK[1], kind or "cpu"
 
 
@@ -75,6 +79,28 @@ def compiled_cost(compiled):
     if flops is None and nbytes is None:
         return None
     return {"flops": float(flops or 0.0), "bytes_accessed": float(nbytes or 0.0)}
+
+
+def pallas_kernel_calls(hlo_text):
+    """``{scope: [operand shapes of each call]}`` for the Pallas kernels
+    (``tpu_custom_call``) of a compiled program's ``as_text()``, keyed by the
+    ``jax.named_scope`` each kernel is dispatched under (``flash_attention``,
+    ``fused_norm``, ``paged_decode_attention``, ``sorted_topk``; ``"?"`` for
+    a kernel without one).  Says whether a kernel is in the program -- i.e.
+    did not give way to its XLA reference -- and on what size of operand."""
+    import re
+
+    found = {}
+    for line in hlo_text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' not in line:
+            continue
+        scope = re.search(r'op_name="[^"]*?(\w+)/pallas_call', line)
+        operands = line.split("operand_layout_constraints={", 1)[-1]
+        operands = re.split(r"}, \w+=", operands, maxsplit=1)[0]
+        shapes = [tuple(int(d) for d in dims.split(",") if d)
+                  for dims in re.findall(r"\w+\[([\d,]*)\]", operands)]
+        found.setdefault(scope.group(1) if scope else "?", []).append(shapes)
+    return found
 
 
 def step_cost(jitted_fn, *args, **kwargs):

@@ -35,14 +35,9 @@ def _moe_cfg(ep, **extra):
     }
 
 
-def test_save_ep2_load_ep4(reset_mesh, tmp_path, no_persistent_compile_cache):
+def test_save_ep2_load_ep4(reset_mesh, tmp_path):
     """Train at ep=2, resume at ep=4: expert weights reshard, trajectory
-    continues (reference save-at-N/load-at-M reshape contract).
-
-    Cache-off: two engines in one process means the second one's donating
-    train step would be served as a deserialized executable with its
-    aliasing dropped (see conftest) -- with the cache disabled the resumed
-    trajectory is exact."""
+    continues (reference save-at-N/load-at-M reshape contract)."""
     model = _moe_model()
     mesh2 = topo.MeshTopology(ep=2)
     e1, _, _, _ = dst.initialize(model=model, config=_moe_cfg(2), mesh=mesh2)
@@ -69,12 +64,10 @@ def test_save_ep2_load_ep4(reset_mesh, tmp_path, no_persistent_compile_cache):
     assert abs(l1 - l2) < 5e-3, (l1, l2)
 
 
-def test_fp16_loss_scale_trajectory_across_save_load(
-        mesh8, tmp_path, no_persistent_compile_cache):
+def test_fp16_loss_scale_trajectory_across_save_load(mesh8, tmp_path):
     """The dynamic scaler state (scale, growth tracker) survives resume so
     the post-resume scale trajectory is identical (reference fp16 resume
-    semantics).  Cache-off: the resumed engine compiles the byte-identical
-    donating step the first engine just cached (see conftest)."""
+    semantics)."""
     model = GPTNeoX(GPTNeoXConfig.tiny())
     cfg = {
         "train_batch_size": 16,

@@ -69,10 +69,8 @@ def test_export_layout_matches_reference(reset_mesh, tmp_path):
         assert f.read().strip() == "global_step3_universal"
 
 
-def test_roundtrip_into_different_mesh(reset_mesh, tmp_path,
-                                       no_persistent_compile_cache):
-    """write reference layout -> load into a tp=2 mesh -> loss continues.
-    Cache-off: second-engine-in-process resume pattern (see conftest)."""
+def test_roundtrip_into_different_mesh(reset_mesh, tmp_path):
+    """write reference layout -> load into a tp=2 mesh -> loss continues."""
     import jax
 
     engine, batch, loss_before, cfg = _train_and_save(tmp_path)

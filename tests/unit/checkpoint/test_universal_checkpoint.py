@@ -73,14 +73,8 @@ def test_universal_roundtrip(saved_ckpt, tmp_path):
         np.testing.assert_array_equal(val, flat[name])
 
 
-def test_load_universal_into_new_topology(saved_ckpt, tmp_path,
-                                          no_persistent_compile_cache):
-    """Save at dp=8 -> universal export -> load under tp=2 mesh.
-
-    Cache-immune (see conftest caveat): the post-load train step donates
-    state, and an equivalent tp=2 GPTNeoX program may already sit in the
-    persistent cache from an earlier pytest run -- a deserialized
-    executable can drop the donation aliasing and poison the step."""
+def test_load_universal_into_new_topology(saved_ckpt, tmp_path):
+    """Save at dp=8 -> universal export -> load under tp=2 mesh."""
     path, engine = saved_ckpt
     out = tmp_path / "uni"
     ds_to_universal(path, str(out))

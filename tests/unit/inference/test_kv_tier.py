@@ -15,6 +15,7 @@ from deeperspeed_tpu.inference.v2 import (
     KVTierConfig,
 )
 from deeperspeed_tpu.inference.v2 import kv_tier as kv_tier_mod
+from deeperspeed_tpu.inference.v2.ragged_manager import chain_key
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 
 
@@ -83,11 +84,23 @@ def test_spill_restore_roundtrip_bit_exact(tiny_model, kv_dtype):
     # the PROMPT's full blocks (leaving >=1 recompute token) -- 2 restores
     assert tier.hits == (len(prompt) - 1) // 8
     assert tier.corrupt == 0
+    # Byte-compare only the blocks the tier RESTORED (the prompt's full
+    # blocks).  The 3rd block is never looked up in the tier: the rerun
+    # RECOMPUTES it from a 4-token prefill tail where the first run
+    # prefilled all 20 tokens at once, and XLA:CPU's matmul (jax 0.9) is not
+    # shape-invariant to the last ulp -- so it is re-published, not restored,
+    # and is held only to the token-level parity asserted above.
+    restored, key = [], b""
+    for idx in range((len(prompt) - 1) // 8):
+        key = chain_key(key, prompt[idx * 8:(idx + 1) * 8])
+        restored.append(key)
+    assert set(restored) < set(truth)
     for key, want in truth.items():
-        block = cache.lookup(key)          # restored + re-published
+        block = cache.lookup(key)          # restored / re-published
         assert block is not None
-        for g, w in zip(eng.export_kv_block(block), want):
-            assert np.array_equal(g, w)
+        if key in restored:
+            for g, w in zip(eng.export_kv_block(block), want):
+                assert np.array_equal(g, w)
     eng.state_manager.allocator.audit()
 
 

@@ -1,0 +1,207 @@
+"""The main path's Pallas kernels, compiled by the TPU compiler for a
+*described* v5e chip (nothing is attached, nothing runs).
+
+Interpret-mode tests execute kernel bodies on the CPU but never lower them
+for Mosaic, so a block shape the TPU lowering refuses, a kernel that
+overflows VMEM, or one GSPMD cannot partition passes them all.  These cases
+ask the installed TPU compiler at the real widths.  A compile that passes
+is not a chip run.
+
+The topology is described inside a module-scoped fixture and only there:
+one process at a time may load libtpu, so it must not happen while any
+module is imported (every xdist worker imports every test file).
+``interpret_mode()`` sees the CPU backend here, so it is patched in the
+modules that imported it by name; the program gets no option for this.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from deeperspeed_tpu.ops import pallas_utils
+from deeperspeed_tpu.ops.adam import pallas_adam
+from deeperspeed_tpu.ops.attention import core as attn_core
+from deeperspeed_tpu.ops.attention import paged, pallas_flash
+from deeperspeed_tpu.ops.quantizer import fused as qfused
+from deeperspeed_tpu.ops.sampling import topk
+from deeperspeed_tpu.ops.transformer import normalize
+from deeperspeed_tpu.parallel import topology as topo_mod
+from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
+
+_BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip is written to the persistent cache
+    # but cannot be read back without the chip; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    saved = [(m, m.interpret_mode) for m in _BY_NAME]
+    for m in _BY_NAME:
+        m.interpret_mode = lambda: False
+    yield desc
+    for m, fn in saved:
+        m.interpret_mode = fn
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+    # traces made while interpret_mode() said "chip" must not outlive it
+    jax.clear_caches()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *shapes, **kw_shapes):
+    """Compile ``fn`` for the shapes' (described) devices; the HLO text."""
+    text = jax.jit(fn).lower(*shapes, **kw_shapes).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def _kernel_operand_shapes(text):
+    """Operand shapes of every Pallas kernel call in compiled HLO text."""
+    return [call for calls in pallas_kernel_calls(text).values()
+            for call in calls]
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _sum_grad(fn, n_diff):
+    """fwd+bwd of ``fn`` w.r.t. its first ``n_diff`` arguments."""
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32)),
+                    argnums=tuple(range(n_diff)))
+
+
+# ------------------------------------------------------------------ one chip
+@pytest.mark.parametrize("shape", [(2, 2048, 16, 64), (2, 2048, 32, 128)])
+def test_flash_mha_fwd_bwd(one_chip, shape):
+    q = _sds(shape, jnp.bfloat16, one_chip)
+    _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+@pytest.mark.parametrize("hidden", [1024, 4096, 6144])
+def test_layer_norm_fwd_bwd(one_chip, hidden, dtype):
+    x = _sds((16384, hidden), dtype, one_chip)
+    g = _sds((hidden,), jnp.float32, one_chip)
+    fn = functools.partial(normalize.layer_norm, use_pallas=True)
+    _compile(fn, x, g, g)
+    _compile(_sum_grad(fn, 3), x, g, g)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_rms_norm_fwd_bwd(one_chip, dtype):
+    x = _sds((16384, 4096), dtype, one_chip)
+    g = _sds((4096,), jnp.float32, one_chip)
+    fn = functools.partial(normalize.rms_norm, use_pallas=True)
+    _compile(fn, x, g)
+    _compile(_sum_grad(fn, 2), x, g)
+
+
+def test_fused_adam_kernel(one_chip):
+    w = _sds((50304, 1024), jnp.float32, one_chip)
+    count = _sds((), jnp.float32, one_chip)
+    _compile(functools.partial(pallas_adam.fused_adam_kernel.__wrapped__,
+                               b1=0.9, b2=0.999, eps=1e-8), w, w, w, count)
+
+
+def _pool_shapes(one_chip, pool_dtype):
+    """N=16, D=64, block 16: 8 rows of up to 32 blocks over a 256-block pool."""
+    pool = _sds((256, 16, 16, 64), pool_dtype, one_chip)
+    tables = _sds((8, 32), jnp.int32, one_chip)
+    kw = {}
+    if pool_dtype == jnp.int8:
+        kw = dict(k_scale=_sds((256, 16, 16), jnp.float32, one_chip),
+                  v_scale=_sds((256, 16, 16), jnp.float32, one_chip))
+    return pool, tables, kw
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8])
+def test_paged_decode_attention(one_chip, pool_dtype):
+    pool, tables, kw = _pool_shapes(one_chip, pool_dtype)
+    q = _sds((8, 16, 64), jnp.bfloat16, one_chip)
+    lens = _sds((8,), jnp.int32, one_chip)
+    fn = paged.paged_decode_attention.__wrapped__
+    _compile(fn, q, pool, pool, tables, lens, **kw)
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8])
+def test_paged_spec_decode_attention(one_chip, pool_dtype):
+    pool, tables, kw = _pool_shapes(one_chip, pool_dtype)
+    q = _sds((8, 4, 16, 64), jnp.bfloat16, one_chip)
+    pos = _sds((8, 4), jnp.int32, one_chip)
+    fn = paged.paged_spec_decode_attention.__wrapped__
+    _compile(fn, q, pool, pool, tables, pos, **kw)
+
+
+def test_sorted_topk(one_chip):
+    x = _sds((8, 50304), jnp.float32, one_chip)
+    _compile(functools.partial(topk.sorted_topk.__wrapped__, k=50), x)
+
+
+@pytest.mark.parametrize("wire", [jnp.int8, jnp.float8_e4m3fn])
+def test_fused_dequant_reduce(one_chip, wire):
+    q = _sds((4, 1024, 1024), wire, one_chip)
+    scale = _sds((4, 1024, 8, 1), jnp.float32, one_chip)
+    _compile(functools.partial(qfused.fused_dequant_reduce, group_size=128),
+             q, scale)
+
+
+# ---------------------------------------------------- dp=4 on the 2x2 mesh
+@pytest.fixture
+def dp4(topo, monkeypatch):
+    """The four described devices as the process-global dp=4 mesh."""
+    mesh = topo_mod.MeshTopology(devices=topo.devices)
+    assert mesh.dp == 4
+    monkeypatch.setattr(topo_mod, "_GLOBAL_MESH", mesh)
+    return mesh.mesh
+
+
+def test_flash_dispatch_partitions_over_dp(dp4):
+    """Through the repo's dispatcher every chip runs flash attention on its
+    own quarter of the batch: [8, S, N, D] -> kernels on [8/4 * N, S, D]."""
+    q = _sds((8, 2048, 16, 64), jnp.bfloat16,
+             NamedSharding(dp4, P("dp", None, None, None)))
+    fn = functools.partial(attn_core.dot_product_attention, use_pallas=True)
+    text = _compile(_sum_grad(fn, 3), q, q, q)
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) >= 2          # forward + backward kernels
+    for operands in calls:
+        assert (2 * 16, 2048, 64) in operands, operands
+        assert (8 * 16, 2048, 64) not in operands, operands
+    assert "all-gather" not in text
+
+
+def test_layer_norm_dispatch_partitions_over_dp(dp4):
+    x = _sds((16384, 1024), jnp.bfloat16, NamedSharding(dp4, P("dp", None)))
+    g = _sds((1024,), jnp.float32, NamedSharding(dp4, P()))
+    fn = functools.partial(normalize.layer_norm, use_pallas=True)
+    text = _compile(_sum_grad(fn, 3), x, g, g)
+    calls = _kernel_operand_shapes(text)
+    assert calls
+    for operands in calls:
+        assert (16384 // 4, 1024) in operands, operands
+        assert (16384, 1024) not in operands, operands
+    assert "all-gather" not in text
+    # dgamma/dbeta: partial sums per chip, reduced across dp
+    assert "all-reduce" in text

@@ -87,8 +87,7 @@ def test_qgz_bucketed_parity(mesh8):
 
 @pytest.mark.parametrize("stage", [0, 1, 2, 3])
 @pytest.mark.parametrize("gas", [1, 2, 4])
-def test_auto_schedule_bitexact_vs_manual(mesh8, baseline_losses, stage, gas,
-                                          no_persistent_compile_cache):
+def test_auto_schedule_bitexact_vs_manual(mesh8, baseline_losses, stage, gas):
     """Acceptance: comm.overlap.schedule.mode=auto plans the same deferred
     schedule the manual path hand-places on dp-only meshes, and the jaxpr
     hoist pass is a pure dataflow reorder -- trajectories bit-identical

@@ -40,7 +40,7 @@ def _masters(eng):
 
 
 @pytest.mark.parametrize("gas", [1, 2])
-def test_planned_bitexact_vs_static(reset_mesh, no_persistent_compile_cache,
+def test_planned_bitexact_vs_static(reset_mesh,
                                     tmp_path, gas):
     """The planner only moves WHEN bytes move: losses and masters after
     identical steps are bit-equal between static and planned schedules,
@@ -63,7 +63,7 @@ def test_planned_bitexact_vs_static(reset_mesh, no_persistent_compile_cache,
 
 
 def test_budget_that_ooms_static_trains_planned(
-        reset_mesh, no_persistent_compile_cache, tmp_path):
+        reset_mesh, tmp_path):
     """The acceptance config: a synthetic HBM budget below the static
     2-chunk window raises at init under ``static``, while ``auto`` plans
     a depth-0 stream that trains within its modeled peak bound."""
@@ -92,7 +92,7 @@ def test_budget_that_ooms_static_trains_planned(
 
 
 def test_generous_budget_pins_resident_and_stays_bitexact(
-        reset_mesh, no_persistent_compile_cache, tmp_path):
+        reset_mesh, tmp_path):
     """With HBM to spare the planner pins everything resident (no per-pass
     streaming) -- and the result is still bit-equal to static."""
     eng_s, tiny = _make(tmp_path / "s", seed=2, memory_schedule="static")

@@ -275,7 +275,7 @@ class TestQuantizedCollectives:
         assert err < 0.02
 
     def test_quantized_reduce_scatter_vs_psum_scatter(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.comm.compressed import quantized_reduce_scatter
         from deeperspeed_tpu.parallel import topology as topo
@@ -287,11 +287,11 @@ class TestQuantizedCollectives:
         qrs = jax.jit(shard_map(
             lambda a: quantized_reduce_scatter(a, "dp"),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P("dp", None), check_rep=False))
+            out_specs=P("dp", None), check_vma=False))
         ref = jax.jit(shard_map(
             lambda a: jax.lax.psum_scatter(a, "dp", scatter_dimension=0, tiled=True),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P("dp", None), check_rep=False))
+            out_specs=P("dp", None), check_vma=False))
         got, want = np.asarray(qrs(x)), np.asarray(ref(x))
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.05
 
@@ -299,7 +299,7 @@ class TestQuantizedCollectives:
         """fp8 e5m2 gradient wire: coarser than int8 (2-bit mantissa) but
         the fused fp32-accumulating dequant-reduce keeps the scattered sum
         within the e5m2 budget of the exact psum."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.comm.compressed import quantized_reduce_scatter
         from deeperspeed_tpu.parallel import topology as topo
@@ -312,16 +312,16 @@ class TestQuantizedCollectives:
             lambda a: quantized_reduce_scatter(a, "dp",
                                                wire_dtype="fp8_e5m2"),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P("dp", None), check_rep=False))
+            out_specs=P("dp", None), check_vma=False))
         ref = jax.jit(shard_map(
             lambda a: jax.lax.psum_scatter(a, "dp", scatter_dimension=0, tiled=True),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P("dp", None), check_rep=False))
+            out_specs=P("dp", None), check_vma=False))
         got, want = np.asarray(qrs(x)), np.asarray(ref(x))
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.2
 
     def test_onebit_allreduce_error_feedback(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.comm.compressed import onebit_all_reduce
         from deeperspeed_tpu.parallel import topology as topo
@@ -338,7 +338,7 @@ class TestQuantizedCollectives:
 
         fn = jax.jit(shard_map(
             step, mesh=mesh.mesh, in_specs=(P("dp", None), P("dp", None)),
-            out_specs=(P(None, None), P("dp", None)), check_rep=False))
+            out_specs=(P(None, None), P("dp", None)), check_vma=False))
 
         target = np.asarray(x).mean(axis=0)
         err = jnp.zeros((8, 128))
@@ -368,7 +368,7 @@ class TestTwoLevelQgZ:
         return mesh
 
     def test_hierarchical_all_reduce_vs_psum(self, reset_mesh):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.comm.compressed import (
             hierarchical_quantized_all_reduce)
@@ -379,11 +379,11 @@ class TestTwoLevelQgZ:
         hq = jax.jit(shard_map(
             lambda a: hierarchical_quantized_all_reduce(a, "zshard", "dp"),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P(None, None), check_rep=False))
+            out_specs=P(None, None), check_vma=False))
         ref = jax.jit(shard_map(
             lambda a: jax.lax.psum(a, ("zshard", "dp")),
             mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P(None, None), check_rep=False))
+            out_specs=P(None, None), check_vma=False))
         got, want = np.asarray(hq(x)), np.asarray(ref(x))
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.05
 
@@ -391,7 +391,7 @@ class TestTwoLevelQgZ:
         """Two-hop RS distributes chunks in intra-rank-major order; the
         concatenation of all chunks (all_gather back) must still be the
         group sum, matching the flat quantized RS up to quantization noise."""
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.comm.compressed import (
             hierarchical_quantized_reduce_scatter)
@@ -407,12 +407,12 @@ class TestTwoLevelQgZ:
 
         got = np.asarray(jax.jit(shard_map(
             two_hop, mesh=mesh.mesh, in_specs=P(None, None),
-            out_specs=P(None, None), check_rep=False))(x))
+            out_specs=P(None, None), check_vma=False))(x))
         want = np.asarray(x).sum(0, keepdims=True) * 0 + np.asarray(
             jax.jit(shard_map(
                 lambda a: jax.lax.psum(a, ("zshard", "dp")),
                 mesh=mesh.mesh, in_specs=P(None, None),
-                out_specs=P(None, None), check_rep=False))(x))
+                out_specs=P(None, None), check_vma=False))(x))
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.05
 
     def test_facade_two_level_eager_matches_fp32_mean(self, reset_mesh):
@@ -429,7 +429,7 @@ class TestTwoLevelQgZ:
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.05
 
     def test_qgz_helpers_delegate_flat_when_single_axis(self, mesh8):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         from deeperspeed_tpu.runtime.zero.quantized import qgz_all_reduce
 
@@ -438,11 +438,11 @@ class TestTwoLevelQgZ:
         got = np.asarray(jax.jit(shard_map(
             lambda a: qgz_all_reduce(a, intra_axis="zshard", inter_axis="dp"),
             mesh=mesh8.mesh, in_specs=P(None, None),
-            out_specs=P(None, None), check_rep=False))(x))
+            out_specs=P(None, None), check_vma=False))(x))
         want = np.asarray(jax.jit(shard_map(
             lambda a: jax.lax.psum(a, "dp"),
             mesh=mesh8.mesh, in_specs=P(None, None),
-            out_specs=P(None, None), check_rep=False))(x))
+            out_specs=P(None, None), check_vma=False))(x))
         assert np.abs(got - want).max() / (np.abs(want).max() + 1e-9) < 0.05
 
 

@@ -4,7 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 import deeperspeed_tpu.comm as dist
@@ -43,7 +43,7 @@ def test_traced_collectives(mesh8):
 
     x = jnp.arange(8.0).reshape(8, 1)
     fn = shard_map(step, mesh=mesh, in_specs=(P("dp"),),
-                   out_specs=(P("dp"), P("dp"), P("dp"), P("dp")), check_rep=False)
+                   out_specs=(P("dp"), P("dp"), P("dp"), P("dp")), check_vma=False)
     s, ar, ag, rs = jax.jit(fn)(x)
     np.testing.assert_allclose(np.asarray(s), np.full((8, 1), 28.0))
     np.testing.assert_allclose(np.asarray(ar), np.asarray(s))
@@ -61,7 +61,7 @@ def test_broadcast_traced(mesh8):
 
     x = jnp.arange(8.0).reshape(8, 1)
     out = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-                            check_rep=False))(x)
+                            check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out), np.full((8, 1), 3.0))
 
 
@@ -74,7 +74,7 @@ def test_all_to_all_traced(mesh8):
     # per-shard input: [1, 8]; after a2a each shard i holds column i: [8, 1]
     x = jnp.arange(64.0).reshape(8, 8)
     out = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-                            check_rep=False))(x)
+                            check_vma=False))(x)
     np.testing.assert_allclose(
         np.asarray(out), np.arange(64.0).reshape(8, 8).T.reshape(64, 1)
     )
@@ -88,7 +88,7 @@ def test_ppermute_ring(mesh8):
 
     x = jnp.arange(8.0).reshape(8, 1)
     out = jax.jit(shard_map(step, mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp"),
-                            check_rep=False))(x)
+                            check_vma=False))(x)
     np.testing.assert_allclose(np.asarray(out)[:, 0], np.roll(np.arange(8.0), 1))
 
 

@@ -28,7 +28,7 @@ def test_find_collectives_shard_map_psum():
     fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P())
     closed = jax.make_jaxpr(fn)(jnp.ones((8, 4)))
     sites = find_collectives(closed)
-    # check_rep=True shard_map re-traces psum as the psum2 primitive
+    # check_vma=True shard_map traces psum as the psum_invariant primitive
     psums = [s for s in sites if s.kind == "all_reduce"]
     assert len(psums) == 1
     (site,) = psums
@@ -68,7 +68,7 @@ def test_find_collectives_quantized_payload_tagged():
         return jax.lax.all_gather(x, "dp")
 
     fn = jax.shard_map(body, mesh=mesh, in_specs=P("dp"), out_specs=P(),
-                       check_rep=False)
+                       check_vma=False)
     sites = find_collectives(
         jax.make_jaxpr(fn)(jnp.ones((8, 4), dtype=jnp.int8)))
     ags = [s for s in sites if s.kind == "all_gather"]
