@@ -42,15 +42,6 @@ LOGIT_MARGIN = 0.05
 LOSS_TOL = 2e-2
 
 
-COMPILES = [0]           # backend compiles so far
-
-
-def _count_compile(event, _seconds, **_kw):
-    """``jax.monitoring`` listener: one more program went to the compiler."""
-    if event.endswith("backend_compile_duration"):
-        COMPILES[0] += 1
-
-
 def emit(phase, **fields):
     print(json.dumps({"phase": phase, **fields}), flush=True)
 
@@ -118,6 +109,7 @@ def run_train(model, params, batch_size, zero_stage, mesh=None):
     import jax
 
     import deeperspeed_tpu as dst
+    from deeperspeed_tpu.telemetry import compile_stats
 
     engine, _, _, _ = dst.initialize(
         model=model, model_parameters=params, mesh=mesh,
@@ -125,10 +117,10 @@ def run_train(model, params, batch_size, zero_stage, mesh=None):
     batch = model.example_batch(batch_size=batch_size, seq_len=SEQ, seed=SEED)
     losses, walls, compiles = [], [], []
     for _ in range(TRAIN_STEPS):
-        t0, c0 = time.perf_counter(), COMPILES[0]
+        t0, c0 = time.perf_counter(), compile_stats().programs
         losses.append(float(engine.train_batch(batch=batch)))  # waits
         walls.append(time.perf_counter() - t0)
-        compiles.append(COMPILES[0] - c0)
+        compiles.append(compile_stats().programs - c0)
     step_s = float(np.median(walls[1:]))
     # the step's compiled HLO, lowered from the engine's own step function
     # (as telemetry/hlo_cost.py does); same program, so a cache hit
@@ -406,7 +398,6 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    jax.monitoring.register_event_duration_secs_listener(_count_compile)
     dev = device_facts()
     if dev["platform"] != "tpu" or dev["count"] != args.chips:
         emit("device", ok=False, wanted=f"{args.chips} x tpu", **dev)

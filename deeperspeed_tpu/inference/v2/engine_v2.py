@@ -43,7 +43,7 @@ from ...parallel import topology as topo
 from ...telemetry import get_registry
 from ...telemetry import serving as serving_events
 from ...telemetry.registry import LATENCY_BUCKETS_S
-from ...telemetry.trace import get_tracer
+from ...telemetry.trace import span
 from ...utils.logging import log_dist
 from ...ops.sampling import sample_tokens, verify_draft
 from .config import RaggedInferenceEngineConfig
@@ -156,6 +156,9 @@ class InferenceEngineV2:
         # so the engine counts what actually hit the device
         self.dispatch_count = 0
         self.jit_cache_misses = 0
+        self._round_stats = {"rounds": 0, "fed_tokens": 0, "padded_tokens": 0,
+                             "decode_rows": 0}
+        self._rounds_by_bucket = {}
         self.redundant_flush_count = 0
         self._kv_bytes_recorded = False
 
@@ -277,10 +280,11 @@ class InferenceEngineV2:
             # pre-copy pool (read-before-write even if a source was
             # reallocated as another row's destination this round); padded
             # rows use dst == num_blocks, dropped by the OOB scatter.
-            cache = jax.tree_util.tree_map(
-                lambda pool: pool.at[copy_dst].set(pool[copy_src],
-                                                   mode="drop"),
-                cache)
+            with jax.named_scope("kv_scatter"):
+                cache = jax.tree_util.tree_map(
+                    lambda pool: pool.at[copy_dst].set(pool[copy_src],
+                                                       mode="drop"),
+                    cache)
             positions = starts[:, None] + jnp.arange(s_pad)[None]   # [n, S]
             write_mask = jnp.arange(s_pad)[None] < lengths[:, None]  # [n, S]
             # ragged logits-gather: the head projects ONLY each row's
@@ -301,10 +305,12 @@ class InferenceEngineV2:
             # per-round PRNG key derived in-graph from the traced nonce:
             # advancing the stream never recompiles, and greedy config
             # (temperature <= 0) compiles the key away entirely
-            key = jax.random.fold_in(jax.random.PRNGKey(sc.seed), nonce)
-            chosen = sample_tokens(logits, key, temperature=sc.temperature,
-                                   top_k=sc.top_k, top_p=sc.top_p)
-            accepted = verify_draft(chosen, draft_tokens, draft_lens)
+            with jax.named_scope("sample"):
+                key = jax.random.fold_in(jax.random.PRNGKey(sc.seed), nonce)
+                chosen = sample_tokens(logits, key,
+                                       temperature=sc.temperature,
+                                       top_k=sc.top_k, top_p=sc.top_p)
+                accepted = verify_draft(chosen, draft_tokens, draft_lens)
             return chosen, accepted, finite, logits[:, -1], mut["cache"]
 
         return jax.jit(step, donate_argnums=(1,))
@@ -405,6 +411,20 @@ class InferenceEngineV2:
         Returns :class:`RoundOutputs`; row i corresponds to input i.
         """
         assert len(batch_uids) == len(batch_tokens)
+        with span("serve/round", dispatch=self.dispatch_count) as round_span:
+            return self._put_round(round_span, batch_uids, batch_tokens,
+                                   batch_drafts)
+
+    def round_stats(self) -> dict:
+        """What ``put_round`` has fed and what it padded that to, counted
+        on every round: rounds, fed tokens, padded tokens (``n_pad *
+        s_pad``), decode rows, rounds by bucket key ``(n_pad, s_pad,
+        r_pad)`` and step programs built."""
+        return {**self._round_stats,
+                "rounds_by_bucket": dict(self._rounds_by_bucket),
+                "step_programs_built": self.jit_cache_misses}
+
+    def _put_round(self, round_span, batch_uids, batch_tokens, batch_drafts):
         t_start = time.perf_counter()
         sm = self.state_manager
         smc = self.config.state_manager
@@ -412,131 +432,144 @@ class InferenceEngineV2:
             batch_drafts = [None] * len(batch_uids)
         assert len(batch_drafts) == len(batch_uids)
 
-        ops, n_decodes, total_tokens, max_len, max_dk = [], 0, 0, 1, 0
-        for i, (uid, toks, draft) in enumerate(
-                zip(batch_uids, batch_tokens, batch_drafts)):
-            toks = np.asarray(toks, np.int32).reshape(-1)
-            if toks.size == 0:
-                raise ValueError(f"empty token list for uid {uid}")
-            draft = (np.asarray(draft, np.int32).reshape(-1)
-                     if draft is not None else np.zeros((0,), np.int32))
-            dk = int(draft.size)
-            if dk:
-                # drafts ride as ordinary fed tokens of the same row: their
-                # KV scatters like any token's, verification is just the
-                # logits of the positions they occupy
-                toks = np.concatenate([toks, draft])
-            total_tokens += toks.size
-            max_len = max(max_len, toks.size)
-            max_dk = max(max_dk, dk)
-            # decode = the sequence has KV *landed* (seen_tokens > 0), not
-            # merely reserved: the SplitFuse scheduler pre-reserves blocks
-            # via sm.extend before the prompt runs, so a known uid with a
-            # 1-token chunk can still be a prefill tail.  Classification is
-            # observability-only now -- decodes run as length-1 rows of the
-            # same fused step, so there is no separate width to overflow.
-            if sm.known(uid) and toks.size - dk == 1 \
-                    and sm.get_sequence(uid).seen_tokens > 0:
-                n_decodes += 1
-            ops.append((i, uid, toks, dk))
+        with span("serve/round/plan"):
+            ops, n_decodes, total_tokens, max_len, max_dk = [], 0, 0, 1, 0
+            for i, (uid, toks, draft) in enumerate(
+                    zip(batch_uids, batch_tokens, batch_drafts)):
+                toks = np.asarray(toks, np.int32).reshape(-1)
+                if toks.size == 0:
+                    raise ValueError(f"empty token list for uid {uid}")
+                draft = (np.asarray(draft, np.int32).reshape(-1)
+                         if draft is not None else np.zeros((0,), np.int32))
+                dk = int(draft.size)
+                if dk:
+                    # drafts ride as ordinary fed tokens of the same row:
+                    # their KV scatters like any token's, verification is
+                    # just the logits of the positions they occupy
+                    toks = np.concatenate([toks, draft])
+                total_tokens += toks.size
+                max_len = max(max_len, toks.size)
+                max_dk = max(max_dk, dk)
+                # decode = the sequence has KV *landed* (seen_tokens > 0),
+                # not merely reserved: the SplitFuse scheduler pre-reserves
+                # blocks via sm.extend before the prompt runs, so a known uid
+                # with a 1-token chunk can still be a prefill tail.
+                # Classification is observability-only now -- decodes run as
+                # length-1 rows of the same fused step, so there is no
+                # separate width to overflow.
+                if sm.known(uid) and toks.size - dk == 1 \
+                        and sm.get_sequence(uid).seen_tokens > 0:
+                    n_decodes += 1
+                ops.append((i, uid, toks, dk))
 
-        # validate the whole batch BEFORE mutating any sequence state, so a
-        # rejected put can be retried without corrupting seen_tokens/blocks
-        if len(batch_uids) > smc.max_ragged_sequence_count:
-            raise ValueError(
-                f"{len(batch_uids)} sequences exceed max_ragged_sequence_count="
-                f"{smc.max_ragged_sequence_count}")
-        if total_tokens > smc.max_ragged_batch_size:
-            raise ValueError(
-                f"{total_tokens} tokens exceed max_ragged_batch_size="
-                f"{smc.max_ragged_batch_size}")
-        # KV capacity + tracked-sequence dry-run BEFORE any mutation (also
-        # rejects duplicate uids -- one DSSequenceDescriptor slot per uid per
-        # ragged batch), so a MemoryError cannot fire mid-batch after
-        # earlier sequences already committed seen_tokens/blocks
-        sm.validate_batch([(uid, toks.size) for _, uid, toks, _ in ops])
+            # validate the whole batch BEFORE mutating any sequence state, so
+            # a rejected put can be retried without corrupting
+            # seen_tokens/blocks
+            if len(batch_uids) > smc.max_ragged_sequence_count:
+                raise ValueError(
+                    f"{len(batch_uids)} sequences exceed "
+                    f"max_ragged_sequence_count="
+                    f"{smc.max_ragged_sequence_count}")
+            if total_tokens > smc.max_ragged_batch_size:
+                raise ValueError(
+                    f"{total_tokens} tokens exceed max_ragged_batch_size="
+                    f"{smc.max_ragged_batch_size}")
+            # KV capacity + tracked-sequence dry-run BEFORE any mutation
+            # (also rejects duplicate uids -- one DSSequenceDescriptor slot
+            # per uid per ragged batch), so a MemoryError cannot fire
+            # mid-batch after earlier sequences already committed
+            # seen_tokens/blocks
+            sm.validate_batch([(uid, toks.size) for _, uid, toks, _ in ops])
 
-        n_pad, s_pad, r_pad = self._round_buckets(len(ops), max_len, max_dk)
-        fn = self._get_step_fn(n_pad, s_pad, r_pad)
-        tokens = np.zeros((n_pad, s_pad), np.int32)
-        starts = np.zeros((n_pad,), np.int32)
-        lengths = np.zeros((n_pad,), np.int32)
-        tables = np.zeros((n_pad, self._max_blocks), np.int32)
-        draft_tokens = np.zeros((n_pad, r_pad - 1), np.int32)
-        draft_lens = np.zeros((n_pad,), np.int32)
-        for row, (i, uid, toks, dk) in enumerate(ops):
-            seq = sm.extend(uid, toks.size)
-            tokens[row, :toks.size] = toks
-            starts[row] = seq.seen_tokens
-            lengths[row] = toks.size
-            tables[row] = sm.block_table(uid, pad_to=self._max_blocks)
-            if dk:
-                # right-aligned so the verifier's cumulative-prefix trick
-                # works on ragged draft counts (left pad = vacuous match)
-                draft_tokens[row, r_pad - 1 - dk:r_pad - 1] = toks[-dk:]
-                draft_lens[row] = dk
-        # copy-on-write block copies queued by the extends (incl. the
-        # scheduler's pre-reserving extends for this round): at most one per
-        # row, padded with an OOB destination that the scatter drops
-        copies = sm.take_pending_copies()
-        if len(copies) > n_pad:
-            raise RuntimeError(
-                f"{len(copies)} pending COW copies exceed the round's "
-                f"{n_pad} rows")
-        copy_src = np.zeros((n_pad,), np.int32)
-        copy_dst = np.full((n_pad,), self.config.kv_cache.num_blocks,
-                           np.int32)
-        for c, (src, dst) in enumerate(copies):
-            copy_src[c], copy_dst[c] = src, dst
+            n_pad, s_pad, r_pad = self._round_buckets(len(ops), max_len,
+                                                      max_dk)
+            fn = self._get_step_fn(n_pad, s_pad, r_pad)
+            tokens = np.zeros((n_pad, s_pad), np.int32)
+            starts = np.zeros((n_pad,), np.int32)
+            lengths = np.zeros((n_pad,), np.int32)
+            tables = np.zeros((n_pad, self._max_blocks), np.int32)
+            draft_tokens = np.zeros((n_pad, r_pad - 1), np.int32)
+            draft_lens = np.zeros((n_pad,), np.int32)
+            for row, (i, uid, toks, dk) in enumerate(ops):
+                seq = sm.extend(uid, toks.size)
+                tokens[row, :toks.size] = toks
+                starts[row] = seq.seen_tokens
+                lengths[row] = toks.size
+                tables[row] = sm.block_table(uid, pad_to=self._max_blocks)
+                if dk:
+                    # right-aligned so the verifier's cumulative-prefix
+                    # trick works on ragged draft counts (left pad = vacuous
+                    # match)
+                    draft_tokens[row, r_pad - 1 - dk:r_pad - 1] = toks[-dk:]
+                    draft_lens[row] = dk
+            # copy-on-write block copies queued by the extends (incl. the
+            # scheduler's pre-reserving extends for this round): at most one
+            # per row, padded with an OOB destination that the scatter drops
+            copies = sm.take_pending_copies()
+            if len(copies) > n_pad:
+                raise RuntimeError(
+                    f"{len(copies)} pending COW copies exceed the round's "
+                    f"{n_pad} rows")
+            copy_src = np.zeros((n_pad,), np.int32)
+            copy_dst = np.full((n_pad,), self.config.kv_cache.num_blocks,
+                               np.int32)
+            for c, (src, dst) in enumerate(copies):
+                copy_src[c], copy_dst[c] = src, dst
 
-        chosen, accepted, finite, last_logits, self.kv_cache = fn(
-            self.params, self.kv_cache, jnp.asarray(tokens),
-            jnp.asarray(starts), jnp.asarray(lengths), jnp.asarray(tables),
-            jnp.asarray(copy_src), jnp.asarray(copy_dst),
-            jnp.asarray(draft_tokens), jnp.asarray(draft_lens),
-            jnp.int32(self.dispatch_count))
+        stats = self._round_stats
+        stats["rounds"] += 1
+        stats["fed_tokens"] += int(total_tokens)
+        stats["padded_tokens"] += n_pad * s_pad
+        stats["decode_rows"] += n_decodes
+        bucket = (n_pad, s_pad, r_pad)
+        self._rounds_by_bucket[bucket] = \
+            self._rounds_by_bucket.get(bucket, 0) + 1
+        round_span.set(n_seqs=len(ops), n_tokens=int(total_tokens),
+                       decodes=n_decodes, n_pad=n_pad, s_pad=s_pad,
+                       r_pad=r_pad)
+
+        with span("serve/round/upload"):
+            device_args = [jnp.asarray(a) for a in (
+                tokens, starts, lengths, tables, copy_src, copy_dst,
+                draft_tokens, draft_lens)]
+        with span("serve/round/dispatch"):
+            chosen, accepted, finite, last_logits, self.kv_cache = fn(
+                self.params, self.kv_cache, *device_args,
+                jnp.int32(self.dispatch_count))
         self.dispatch_count += 1
-        outputs = RoundOutputs(
-            uids=list(batch_uids),
-            tokens=np.asarray(chosen)[:len(ops)],
-            accepted=np.asarray(accepted)[:len(ops)],
-            draft_lens=draft_lens[:len(ops)].copy(),
-            finite=np.asarray(finite)[:len(ops)],
-            R=r_pad,
-            logits=last_logits)
+        with span("serve/round/harvest"):    # the round's one sync
+            outputs = RoundOutputs(
+                uids=list(batch_uids),
+                tokens=np.asarray(chosen)[:len(ops)],
+                accepted=np.asarray(accepted)[:len(ops)],
+                draft_lens=draft_lens[:len(ops)].copy(),
+                finite=np.asarray(finite)[:len(ops)],
+                R=r_pad,
+                logits=last_logits)
         # chaos seam (identity in production): may delay, corrupt, or raise
         # -- BEFORE commit_tokens, so an injected round failure leaves
         # sequence bookkeeping exactly as a real device fault would
         outputs = _round_seam(batch_uids, outputs)
 
         drafted_total, accepted_total, emitted_total = 0, 0, 0
-        for row, (i, uid, toks, dk) in enumerate(ops):
-            a = min(int(outputs.accepted[row]), dk)
-            # fed tokens whose KV is VALID: everything up to the last
-            # accepted draft (accepted drafts equal the model's choices, so
-            # their KV is exactly what non-speculative decoding would have
-            # written); rejected drafts' fed tokens are not committed
-            sm.commit_tokens(uid, toks[:toks.size - dk + a])
-            if dk:
-                # rejection = drop the forked tail: blocks wholly beyond
-                # the committed range free at refcount 0 (accepted tails
-                # keep theirs -- this is a no-op then)
-                sm.rollback_draft_tail(uid)
-                drafted_total += dk
-                accepted_total += a
-            emitted_total += a + 1
+        with span("serve/round/commit"):
+            for row, (i, uid, toks, dk) in enumerate(ops):
+                a = min(int(outputs.accepted[row]), dk)
+                # fed tokens whose KV is VALID: everything up to the last
+                # accepted draft (accepted drafts equal the model's choices,
+                # so their KV is exactly what non-speculative decoding would
+                # have written); rejected drafts' fed tokens are not committed
+                sm.commit_tokens(uid, toks[:toks.size - dk + a])
+                if dk:
+                    # rejection = drop the forked tail: blocks wholly beyond
+                    # the committed range free at refcount 0 (accepted tails
+                    # keep theirs -- this is a no-op then)
+                    sm.rollback_draft_tail(uid)
+                    drafted_total += dk
+                    accepted_total += a
+                emitted_total += a + 1
 
         reg = get_registry()
-        tracer = get_tracer()
-        if tracer.enabled:
-            # engine-side round span: one record per ragged dispatch, on
-            # the engine's own lane (requests' per-round spans live with
-            # the scheduler, which knows their TraceContexts)
-            tracer.record_span(
-                "engine_round", "engine",
-                dur_s=time.perf_counter() - t_start,
-                n_seqs=len(ops), n_tokens=int(total_tokens),
-                decodes=n_decodes, dispatch=self.dispatch_count - 1)
         if reg.enabled:
             # np.asarray above already synced the dispatch, so the wall
             # time covers the full ragged round

@@ -52,7 +52,7 @@ import numpy as np
 
 from ...telemetry.serving import (emit_host_tier_hit, emit_host_tier_restore,
                                   emit_host_tier_spill)
-from ...telemetry.trace import get_tracer
+from ...telemetry.trace import get_tracer, span
 
 
 def payload_digest(payloads: List[np.ndarray]) -> bytes:
@@ -212,13 +212,9 @@ class HostKVTier:
         if key in self._entries:
             self._entries.move_to_end(key)
             return False
-        tracer = get_tracer()
-        t0 = time.perf_counter() if tracer.enabled else 0.0
-        self._insert(key, self._read_block(block))
-        if tracer.enabled:
-            tracer.record_span("kv_spill", "kvtier",
-                               dur_s=time.perf_counter() - t0,
-                               key=key.hex()[:12], block=int(block))
+        with span("serve/kv_spill", trace_id="kvtier", key=key.hex()[:12],
+                  block=int(block)):
+            self._insert(key, self._read_block(block))
         return True
 
     def insert(self, key: bytes, payloads: List[np.ndarray]) -> bool:

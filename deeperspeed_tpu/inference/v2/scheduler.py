@@ -38,7 +38,7 @@ import numpy as np
 from ...telemetry import get_registry
 from ...telemetry import serving as serving_events
 from ...telemetry.registry import LATENCY_BUCKETS_S
-from ...telemetry.trace import get_tracer
+from ...telemetry.trace import get_tracer, span
 from ...utils.logging import log_dist
 
 
@@ -392,6 +392,11 @@ class DSScheduler:
     def step(self) -> Dict[object, np.ndarray]:
         """Run one scheduling round; returns the new token ids (int32
         array, >= 1 entries when speculation lands) for completed feeds."""
+        with span("serve/sched_step", waiting=len(self.waiting),
+                  live=len(self.live)):
+            return self._step()
+
+    def _step(self) -> Dict[object, np.ndarray]:
         sm = self.engine.state_manager
         budget = self.token_budget
         sched: List = []          # (req, n_tokens, completes, draft)

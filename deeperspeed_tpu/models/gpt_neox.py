@@ -178,8 +178,9 @@ class GPTNeoXAttention(nn.Module):
         cfg = self.config
         B, S, H = x.shape
         qkv = nn.Dense(3 * H, dtype=cfg.dtype, name="query_key_value")(x)
-        qkv = qkv.reshape(B, S, cfg.num_heads, 3 * cfg.head_dim)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+        with jax.named_scope("attention_layout"):
+            qkv = qkv.reshape(B, S, cfg.num_heads, 3 * cfg.head_dim)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
 
         rot_dim = int(cfg.head_dim * cfg.rotary_pct)
         if rot_dim > 0:
@@ -263,7 +264,8 @@ class GPTNeoXAttention(nn.Module):
                 q, k, v, mask=mask, causal=True, dropout_rng=dropout_rng,
                 dropout_rate=0.0 if deterministic else cfg.attention_dropout,
             )
-        out = out.reshape(B, S, H)
+        with jax.named_scope("attention_layout"):
+            out = out.reshape(B, S, H)
         return nn.Dense(H, dtype=cfg.dtype, name="dense")(out)
 
     def _paged_attention(self, q, k, v, positions, paged_state):
@@ -341,24 +343,26 @@ class GPTNeoXAttention(nn.Module):
         oob = cfg.paged_num_blocks * bs
         flat = jnp.where(write_mask, flat, oob)
         N, D = cfg.num_heads, cfg.head_dim
-        if quant_kv:
-            # quantize-on-write: the pool never holds fp values
-            from ..ops.quantizer import quantize_kv
+        # the pool update: ``kv_scatter`` in a device trace
+        with jax.named_scope("kv_scatter"):
+            if quant_kv:
+                # quantize-on-write: the pool never holds fp values
+                from ..ops.quantizer import quantize_kv
 
-            k, k_scale = quantize_kv(k, cfg.paged_kv_dtype)
-            v, v_scale = quantize_kv(v, cfg.paged_kv_dtype)
-            pool_sk = psk.value.reshape(-1, N).at[flat.reshape(-1)].set(
-                k_scale.reshape(-1, N), mode="drop")
-            pool_sv = psv.value.reshape(-1, N).at[flat.reshape(-1)].set(
-                v_scale.reshape(-1, N), mode="drop")
-            psk.value = pool_sk.reshape(shape[:3])
-            psv.value = pool_sv.reshape(shape[:3])
-        pool_k = pk.value.reshape(-1, N, D).at[flat.reshape(-1)].set(
-            k.reshape(-1, N, D), mode="drop")
-        pool_v = pv.value.reshape(-1, N, D).at[flat.reshape(-1)].set(
-            v.reshape(-1, N, D), mode="drop")
-        pk.value = pool_k.reshape(shape)
-        pv.value = pool_v.reshape(shape)
+                k, k_scale = quantize_kv(k, cfg.paged_kv_dtype)
+                v, v_scale = quantize_kv(v, cfg.paged_kv_dtype)
+                pool_sk = psk.value.reshape(-1, N).at[flat.reshape(-1)].set(
+                    k_scale.reshape(-1, N), mode="drop")
+                pool_sv = psv.value.reshape(-1, N).at[flat.reshape(-1)].set(
+                    v_scale.reshape(-1, N), mode="drop")
+                psk.value = pool_sk.reshape(shape[:3])
+                psv.value = pool_sv.reshape(shape[:3])
+            pool_k = pk.value.reshape(-1, N, D).at[flat.reshape(-1)].set(
+                k.reshape(-1, N, D), mode="drop")
+            pool_v = pv.value.reshape(-1, N, D).at[flat.reshape(-1)].set(
+                v.reshape(-1, N, D), mode="drop")
+            pk.value = pool_k.reshape(shape)
+            pv.value = pool_v.reshape(shape)
 
         if paged_state.get("attn_partial", False):
             # capture pass: KV is committed above; attention itself runs as
@@ -395,19 +399,20 @@ class GPTNeoXAttention(nn.Module):
                 v_scale=psv.value if quant_kv else None)
             return out.astype(q.dtype)
         # prefill: attention over the gathered blocks
-        # -> [B, max_blocks*bs, N, D]
-        K = pool_k.reshape(shape)[block_tables].reshape(B, -1, N, D)
-        V = pool_v.reshape(shape)[block_tables].reshape(B, -1, N, D)
-        if quant_kv:
-            from ..ops.quantizer import dequantize_kv
+        with jax.named_scope("prefill_gather"):
+            # -> [B, max_blocks*bs, N, D]
+            K = pool_k.reshape(shape)[block_tables].reshape(B, -1, N, D)
+            V = pool_v.reshape(shape)[block_tables].reshape(B, -1, N, D)
+            if quant_kv:
+                from ..ops.quantizer import dequantize_kv
 
-            K = dequantize_kv(K, pool_sk.reshape(shape[:3])[
-                block_tables].reshape(B, -1, N), q.dtype)
-            V = dequantize_kv(V, pool_sv.reshape(shape[:3])[
-                block_tables].reshape(B, -1, N), q.dtype)
-        kv_pos = jnp.arange(K.shape[1])
-        mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
-        return dot_product_attention(q, K, V, mask=mask, causal=False)
+                K = dequantize_kv(K, pool_sk.reshape(shape[:3])[
+                    block_tables].reshape(B, -1, N), q.dtype)
+                V = dequantize_kv(V, pool_sv.reshape(shape[:3])[
+                    block_tables].reshape(B, -1, N), q.dtype)
+            kv_pos = jnp.arange(K.shape[1])
+            mask = kv_pos[None, None, None, :] <= positions[:, None, :, None]
+            return dot_product_attention(q, K, V, mask=mask, causal=False)
 
 
 class GPTNeoXMLP(nn.Module):
@@ -455,22 +460,24 @@ class GPTNeoXBlock(nn.Module):
                  paged_state=None):
         cfg = self.config
         x = maybe_constrain(x, (BATCH_AXES, "sp", None))
-        attn_out = GPTNeoXAttention(cfg, decode=self.decode, paged=self.paged,
-                                    name="attention")(
-            ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
-                           fused=cfg.fused_norms, name="input_layernorm")(x),
-            positions, deterministic=deterministic, attention_mask=attention_mask,
-            paged_state=paged_state)
-        if cfg.use_parallel_residual:
+        # the scopes ``attention`` and ``mlp`` (each sublayer with its norm)
+        # are what a device trace is read by: PERF.md section 3
+        with jax.named_scope("attention"):
+            attn_out = GPTNeoXAttention(
+                cfg, decode=self.decode, paged=self.paged, name="attention")(
+                ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
+                               fused=cfg.fused_norms, name="input_layernorm")(x),
+                positions, deterministic=deterministic,
+                attention_mask=attention_mask, paged_state=paged_state)
+        if not cfg.use_parallel_residual:
+            x = x + attn_out
+        with jax.named_scope("mlp"):
             mlp_out = self._mlp(
                 ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
                                fused=cfg.fused_norms, name="post_attention_layernorm")(x), deterministic)
+        if cfg.use_parallel_residual:
             x = x + attn_out + mlp_out
         else:
-            x = x + attn_out
-            mlp_out = self._mlp(
-                ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
-                               fused=cfg.fused_norms, name="post_attention_layernorm")(x), deterministic)
             x = x + mlp_out
         if cfg.hidden_dropout > 0.0 and not deterministic:
             x = nn.Dropout(cfg.hidden_dropout)(x, deterministic=False)
@@ -496,8 +503,9 @@ class GPTNeoX(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(S), (B, S))
         # f32 lookup + downcast: embedding grads accumulate via scatter-add,
         # which wants f32 (and bf16 scatter aborts XLA:CPU under shard_map)
-        x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
-                     name="embed_in")(input_ids).astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
+                         name="embed_in")(input_ids).astype(cfg.dtype)
         block = GPTNeoXBlock
         if cfg.remat:
             block = nn.remat(GPTNeoXBlock, static_argnums=(3,))
@@ -529,8 +537,10 @@ class GPTNeoX(nn.Module):
                     jax.random.fold_in(self.make_rng("pld"), i), keep_p)
                 y = jnp.where(keep, y, x)
             x = y
-        x = ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
-                           fused=cfg.fused_norms, name="final_layer_norm")(x)
+        with jax.named_scope("head_ce"):    # the head, from its norm on
+            x = ModelLayerNorm(epsilon=cfg.layernorm_eps, dtype=cfg.dtype,
+                               fused=cfg.fused_norms,
+                               name="final_layer_norm")(x)
         if return_hidden:
             # chunked-CE path: the caller owns the head projection
             return x
@@ -545,8 +555,9 @@ class GPTNeoX(nn.Module):
             if lp.ndim == 1:
                 lp = lp[:, None]
             x = jnp.take_along_axis(x, lp[..., None], axis=1)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          name="embed_out")(x)
+        with jax.named_scope("head_ce"):
+            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                              name="embed_out")(x)
         return logits
 
     # ------------------------------------------------------------ engine API
@@ -595,17 +606,19 @@ class GPTNeoX(nn.Module):
                                      deterministic=deterministic, rngs=rngs,
                                      **kwargs)
             labels = batch["labels"]
-            logits = logits.astype(jnp.float32)
-            # ce = logsumexp - gold logit: identical math to
-            # log_softmax + gather, but never materializes the [B, S, V]
-            # fp32 log-prob tensor (a ~3 GB HBM round-trip per microbatch
-            # at bench shapes)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, labels[..., None],
-                                       axis=-1)[..., 0]
-            token_ll = gold - lse
-            mask = batch.get("loss_mask", jnp.ones_like(token_ll))
-            ce = -jnp.sum(token_ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+            with jax.named_scope("head_ce"):   # the head GEMM is in it too
+                logits = logits.astype(jnp.float32)
+                # ce = logsumexp - gold logit: identical math to
+                # log_softmax + gather, but never materializes the [B, S, V]
+                # fp32 log-prob tensor (a ~3 GB HBM round-trip per microbatch
+                # at bench shapes)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                gold = jnp.take_along_axis(logits, labels[..., None],
+                                           axis=-1)[..., 0]
+                token_ll = gold - lse
+                mask = batch.get("loss_mask", jnp.ones_like(token_ll))
+                ce = -jnp.sum(token_ll * mask) / jnp.maximum(jnp.sum(mask),
+                                                              1.0)
             return ce + aux
 
         def loss_chunked(params, batch, rng=None, model=self,
@@ -652,10 +665,11 @@ class GPTNeoX(nn.Module):
                 den = den + jnp.sum(mc)
                 return (num, den), None
 
-            (num, den), _ = jax.lax.scan(
-                jax.checkpoint(chunk), (jnp.float32(0.0), jnp.float32(0.0)),
-                (x, labels, mask))
-            return -num / jnp.maximum(den, 1.0)
+            with jax.named_scope("head_ce"):
+                (num, den), _ = jax.lax.scan(
+                    jax.checkpoint(chunk),
+                    (jnp.float32(0.0), jnp.float32(0.0)), (x, labels, mask))
+                return -num / jnp.maximum(den, 1.0)
 
         if cfg.ce_chunk_tokens > 0:
             if cfg.has_moe:

@@ -449,11 +449,15 @@ def mha(q, k, v, causal=True, scale=None, block=None):
         s128 = -(-S // LANES) * LANES
         block = next(b for b in (1024, 512, 256, LANES) if s128 % b == 0)
 
+    # the copies on both sides of the kernel, forward and backward, are
+    # ``attention_layout`` in a device trace
+    @jax.named_scope("attention_layout")
     def fold(t):
         return jnp.swapaxes(t, 1, 2).reshape(B * N, S, D)
 
     o = _mha(fold(q), fold(k), fold(v), causal, float(scale), block)
-    return jnp.swapaxes(o.reshape(B, N, S, D), 1, 2)
+    with jax.named_scope("attention_layout"):
+        return jnp.swapaxes(o.reshape(B, N, S, D), 1, 2)
 
 
 # keep the historical name used by ring attention / docs

@@ -14,6 +14,8 @@ import collections
 
 import numpy as np
 
+from ..telemetry.trace import span
+
 
 class DevicePrefetchingLoader:
     """Double-buffers device transfer of batch N+1 while step N runs.
@@ -50,16 +52,18 @@ class DevicePrefetchingLoader:
     def _fill(self):
         while not self._exhausted and len(self._buf) < self.depth:
             pos = self.position_fn() if self.position_fn is not None else None
-            try:
-                if self.pulls_per_batch == 1:
-                    batch = next(self.iterator)
-                else:
-                    batch = [next(self.iterator)
-                             for _ in range(self.pulls_per_batch)]
-            except StopIteration:
-                self._exhausted = True
-                return
-            self._buf.append((self.put_fn(batch), pos))
+            # one pull and its device_put: inside the engine's train/input
+            with span("train/prefetch", buffered=len(self._buf)):
+                try:
+                    if self.pulls_per_batch == 1:
+                        batch = next(self.iterator)
+                    else:
+                        batch = [next(self.iterator)
+                                 for _ in range(self.pulls_per_batch)]
+                except StopIteration:
+                    self._exhausted = True
+                    return
+                self._buf.append((self.put_fn(batch), pos))
 
     def __iter__(self):
         return self

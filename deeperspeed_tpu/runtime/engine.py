@@ -27,6 +27,9 @@ from .. import comm as dist
 from ..accelerator import get_accelerator
 from ..monitor.monitor import MonitorMaster
 from ..parallel import topology as topo
+from ..telemetry.trace import (TraceSessionWatch, compile_stats,
+                               publish_step_scopes, span, step_scopes,
+                               step_span)
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -456,6 +459,7 @@ class DeeperSpeedEngine:
         self._step_cost = None       # HLO cost_analysis of the compiled step
         self._comm_footprint = None  # trace-time collective wire footprint
         self._tele_captured = False
+        self._trace_watch = TraceSessionWatch()
 
         # ---- resilience: preemption handlers + loss sentinel (PR 3)
         from .resilience import build_resilience
@@ -774,11 +778,13 @@ class DeeperSpeedEngine:
             grads, loss = self._grads_for_batch(
                 params, batch, rng, jnp.float32(1.0),
                 ltd_tokens=ltd_tokens, step=step)
-            grads = jax.tree_util.tree_map(
-                lambda g: g.astype(jnp.float32), grads)
-            norm = tree_global_norm(grads)
-            grads = _clip_by_global_norm(grads, norm, clip)
-            grads = jax.tree_util.tree_map(lambda g: g.astype(wire), grads)
+            with jax.named_scope("grad_norm_clip"):
+                grads = jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32), grads)
+                norm = tree_global_norm(grads)
+                grads = _clip_by_global_norm(grads, norm, clip)
+                grads = jax.tree_util.tree_map(lambda g: g.astype(wire),
+                                               grads)
             return grads, loss, norm
 
         return jax.jit(gs)
@@ -1207,7 +1213,8 @@ class DeeperSpeedEngine:
 
     def _compute_params(self, master, step=None):
         """Derive compute-dtype params at their ZeRO placement."""
-        params = self.precision.cast_for_compute(master, self._no_cast)
+        with jax.named_scope("optimizer"):   # the masters' cast is its tail
+            params = self.precision.cast_for_compute(master, self._no_cast)
         if self._compression is not None and step is not None:
             from ..compression.compress import compress_params
 
@@ -1227,9 +1234,12 @@ class DeeperSpeedEngine:
                 return jax.checkpoint(
                     lambda a: quantized_resharding(a, target))(x)
 
-            return jax.tree_util.tree_map(
-                gather, params, self._qwz_targets, self._qwz_mask)
-        return jax.lax.with_sharding_constraint(params, self.param_shardings)
+            with jax.named_scope("zero3_gather"):
+                return jax.tree_util.tree_map(
+                    gather, params, self._qwz_targets, self._qwz_mask)
+        with jax.named_scope("zero3_gather"):
+            return jax.lax.with_sharding_constraint(params,
+                                                    self.param_shardings)
 
     def _micro_loss_and_grads(self, master, microbatch, rng, scale,
                               ltd_tokens=None, step=None):
@@ -1252,7 +1262,8 @@ class DeeperSpeedEngine:
         # so casting HERE (before the caller's sharding constraint) sets the
         # collective's wire dtype; accumulation re-casts after.
         wire = self.precision.reduce_dtype or self.precision.accum_dtype
-        grads = tree_cast(grads, wire)
+        with jax.named_scope("grad_accumulate"):
+            grads = tree_cast(grads, wire)
         return loss, grads
 
     def _grad_reduce_plan(self, master):
@@ -1323,17 +1334,23 @@ class DeeperSpeedEngine:
                                                      step=step)
             # reduction happens into this constrained layout, in the wire
             # dtype chosen by _micro_loss_and_grads; accumulate in accum_dtype
-            grads = jax.lax.with_sharding_constraint(grads, self.grad_shardings)
-            grads = tree_cast(grads, self.precision.accum_dtype)
-            new_acc = jax.tree_util.tree_map(jnp.add, acc[0], grads)
+            with jax.named_scope("grad_accumulate"):
+                with jax.named_scope("zero3_reduce"):
+                    grads = jax.lax.with_sharding_constraint(
+                        grads, self.grad_shardings)
+                grads = tree_cast(grads, self.precision.accum_dtype)
+                new_acc = jax.tree_util.tree_map(jnp.add, acc[0], grads)
             return (new_acc, acc[1] + 1), loss
 
-        zero_grads = jax.tree_util.tree_map(
-            lambda x: jnp.zeros(x.shape, self.precision.accum_dtype), master
-        )
-        zero_grads = jax.lax.with_sharding_constraint(zero_grads, self.grad_shardings)
+        with jax.named_scope("grad_accumulate"):
+            zero_grads = jax.tree_util.tree_map(
+                lambda x: jnp.zeros(x.shape, self.precision.accum_dtype),
+                master)
+            zero_grads = jax.lax.with_sharding_constraint(
+                zero_grads, self.grad_shardings)
         (grads, _), losses = jax.lax.scan(micro, (zero_grads, jnp.int32(0)), batch)
-        grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
+        with jax.named_scope("grad_accumulate"):
+            grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
         return grads, jnp.mean(losses)
 
     def _grads_for_batch_deferred(self, master, batch, rng, scale,
@@ -1417,32 +1434,33 @@ class DeeperSpeedEngine:
             flat, gdef = jax.tree_util.tree_flatten(gsum)
             inv = 1.0 / (gas * n_red)
             out = list(flat)
-            for bucket in buckets:
-                ar = [i for i in bucket if plan_flat[i][0] == "all_reduce"]
-                rs = [i for i in bucket
-                      if plan_flat[i][0] == "reduce_scatter"]
-                if ar:
-                    # fuse the bucket's replicated-layout leaves into one
-                    # flattened all-reduce (wire dtype set by the cast)
-                    vecs = [(out[i] * inv).astype(wire).reshape(-1)
-                            for i in ar]
-                    vec = jnp.concatenate(vecs) if len(vecs) > 1 else vecs[0]
-                    vec = jax.lax.psum(vec, reduce_axes)
-                    sizes = np.cumsum([flat[i].size for i in ar])[:-1]
-                    for i, piece in zip(ar, jnp.split(vec, sizes)):
-                        out[i] = piece.reshape(flat[i].shape).astype(acc_dt)
-                for i in rs:
-                    _, dim, axes = plan_flat[i]
-                    g = (out[i] * inv).astype(wire)
-                    g = jax.lax.psum_scatter(
-                        g, axes if len(axes) > 1 else axes[0],
-                        scatter_dimension=dim, tiled=True)
-                    # grad-spec axes may be a subgroup (MiCS/hpZ): finish
-                    # the reduction over the remaining batch axes
-                    rest = tuple(a for a in reduce_axes if a not in axes)
-                    if rest:
-                        g = jax.lax.psum(g, rest)
-                    out[i] = g.astype(acc_dt)
+            with jax.named_scope("zero3_reduce"):
+                for bucket in buckets:
+                    ar = [i for i in bucket if plan_flat[i][0] == "all_reduce"]
+                    rs = [i for i in bucket
+                          if plan_flat[i][0] == "reduce_scatter"]
+                    if ar:
+                        # fuse the bucket's replicated-layout leaves into one
+                        # flattened all-reduce (wire dtype set by the cast)
+                        vecs = [(out[i] * inv).astype(wire).reshape(-1)
+                                for i in ar]
+                        vec = jnp.concatenate(vecs) if len(vecs) > 1 else vecs[0]
+                        vec = jax.lax.psum(vec, reduce_axes)
+                        sizes = np.cumsum([flat[i].size for i in ar])[:-1]
+                        for i, piece in zip(ar, jnp.split(vec, sizes)):
+                            out[i] = piece.reshape(flat[i].shape).astype(acc_dt)
+                    for i in rs:
+                        _, dim, axes = plan_flat[i]
+                        g = (out[i] * inv).astype(wire)
+                        g = jax.lax.psum_scatter(
+                            g, axes if len(axes) > 1 else axes[0],
+                            scatter_dimension=dim, tiled=True)
+                        # grad-spec axes may be a subgroup (MiCS/hpZ): finish
+                        # the reduction over the remaining batch axes
+                        rest = tuple(a for a in reduce_axes if a not in axes)
+                        if rest:
+                            g = jax.lax.psum(g, rest)
+                        out[i] = g.astype(acc_dt)
             grads = jax.tree_util.tree_unflatten(gdef, out)
             loss = jnp.mean(losses)
             if reduce_axes:
@@ -1662,6 +1680,37 @@ class DeeperSpeedEngine:
         )
         return fn(master, batch, rng)
 
+    @jax.named_scope("grad_norm_clip")
+    def _unscale_and_clip(self, grads, inv, clip, fp16):
+        """Traced: fp32 grads times ``inv``, the overflow flag, the global
+        norm and the clip -> (grads, overflow, norm)."""
+        grads = jax.tree_util.tree_map(
+            lambda g: (g * inv).astype(jnp.float32), grads)
+        overflow = (has_inf_or_nan(grads) if fp16 is not None
+                    else jnp.zeros((), bool))
+        grad_norm = tree_global_norm(grads)
+        return _clip_by_global_norm(grads, grad_norm, clip), overflow, grad_norm
+
+    @jax.named_scope("optimizer")
+    def _optimizer_pass(self, state, dev, master, grads, overflow, fp16):
+        """Traced: the optimizer's update of masters and moments (kept as
+        they were on an fp16 overflow) -> (new state, lr)."""
+        lr = jnp.asarray(self._lr_fn(state["step"]), jnp.float32)
+        updates, new_opt = self.tx.update(grads, dev["opt_state"], master)
+        new_master = self._apply_update(master, updates, lr)
+        if fp16 is not None:
+            keep = lambda new, old: jax.tree_util.tree_map(
+                lambda n, o: jnp.where(overflow, o, n), new, old
+            )
+            new_master = keep(new_master, master)
+            new_opt = keep(new_opt, dev["opt_state"])
+        return {
+            "master_params": new_master,
+            "opt_state": new_opt,
+            "step": state["step"] + jnp.where(overflow, 0, 1).astype(jnp.int32),
+            "loss_scale": update_loss_scale(state["loss_scale"], overflow, fp16),
+        }, lr
+
     def _make_train_step(self, ltd_tokens=None):
         clip = self.config.gradient_clipping
         fp16 = self.config.fp16 if self.precision.is_fp16 else None
@@ -1681,32 +1730,11 @@ class DeeperSpeedEngine:
                 grads, loss_mean = self._grads_for_batch(
                     master, batch, rng, scale, ltd_tokens=ltd_tokens,
                     step=state["step"])
-            inv = 1.0 / scale
-            grads = jax.tree_util.tree_map(lambda g: (g * inv).astype(jnp.float32), grads)
-
-            overflow = has_inf_or_nan(grads) if fp16 is not None else jnp.zeros((), bool)
-
-            grad_norm = tree_global_norm(grads)
-            grads = _clip_by_global_norm(grads, grad_norm, clip)
-
-            lr = jnp.asarray(self._lr_fn(state["step"]), jnp.float32)
-            updates, new_opt = self.tx.update(grads, dev["opt_state"], master)
-            new_master = self._apply_update(master, updates, lr)
-
-            if fp16 is not None:
-                keep = lambda new, old: jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(overflow, o, n), new, old
-                )
-                new_master = keep(new_master, master)
-                new_opt = keep(new_opt, dev["opt_state"])
-            new_scale = update_loss_scale(state["loss_scale"], overflow, fp16)
-
-            new_state = {
-                "master_params": new_master,
-                "opt_state": new_opt,
-                "step": state["step"] + jnp.where(overflow, 0, 1).astype(jnp.int32),
-                "loss_scale": new_scale,
-            }
+            grads, overflow, grad_norm = self._unscale_and_clip(
+                grads, 1.0 / scale, clip, fp16)
+            new_state, lr = self._optimizer_pass(state, dev, master, grads,
+                                                 overflow, fp16)
+            new_scale = new_state["loss_scale"]
             if new_error is not None:
                 new_state["onebit_error"] = new_error
             metrics = {
@@ -1811,29 +1839,12 @@ class DeeperSpeedEngine:
                 dev = self._materialize_state(state)
                 master = dev["master_params"]
             scale = state["loss_scale"].scale if fp16 is not None else jnp.float32(1.0)
-            inv = 1.0 / (gas * scale)
-            grads = jax.tree_util.tree_map(lambda g: (g * inv).astype(jnp.float32), grads)
-            overflow = has_inf_or_nan(grads) if fp16 is not None else jnp.zeros((), bool)
-            grad_norm = tree_global_norm(grads)
-            grads = _clip_by_global_norm(grads, grad_norm, clip)
-            lr = jnp.asarray(self._lr_fn(state["step"]), jnp.float32)
-            updates, new_opt = self.tx.update(grads, dev["opt_state"], master)
-            new_master = self._apply_update(master, updates, lr)
-            if fp16 is not None:
-                keep = lambda new, old: jax.tree_util.tree_map(
-                    lambda n, o: jnp.where(overflow, o, n), new, old
-                )
-                new_master = keep(new_master, master)
-                new_opt = keep(new_opt, dev["opt_state"])
-            new_scale = update_loss_scale(state["loss_scale"], overflow, fp16)
-            new_state = {
-                "master_params": new_master,
-                "opt_state": new_opt,
-                "step": state["step"] + jnp.where(overflow, 0, 1).astype(jnp.int32),
-                "loss_scale": new_scale,
-            }
+            grads, overflow, grad_norm = self._unscale_and_clip(
+                grads, 1.0 / (gas * scale), clip, fp16)
+            new_state, lr = self._optimizer_pass(state, dev, master, grads,
+                                                 overflow, fp16)
             return new_state, {"grad_norm": grad_norm, "lr": lr, "overflow": overflow,
-                               "loss_scale": new_scale.scale}
+                               "loss_scale": new_state["loss_scale"].scale}
 
         return jax.jit(apply_step, **self._state_jit_kwargs((self.grad_shardings,)))
 
@@ -1924,40 +1935,81 @@ class DeeperSpeedEngine:
             dist.comms_logger.begin_trace_capture()
         if self.watchdog is not None:
             self.watchdog.heartbeat("train_batch", self.micro_steps)
+        # after a profiler session that covered a step: publish the scopes
+        # of the step program about to run, for whoever reads that trace
+        publish = self._trace_watch.ended()
+        with step_span("train/step", self.global_steps):
+            return self._train_step_phases(data, capture, publish)
+
+    def _run_step(self, dispatch, publish, fn, *args):
+        """Call a step program inside its ``train/dispatch`` span, which
+        gets ``compiled=1`` if the call compiled anything."""
+        if publish:
+            self._publish_scopes(fn, *args)
+        compiled = compile_stats().programs
+        out = fn(*args)
+        if compile_stats().programs != compiled:
+            dispatch.set(compiled=1)
+        return out
+
+    def _publish_scopes(self, fn, *args):
+        """``telemetry.step_scopes()`` gets the scope of every instruction
+        of ``fn``'s compiled program.  With the live arguments of the call
+        about to be made the executable comes from jit's in-memory cache:
+        nothing compiles and nothing is loaded."""
+        t0 = time.perf_counter()
+        try:
+            name = publish_step_scopes(fn.lower(*args).compile().as_text())
+        except Exception as e:
+            logger.warning(f"telemetry: step scopes not published ({e})")
+            return
+        logger.info(f"telemetry: scopes of {name} published in "
+                    f"{time.perf_counter() - t0:.3f}s "
+                    f"({len(step_scopes()[name])} instructions)")
+
+    def _train_step_phases(self, data, capture, publish):
+        """The step itself, phase by phase (``dst:train/<phase>``)."""
         lowered = None
         t_start = time.perf_counter()
 
         self.tput_timer.start()
         self.timers(TRAIN_BATCH_TIMER).start()
-        if data is self._prefetcher and self._prefetcher is not None:
-            stacked = next(self._prefetcher)  # already stacked + device_put
-        else:
-            stacked = self._stack_microbatches(data)
-        stacked, ltd_tokens = self._apply_data_efficiency(stacked)
+        with span("train/input"):
+            if data is self._prefetcher and self._prefetcher is not None:
+                stacked = next(self._prefetcher)  # already stacked + device_put
+            else:
+                stacked = self._stack_microbatches(data)
+            stacked, ltd_tokens = self._apply_data_efficiency(stacked)
         self._maybe_profile_flops(stacked)
         if self._host_adam is not None:
             # host-update mode: device computes clipped fp32 grads over the
             # compute params; the native SIMD Adam updates host-resident
             # fp32 masters + moments; the refreshed compute cast uploads.
             # Reference ZeRO-Offload flow (CPU Adam + fp16 param upload).
-            grads_fn = self._get_grads_step_host(ltd_tokens)
-            rng = self._next_rng()
-            step_arr = jnp.asarray(self.global_steps, jnp.int32)
-            if capture:
-                lowered = self._lower_for_cost(
-                    grads_fn, self.state["master_params"], stacked, rng, step_arr)
-            grads, loss_dev, norm = grads_fn(
-                self.state["master_params"], stacked, rng, step_arr)
-            # one batched fetch: device_get overlaps the per-leaf D2H
-            # copies instead of serializing blocking np.asarray calls
-            grads = jax.device_get(grads)
-            ghost = dict(self._host_flat_names(grads))
-            del grads
-            lr = float(np.asarray(self._lr_fn(self.global_steps)))
-            self._host_adam.step(self._host_master, ghost, lr=lr)
-            self.state["master_params"] = self._upload_compute()
-            self.state["step"] = jax.device_put(
-                jnp.asarray(self.global_steps + 1, jnp.int32), self._repl)
+            with span("train/dispatch") as dispatch:
+                grads_fn = self._get_grads_step_host(ltd_tokens)
+                rng = self._next_rng()
+                step_arr = jnp.asarray(self.global_steps, jnp.int32)
+                if capture:
+                    lowered = self._lower_for_cost(
+                        grads_fn, self.state["master_params"], stacked, rng,
+                        step_arr)
+                grads, loss_dev, norm = self._run_step(
+                    dispatch, publish, grads_fn, self.state["master_params"],
+                    stacked, rng, step_arr)
+            with span("train/readback"):
+                # one batched fetch: device_get overlaps the per-leaf D2H
+                # copies instead of serializing blocking np.asarray calls
+                grads = jax.device_get(grads)
+                ghost = dict(self._host_flat_names(grads))
+                del grads
+                lr = float(np.asarray(self._lr_fn(self.global_steps)))
+            with span("train/host_adam"):
+                self._host_adam.step(self._host_master, ghost, lr=lr)
+            with span("train/dispatch"):
+                self.state["master_params"] = self._upload_compute()
+                self.state["step"] = jax.device_put(
+                    jnp.asarray(self.global_steps + 1, jnp.int32), self._repl)
             new_state = self.state
             metrics = {"loss": loss_dev, "grad_norm": norm, "lr": lr,
                        "overflow": False, "loss_scale": 1.0}
@@ -1969,32 +2021,44 @@ class DeeperSpeedEngine:
             # fwd/bwd; the update half then consumes both.  Symmetrically,
             # swap_out's flush (pipeline_write default) overlaps the NEXT
             # batch's grads and is waited at its swap_in.
-            grads_fn = self._get_grads_step(ltd_tokens)
-            sub_state = {"master_params": self.state["master_params"],
-                         "loss_scale": self.state["loss_scale"],
-                         "step": self.state["step"]}
-            rng = self._next_rng()
-            if capture:
-                lowered = self._lower_for_cost(grads_fn, sub_state, stacked, rng)
-            grads, loss_mean, master_dev = grads_fn(sub_state, stacked, rng)
-            self._ensure_opt_resident()
-            if self._apply_batch_fn is None:
-                self._apply_batch_fn = self._make_apply(divisor=1,
-                                                        device_master=True)
-            new_state, metrics = self._apply_batch_fn(self.state, grads,
-                                                      master_dev)
+            with span("train/dispatch") as dispatch:
+                grads_fn = self._get_grads_step(ltd_tokens)
+                sub_state = {"master_params": self.state["master_params"],
+                             "loss_scale": self.state["loss_scale"],
+                             "step": self.state["step"]}
+                rng = self._next_rng()
+                if capture:
+                    lowered = self._lower_for_cost(grads_fn, sub_state,
+                                                   stacked, rng)
+                grads, loss_mean, master_dev = self._run_step(
+                    dispatch, publish, grads_fn, sub_state, stacked, rng)
+            with span("train/swap_in"):
+                self._ensure_opt_resident()
+            with span("train/dispatch") as dispatch:
+                if self._apply_batch_fn is None:
+                    self._apply_batch_fn = self._make_apply(
+                        divisor=1, device_master=True)
+                new_state, metrics = self._run_step(
+                    dispatch, publish, self._apply_batch_fn, self.state,
+                    grads, master_dev)
             metrics = {**metrics, "loss": loss_mean}
         else:
-            self._ensure_opt_resident()
-            step_fn = self._get_train_step(ltd_tokens)
-            rng = self._next_rng()
-            if capture:
-                # lowering first also primes the jit trace cache, so the
-                # collective records land exactly once inside the capture
-                lowered = self._lower_for_cost(step_fn, self.state, stacked, rng)
-            new_state, metrics = step_fn(self.state, stacked, rng)
-        poisoned = (self._sentinel is not None
-                    and self._sentinel.observe(float(np.asarray(metrics["loss"]))))
+            with span("train/dispatch") as dispatch:
+                self._ensure_opt_resident()
+                step_fn = self._get_train_step(ltd_tokens)
+                rng = self._next_rng()
+                if capture:
+                    # lowering first also primes the jit trace cache, so the
+                    # collective records land exactly once inside the capture
+                    lowered = self._lower_for_cost(step_fn, self.state,
+                                                   stacked, rng)
+                new_state, metrics = self._run_step(
+                    dispatch, publish, step_fn, self.state, stacked, rng)
+        poisoned = False
+        if self._sentinel is not None:
+            with span("train/readback"):
+                loss_now = float(np.asarray(metrics["loss"]))
+            poisoned = self._sentinel.observe(loss_now)
         rolled_back = False
         if poisoned:
             # keep the pre-step state: donation is disabled while the
@@ -2006,8 +2070,9 @@ class DeeperSpeedEngine:
             if self._sentinel.should_rollback():
                 rolled_back = self._rollback_last_valid()
         else:
-            self.state = self._dehydrate_state(new_state)
-            self._spill_opt()
+            with span("train/report"):
+                self.state = self._dehydrate_state(new_state)
+                self._spill_opt()
         self.timers(TRAIN_BATCH_TIMER).stop()
         self.tput_timer.stop(global_step=True)
         step_time = time.perf_counter() - t_start
@@ -2029,17 +2094,19 @@ class DeeperSpeedEngine:
             self.micro_steps += self.gradient_accumulation_steps()
             self.global_samples += self.train_batch_size()
         self._last_metrics = metrics
-        if self.precision.is_fp16 and bool(metrics["overflow"]) \
-                and not rolled_back:
-            self.skipped_steps += 1
-        loss = metrics["loss"]
-        self._report_step(metrics)
-        self._emit_step_telemetry(step_time)
-        if self.resilience is not None:
-            # preemption signal (or watchdog escalation) lands here, at the
-            # step boundary: emergency save + TrainingPreempted
-            self.resilience.check_step_boundary(self)
-        return loss
+        if self.precision.is_fp16 and not rolled_back:
+            with span("train/readback"):
+                overflow = bool(metrics["overflow"])
+            if overflow:
+                self.skipped_steps += 1
+        with span("train/report"):
+            self._report_step(metrics)
+            self._emit_step_telemetry(step_time)
+            if self.resilience is not None:
+                # preemption signal (or watchdog escalation) lands here, at
+                # the step boundary: emergency save + TrainingPreempted
+                self.resilience.check_step_boundary(self)
+        return metrics["loss"]
 
     def eval_batch(self, data_iter=None, batch=None, compute_loss=True, bcast_loss=True):
         data = batch if batch is not None else data_iter

@@ -28,17 +28,43 @@ The hot-path contract: a disabled tracer costs one attribute read
 (``get_tracer().enabled``) per call site and zero per-token work -- call
 sites must check ``enabled`` before building spans, exactly like the
 ``reg.enabled`` idiom in :mod:`.serving`.
+
+Live work is wrapped in :func:`span` (also behind ``tracer.span`` and
+``ctx.span``): every span is a ``jax.profiler.TraceAnnotation`` named
+``dst:<layer>/<phase>`` first, so it sits on the device trace's clock in any
+profiler session, and a ring record second, when the tracer is enabled.
+The module also keeps the two things only the program can tell a trace
+reader: what was compiled (:func:`compile_stats`) and which scope each
+instruction of a step program was traced under (:func:`step_scopes`).
 """
 
 import json
 import os
+import re
 import threading
 import time
 import uuid
 from collections import deque
 
+import jax
+
 from ..utils.logging import logger
 from .registry import JsonlSink, _is_rank0, get_registry
+
+
+#: every program span is ``dst:<layer>/<phase>`` on the profiler's timeline
+#: (a harness's own are ``bench:<span>``)
+SPAN_PREFIX = "dst:"
+_THREAD = threading.local()
+
+
+def _open_spans():
+    """The ring spans this thread has open, outermost first."""
+    try:
+        return _THREAD.open
+    except AttributeError:
+        _THREAD.open = []
+        return _THREAD.open
 
 
 def new_id():
@@ -82,21 +108,59 @@ class Span:
 
 
 class _SpanScope:
-    """``with tracer.span(...)`` / ``ctx.span(...)`` helper."""
+    """One live program span (``span(...)`` / ``tracer.span(...)`` /
+    ``ctx.span(...)``): a ``jax.profiler.TraceAnnotation`` named
+    ``dst:<name>`` on the profiler's clock and, when its tracer is enabled,
+    the same interval as a ring :class:`Span` (same name, attributes and
+    parent).  With no ``parent_id`` given the ring span nests under the
+    innermost span this thread has open."""
 
-    __slots__ = ("_tracer", "span")
+    __slots__ = ("_tracer", "name", "attrs", "_ids", "_annotation", "span")
 
-    def __init__(self, tracer, span):
+    def __init__(self, tracer, name, trace_id, parent_id, attrs):
         self._tracer = tracer
-        self.span = span
+        self.name = name
+        self.attrs = attrs
+        self._ids = (trace_id, parent_id)
+        self.span = None
 
     def __enter__(self):
-        return self.span
+        self._annotation = jax.profiler.TraceAnnotation(
+            SPAN_PREFIX + self.name, **self.attrs)
+        self._annotation.__enter__()
+        if self._tracer.enabled:
+            trace_id, parent_id = self._ids
+            stack = _open_spans()
+            if stack and parent_id is None:
+                trace_id = trace_id or stack[-1].trace_id
+                parent_id = stack[-1].span_id
+            self.span = self._tracer.start_span(
+                self.name, trace_id=trace_id, parent_id=parent_id,
+                **self.attrs)
+            stack.append(self.span)
+        return self
+
+    @property
+    def trace_id(self):
+        return self.span.trace_id if self.span is not None else None
+
+    @property
+    def span_id(self):
+        return self.span.span_id if self.span is not None else None
+
+    def set(self, **attrs):
+        """Attributes known only once the work is under way."""
+        self._annotation.set_metadata(**attrs)
+        if self.span is not None:
+            self.span.attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb):
-        if exc_type is not None:
-            self.span.attrs["error"] = exc_type.__name__
-        self._tracer.end_span(self.span)
+        if self.span is not None:
+            if exc_type is not None:
+                self.span.attrs["error"] = exc_type.__name__
+            _open_spans().pop()       # spans of one thread close in order
+            self._tracer.end_span(self.span)
+        self._annotation.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -189,8 +253,7 @@ class Tracer:
         return rec
 
     def span(self, name, trace_id=None, parent_id=None, **attrs):
-        return _SpanScope(self, self.start_span(name, trace_id=trace_id,
-                                                parent_id=parent_id, **attrs))
+        return _SpanScope(self, name, trace_id, parent_id, attrs)
 
     def record_span(self, name, trace_id, parent_id=None, start_unix=None,
                     dur_s=0.0, **attrs):
@@ -469,6 +532,147 @@ def tenant_percentiles(records, quantiles=(0.5, 0.95, 0.99)):
                              for q in quantiles}
         out[tenant] = table
     return out
+
+
+# ------------------------------------------------------------ compile stats
+class _CompileStats:
+    """What this process compiled, from ``jax.monitoring``: programs handed
+    to the backend compiler, persistent-cache hits (programs loaded instead)
+    and misses (programs compiled and written to it), with the instant
+    (``time.perf_counter``) and the seconds of each compile and load."""
+
+    KEEP = 4096   # instants kept; the counts go on
+
+    def __init__(self):
+        self.programs = self.cache_hits = self.cache_misses = 0
+        self.compiles = deque(maxlen=self.KEEP)      # (done at, seconds)
+        self.cache_loads = deque(maxlen=self.KEEP)   # (done at, seconds)
+
+    def on_event(self, event, **_kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def on_duration(self, event, seconds, **_kw):
+        if event.endswith("backend_compile_duration"):
+            self.programs += 1
+            kept = self.compiles
+        elif event.endswith("cache_retrieval_time_sec"):
+            kept = self.cache_loads
+        else:
+            return
+        kept.append((time.perf_counter(), float(seconds)))
+        tracer = get_tracer()
+        if tracer.enabled:
+            tracer.record_span("compile", "compile", dur_s=float(seconds),
+                               from_cache=kept is self.cache_loads)
+
+
+_COMPILE_STATS = _CompileStats()
+jax.monitoring.register_event_listener(_COMPILE_STATS.on_event)
+jax.monitoring.register_event_duration_secs_listener(
+    _COMPILE_STATS.on_duration)
+
+
+def compile_stats():
+    """The process's compile counters, live (see :class:`_CompileStats`):
+    read ``.programs`` before and after a call to know whether it compiled."""
+    return _COMPILE_STATS
+
+
+# ------------------------------------------------------------- step scopes
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = (.*)$")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CALLED = re.compile(r"\b(?:calls|to_apply)=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_STEP_SCOPES = {}
+
+
+def instruction_scopes(hlo_text):
+    """``{instruction name: op_name}`` of a compiled program's text.  An
+    instruction the compiler made without metadata (a fusion it cut out, a
+    convert it split off a matmul) takes the commonest ``op_name`` of the
+    computation it calls, else that of its first operand that has one: it
+    goes with what it was cut from."""
+    scopes, by_computation, inside = {}, {}, None
+    for line in hlo_text.splitlines():
+        found = _INSTRUCTION.match(line)
+        if found is None:
+            head = _COMPUTATION.match(line)
+            if head is not None:
+                inside = by_computation.setdefault(head.group(1), {})
+            continue
+        name, rest = found.groups()
+        own = _OP_NAME.search(rest)
+        op_name = own.group(1) if own else None
+        if op_name is None:
+            called = _CALLED.search(rest)
+            counts = by_computation.get(called.group(1)) if called else None
+            if counts:
+                op_name = max(counts, key=counts.get)
+            else:
+                op_name = next((scopes[o] for o in _OPERAND.findall(rest)
+                                if o in scopes), None)
+        if op_name is not None:
+            scopes[name] = op_name
+            if inside is not None:
+                inside[op_name] = inside.get(op_name, 0) + 1
+    return scopes
+
+
+def step_scopes():
+    """``{program name: {instruction name: op_name}}`` of the step programs
+    published so far: what puts a device trace's events (a v5e trace names
+    an event by its instruction and carries no scope) under the
+    ``jax.named_scope`` they were traced in.  Empty in a process that no
+    profiler session has covered."""
+    return _STEP_SCOPES
+
+
+def publish_step_scopes(hlo_text):
+    """Keep the scope of every instruction of a compiled program's text
+    under the program's name -> that name."""
+    head = re.match(r"HloModule ([^\s,]+)", hlo_text)
+    name = head.group(1) if head else "unknown"
+    _STEP_SCOPES[name] = instruction_scopes(hlo_text)
+    return name
+
+
+class TraceSessionWatch:
+    """Tells a loop when a profiler session that covered one of its steps
+    has ended: ``ended()`` is one ``TraceAnnotation.is_enabled()`` a step,
+    true once per session, on the first step after it."""
+
+    __slots__ = ("_covered",)
+
+    def __init__(self):
+        self._covered = False
+
+    def ended(self):
+        if jax.profiler.TraceAnnotation.is_enabled():
+            self._covered = True
+            return False
+        covered, self._covered = self._covered, False
+        return covered
+
+
+# ------------------------------------------------------------ program spans
+def span(name, trace_id=None, parent_id=None, **attrs):
+    """The program's one span primitive: ``with span("train/input"): ...``
+    is a ``dst:train/input`` event on the profiler's timeline (collected by
+    whoever has a ``jax.profiler`` session open; under a microsecond
+    otherwise) and, when the process tracer is enabled, the same interval
+    in its ring.  Attributes arrive as the event's stats."""
+    return _SpanScope(get_tracer(), name, trace_id, parent_id, attrs)
+
+
+def step_span(name, step_num):
+    """A whole step (``jax.profiler.StepTraceAnnotation``), so that the
+    profiler's own tools group device work by step."""
+    return jax.profiler.StepTraceAnnotation(SPAN_PREFIX + name,
+                                            step_num=step_num)
 
 
 # ------------------------------------------------------------- process glue

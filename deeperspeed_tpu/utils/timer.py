@@ -19,17 +19,22 @@ STEP_GLOBAL_TIMER = "step"
 TRAIN_BATCH_TIMER = "train_batch"
 
 
-def _sync_device():
+def _sync_device(who):
+    """Fence the device queues; ``who`` names the caller on the fence's
+    span (``dst:train/fence``), so a trace says who made the host wait."""
     try:
         import jax
         import jax.numpy as jnp
+
+        from ..telemetry.trace import span
 
         # Fence: block on a trivial device computation.  Per-device queues
         # execute in order, so this lands only after all pending work;
         # jax.effects_barrier() only waits on *effectful* ops and does not
         # drain ordinary pending computations.
-        for d in jax.local_devices():
-            jax.device_put(jnp.zeros(()), d).block_until_ready()
+        with span("train/fence", who=who):
+            for d in jax.local_devices():
+                jax.device_put(jnp.zeros(()), d).block_until_ready()
     except Exception:
         pass
 
@@ -137,7 +142,7 @@ class SynchronizedWallClockTimer:
     def log(self, names, normalizer=1.0, reset=True, memory_breakdown=False, ranks=None):
         assert normalizer > 0.0
         if self.synchronize:
-            _sync_device()
+            _sync_device("wall_clock")
         string = "time (ms)"
         for name in names:
             if name in self.timers:
@@ -188,7 +193,7 @@ class ThroughputTimer:
         self._init_timer()
         self.started = True
         if self.global_step_count >= self.start_step:
-            _sync_device()
+            _sync_device("throughput_timer.start")
             self.start_time = time.time()
 
     def stop(self, global_step=False, report_speed=True):
@@ -199,7 +204,7 @@ class ThroughputTimer:
         if global_step:
             self.global_step_count += 1
         if self.start_time > 0:
-            _sync_device()
+            _sync_device("throughput_timer.stop")
             self.end_time = time.time()
             duration = self.end_time - self.start_time
             self.total_elapsed_time += duration
