@@ -14,6 +14,7 @@ import hashlib
 import os
 import shutil
 import subprocess
+import tempfile
 import threading
 
 from ..utils.logging import logger
@@ -69,18 +70,28 @@ class OpBuilder:
             if os.path.isfile(lib):
                 return lib
             os.makedirs(_BUILD_DIR, exist_ok=True)
+            # other processes may build the same library at the same time
+            # (xdist workers on a fresh tree): each compiles to a name of
+            # its own and renames it in, so none can take another's file
+            fd, tmp = tempfile.mkstemp(
+                prefix=os.path.basename(lib) + ".", suffix=".tmp",
+                dir=_BUILD_DIR)
+            os.close(fd)
             cmd = [self.compiler(), "-O3", "-march=native", "-fopenmp",
                    "-shared", "-fPIC", "-std=c++17",
                    *self.extra_compile_args(),
-                   *self.absolute_sources(), "-o", lib + ".tmp"]
+                   *self.absolute_sources(), "-o", tmp]
             if verbose:
                 logger.info(f"[{self.NAME}] building: {' '.join(cmd)}")
             try:
                 subprocess.run(cmd, check=True, capture_output=True, text=True)
+                os.replace(tmp, lib)
             except subprocess.CalledProcessError as e:
                 raise RuntimeError(
                     f"native build of {self.NAME} failed:\n{e.stderr}") from e
-            os.replace(lib + ".tmp", lib)
+            finally:
+                if os.path.exists(tmp):
+                    os.remove(tmp)
         return lib
 
     def load(self, verbose=False):

@@ -5,21 +5,41 @@ fwd + grads, interpret mode off-TPU).
 Reference parity target: the fused attention/softmax kernels of
 ``csrc/transformer/softmax_kernels.cu`` -- here the checklist is exactness
 against the naive [S, S] softmax attention, including NON-multiple-of-128
-sequence lengths (VERDICT r1 required S=1000)."""
+sequence lengths (VERDICT r1 required S=1000), under the tile sizes the
+kernel picks for itself (``tile_plan``) and under forced ones."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from deeperspeed_tpu.ops.attention import pallas_flash
 from deeperspeed_tpu.ops.attention.core import _reference_attention
-from deeperspeed_tpu.ops.attention.pallas_flash import mha
+from deeperspeed_tpu.ops.attention.pallas_flash import (mha, tile_plan,
+                                                        walk_counts)
 
 
 def _qkv(B=2, S=256, N=2, D=16, dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (B, S, N, D)
     return tuple(jax.random.normal(k, shape, dtype) for k in ks)
+
+
+def _grads(fn, q, k, v):
+    return jax.grad(lambda *a: jnp.sum(jnp.square(fn(*a).astype(jnp.float32))),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
+def _assert_fwd_and_grads(fn, ref, q, k, v, tol, what):
+    np.testing.assert_allclose(
+        np.asarray(fn(q, k, v), np.float32), np.asarray(ref(q, k, v)),
+        rtol=tol, atol=tol, err_msg=f"forward mismatch ({what})")
+    for a, b, name in zip(_grads(fn, q, k, v), _grads(ref, q, k, v), "qkv"):
+        scale = max(1.0, float(jnp.max(jnp.abs(b))))
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32) / scale, np.asarray(b) / scale,
+            rtol=10 * tol, atol=10 * tol,
+            err_msg=f"d{name} mismatch ({what})")
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -34,9 +54,8 @@ def test_forward_matches_reference(S, causal):
 
 @pytest.mark.parametrize("S,block", [(256, None), (1000, None), (1024, 128)])
 def test_grads_match_reference(S, block):
-    # block=128 at S=1024 forces nk=8 > _FUSED_DQ_MAX_NK: covers the classic
-    # two-pass backward (_dq_kernel + _dkv_kernel); the None cases take the
-    # fused one-pass backward (_dkv_fused_kernel)
+    # block=128 at S=1024: eight owner blocks, so the walk inside the kernel
+    # runs up to seven chunks off the edge before the one on it
     q, k, v = _qkv(S=S, B=1, N=2, D=16)
 
     def loss_kernel(q, k, v):
@@ -53,6 +72,70 @@ def test_grads_match_reference(S, block):
                                    err_msg=f"d{name} mismatch (S={S})")
 
 
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("S", [128, 384, 1000, 1024, 2048])
+def test_fwd_and_grads_at_the_plans_own_tiles(S, causal):
+    """D = 64 under the tile sizes the code picks: one owner block at
+    S <= 2048 (every row group against all its columns at once), several at
+    S = 384; S = 1000 pads the chunk the diagonal crosses."""
+    q, k, v = _qkv(S=S, B=1, N=1, D=64)
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=causal),
+        lambda *a: _reference_attention(*a, causal=causal),
+        q, k, v, 3e-5, f"S={S} causal={causal}")
+
+
+@pytest.mark.parametrize("D", [96, 128])
+def test_fwd_and_grads_wide_heads(D):
+    q, k, v = _qkv(S=512, B=1, N=2, D=D)
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=True),
+        lambda *a: _reference_attention(*a, causal=True),
+        q, k, v, 3e-5, f"D={D}")
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_grads_when_the_edge_tile_is_the_padded_one(causal):
+    """S = 40: one 128-row block whose single tile is on the diagonal, holds
+    the padded columns and the padded rows at once."""
+    q, k, v = _qkv(S=40, B=1, N=2, D=16)
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=causal),
+        lambda *a: _reference_attention(*a, causal=causal),
+        q, k, v, 3e-5, f"S=40 causal={causal}")
+
+
+def _folded(S, D, causal=True, **plan_changes):
+    """``_mha`` on folded [B*N, S, D] inputs under a changed plan."""
+    plan = tile_plan(S, D, jnp.float32)._replace(**plan_changes)
+
+    def fn(q, k, v):
+        B, _, N, _ = q.shape
+        fold = lambda t: jnp.swapaxes(t, 1, 2).reshape(B * N, S, D)  # noqa: E731
+        o = pallas_flash._mha(fold(q), fold(k), fold(v), causal,
+                              float(D) ** -0.5, plan)
+        return jnp.swapaxes(o.reshape(B, N, S, D), 1, 2)
+    return fn
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("changes", [
+    dict(resident_bwd=False),                  # two-pass backward (long S)
+    dict(block=256, sub=128, rows=128, span=256),   # forward over 4 spans
+    dict(block=512, sub=256, rows=256),        # interior tiles in row groups
+    dict(block=256, sub=256, rows=256),        # sub == block: one edge tile
+], ids=["two_pass_bwd", "spans", "row_groups", "sub_is_block"])
+def test_plans_the_cells_do_not_take(changes, causal):
+    """Paths ``tile_plan`` keeps for shapes no test can afford (S beyond
+    8k: a forward that holds a span of k/v, the two-pass backward) and tile
+    sizes other shapes get, forced at S = 1000."""
+    q, k, v = _qkv(S=1000, B=1, N=2, D=16)
+    _assert_fwd_and_grads(
+        _folded(1000, 16, causal, **changes),
+        lambda *a: _reference_attention(*a, causal=causal),
+        q, k, v, 3e-5, f"{changes} causal={causal}")
+
+
 def test_bf16_forward_close():
     q, k, v = _qkv(S=256, dtype=jnp.bfloat16)
     got = mha(q, k, v, causal=True)
@@ -63,10 +146,37 @@ def test_bf16_forward_close():
                                rtol=2e-2, atol=2e-2)
 
 
+@pytest.mark.parametrize("S", [1000, 2048])
+def test_bf16_fwd_and_grads_close(S):
+    """bf16 inputs against the fp32 reference on the same values, with
+    ``test_bf16_forward_close``'s tolerances."""
+    q, k, v = _qkv(S=S, B=1, N=1, D=64, dtype=jnp.bfloat16)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    got = mha(q, k, v, causal=True)
+    assert got.dtype == jnp.bfloat16
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=True),
+        lambda *a: _reference_attention(*map(f32, a), causal=True),
+        q, k, v, 2e-2, f"bf16 S={S}")
+    assert all(g.dtype == jnp.bfloat16
+               for g in _grads(lambda *a: mha(*a, causal=True), q, k, v))
+
+
 def test_scale_override():
     q, k, v = _qkv(S=128)
     got = mha(q, k, v, causal=True, scale=0.5)
     want = _reference_attention(q, k, v, causal=True, scale=0.5)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("block", [128, 256, 512])
+def test_block_override_is_honoured(block):
+    plan = tile_plan(1024, 64, jnp.bfloat16, block)
+    assert plan.block == block and block % plan.sub == 0
+    q, k, v = _qkv(S=1024, B=1, N=1, D=16)
+    got = mha(q, k, v, causal=True, block=block)
+    want = _reference_attention(q, k, v, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
 
@@ -88,3 +198,49 @@ def test_grad_of_padded_rows_is_zero_free():
     q, k, v = _qkv(S=40, B=1, N=1, D=8)
     g = jax.grad(lambda q: jnp.sum(mha(q, k, v, causal=True)))(q)
     assert np.isfinite(np.asarray(g)).all()
+
+
+# ------------------------------------------------ the tile plan, as counts
+@pytest.mark.parametrize("S", [1024, 2048, 1000, 384, 4096, 8192])
+@pytest.mark.parametrize("D", [64, 128])
+def test_walk_counts_closed_form(S, D):
+    """Causal: with n = padded S / sub, the walk computes n(n+1)/2 of the
+    n^2 squares and masks the n on the diagonal, whatever the block."""
+    plan = tile_plan(S, D, jnp.bfloat16)
+    sp = -(-S // plan.block) * plan.block
+    n = sp // plan.sub
+    assert sp % plan.block == 0 and plan.block % plan.sub == 0
+    assert plan.span % plan.block == 0 and sp % plan.span == 0
+    assert walk_counts(plan, S, True) == (n * (n + 1) // 2, n, n * n)
+
+
+@pytest.mark.parametrize("S,was,now", [(2048, 0.75, 0.5625),
+                                       (1024, 1.00, 0.625)])
+def test_executed_share_at_the_cells_shapes(S, was, now):
+    """D = 64: the share of the S x S square the walk computes, against the
+    one-level 1024 tiles' (PERF.md section 6, PR 28), and the masked share
+    (was 0.50 and 1.00)."""
+    executed, masked, total = walk_counts(
+        tile_plan(S, 64, jnp.bfloat16), S, True)
+    assert executed / total == now < was
+    assert masked / total == {2048: 0.125, 1024: 0.25}[S]
+
+
+@pytest.mark.parametrize("S", [1024, 1000])
+def test_walk_counts_non_causal(S):
+    """Non-causal: every square computed; only a padded length masks, and
+    then only the last column of squares."""
+    plan = tile_plan(S, 64, jnp.bfloat16)
+    n = 1024 // plan.sub
+    assert walk_counts(plan, S, False) == (n * n, n if S == 1000 else 0,
+                                           n * n)
+
+
+def test_plan_splits_what_does_not_fit_vmem():
+    """S = 32k at D = 128: the forward holds a span of k/v, not the whole,
+    and the backward goes two-pass; the cells' shapes hold everything."""
+    long = tile_plan(32768, 128, jnp.bfloat16)
+    assert long.span < 32768 and not long.resident_bwd
+    for S in (1024, 2048, 8192):
+        plan = tile_plan(S, 128, jnp.bfloat16)
+        assert plan.span == S and plan.resident_bwd

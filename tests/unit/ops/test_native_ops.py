@@ -5,6 +5,8 @@ check the op against a pure-python reference.
 """
 
 import os
+import subprocess
+import sys
 
 import numpy as np
 import pytest
@@ -30,6 +32,26 @@ class TestOpBuilder:
         m1 = os.path.getmtime(p1)
         p2 = b.build()
         assert p1 == p2 and os.path.getmtime(p2) == m1
+
+
+    def test_concurrent_builds_into_an_empty_dir(self, tmp_path):
+        """Several processes build the same library at once into an empty
+        ``DST_BUILD_DIR`` (xdist workers on a fresh tree): each must load
+        it, and none may take another's half-written file."""
+        code = ("from deeperspeed_tpu.op_builder import CPUAdamBuilder; "
+                "lib = CPUAdamBuilder().load(); "
+                "print('loaded', lib.dst_cpu_adam_step is not None)")
+        env = dict(os.environ, DST_BUILD_DIR=str(tmp_path / "build"))
+        procs = [subprocess.Popen([sys.executable, "-c", code], env=env,
+                                  stdout=subprocess.PIPE,
+                                  stderr=subprocess.PIPE, text=True)
+                 for _ in range(4)]
+        for p in procs:
+            out, err = p.communicate(timeout=300)
+            assert p.returncode == 0, err[-2000:]
+            assert "loaded True" in out
+        left = sorted(os.listdir(tmp_path / "build"))
+        assert len(left) == 1 and left[0].endswith(".so"), left
 
 
 class TestAsyncIO:

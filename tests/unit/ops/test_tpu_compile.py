@@ -93,10 +93,43 @@ def _sum_grad(fn, n_diff):
 
 
 # ------------------------------------------------------------------ one chip
-@pytest.mark.parametrize("shape", [(2, 2048, 16, 64), (2, 2048, 32, 128)])
-def test_flash_mha_fwd_bwd(one_chip, shape):
-    q = _sds(shape, jnp.bfloat16, one_chip)
-    _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
+@pytest.mark.parametrize("shape,dtype", [
+    ((2, 2048, 16, 64), jnp.bfloat16),
+    ((2, 2048, 32, 128), jnp.bfloat16),
+    # the benchmark cells' own calls: train-410m, train-160m
+    ((8, 2048, 16, 64), jnp.bfloat16),
+    ((16, 1024, 12, 64), jnp.bfloat16),
+    # heads of 96 (NeoX-20B) and the longest length whose backward still
+    # keeps a head's q side in VMEM; fp32 once; a padded length
+    ((4, 2048, 8, 96), jnp.bfloat16),
+    ((2, 8192, 8, 128), jnp.bfloat16),
+    ((2, 1024, 8, 64), jnp.float32),
+    ((2, 1000, 8, 64), jnp.bfloat16),
+])
+def test_flash_mha_fwd_bwd(one_chip, shape, dtype):
+    """Forward + backward are exactly two kernel calls: what the benchmark's
+    ``flash_attention_roofline`` counts on (one event per forward, one per
+    backward, no third kernel under the scope)."""
+    q = _sds(shape, dtype, one_chip)
+    text = _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
+    assert pallas_flash.tile_plan(shape[1], shape[3], dtype).resident_bwd
+    assert len(_kernel_operand_shapes(text)) == 2
+
+
+def test_flash_mha_long_sequence_two_pass(one_chip):
+    """S = 16k: the forward still holds the whole k/v of a head; the
+    backward's q side no longer fits and it goes two-pass (three calls)."""
+    q = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
+    assert not pallas_flash.tile_plan(16384, 128, jnp.bfloat16).resident_bwd
+    assert len(_kernel_operand_shapes(text)) == 3
+
+
+def test_flash_mha_non_causal(one_chip):
+    q = _sds((2, 1000, 8, 64), jnp.bfloat16, one_chip)
+    fn = functools.partial(pallas_flash.mha, causal=False)
+    assert len(_kernel_operand_shapes(
+        _compile(_sum_grad(fn, 3), q, q, q))) == 2
 
 
 @pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])

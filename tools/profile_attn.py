@@ -1,15 +1,22 @@
-"""Isolate flash-attention kernel timing at bench shape (fwd, bwd, vs XLA).
+"""Time the flash-attention kernels alone on the chip, by shape and tile plan.
+
+For each shape: the forward and forward + backward of the folded
+``[B*N, S, D]`` call (no layout copies), under the plan ``tile_plan`` picks
+and under every ``--plans block:sub:rows`` given, and -- with ``--parent DIR``, a
+second checkout of this repo -- under that checkout's kernel for comparison
+on the same chip in the same process.
 
 Timing uses ``tputime.timed_inner`` (loop inside one jit, ended by
 ``block_until_ready``), so per-dispatch host overhead does not count as
-kernel time.
+kernel time.  TFLOP/s credit the causal half of the square only
+(``tputime.attn_flops``: forward 2 matmul units, forward + backward 7).
 
-FLOP accounting via ``tputime.attn_flops``: flash fwdbwd = 7 matmul units
-(bwd recomputes S/P); the XLA dense path stores P instead of recomputing, so
-its fwdbwd executes ~5 units — both are credited with the work they actually
-run so TFLOPs are comparable as "achieved rate", not "useful-work rate".
+    python tools/profile_attn.py --shapes 8x2048x16x64 16x1024x12x64 \
+        --plans 1024:256:1024 512:128:512 --parent _parent
 """
 
+import argparse
+import importlib.util
 import os
 import sys
 
@@ -21,48 +28,78 @@ import jax.numpy as jnp
 
 from tputime import attn_flops, emit, timed_inner
 
+# the benchmark cells' shapes, then BENCH_KERNELS.md's milestone shapes
+DEFAULT_SHAPES = ["8x2048x16x64", "16x1024x12x64", "4x2048x8x96",
+                  "4x2048x8x128", "2x4096x8x128", "2x8192x8x128"]
+
+
+def _parent_mha(checkout):
+    """Another checkout's kernel module, loaded beside this one's (its
+    relative imports -- ``pallas_utils`` -- resolve to this checkout)."""
+    name = "deeperspeed_tpu.ops.attention._parent_pallas_flash"
+    spec = importlib.util.spec_from_file_location(name, os.path.join(
+        checkout, "deeperspeed_tpu", "ops", "attention", "pallas_flash.py"))
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _time(name, call, x, flops, iters):
+    try:
+        dt = timed_inner(lambda t: call(t, t, t), x, iters=iters)
+        emit(f"{name}_fwd", dt, tflops=round(flops["fwd"] / dt / 1e12, 2))
+        dt = timed_inner(
+            lambda t: jax.grad(lambda u: call(u, u, u).astype(
+                jnp.float32).sum())(t), x, iters=iters)
+        emit(f"{name}_fwdbwd", dt,
+             tflops=round(flops["fwdbwd"] / dt / 1e12, 2))
+    except Exception as e:  # noqa: BLE001 -- a plan the compiler refuses
+        emit(f"{name}_error", error=str(e)[:300])
+
 
 def main():
-    from deeperspeed_tpu.ops.attention.core import _reference_attention
-    from deeperspeed_tpu.ops.attention.flash import flash_attention
-    from deeperspeed_tpu.ops.attention.pallas_flash import mha
+    from deeperspeed_tpu.ops.attention import pallas_flash as pf
 
-    B, S, N, D = 16, 1024, 12, 64
-    q = jax.random.normal(jax.random.PRNGKey(2), (B, S, N, D), jnp.bfloat16)
-    fwd = attn_flops(B, S, N, D, mode="fwd")
-    fwdbwd = attn_flops(B, S, N, D, mode="fwdbwd")
-    dense_fwdbwd = fwd + attn_flops(B, S, N, D, mode="bwd_stored")
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--shapes", nargs="*", default=DEFAULT_SHAPES)
+    ap.add_argument("--plans", nargs="*", default=[],
+                    help="block:sub:rows triples to time beside tile_plan's")
+    ap.add_argument("--parent", default=None)
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--non-causal", action="store_true")
+    ap.add_argument("--iters", type=int, default=20)
+    args = ap.parse_args()
+    dtype, causal = jnp.dtype(args.dtype), not args.non_causal
+    parent = _parent_mha(args.parent) if args.parent else None
 
-    for blk in (256, 512, 1024):
-        dt = timed_inner(
-            lambda x, b=blk: mha(x, x, x, causal=True, block=b), q, iters=30)
-        emit(f"flash_fwd_b{blk}", dt, tflops=round(fwd / dt / 1e12, 1))
-        dt = timed_inner(
-            lambda x, b=blk: jax.grad(lambda t: mha(
-                t, t, t, causal=True, block=b).astype(jnp.float32).sum())(x),
-            q, iters=20)
-        emit(f"flash_fwdbwd_b{blk}", dt, tflops=round(fwdbwd / dt / 1e12, 1))
-
-    dt = timed_inner(
-        lambda x: flash_attention(x, x, x, causal=True, impl="upstream"),
-        q, iters=30)
-    emit("upstream_fwd", dt, tflops=round(fwd / dt / 1e12, 1))
-    dt = timed_inner(
-        lambda x: jax.grad(lambda t: flash_attention(
-            t, t, t, causal=True, impl="upstream").astype(
-                jnp.float32).sum())(x), q, iters=20)
-    emit("upstream_fwdbwd", dt, tflops=round(fwdbwd / dt / 1e12, 1))
-
-    dt = timed_inner(
-        lambda x: _reference_attention(x, x, x, causal=True).astype(
-            jnp.bfloat16), q, iters=20)
-    emit("xla_dense_fwd", dt, tflops=round(fwd / dt / 1e12, 1))
-    dt = timed_inner(
-        lambda x: jax.grad(lambda t: _reference_attention(
-            t, t, t, causal=True).astype(jnp.float32).sum())(x).astype(
-                jnp.bfloat16), q, iters=20)
-    emit("xla_dense_fwdbwd", dt,
-         tflops=round(dense_fwdbwd / dt / 1e12, 1))
+    for shape in args.shapes:
+        B, S, N, D = (int(t) for t in shape.split("x"))
+        x = jax.random.normal(jax.random.PRNGKey(2), (B * N, S, D), dtype)
+        scale = float(D) ** -0.5
+        flops = {m: attn_flops(B, S, N, D, causal, mode=m)
+                 for m in ("fwd", "fwdbwd")}
+        own = pf.tile_plan(S, D, dtype)
+        plans = [("auto", own)]
+        for text in args.plans:
+            block, sub, rows = (int(t) for t in text.split(":"))
+            if S % block == 0:
+                plans.append((text, own._replace(block=block, sub=sub,
+                                                 rows=rows)))
+        for label, plan in plans:
+            executed, masked, total = pf.walk_counts(plan, S, causal)
+            emit(f"{shape}_{label}_plan", **plan._asdict(),
+                 executed_share=round(executed / total, 4),
+                 masked_share=round(masked / total, 4))
+            _time(f"{shape}_{label}",
+                  lambda q, k, v, p=plan: pf._mha(q, k, v, causal, scale, p),
+                  x, flops, args.iters)
+        if parent is not None:
+            s128 = -(-S // 128) * 128
+            blk = next(b for b in (1024, 512, 256, 128) if s128 % b == 0)
+            _time(f"{shape}_parent_b{blk}",
+                  lambda q, k, v: parent._mha(q, k, v, causal, scale, blk),
+                  x, flops, args.iters)
 
 
 if __name__ == "__main__":
