@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
+from ..ops.transformer.cross_entropy import chunked_linear_cross_entropy
 from ..parallel.topology import BATCH_AXES
 
 
@@ -626,10 +627,9 @@ class GPTNeoX(nn.Module):
             """Chunked fused-linear CE (``ce_chunk_tokens`` > 0): the step
             is HBM-bound at bench shapes (XLA cost analysis: 75 GB
             accessed vs 12 TFLOPs -- PROFILE.md round 5), and the single
-            largest tensor is the [B, S, V] logits + fp32 cast.  Scanning
-            head+CE over token chunks keeps only [C, V] logits live;
-            ``jax.checkpoint`` recomputes each chunk's logits in backward
-            so the saved residuals are [C, H] activations, not logits."""
+            largest tensor is the [B, S, V] logits + fp32 cast.  Head + CE
+            run chunk by chunk (``ops/transformer/cross_entropy.py``), each
+            chunk folded into the running (sum, count)."""
             deterministic, rngs, kwargs = _apply_setup(
                 batch, rng, deterministic, random_ltd_tokens)
             hidden = model.apply({"params": params}, batch["input_ids"],
@@ -637,38 +637,19 @@ class GPTNeoX(nn.Module):
                                  return_hidden=True, **kwargs)
             w = params["embed_out"]["kernel"]          # [H, V]
             B, S, H = hidden.shape
-            labels = batch["labels"].reshape(-1)
             mask = batch.get("loss_mask")
             mask = (jnp.ones((B * S,), jnp.float32) if mask is None
                     else mask.reshape(-1).astype(jnp.float32))
-            T = B * S
-            C = min(cfg.ce_chunk_tokens, T)
-            n_chunks = -(-T // C)
-            pad = n_chunks * C - T
-            x = hidden.reshape(T, H)
-            if pad:
-                x = jnp.pad(x, ((0, pad), (0, 0)))
-                labels = jnp.pad(labels, (0, pad))
-                mask = jnp.pad(mask, (0, pad))
-            x = x.reshape(n_chunks, C, H)
-            labels = labels.reshape(n_chunks, C)
-            mask = mask.reshape(n_chunks, C)
 
-            def chunk(carry, op):
+            def fold(carry, token_ll, mc):
                 num, den = carry
-                xc, lc, mc = op
-                logits = (xc @ w.astype(xc.dtype)).astype(jnp.float32)
-                lse = jax.nn.logsumexp(logits, axis=-1)
-                gold = jnp.take_along_axis(logits, lc[:, None],
-                                           axis=-1)[:, 0]
-                num = num + jnp.sum((gold - lse) * mc)
-                den = den + jnp.sum(mc)
-                return (num, den), None
+                return num + jnp.sum(token_ll * mc), den + jnp.sum(mc)
 
             with jax.named_scope("head_ce"):
-                (num, den), _ = jax.lax.scan(
-                    jax.checkpoint(chunk),
-                    (jnp.float32(0.0), jnp.float32(0.0)), (x, labels, mask))
+                num, den = chunked_linear_cross_entropy(
+                    hidden.reshape(B * S, H), w, batch["labels"].reshape(-1),
+                    cfg.ce_chunk_tokens, extras=(mask,), fold=fold,
+                    init=(jnp.float32(0.0), jnp.float32(0.0)))
                 return -num / jnp.maximum(den, 1.0)
 
         if cfg.ce_chunk_tokens > 0:

@@ -181,7 +181,8 @@ class LlamaAttention(nn.Module):
                 return nn.Dense(H, use_bias=False, dtype=cfg.dtype,
                                 name="o_proj")(out.reshape(B, S, H))
 
-        k, v = self._repeat_kv(k), self._repeat_kv(v)
+        with jax.named_scope("attention_layout"):   # GQA's copy of k and v
+            k, v = self._repeat_kv(k), self._repeat_kv(v)
         mask = None
         if cfg.sliding_window is not None:
             qpos = jnp.arange(S)[:, None]
@@ -191,8 +192,10 @@ class LlamaAttention(nn.Module):
             am = attention_mask[:, None, None, :].astype(bool)
             mask = am if mask is None else (mask & am)
         out = dot_product_attention(q, k, v, mask=mask, causal=True)
+        with jax.named_scope("attention_layout"):
+            out = out.reshape(B, S, H)
         return nn.Dense(H, use_bias=False, dtype=cfg.dtype,
-                        name="o_proj")(out.reshape(B, S, H))
+                        name="o_proj")(out)
 
     def _cached(self, q, k, v, attention_mask):
         """v1 engine autoregressive cache (same scheme as GPT-NeoX)."""
@@ -375,13 +378,17 @@ class LlamaBlock(nn.Module):
                  paged_state=None):
         cfg = self.config
         x = maybe_constrain(x, (BATCH_AXES, "sp", None))
-        h = _Norm(cfg, name="input_norm")(x)
-        x = x + LlamaAttention(cfg, decode=self.decode, paged=self.paged,
-                               name="attention")(
-            h, positions, deterministic=deterministic,
-            attention_mask=attention_mask, paged_state=paged_state)
-        h = _Norm(cfg, name="post_attention_norm")(x)
-        x = x + LlamaMLP(cfg, name="mlp")(h)
+        # each sublayer with its norm under the scope a device trace is read
+        # by (PERF.md section 3), as in ``GPTNeoXBlock``
+        with jax.named_scope("attention"):
+            h = _Norm(cfg, name="input_norm")(x)
+            x = x + LlamaAttention(cfg, decode=self.decode, paged=self.paged,
+                                   name="attention")(
+                h, positions, deterministic=deterministic,
+                attention_mask=attention_mask, paged_state=paged_state)
+        with jax.named_scope("mlp"):
+            h = _Norm(cfg, name="post_attention_norm")(x)
+            x = x + LlamaMLP(cfg, name="mlp")(h)
         return maybe_constrain(x, (BATCH_AXES, "sp", None))
 
 
@@ -402,11 +409,12 @@ class Llama(nn.Module):
             positions = jnp.broadcast_to(jnp.arange(S), (B, S))
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
                          name="embed_tokens")
-        x = embed(input_ids).astype(cfg.dtype)
-        if cfg.learned_positions:
-            x = x + nn.Embed(cfg.max_seq_len, cfg.hidden_size,
-                             dtype=jnp.float32,
-                             name="embed_positions")(positions).astype(cfg.dtype)
+        with jax.named_scope("embed"):
+            x = embed(input_ids).astype(cfg.dtype)
+            if cfg.learned_positions:
+                x = x + nn.Embed(
+                    cfg.max_seq_len, cfg.hidden_size, dtype=jnp.float32,
+                    name="embed_positions")(positions).astype(cfg.dtype)
         block = LlamaBlock
         if cfg.remat:
             block = nn.remat(LlamaBlock, static_argnums=(3,))
@@ -414,18 +422,20 @@ class Llama(nn.Module):
             x = block(cfg, decode=self.decode, paged=self.paged,
                       name=f"layers_{i}")(
                 x, positions, deterministic, attention_mask, paged_state)
-        x = _Norm(cfg, name="final_norm")(x)
+        with jax.named_scope("head_ce"):    # the head, from its norm on
+            x = _Norm(cfg, name="final_norm")(x)
         if logits_positions is not None:
             # ragged logits-gather ([B] or [B, R]): see GPTNeoX.__call__
             lp = jnp.asarray(logits_positions, jnp.int32)
             if lp.ndim == 1:
                 lp = lp[:, None]
             x = jnp.take_along_axis(x, lp[..., None], axis=1)
-        if cfg.tie_embeddings:
-            logits = embed.attend(x.astype(jnp.float32))
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                              name="lm_head")(x)
+        with jax.named_scope("head_ce"):
+            if cfg.tie_embeddings:
+                logits = embed.attend(x.astype(jnp.float32))
+            else:
+                logits = nn.Dense(cfg.vocab_size, use_bias=False,
+                                  dtype=cfg.dtype, name="lm_head")(x)
         return logits
 
     # ---------------------------------------------------- engine API
@@ -444,12 +454,13 @@ class Llama(nn.Module):
             logits = model.apply({"params": params}, batch["input_ids"],
                                  deterministic=rng is None)
             labels = batch["labels"]
-            logits = logits.astype(jnp.float32)
-            lse = jax.nn.logsumexp(logits, axis=-1)
-            ll = jnp.take_along_axis(logits, labels[..., None],
-                                     axis=-1)[..., 0] - lse
-            mask = batch.get("loss_mask", jnp.ones_like(ll))
-            return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+            with jax.named_scope("head_ce"):   # the head GEMM is in it too
+                logits = logits.astype(jnp.float32)
+                lse = jax.nn.logsumexp(logits, axis=-1)
+                ll = jnp.take_along_axis(logits, labels[..., None],
+                                         axis=-1)[..., 0] - lse
+                mask = batch.get("loss_mask", jnp.ones_like(ll))
+                return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
 
         return loss
 

@@ -28,8 +28,8 @@ from ..accelerator import get_accelerator
 from ..monitor.monitor import MonitorMaster
 from ..parallel import topology as topo
 from ..telemetry.trace import (TraceSessionWatch, compile_stats,
-                               publish_step_scopes, span, step_scopes,
-                               step_span)
+                               publish_step_counters, publish_step_scopes,
+                               span, step_scopes, step_span)
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -775,7 +775,7 @@ class DeeperSpeedEngine:
             off is not None and off.wire_dtype == "bf16") else jnp.float32
 
         def gs(params, batch, rng, step):
-            grads, loss = self._grads_for_batch(
+            grads, loss, _ = self._grads_for_batch(
                 params, batch, rng, jnp.float32(1.0),
                 ltd_tokens=ltd_tokens, step=step)
             with jax.named_scope("grad_norm_clip"):
@@ -1251,11 +1251,17 @@ class DeeperSpeedEngine:
                                      random_ltd_tokens=ltd_tokens)
             else:
                 loss = self._loss_fn(p, microbatch, rng)
+            # a model may return (loss, {name: number}): what its step says
+            # of itself (a looped model's exit shares and counters)
+            stats = {}
             if isinstance(loss, tuple):
+                if len(loss) > 1 and isinstance(loss[1], dict):
+                    stats = loss[1]
                 loss = loss[0]
-            return (loss * scale).astype(jnp.float32), loss
+            return (loss * scale).astype(jnp.float32), (loss, stats)
 
-        (_, loss), grads = jax.value_and_grad(scaled_loss, has_aux=True)(params)
+        (_, (loss, stats)), grads = jax.value_and_grad(
+            scaled_loss, has_aux=True)(params)
         # communication_data_type (reference ``engine.py:1142-1144``): the
         # cross-replica grad reduction runs in this dtype -- XLA places the
         # psum/reduce-scatter where the grad's sharded layout is demanded,
@@ -1264,7 +1270,7 @@ class DeeperSpeedEngine:
         wire = self.precision.reduce_dtype or self.precision.accum_dtype
         with jax.named_scope("grad_accumulate"):
             grads = tree_cast(grads, wire)
-        return loss, grads
+        return loss, grads, stats
 
     def _grad_reduce_plan(self, master):
         """Per-leaf (collective, dim, axes) for the dp grad reduction --
@@ -1316,22 +1322,24 @@ class DeeperSpeedEngine:
 
     def _grads_for_batch(self, master, batch, rng, scale, ltd_tokens=None,
                          step=None):
-        """Mean-loss grads (still multiplied by ``scale``) over gas microbatches.
+        """Mean-loss grads (still multiplied by ``scale``) over gas
+        microbatches -> (grads, mean loss, the model's own numbers of the
+        step averaged over the microbatches: {} from a model that reports
+        none, and on the deferred and pipeline paths).
 
         Subclasses re-express this: the pipeline engine replaces the microbatch
         scan with the compiled pipeline over the pp axis."""
         gas = self.gradient_accumulation_steps()
         if self._deferred_reduce:
-            return self._grads_for_batch_deferred(master, batch, rng, scale,
-                                                  ltd_tokens=ltd_tokens)
+            return (*self._grads_for_batch_deferred(
+                master, batch, rng, scale, ltd_tokens=ltd_tokens), {})
         self._record_grad_reduce_wire(master, gas)
 
         def micro(carry, mb):
             acc = carry
             sub_rng = jax.random.fold_in(rng, acc[1])
-            loss, grads = self._micro_loss_and_grads(master, mb, sub_rng, scale,
-                                                     ltd_tokens=ltd_tokens,
-                                                     step=step)
+            loss, grads, stats = self._micro_loss_and_grads(
+                master, mb, sub_rng, scale, ltd_tokens=ltd_tokens, step=step)
             # reduction happens into this constrained layout, in the wire
             # dtype chosen by _micro_loss_and_grads; accumulate in accum_dtype
             with jax.named_scope("grad_accumulate"):
@@ -1340,7 +1348,7 @@ class DeeperSpeedEngine:
                         grads, self.grad_shardings)
                 grads = tree_cast(grads, self.precision.accum_dtype)
                 new_acc = jax.tree_util.tree_map(jnp.add, acc[0], grads)
-            return (new_acc, acc[1] + 1), loss
+            return (new_acc, acc[1] + 1), (loss, stats)
 
         with jax.named_scope("grad_accumulate"):
             zero_grads = jax.tree_util.tree_map(
@@ -1348,10 +1356,13 @@ class DeeperSpeedEngine:
                 master)
             zero_grads = jax.lax.with_sharding_constraint(
                 zero_grads, self.grad_shardings)
-        (grads, _), losses = jax.lax.scan(micro, (zero_grads, jnp.int32(0)), batch)
+        (grads, _), (losses, stats) = jax.lax.scan(
+            micro, (zero_grads, jnp.int32(0)), batch)
         with jax.named_scope("grad_accumulate"):
             grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
-        return grads, jnp.mean(losses)
+        stats = jax.tree_util.tree_map(
+            lambda s: jnp.mean(s.astype(jnp.float32), axis=0), stats)
+        return grads, jnp.mean(losses), stats
 
     def _grads_for_batch_deferred(self, master, batch, rng, scale,
                                   ltd_tokens=None):
@@ -1720,14 +1731,14 @@ class DeeperSpeedEngine:
             master = dev["master_params"]
             scale = state["loss_scale"].scale if fp16 is not None else jnp.float32(1.0)
 
-            new_error = None
+            new_error, model_stats = None, {}
             if self._onebit:
                 grads, loss_mean, new_error = self._grads_for_batch_onebit(
                     master, batch, rng, state["onebit_error"], state["step"])
             elif self._qgz:
                 grads, loss_mean = self._grads_for_batch_qgz(master, batch, rng)
             else:
-                grads, loss_mean = self._grads_for_batch(
+                grads, loss_mean, model_stats = self._grads_for_batch(
                     master, batch, rng, scale, ltd_tokens=ltd_tokens,
                     step=state["step"])
             grads, overflow, grad_norm = self._unscale_and_clip(
@@ -1744,6 +1755,8 @@ class DeeperSpeedEngine:
                 "overflow": overflow,
                 "loss_scale": new_scale.scale,
             }
+            if model_stats:
+                metrics["model"] = model_stats
             return new_state, metrics
 
         return self._schedule_jit(
@@ -1775,7 +1788,7 @@ class DeeperSpeedEngine:
 
         def micro_step(state, microbatch, rng):
             scale = state["loss_scale"].scale if self.precision.is_fp16 else jnp.float32(1.0)
-            loss, grads = self._micro_loss_and_grads(
+            loss, grads, _ = self._micro_loss_and_grads(
                 self._materialize_state(state)["master_params"], microbatch,
                 rng, scale, step=state["step"]
             )
@@ -1801,7 +1814,7 @@ class DeeperSpeedEngine:
                 {**state, "opt_state": None})["master_params"]
             scale = (state["loss_scale"].scale if fp16 is not None
                      else jnp.float32(1.0))
-            grads, loss_mean = self._grads_for_batch(
+            grads, loss_mean, _ = self._grads_for_batch(
                 master, batch, rng, scale, ltd_tokens=ltd_tokens,
                 step=state["step"])
             # hand the device-resident master to the apply half too: the
@@ -2094,6 +2107,9 @@ class DeeperSpeedEngine:
             self.micro_steps += self.gradient_accumulation_steps()
             self.global_samples += self.train_batch_size()
         self._last_metrics = metrics
+        if "model" in metrics:
+            # kept as device arrays: whoever asks ``step_counters()`` waits
+            publish_step_counters("train_step", metrics["model"])
         if self.precision.is_fp16 and not rolled_back:
             with span("train/readback"):
                 overflow = bool(metrics["overflow"])
@@ -2241,6 +2257,11 @@ class DeeperSpeedEngine:
                 util["mfu"], step=step, device_kind=util["device_kind"],
                 n_devices=util["n_devices"])
             tele.scalar("train/mbu").record(util["mbu"], step=step)
+        for name, value in self._last_metrics.get("model", {}).items():
+            # a vector (a looped model's share of each exit) by index
+            for i, x in enumerate(np.atleast_1d(np.asarray(value))):
+                tele.scalar(f"train/model/{name}").record(
+                    float(x), step=step, index=i)
         if self._comm_footprint:
             from ..telemetry.wire import variant_dtype
             total = 0.0

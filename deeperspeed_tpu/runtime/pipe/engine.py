@@ -122,7 +122,7 @@ class PipelineEngine(DeeperSpeedEngine):
             (_, loss), grads = jax.value_and_grad(scaled, has_aux=True)(master)
         grads = tree_cast(grads, self.precision.accum_dtype)
         grads = jax.lax.with_sharding_constraint(grads, self.grad_shardings)
-        return grads, loss
+        return grads, loss, {}
 
     def _record_pipe_wire(self, batch):
         """Trace-time analytic bytes for the stage-to-stage ppermute traffic.

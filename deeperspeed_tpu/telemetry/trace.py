@@ -47,6 +47,7 @@ import uuid
 from collections import deque
 
 import jax
+import numpy as np
 
 from ..utils.logging import logger
 from .registry import JsonlSink, _is_rank0, get_registry
@@ -638,6 +639,27 @@ def publish_step_scopes(hlo_text):
     name = head.group(1) if head else "unknown"
     _STEP_SCOPES[name] = instruction_scopes(hlo_text)
     return name
+
+
+_STEP_COUNTERS = {}
+
+
+def publish_step_counters(program, counters):
+    """Keep what a step program's model reported of its own last step
+    (``{name: device array}``: a looped model's layer and head applications,
+    its exits' shares) under the program's name.  Nothing is read here: the
+    arrays stay on the device until :func:`step_counters` is asked."""
+    _STEP_COUNTERS[program] = counters
+
+
+def step_counters():
+    """``{program name: {counter: number or list}}`` of the last step each
+    program ran: the counters a model returns beside its loss (``(loss,
+    {name: value})``), averaged over the step's microbatches.  Reading waits
+    for that step."""
+    return {program: {name: np.asarray(value).tolist()
+                      for name, value in counters.items()}
+            for program, counters in _STEP_COUNTERS.items()}
 
 
 class TraceSessionWatch:

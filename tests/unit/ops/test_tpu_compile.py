@@ -99,6 +99,8 @@ def _sum_grad(fn, n_diff):
     # the benchmark cells' own calls: train-410m, train-160m
     ((8, 2048, 16, 64), jnp.bfloat16),
     ((16, 1024, 12, 64), jnp.bfloat16),
+    # train-ouro-2.6b-loop4: 2 x 16 heads of 128 folded to [32, 4096, 128]
+    ((2, 4096, 16, 128), jnp.bfloat16),
     # heads of 96 (NeoX-20B) and the longest length whose backward still
     # keeps a head's q side in VMEM; fp32 once; a padded length
     ((4, 2048, 8, 96), jnp.bfloat16),
