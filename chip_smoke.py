@@ -154,12 +154,19 @@ def check_losses(losses):
 # ------------------------------------------------------------------- train
 def phase_train(model, params):
     engine, hlo, facts = run_train(model, params, MICRO_BATCH, zero_stage=0)
+    from deeperspeed_tpu.telemetry import kernel_paths
+
     calls = kernel_calls(hlo)
     problems = check_losses(facts["losses"])
     for scope in ("flash_attention", "fused_norm"):
         if not calls.get(scope):
             problems.append(f"no {scope} Pallas kernel in the step's HLO")
-    emit("train", ok=not problems, problems=problems,
+    # which form of a kernel the traced programs hold: every flash call of
+    # this model should read the projections' layout in place
+    paths = kernel_paths()
+    if set(paths.get("flash_attention", ())) != {"in_place_2"}:
+        problems.append(f"flash attention not in place: {paths}")
+    emit("train", ok=not problems, problems=problems, kernel_paths=paths,
          model="pythia_410m", layers=model.config.num_layers,
          hidden=model.config.hidden_size, seq=SEQ, batch=MICRO_BATCH,
          steps=TRAIN_STEPS, zero_stage=0, dtype="bfloat16",

@@ -10,6 +10,22 @@ FlashAttention-2 style:
 * backward recomputes P = exp(S - LSE) per tile, seeded by
   ``delta = rowsum(dO * O)``.
 
+Layout: the kernels read q, k, v (and dO) and write O, dQ, dK, dV as the
+projections hold them, ``[B, S, N*D]``, with heads addressed as *column
+groups*: the grid runs over ``(batch, group, ...)`` and a block is ``(1,
+rows, width)`` at ``(b, i, g)`` -- whole 128-lane tiles, strided by the row.
+``mha``'s ``[B, S, N, D] -> [B, S, N*D]`` is a reshape of contiguous
+dimensions, so the kernels need no transpose on either side of a call (the
+TPU compiler may still hold a model's 4-D q, k, v in another physical layout
+and pay a lane-dense copy to bring them here: PERF.md section 6, PR 30).  A
+group is one head where D is a multiple of 128, and two heads side by side
+at D = 64: a program then runs both heads' walks on full-width operands, the
+row-side operand zeroed outside the head's own lanes (see ``_own_lanes``).
+Other shapes (``_heads_to_a_block`` says which, from N and D alone) are
+folded to ``[B*N, S, D]`` by a copy each way, and run the same kernels with
+one group a batch row.  The statistics (LSE, and the two-pass backward's
+delta) are the kernels' own: ``[B*N, S, 128]``, a row per head.
+
 Two-level tiling: the block the grid *loads* and the tile the kernel
 *computes* are separate sizes, chosen from the shapes by ``tile_plan``.
 
@@ -46,14 +62,14 @@ Two-level tiling: the block the grid *loads* and the tile the kernel
 At small head dim the matmuls are D-thin (they half-fill the MXU at D = 64)
 and the fp32 softmax ops on each score tile weigh as much, so the structure
 also minimizes VPU work per tile: q is pre-scaled once outside the kernel
-(one [B,S,N,D] multiply) and dq post-scaled symmetrically; the padding mask
+(one elementwise multiply) and dq post-scaled symmetrically; the padding mask
 is compiled out unless the call is non-causal over a padded length (under
 the causal mask a valid row never sees a padded column).
 
 Arbitrary sequence lengths are handled by padding S up to the 128-lane tile
 and masking padded *columns* out of the softmax (padded rows cost dead FLOPs
 but keep >=1 valid column, so no NaNs; their dO is zero so they contribute
-nothing to dK/dV).  LSE is stored lane-replicated ([BN, S, 128] fp32) --
+nothing to dK/dV).  LSE is stored lane-replicated ([B*N, S, 128] fp32) --
 the upstream TPU kernel's idiom -- so the backward reads it as a
 sublane-aligned column with no relayout.
 """
@@ -84,11 +100,31 @@ class Plan(NamedTuple):
     rows: int           # q rows of a backward tile off the edge
     span: int           # k/v rows resident in the forward (multiple of block)
     resident_bwd: bool  # one-kernel backward (q side of a head fits VMEM)
+    group: int          # heads to a lane block of [B, S, N*D]; 0 = folded
+    width: int          # lanes of the block a program loads: group * D, or D
 
 
-def tile_plan(S, D, dtype, block=None):
+def _heads_to_a_block(N, D):
+    """How the kernel addresses heads, from ``(N, D)`` alone: as column
+    groups of the projections' own ``[B, S, N*D]`` layout where those are
+    whole 128-lane blocks -- one head of a multiple of 128, or two of 64
+    side by side -- and else (0) on operands folded to ``[B*N, S, D]``:
+    D = 80 or 96, an odd head count at D = 64 (the local heads under tensor
+    or Ulysses sharding may be), or heads thinner than 64 (four heads'
+    tiles at once overflowed VMEM in the TPU compiler at D = 32, and no
+    model here has such heads)."""
+    if D % LANES == 0:
+        return 1
+    if 2 * D == LANES and N % 2 == 0:
+        return 2
+    return 0
+
+
+def tile_plan(S, D, dtype, block=None, N=1):
     """Tile sizes from what the call can see.  ``block`` overrides the
-    owner block (tests); everything else follows from the shapes.
+    owner block (tests); everything else follows from the shapes: the tiles
+    from S and D, the layout the kernels take (``group``, ``width``) from
+    the head count N and D alone (``_heads_to_a_block``).
 
     Measured on the v5e for causal bf16 at D = 64, S = 1024 / 2048 (the
     benchmark's cells) and at D = 96 / 128, S = 2048-8192 (PERF.md section 6,
@@ -109,20 +145,25 @@ def tile_plan(S, D, dtype, block=None):
     # finest; thin heads gain more from the skip, fat ones from the width
     sub = min(block, 256 if D <= 64 else 512)
     rows = min(block, 512)
+    group = _heads_to_a_block(N, D)
+    heads = max(group, 1)
+    width = heads * D
     # forward: k and v of a span, double-buffered
     n = sp // block
     cps = next(c for c in range(n, 0, -1)
                if n % c == 0
-               and 4 * c * block * D * itemsize <= _VMEM_BUDGET // 2)
+               and 4 * c * block * width * itemsize <= _VMEM_BUDGET // 2)
     return Plan(block, sub, rows, cps * block,
-                _bwd_resident_bytes(sp, D, itemsize) <= _VMEM_BUDGET)
+                _bwd_resident_bytes(sp, width, itemsize, heads)
+                <= _VMEM_BUDGET, group, width)
 
 
-def _bwd_resident_bytes(sp, d, itemsize):
-    """VMEM the one-kernel backward holds per head: q, do, o and lse
-    double-buffered, the fp32 dq accumulator, and the dq output block."""
-    return (2 * 3 * sp * d * itemsize + 2 * sp * LANES * 4
-            + sp * d * 4 + 2 * sp * d * itemsize)
+def _bwd_resident_bytes(sp, w, itemsize, heads=1):
+    """VMEM the one-kernel backward holds per program: q, do, o and each
+    head's lse double-buffered, the fp32 dq accumulator, the dq output
+    block."""
+    return (2 * 3 * sp * w * itemsize + 2 * heads * sp * LANES * 4
+            + sp * w * 4 + 2 * sp * w * itemsize)
 
 
 def _edge_tiles(block, sub, causal):
@@ -208,14 +249,49 @@ def _walk(lo, hi, chunk):
     jax.lax.fori_loop(lo, hi, lambda c, carry: (chunk(c), carry)[1], 0)
 
 
+# ------------------------------------------- heads side by side in a block
+# A program whose block holds ``heads`` > 1 heads (D < 128) runs each head's
+# walk on full-width operands: the row-side operand (q, do) is zeroed
+# outside the head's own lanes, so a contraction over the block's 128 lanes
+# is the head's own over its D, and a product with it as the right-hand side
+# lands in the head's lanes of a 128-wide accumulator with zeros elsewhere.
+# At D = 64 a matmul half-fills the 128-deep MXU either way, so the passes
+# are the same, and every fp32 accumulator and output is lane-dense.  (Static
+# lane slices of the refs, each head on [rows, 64] operands, were measured
+# 3-9 % slower, kernel alone: BENCH_KERNELS.md, last section.)
+def _own_lanes(x, h, heads):
+    """``x`` with the lanes of the other heads of its block zeroed."""
+    if heads == 1:
+        return x
+    d = x.shape[-1] // heads
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    return jnp.where((lane >= h * d) & (lane < (h + 1) * d), x,
+                     jnp.zeros_like(x))
+
+
+def _each_its_lanes(parts, width):
+    """One ``[rows, width]`` value that holds, in each head's lanes, that
+    head's part (``[rows, width]``, or a ``[rows, 1]`` column)."""
+    if len(parts) == 1:
+        return jnp.broadcast_to(parts[0], (parts[0].shape[0], width))
+    d = width // len(parts)
+    lane = jax.lax.broadcasted_iota(
+        jnp.int32, (parts[0].shape[0], width), 1)
+    out = parts[-1]
+    for h in range(len(parts) - 2, -1, -1):
+        out = jnp.where(lane < (h + 1) * d, parts[h], out)
+    return out
+
+
 # --------------------------------------------------------------------- fwd
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                causal, pad, s_valid, block, sub, n):
-    """One q block against one resident span of its head's k/v.  With one
+                causal, pad, s_valid, block, sub, n, heads):
+    """One q block against one resident span of its heads' k/v.  With one
     block to the head (``n == 1``) a tile is its rows' whole softmax: no
     running statistics, no scratch."""
-    qi, kj = pl.program_id(1), pl.program_id(2)
+    qi, kj = pl.program_id(2), pl.program_id(3)
     cps = k_ref.shape[1] // block          # chunks per span
+    width = q_ref.shape[2]
     if n > 1:
         m_scr, l_scr, acc_scr = scratch
 
@@ -230,36 +306,45 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
         the q block) by ``ncols`` columns from ``col0`` (within the span):
         one update of the row statistics whatever the width."""
         rows = pl.ds(row0, sub)
-        q = q_ref[0, rows, :]
         pieces = [(pl.ds(col0 + c0, nc), masked)
                   for c0, nc, masked in _pieces(ncols, sub, on_edge)]
-        # q arrives pre-scaled; no per-tile scale multiply
-        ss = [jax.lax.dot_general(q, k_ref[0, cols, :], _NT,
-                                  preferred_element_type=jnp.float32)
-              for cols, _ in pieces]
-        ss = [_mask_edge(s, causal, n * block - sub, s_valid) if masked else s
-              for s, (_, masked) in zip(ss, pieces)]
-        m_new = functools.reduce(
-            jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in ss])
-        if n > 1:
-            m_prev = m_scr[rows, :1]
-            m_new = jnp.maximum(m_prev, m_new)
-        ps = [jnp.exp(s - m_new) for s in ss]
-        l_new = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
-        acc = sum(
-            jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, cols, :],
-                                _NN, preferred_element_type=jnp.float32)
-            for p, (cols, _) in zip(ps, pieces))
+        accs, alphas = [], []
+        for h in range(heads):
+            # q arrives pre-scaled; no per-tile scale multiply
+            q = _own_lanes(q_ref[0, rows, :], h, heads)
+            ss = [jax.lax.dot_general(q, k_ref[0, cols, :], _NT,
+                                      preferred_element_type=jnp.float32)
+                  for cols, _ in pieces]
+            ss = [_mask_edge(s, causal, n * block - sub, s_valid)
+                  if masked else s for s, (_, masked) in zip(ss, pieces)]
+            m_new = functools.reduce(
+                jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in ss])
+            if n > 1:
+                m_prev = m_scr[h, rows, :1]
+                m_new = jnp.maximum(m_prev, m_new)
+            ps = [jnp.exp(s - m_new) for s in ss]
+            l_new = sum(jnp.sum(p, axis=1, keepdims=True) for p in ps)
+            acc = sum(
+                jax.lax.dot_general(p.astype(v_ref.dtype), v_ref[0, cols, :],
+                                    _NN, preferred_element_type=jnp.float32)
+                for p, (cols, _) in zip(ps, pieces))
+            if n == 1:
+                accs.append(acc / l_new)
+                lse_ref[h, rows, :] = jnp.broadcast_to(
+                    m_new + jnp.log(l_new), (sub, LANES))
+                continue
+            alpha = jnp.exp(m_prev - m_new)
+            accs.append(acc)
+            alphas.append(alpha)
+            m_scr[h, rows, :] = jnp.broadcast_to(m_new, (sub, LANES))
+            l_scr[h, rows, :] = jnp.broadcast_to(
+                l_scr[h, rows, :1] * alpha + l_new, (sub, LANES))
+        acc = _each_its_lanes(accs, width)
         if n == 1:
-            o_ref[0, rows, :] = (acc / l_new).astype(o_ref.dtype)
-            lse_ref[0, rows, :] = jnp.broadcast_to(m_new + jnp.log(l_new),
-                                                   (sub, LANES))
-            return
-        alpha = jnp.exp(m_prev - m_new)
-        acc_scr[rows, :] = acc_scr[rows, :] * alpha + acc
-        m_scr[rows, :] = jnp.broadcast_to(m_new, (sub, LANES))
-        l_scr[rows, :] = jnp.broadcast_to(l_scr[rows, :1] * alpha + l_new,
-                                          (sub, LANES))
+            o_ref[0, rows, :] = acc.astype(o_ref.dtype)
+        else:
+            acc_scr[rows, :] = (acc_scr[rows, :]
+                                * _each_its_lanes(alphas, width) + acc)
 
     def interior(c):
         col0 = pl.multiple_of((c - kj * cps) * block, block)
@@ -281,21 +366,22 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     _walk(kj * cps, jnp.minimum((kj + 1) * cps, edge), interior)
     pl.when(edge // cps == kj)(edge_chunk)
 
-    @pl.when(kj == pl.num_programs(2) - 1)
+    @pl.when(kj == pl.num_programs(3) - 1)
     def _finalize():
-        l = l_scr[:, :1]
+        l = _each_its_lanes([l_scr[h, :, :1] for h in range(heads)], width)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
+        lse_ref[:] = m_scr[:] + jnp.log(l_scr[:])
 
 
 # ---------------------------------------------------------------------- bwd
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *dq_scr,
-                causal, pad, s_valid, block, sub, rows, n):
-    """One k/v block against its head's resident q side: dk and dv of the
-    block, and the block's share of the head's dq (all of it when the head
+                causal, pad, s_valid, block, sub, rows, n, heads):
+    """One k/v block against its heads' resident q side: dk and dv of the
+    block, and the block's share of the heads' dq (all of it when a head
     is one block, ``n == 1``: then dq needs no accumulator)."""
-    kj = pl.program_id(1)
+    kj = pl.program_id(2)
+    width = q_ref.shape[2]
     if n > 1:
         dq_scr, = dq_scr
 
@@ -310,36 +396,41 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         """``nrows`` q rows from ``row0`` (within the head) against the
         first ``ncols`` columns of the k/v block."""
         rows = _ds(row0, nrows)
-        q, do = q_ref[0, rows, :], do_ref[0, rows, :]
-        lse = lse_ref[0, rows, :1]
-        # delta = rowsum(dO * O), recomputed per tile: [nrows, D] of work
-        # beside the tile's [nrows, ncols]
-        delta = jnp.sum(do.astype(jnp.float32)
-                        * o_ref[0, rows, :].astype(jnp.float32),
-                        axis=1, keepdims=True)
-        dq = None
-        for c0, nc, masked in _pieces(ncols, sub, on_edge):
-            cols = pl.ds(c0, nc)
-            k, v = k_ref[0, cols, :], v_ref[0, cols, :]
-            s = jax.lax.dot_general(q, k, _NT,
-                                    preferred_element_type=jnp.float32)
-            if masked:
-                s = _mask_edge(s, causal, n * block - sub, s_valid)
-            p = jnp.exp(s - lse)
-            # dV += P^T dO   (contracting the q rows)
-            dv_scr[cols, :] += jax.lax.dot_general(
-                p.astype(do.dtype), do, _TN,
-                preferred_element_type=jnp.float32)
-            dp = jax.lax.dot_general(do, v, _NT,
-                                     preferred_element_type=jnp.float32)
-            ds = (p * (dp - delta)).astype(q.dtype)
-            # dK += dS^T Q
-            dk_scr[cols, :] += jax.lax.dot_general(
-                ds, q, _TN, preferred_element_type=jnp.float32)
-            part = jax.lax.dot_general(ds, k, _NN,
-                                       preferred_element_type=jnp.float32)
-            dq = part if dq is None else dq + part
-        # dQ = dS K: this block's columns' share, into the head's accumulator
+        dqs = []
+        for h in range(heads):
+            q = _own_lanes(q_ref[0, rows, :], h, heads)
+            do = _own_lanes(do_ref[0, rows, :], h, heads)
+            lse = lse_ref[h, rows, :1]
+            # delta = rowsum(dO * O), recomputed per tile: [nrows, D] of
+            # work beside the tile's [nrows, ncols]
+            delta = jnp.sum(do.astype(jnp.float32)
+                            * o_ref[0, rows, :].astype(jnp.float32),
+                            axis=1, keepdims=True)
+            dq = None
+            for c0, nc, masked in _pieces(ncols, sub, on_edge):
+                cols = pl.ds(c0, nc)
+                k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+                s = jax.lax.dot_general(q, k, _NT,
+                                        preferred_element_type=jnp.float32)
+                if masked:
+                    s = _mask_edge(s, causal, n * block - sub, s_valid)
+                p = jnp.exp(s - lse)
+                # dV += P^T dO   (contracting the q rows)
+                dv_scr[cols, :] += jax.lax.dot_general(
+                    p.astype(do.dtype), do, _TN,
+                    preferred_element_type=jnp.float32)
+                dp = jax.lax.dot_general(do, v, _NT,
+                                         preferred_element_type=jnp.float32)
+                ds = (p * (dp - delta)).astype(q.dtype)
+                # dK += dS^T Q
+                dk_scr[cols, :] += jax.lax.dot_general(
+                    ds, q, _TN, preferred_element_type=jnp.float32)
+                part = jax.lax.dot_general(ds, k, _NN,
+                                           preferred_element_type=jnp.float32)
+                dq = part if dq is None else dq + part
+            dqs.append(dq)
+        # dQ = dS K: this block's columns' share, into the accumulator
+        dq = _each_its_lanes(dqs, width)
         if n == 1:
             dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
         else:
@@ -373,26 +464,32 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 # ------------------------------------------------- two-pass bwd (long S only)
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, causal, pad, s_valid, bq, bk):
-    qi, ki = pl.program_id(1), pl.program_id(2)
-    nk = pl.num_programs(2)
+               dq_scr, *, causal, pad, s_valid, bq, bk, heads):
+    qi, ki = pl.program_id(2), pl.program_id(3)
+    nk = pl.num_programs(3)
 
     @pl.when(ki == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
     def _tile(masked):
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(q, k, _NT,
-                                preferred_element_type=jnp.float32)
-        if masked:
-            s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dp = jax.lax.dot_general(do, v, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = p * (dp - delta_ref[0][:, :1])
-        dq_scr[:] += jax.lax.dot_general(
-            ds.astype(k.dtype), k, _NN, preferred_element_type=jnp.float32)
+        k, v = k_ref[0], v_ref[0]
+        dqs = []
+        for h in range(heads):
+            q = _own_lanes(q_ref[0], h, heads)
+            do = _own_lanes(do_ref[0], h, heads)
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32)
+            if masked:
+                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
+            p = jnp.exp(s - lse_ref[h][:, :1])
+            dp = jax.lax.dot_general(do, v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = p * (dp - delta_ref[h][:, :1])
+            dqs.append(jax.lax.dot_general(
+                ds.astype(k.dtype), k, _NN,
+                preferred_element_type=jnp.float32))
+        dq_scr[:] += _each_its_lanes(dqs, dq_scr.shape[1])
 
     if causal:
         pl.when(ki < qi)(lambda: _tile(False))
@@ -407,9 +504,9 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, pad, s_valid, bq, bk):
-    ki, qi = pl.program_id(1), pl.program_id(2)
-    nq = pl.num_programs(2)
+                *, causal, pad, s_valid, bq, bk, heads):
+    ki, qi = pl.program_id(2), pl.program_id(3)
+    nq = pl.num_programs(3)
 
     @pl.when(qi == 0)
     def _init():
@@ -417,19 +514,23 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_scr[:] = jnp.zeros_like(dv_scr)
 
     def _tile(masked):
-        q, k, v, do = q_ref[0], k_ref[0], v_ref[0], do_ref[0]
-        s = jax.lax.dot_general(q, k, _NT,
-                                preferred_element_type=jnp.float32)
-        if masked:
-            s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
-        p = jnp.exp(s - lse_ref[0][:, :1])
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v, _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_ref[0][:, :1])).astype(q.dtype)
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, _TN, preferred_element_type=jnp.float32)
+        k, v = k_ref[0], v_ref[0]
+        for h in range(heads):
+            q = _own_lanes(q_ref[0], h, heads)
+            do = _own_lanes(do_ref[0], h, heads)
+            s = jax.lax.dot_general(q, k, _NT,
+                                    preferred_element_type=jnp.float32)
+            if masked:
+                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
+            p = jnp.exp(s - lse_ref[h][:, :1])
+            dv_scr[:] += jax.lax.dot_general(
+                p.astype(do.dtype), do, _TN,
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_ref[h][:, :1])).astype(q.dtype)
+            dk_scr[:] += jax.lax.dot_general(
+                ds, q, _TN, preferred_element_type=jnp.float32)
 
     if causal:
         pl.when(qi > ki)(lambda: _tile(False))
@@ -444,6 +545,10 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 # ------------------------------------------------------------------ calls
+# Operands are ``[B', S, G * width]``: ``G`` column groups of ``width``
+# lanes, each ``heads`` heads side by side.  In place B' = B and
+# G = N / heads; folded B' = B * N and G = 1.  The statistics (lse, delta)
+# are the kernels' own, ``[B' * G * heads, S, 128]``: a row per head.
 def _pad_seq(x, block):
     s = x.shape[1]
     sp = -(-s // block) * block
@@ -463,9 +568,9 @@ def _vmem_limit(need):
 
 
 def _params(*semantics, vmem=None):
-    """Mosaic grid annotations: the batch*head axis and the axis of owner
-    blocks are independent; an axis that carries a scratch accumulator
-    from step to step is "arbitrary" (sequential)."""
+    """Mosaic grid annotations: the batch axis, the axis of column groups
+    and the axis of owner blocks are independent; an axis that carries a
+    scratch accumulator from step to step is "arbitrary" (sequential)."""
     from jax.experimental.pallas import tpu as pltpu
 
     return dict(compiler_params=pltpu.CompilerParams(
@@ -473,136 +578,144 @@ def _params(*semantics, vmem=None):
 
 
 def _fwd_call(q, k, v, causal, s_valid, plan):
-    bn, sp, d = q.shape
-    block, span = plan.block, plan.span
-    n = sp // block
+    b, sp, hw = q.shape
+    block, span, w = plan.block, plan.span, plan.width
+    heads, groups, n = max(plan.group, 1), hw // w, sp // block
     from jax.experimental.pallas import tpu as pltpu
 
     if causal:
         # a span wholly above the diagonal is not walked: name the last
         # needed one again, so it is not loaded either
-        def kv_index(b, i, j):
-            return (b, jnp.minimum(j, (i * block) // span), 0)
+        def kv_index(b, g, i, j):
+            return (b, jnp.minimum(j, (i * block) // span), g)
     else:
-        def kv_index(b, i, j):
-            return (b, j, 0)
+        def kv_index(b, g, i, j):
+            return (b, j, g)
 
     itemsize = q.dtype.itemsize
-    need = (4 * span * d * itemsize             # k, v, double-buffered
-            + 4 * block * d * itemsize          # q, o
-            + 4 * block * LANES * 4             # lse out; m, l
-            + block * d * 4                     # acc
+    need = (4 * span * w * itemsize             # k, v, double-buffered
+            + 4 * block * w * itemsize          # q, o
+            + 4 * heads * block * LANES * 4     # lse out; m, l
+            + block * w * 4                     # acc
             + 3 * plan.sub * block * 4)         # a score tile, its exp, slack
     kernel = functools.partial(
         _fwd_kernel, causal=causal, pad=s_valid != sp, s_valid=s_valid,
-        block=block, sub=plan.sub, n=n)
+        block=block, sub=plan.sub, n=n, heads=heads)
+    owned = pl.BlockSpec((1, block, w), lambda b, g, i, j: (b, i, g))
     o, lse = pl.pallas_call(
         kernel,
-        grid=(bn, n, sp // span),
-        in_specs=[
-            pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, span, d), kv_index),
-            pl.BlockSpec((1, span, d), kv_index),
-        ],
+        grid=(b, groups, n, sp // span),
+        in_specs=[owned,
+                  pl.BlockSpec((1, span, w), kv_index),
+                  pl.BlockSpec((1, span, w), kv_index)],
         out_specs=[
-            pl.BlockSpec((1, block, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block, LANES), lambda b, i, j: (b, i, 0)),
+            owned,
+            pl.BlockSpec((heads, block, LANES),
+                         lambda b, g, i, j: (b * groups + g, i, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bn, sp, d), q.dtype),
-            jax.ShapeDtypeStruct((bn, sp, LANES), jnp.float32),
+            jax.ShapeDtypeStruct((b, sp, hw), q.dtype),
+            jax.ShapeDtypeStruct((b * groups * heads, sp, LANES),
+                                 jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block, LANES), jnp.float32),
-            pltpu.VMEM((block, LANES), jnp.float32),
-            pltpu.VMEM((block, d), jnp.float32),
+            pltpu.VMEM((heads, block, LANES), jnp.float32),
+            pltpu.VMEM((heads, block, LANES), jnp.float32),
+            pltpu.VMEM((block, w), jnp.float32),
         ] if n > 1 else [],
         interpret=interpret_mode(),
-        **_params("parallel", "parallel", "arbitrary",
+        **_params("parallel", "parallel", "parallel", "arbitrary",
                   vmem=_vmem_limit(need)),
     )(q, k, v)
     return o, lse
 
 
 def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
-    """One-kernel backward: grid (head, k/v block), the q side resident."""
-    bn, sp, d = q.shape
-    block = plan.block
-    n = sp // block
+    """One-kernel backward: grid (batch, column group, k/v block), the q
+    side resident."""
+    b, sp, hw = q.shape
+    block, w = plan.block, plan.width
+    heads, groups, n = max(plan.group, 1), hw // w, sp // block
     from jax.experimental.pallas import tpu as pltpu
 
-    head = pl.BlockSpec((1, sp, d), lambda b, j: (b, 0, 0))
-    head_stat = pl.BlockSpec((1, sp, LANES), lambda b, j: (b, 0, 0))
-    owned = pl.BlockSpec((1, block, d), lambda b, j: (b, j, 0))
-    out = jax.ShapeDtypeStruct((bn, sp, d), q.dtype)
+    head = pl.BlockSpec((1, sp, w), lambda b, g, j: (b, 0, g))
+    head_stat = pl.BlockSpec((heads, sp, LANES),
+                             lambda b, g, j: (b * groups + g, 0, 0))
+    owned = pl.BlockSpec((1, block, w), lambda b, g, j: (b, j, g))
+    out = jax.ShapeDtypeStruct((b, sp, hw), q.dtype)
     itemsize = q.dtype.itemsize
-    need = (_bwd_resident_bytes(sp, d, itemsize)
-            + 8 * block * d * itemsize          # k, v, dk, dv
-            + 2 * block * d * 4                 # dk, dv accumulators
+    need = (_bwd_resident_bytes(sp, w, itemsize, heads)
+            + 8 * block * w * itemsize          # k, v, dk, dv
+            + 2 * block * w * 4                 # dk, dv accumulators
             # s, p, dp, ds of the tallest tile, and their low-precision casts
             + 5 * max(plan.rows, plan.sub) * block * 4)
     return pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, pad=s_valid != sp,
                           s_valid=s_valid, block=block, sub=plan.sub,
-                          rows=plan.rows, n=n),
-        grid=(bn, n),
+                          rows=plan.rows, n=n, heads=heads),
+        grid=(b, groups, n),
         in_specs=[head, owned, owned, head, head, head_stat],
         out_specs=[head, owned, owned],
         out_shape=[out, out, out],
-        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                        pltpu.VMEM((block, d), jnp.float32)]
-        + [pltpu.VMEM((sp, d), jnp.float32)] * (n > 1),
+        scratch_shapes=[pltpu.VMEM((block, w), jnp.float32),
+                        pltpu.VMEM((block, w), jnp.float32)]
+        + [pltpu.VMEM((sp, w), jnp.float32)] * (n > 1),
         interpret=interpret_mode(),
-        **_params("parallel", "arbitrary", vmem=_vmem_limit(need)),
+        **_params("parallel", "parallel", "arbitrary",
+                  vmem=_vmem_limit(need)),
     )(q, k, v, do, o, lse)
 
 
-def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid):
+def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     """dq pass + dk/dv pass over a one-level grid: for lengths whose q side
     does not fit VMEM (``Plan.resident_bwd`` false)."""
-    bn, sp, d = q.shape
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)
-    delta = jnp.broadcast_to(delta, (bn, sp, LANES))
-    bq = bk = next(b for b in (1024, 512, 256, LANES) if sp % b == 0)
+    b, sp, hw = q.shape
+    w = plan.width
+    heads, groups = max(plan.group, 1), hw // w
+    # a row of statistics per head, as the lse has it
+    delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
+                    .reshape(b, sp, groups * heads, w // heads), axis=-1)
+    delta = jnp.broadcast_to(
+        jnp.swapaxes(delta, 1, 2).reshape(b * groups * heads, sp, 1),
+        (b * groups * heads, sp, LANES))
+    bq = bk = next(t for t in (1024, 512, 256, LANES) if sp % t == 0)
     nq, nk = sp // bq, sp // bk
     from jax.experimental.pallas import tpu as pltpu
 
-    pad = s_valid != sp
-    q_spec_i = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
-    k_spec_j = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, j, 0))
-    lse_spec_i = pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0))
+    static = dict(causal=causal, pad=s_valid != sp, s_valid=s_valid,
+                  bq=bq, bk=bk, heads=heads)
+    out = jax.ShapeDtypeStruct((b, sp, hw), q.dtype)
+    q_spec_i = pl.BlockSpec((1, bq, w), lambda b, g, i, j: (b, i, g))
+    k_spec_j = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (b, j, g))
+    stat_i = pl.BlockSpec((heads, bq, LANES),
+                          lambda b, g, i, j: (b * groups + g, i, 0))
 
     dq = pl.pallas_call(
-        functools.partial(_dq_kernel, causal=causal, pad=pad,
-                          s_valid=s_valid, bq=bq, bk=bk),
-        grid=(bn, nq, nk),
-        in_specs=[q_spec_i, k_spec_j, k_spec_j, q_spec_i, lse_spec_i,
-                  lse_spec_i],
+        functools.partial(_dq_kernel, **static),
+        grid=(b, groups, nq, nk),
+        in_specs=[q_spec_i, k_spec_j, k_spec_j, q_spec_i, stat_i, stat_i],
         out_specs=q_spec_i,
-        out_shape=jax.ShapeDtypeStruct((bn, sp, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        out_shape=out,
+        scratch_shapes=[pltpu.VMEM((bq, w), jnp.float32)],
         interpret=interpret_mode(),
-        **_params("parallel", "parallel", "arbitrary"),
+        **_params("parallel", "parallel", "parallel", "arbitrary"),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid's 2nd dim walks k tiles, 3rd dim scans q tiles
-    q_spec_j = pl.BlockSpec((1, bq, d), lambda b, i, j: (b, j, 0))
-    k_spec_i = pl.BlockSpec((1, bk, d), lambda b, i, j: (b, i, 0))
-    lse_spec_j = pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, j, 0))
+    # dk/dv: grid's 3rd dim walks k tiles, 4th dim scans q tiles
+    q_spec_j = pl.BlockSpec((1, bq, w), lambda b, g, i, j: (b, j, g))
+    k_spec_i = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (b, i, g))
+    stat_j = pl.BlockSpec((heads, bq, LANES),
+                          lambda b, g, i, j: (b * groups + g, j, 0))
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, causal=causal, pad=pad,
-                          s_valid=s_valid, bq=bq, bk=bk),
-        grid=(bn, nk, nq),
-        in_specs=[q_spec_j, k_spec_i, k_spec_i, q_spec_j, lse_spec_j,
-                  lse_spec_j],
+        functools.partial(_dkv_kernel, **static),
+        grid=(b, groups, nk, nq),
+        in_specs=[q_spec_j, k_spec_i, k_spec_i, q_spec_j, stat_j, stat_j],
         out_specs=[k_spec_i, k_spec_i],
-        out_shape=[jax.ShapeDtypeStruct((bn, sp, d), q.dtype),
-                   jax.ShapeDtypeStruct((bn, sp, d), q.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        out_shape=[out, out],
+        scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
+                        pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret_mode(),
-        **_params("parallel", "parallel", "arbitrary"),
+        **_params("parallel", "parallel", "parallel", "arbitrary"),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -617,7 +730,7 @@ def _mha(q, k, v, causal, scale, plan):
 def _mha_fwd(q, k, v, causal, scale, plan):
     s_valid = q.shape[1]
     qp, kp, vp = (_pad_seq(t, plan.block) for t in (q, k, v))
-    # pre-scale q once (one [BN, S, D] multiply) instead of scaling every
+    # pre-scale q once (one elementwise multiply) instead of scaling every
     # score tile inside the kernels; dq is post-scaled in _mha_bwd
     qp = qp * jnp.asarray(scale, qp.dtype)
     o, lse = _fwd_call(qp, kp, vp, causal, s_valid, plan)
@@ -629,12 +742,8 @@ def _mha_bwd(causal, scale, plan, res, do):
     qp, kp, vp, o, lse = res
     s_valid = do.shape[1]
     dop = _pad_seq(do, plan.block)
-    if plan.resident_bwd:
-        dq, dk, dv = _bwd_call(qp, kp, vp, dop, o, lse, causal, s_valid,
-                               plan)
-    else:
-        dq, dk, dv = _bwd_call_two_pass(qp, kp, vp, dop, o, lse, causal,
-                                        s_valid)
+    call = _bwd_call if plan.resident_bwd else _bwd_call_two_pass
+    dq, dk, dv = call(qp, kp, vp, dop, o, lse, causal, s_valid, plan)
     # s was computed from the pre-scaled q, so d/dq gains the scale factor
     dq = dq * jnp.asarray(scale, dq.dtype)
     return dq[:, :s_valid], dk[:, :s_valid], dv[:, :s_valid]
@@ -654,14 +763,32 @@ def mha(q, k, v, causal=True, scale=None, block=None):
     Any S (padded to the 128 tile internally); D should be a multiple of 8.
     Differentiable (custom VJP, FlashAttention-2 backward).  Tile sizes come
     from ``tile_plan``; ``block`` overrides the owner block only.
+
+    Where ``tile_plan`` finds that heads are whole lane blocks of the
+    projections' output (``Plan.group``), the kernels take ``[B, S, N*D]``
+    -- a reshape of contiguous dimensions -- and address heads as column
+    groups: nothing is transposed in HBM on either side.  Else the operands
+    are folded to ``[B*N, S, D]`` (a copy each, ``attention_layout`` in a
+    device trace).
     """
+    from ...telemetry.trace import count_kernel_path
+
     B, S, N, D = q.shape
     if scale is None:
         scale = float(D) ** -0.5
-    plan = tile_plan(S, D, q.dtype, block)
+    plan = tile_plan(S, D, q.dtype, block, N)
+    count_kernel_path("flash_attention",
+                      f"in_place_{plan.group}" if plan.group else "folded")
+    if plan.group:
+        # a reshape of contiguous dimensions; whatever the compiler still
+        # pays for it (it may hold a 4-D operand in another physical layout)
+        # is ``attention_layout`` in a device trace, as the folds below are
+        with jax.named_scope("attention_layout"):
+            q, k, v = (t.reshape(B, S, N * D) for t in (q, k, v))
+        o = _mha(q, k, v, causal, float(scale), plan)
+        with jax.named_scope("attention_layout"):
+            return o.reshape(B, S, N, D)
 
-    # the copies on both sides of the kernel, forward and backward, are
-    # ``attention_layout`` in a device trace
     @jax.named_scope("attention_layout")
     def fold(t):
         return jnp.swapaxes(t, 1, 2).reshape(B * N, S, D)

@@ -662,6 +662,24 @@ def step_counters():
             for program, counters in _STEP_COUNTERS.items()}
 
 
+_KERNEL_PATHS = {}
+
+
+def count_kernel_path(kernel, path):
+    """Count one traced call of ``kernel`` on ``path``, where a kernel picks
+    between forms from its operands' shapes (flash attention: ``in_place_2``
+    = two heads to a lane block of ``[B, S, N*D]``, ``in_place_1``,
+    ``folded``).  Counted when a program is traced, not when it runs."""
+    paths = _KERNEL_PATHS.setdefault(kernel, {})
+    paths[path] = paths.get(path, 0) + 1
+
+
+def kernel_paths():
+    """``{kernel: {path: traced calls}}`` of this process so far: says
+    which form of a kernel the programs traced here hold."""
+    return {kernel: dict(paths) for kernel, paths in _KERNEL_PATHS.items()}
+
+
 class TraceSessionWatch:
     """Tells a loop when a profiler session that covered one of its steps
     has ended: ``ended()`` is one ``TraceAnnotation.is_enabled()`` a step,

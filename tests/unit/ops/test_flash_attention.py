@@ -19,6 +19,11 @@ from deeperspeed_tpu.ops.attention.pallas_flash import (mha, tile_plan,
                                                         walk_counts)
 
 
+# the benchmark cells' attention calls: train-410m, train-160m,
+# train-ouro-2.6b-loop4
+CELL_SHAPES = [(8, 2048, 16, 64), (16, 1024, 12, 64), (4, 4096, 16, 128)]
+
+
 def _qkv(B=2, S=256, N=2, D=16, dtype=jnp.float32, seed=0):
     ks = jax.random.split(jax.random.PRNGKey(seed), 3)
     shape = (B, S, N, D)
@@ -95,6 +100,46 @@ def test_fwd_and_grads_wide_heads(D):
 
 
 @pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("B,S,N,D,group", [
+    (2, 256, 4, 64, 2),      # two heads to a 128-lane block
+    (1, 384, 16, 64, 2),     # train-410m's head count, three owner blocks
+    (1, 256, 12, 64, 2),     # train-160m's
+    (1, 1000, 2, 64, 2),     # a padded length: axis 1 of [B, S, N*D]
+    (1, 512, 2, 128, 1),     # a head is a block
+    (1, 256, 2, 256, 1),     # a head is two blocks' worth of lanes
+    (1, 256, 4, 32, 0),      # folded: heads thinner than half a block
+    (1, 256, 2, 96, 0),      # folded: heads are no whole lane blocks
+    (2, 256, 3, 64, 0),      # folded: the last block would be half a head
+], ids=lambda t: str(t))
+def test_fwd_and_grads_by_layout(B, S, N, D, group, causal):
+    """Forward and the three gradients against the fp32 reference where the
+    kernel takes the projections' own ``[B, S, N*D]`` (heads as column
+    groups, ``group`` to a block) and where it folds to ``[B*N, S, D]``."""
+    assert tile_plan(S, D, jnp.float32, N=N).group == group
+    q, k, v = _qkv(S=S, B=B, N=N, D=D)
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=causal),
+        lambda *a: _reference_attention(*a, causal=causal),
+        q, k, v, 3e-5, f"{(B, S, N, D)} causal={causal}")
+
+
+@pytest.mark.parametrize("B,S,N,D", [(1, 1000, 4, 64), (2, 256, 2, 128),
+                                     (1, 256, 3, 64)])
+def test_bf16_fwd_and_grads_by_layout(B, S, N, D):
+    """bf16 through the in-place paths (two heads to a block; a head a
+    block) and the folded one, against the fp32 reference on the same
+    values."""
+    q, k, v = _qkv(S=S, B=B, N=N, D=D, dtype=jnp.bfloat16)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=True),
+        lambda *a: _reference_attention(*map(f32, a), causal=True),
+        q, k, v, 2e-2, f"bf16 {(B, S, N, D)}")
+    assert all(g.dtype == jnp.bfloat16
+               for g in _grads(lambda *a: mha(*a, causal=True), q, k, v))
+
+
+@pytest.mark.parametrize("causal", [True, False])
 def test_grads_when_the_edge_tile_is_the_padded_one(causal):
     """S = 40: one 128-row block whose single tile is on the diagonal, holds
     the padded columns and the padded rows at once."""
@@ -134,6 +179,76 @@ def test_plans_the_cells_do_not_take(changes, causal):
         _folded(1000, 16, causal, **changes),
         lambda *a: _reference_attention(*a, causal=causal),
         q, k, v, 3e-5, f"{changes} causal={causal}")
+
+
+def _in_place(S, N, D, causal=True, **plan_changes):
+    """``_mha`` on ``[B, S, N*D]`` inputs (heads as column groups) under a
+    changed plan."""
+    plan = tile_plan(S, D, jnp.float32, N=N)._replace(**plan_changes)
+    assert plan.group
+
+    def fn(q, k, v):
+        B = q.shape[0]
+        o = pallas_flash._mha(*(t.reshape(B, S, N * D) for t in (q, k, v)),
+                              causal, float(D) ** -0.5, plan)
+        return o.reshape(B, S, N, D)
+    return fn
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("N,D", [(2, 64), (1, 128)], ids=["two_heads", "one"])
+@pytest.mark.parametrize("changes", [
+    dict(resident_bwd=False),                  # two-pass backward (S >= 16k)
+    dict(block=256, sub=128, rows=128, span=256),   # forward over spans
+], ids=["two_pass_bwd", "spans"])
+def test_long_sequence_plans_in_place(changes, N, D, causal):
+    """The paths S of 16k and more take (a forward that holds a span of
+    k/v with running statistics per head, the two-pass backward with a row
+    of ``delta`` per head), forced at S = 1000 on the in-place layout."""
+    q, k, v = _qkv(S=1000, B=2, N=N, D=D)
+    _assert_fwd_and_grads(
+        _in_place(1000, N, D, causal, **changes),
+        lambda *a: _reference_attention(*a, causal=causal),
+        q, k, v, 3e-5, f"{changes} N={N} D={D} causal={causal}")
+
+
+def _primitives(jaxpr):
+    """Names of every primitive of a jaxpr, those of nested jaxprs (a jit,
+    a custom VJP's forward) included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _primitives(inner)
+
+
+@pytest.mark.parametrize("shape,group", list(zip(CELL_SHAPES, (2, 2, 1)))
+                         + [((2, 2048, 8, 96), 0), ((2, 2048, 3, 64), 0),
+                            ((2, 2048, 8, 32), 0), ((2, 2048, 4, 256), 1)],
+                         ids=lambda t: str(t))
+def test_layout_is_decided_from_heads_and_head_dim(shape, group):
+    """At the cells' shapes the program of ``mha`` and of its gradient
+    holds no transpose and one kernel call a pass; ``tile_plan`` says so
+    from (N, D) alone, whatever S; the fallbacks fold (a transpose each
+    way).  A traced call is counted by its path."""
+    from deeperspeed_tpu.telemetry import kernel_paths
+
+    B, S, N, D = shape
+    assert tile_plan(S, D, jnp.bfloat16, N=N).group == group
+    assert {tile_plan(s, D, jnp.float32, N=N).group
+            for s in (128, 1000, 32768)} == {group}
+    path = f"in_place_{group}" if group else "folded"
+    before = kernel_paths().get("flash_attention", {}).get(path, 0)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    fwd = list(_primitives(jax.make_jaxpr(mha)(x, x, x).jaxpr))
+    both = list(_primitives(jax.make_jaxpr(jax.grad(
+        lambda *a: mha(*a).astype(jnp.float32).sum(), (0, 1, 2)))(x, x, x)
+        .jaxpr))
+    assert fwd.count("pallas_call") == 1 and both.count("pallas_call") == 2
+    assert ("transpose" in fwd) == ("transpose" in both) == (group == 0)
+    assert kernel_paths()["flash_attention"][path] == before + 2
 
 
 def test_bf16_forward_close():

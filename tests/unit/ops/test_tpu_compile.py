@@ -99,7 +99,7 @@ def _sum_grad(fn, n_diff):
     # the benchmark cells' own calls: train-410m, train-160m
     ((8, 2048, 16, 64), jnp.bfloat16),
     ((16, 1024, 12, 64), jnp.bfloat16),
-    # train-ouro-2.6b-loop4: 2 x 16 heads of 128 folded to [32, 4096, 128]
+    # train-ouro-2.6b-loop4's heads of 128: a head is a lane block
     ((2, 4096, 16, 128), jnp.bfloat16),
     # heads of 96 (NeoX-20B) and the longest length whose backward still
     # keeps a head's q side in VMEM; fp32 once; a padded length
@@ -107,15 +107,29 @@ def _sum_grad(fn, n_diff):
     ((2, 8192, 8, 128), jnp.bfloat16),
     ((2, 1024, 8, 64), jnp.float32),
     ((2, 1000, 8, 64), jnp.bfloat16),
+    # two heads to a lane block over several owner blocks (running
+    # statistics per head, a dq accumulator), and the folded fallbacks
+    # (heads of 32; an odd head count at D = 64)
+    ((1, 4096, 2, 64), jnp.bfloat16),
+    ((2, 2048, 8, 32), jnp.bfloat16),
+    ((2, 2048, 3, 64), jnp.bfloat16),
 ])
 def test_flash_mha_fwd_bwd(one_chip, shape, dtype):
     """Forward + backward are exactly two kernel calls: what the benchmark's
     ``flash_attention_roofline`` counts on (one event per forward, one per
-    backward, no third kernel under the scope)."""
+    backward, no third kernel under the scope).  Their operands are the
+    projections' own ``[B, S, N*D]`` wherever heads are whole lane blocks,
+    and ``[B*N, S, D]`` where not."""
+    B, S, N, D = shape
     q = _sds(shape, dtype, one_chip)
     text = _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
-    assert pallas_flash.tile_plan(shape[1], shape[3], dtype).resident_bwd
-    assert len(_kernel_operand_shapes(text)) == 2
+    plan = pallas_flash.tile_plan(S, D, dtype, N=N)
+    assert plan.resident_bwd
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) == 2
+    sp = -(-S // plan.block) * plan.block
+    want = (B, sp, N * D) if plan.group else (B * N, sp, D)
+    assert all(want in operands for operands in calls), (want, calls)
 
 
 def test_flash_mha_long_sequence_two_pass(one_chip):
@@ -123,7 +137,19 @@ def test_flash_mha_long_sequence_two_pass(one_chip):
     backward's q side no longer fits and it goes two-pass (three calls)."""
     q = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
     text = _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
-    assert not pallas_flash.tile_plan(16384, 128, jnp.bfloat16).resident_bwd
+    assert not pallas_flash.tile_plan(16384, 128, jnp.bfloat16,
+                                      N=4).resident_bwd
+    assert len(_kernel_operand_shapes(text)) == 3
+
+
+def test_flash_mha_two_heads_two_pass(one_chip):
+    """Two heads to a block at S = 16k: each head's statistics double what
+    the one-kernel backward would hold, so it goes two-pass, a row of
+    ``delta`` per head."""
+    q = _sds((1, 16384, 2, 64), jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(pallas_flash.mha, 3), q, q, q)
+    plan = pallas_flash.tile_plan(16384, 64, jnp.bfloat16, N=2)
+    assert plan.group == 2 and not plan.resident_bwd
     assert len(_kernel_operand_shapes(text)) == 3
 
 
@@ -214,7 +240,7 @@ def dp4(topo, monkeypatch):
 
 def test_flash_dispatch_partitions_over_dp(dp4):
     """Through the repo's dispatcher every chip runs flash attention on its
-    own quarter of the batch: [8, S, N, D] -> kernels on [8/4 * N, S, D]."""
+    own quarter of the batch: [8, S, N, D] -> kernels on [8/4, S, N * D]."""
     q = _sds((8, 2048, 16, 64), jnp.bfloat16,
              NamedSharding(dp4, P("dp", None, None, None)))
     fn = functools.partial(attn_core.dot_product_attention, use_pallas=True)
@@ -222,8 +248,8 @@ def test_flash_dispatch_partitions_over_dp(dp4):
     calls = _kernel_operand_shapes(text)
     assert len(calls) >= 2          # forward + backward kernels
     for operands in calls:
-        assert (2 * 16, 2048, 64) in operands, operands
-        assert (8 * 16, 2048, 64) not in operands, operands
+        assert (2, 2048, 16 * 64) in operands, operands
+        assert (8, 2048, 16 * 64) not in operands, operands
     assert "all-gather" not in text
 
 

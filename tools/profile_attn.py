@@ -1,24 +1,33 @@
-"""Time the flash-attention kernels alone on the chip, by shape and tile plan.
+"""Time flash attention on the chip as a model calls it: ``mha`` on
+``[B, S, N, D]``, the kernel and what stands around it told apart.
 
-For each shape: the forward and forward + backward of the folded
-``[B*N, S, D]`` call (no layout copies), under the plan ``tile_plan`` picks
-and under every ``--plans block:sub:rows`` given, and -- with ``--parent DIR``, a
-second checkout of this repo -- under that checkout's kernel for comparison
-on the same chip in the same process.
+For each shape the forward and forward + backward run under a profiler
+session, and the device's events are summed by what they are: the kernel
+(events named by its scope, ``flash_attention``) and everything else the
+program runs around it (``around``: layout copies, the q pre-scale and dq
+post-scale, the sum that feeds the gradient).  A program of ``mha`` alone
+takes and returns ``[B, S, N, D]`` arrays in the layout the compiler gives a
+program's arguments, so ``around`` is an upper bound of what a model pays,
+where producers and consumers are fused; the kernel's time is the kernel's.
 
-Timing uses ``tputime.timed_inner`` (loop inside one jit, ended by
-``block_until_ready``), so per-dispatch host overhead does not count as
-kernel time.  TFLOP/s credit the causal half of the square only
-(``tputime.attn_flops``: forward 2 matmul units, forward + backward 7).
+``--parent DIR`` (a second checkout of this repo) times that checkout's
+``mha`` the same way, on the same chip in the same process; ``--plans``
+adds ``block:sub:rows`` triples beside the plan ``tile_plan`` picks.
 
     python tools/profile_attn.py --shapes 8x2048x16x64 16x1024x12x64 \
-        --plans 1024:256:1024 512:128:512 --parent _parent
+        --plans 1024:256:1024 --parent _parent
+
+Wall time per call comes from ``tputime.timed_inner`` (loop inside one jit,
+ended by ``block_until_ready``).  TFLOP/s credit the causal half of the
+square only (``tputime.attn_flops``: forward 2 matmul units, forward +
+backward 7) over the kernel's time.
 """
 
 import argparse
 import importlib.util
 import os
 import sys
+import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -26,14 +35,15 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import jax
 import jax.numpy as jnp
 
-from tputime import attn_flops, emit, timed_inner
+from tputime import attn_flops, drain, emit, timed_inner
 
 # the benchmark cells' shapes, then BENCH_KERNELS.md's milestone shapes
-DEFAULT_SHAPES = ["8x2048x16x64", "16x1024x12x64", "4x2048x8x96",
-                  "4x2048x8x128", "2x4096x8x128", "2x8192x8x128"]
+DEFAULT_SHAPES = ["8x2048x16x64", "16x1024x12x64", "4x4096x16x128",
+                  "4x2048x8x96", "2x8192x8x128"]
+KERNEL = "flash_attention"
 
 
-def _parent_mha(checkout):
+def _parent_module(checkout):
     """Another checkout's kernel module, loaded beside this one's (its
     relative imports -- ``pallas_utils`` -- resolve to this checkout)."""
     name = "deeperspeed_tpu.ops.attention._parent_pallas_flash"
@@ -45,17 +55,51 @@ def _parent_mha(checkout):
     return mod
 
 
+def device_ms(fn, x, calls=4):
+    """Device milliseconds a call of ``fn(x)``, by kind of event: the
+    kernel's and everything else's, from a profiler session over ``calls``
+    calls (compiled before it opens)."""
+    from benchmarks import trace_reduce
+
+    drain(fn(x))
+    with tempfile.TemporaryDirectory() as tmp:
+        with jax.profiler.trace(tmp):
+            for _ in range(calls):
+                out = fn(x)
+            drain(out)
+        trace = trace_reduce.read_xplane(trace_reduce.find_xplane(tmp))
+    if not trace["devices"]:
+        raise RuntimeError("the trace holds no device plane: not a chip run")
+    ops = [(trace_reduce.instruction_kind(name), dur)
+           for name, _, dur in next(iter(trace["devices"].values()))["ops"]]
+    # the kernel's instruction carries its scope: ``flash_attention.3`` in a
+    # model's step, ``transpose_jvp_flash_attention__.1`` under a bare grad
+    kernel = sum(dur for kind, dur in ops if KERNEL in kind)
+    n_kernel = sum(KERNEL in kind for kind, _ in ops)
+    total = sum(dur for _, dur in ops)
+    around = {}
+    for kind, dur in ops:
+        if KERNEL not in kind:
+            around[kind] = around.get(kind, 0) + dur
+    top = sorted(around.items(), key=lambda kv: -kv[1])[:4]
+    return {"kernel_ms": round(kernel / calls / 1e6, 4),
+            "around_ms": round((total - kernel) / calls / 1e6, 4),
+            "kernel_events": n_kernel // calls,
+            "around_top": {k: round(v / calls / 1e6, 4) for k, v in top}}
+
+
 def _time(name, call, x, flops, iters):
+    fwd = jax.jit(lambda t: call(t, t, t))
+    fwdbwd = jax.jit(jax.grad(
+        lambda t: call(t, t, t).astype(jnp.float32).sum()))
     try:
-        dt = timed_inner(lambda t: call(t, t, t), x, iters=iters)
-        emit(f"{name}_fwd", dt, tflops=round(flops["fwd"] / dt / 1e12, 2))
-        dt = timed_inner(
-            lambda t: jax.grad(lambda u: call(u, u, u).astype(
-                jnp.float32).sum())(t), x, iters=iters)
-        emit(f"{name}_fwdbwd", dt,
-             tflops=round(flops["fwdbwd"] / dt / 1e12, 2))
+        for mode, fn in (("fwd", fwd), ("fwdbwd", fwdbwd)):
+            wall = timed_inner(fn, x, iters=iters)
+            dev = device_ms(fn, x)
+            emit(f"{name}_{mode}", wall, **dev, tflops=round(
+                flops[mode] / (dev["kernel_ms"] * 1e-3) / 1e12, 2))
     except Exception as e:  # noqa: BLE001 -- a plan the compiler refuses
-        emit(f"{name}_error", error=str(e)[:300])
+        emit(f"{name}_error", error=repr(e)[:300])
 
 
 def main():
@@ -71,15 +115,15 @@ def main():
     ap.add_argument("--iters", type=int, default=20)
     args = ap.parse_args()
     dtype, causal = jnp.dtype(args.dtype), not args.non_causal
-    parent = _parent_mha(args.parent) if args.parent else None
+    parent = _parent_module(args.parent) if args.parent else None
+    planned = pf.tile_plan
 
     for shape in args.shapes:
         B, S, N, D = (int(t) for t in shape.split("x"))
-        x = jax.random.normal(jax.random.PRNGKey(2), (B * N, S, D), dtype)
-        scale = float(D) ** -0.5
+        x = jax.random.normal(jax.random.PRNGKey(2), (B, S, N, D), dtype)
         flops = {m: attn_flops(B, S, N, D, causal, mode=m)
                  for m in ("fwd", "fwdbwd")}
-        own = pf.tile_plan(S, D, dtype)
+        own = planned(S, D, dtype, N=N)
         plans = [("auto", own)]
         for text in args.plans:
             block, sub, rows = (int(t) for t in text.split(":"))
@@ -91,14 +135,17 @@ def main():
             emit(f"{shape}_{label}_plan", **plan._asdict(),
                  executed_share=round(executed / total, 4),
                  masked_share=round(masked / total, 4))
-            _time(f"{shape}_{label}",
-                  lambda q, k, v, p=plan: pf._mha(q, k, v, causal, scale, p),
-                  x, flops, args.iters)
+            # mha asks tile_plan; hand it this plan for the call
+            pf.tile_plan = lambda *a, plan=plan, **kw: plan
+            try:
+                _time(f"{shape}_{label}",
+                      lambda q, k, v: pf.mha(q, k, v, causal=causal),
+                      x, flops, args.iters)
+            finally:
+                pf.tile_plan = planned
         if parent is not None:
-            s128 = -(-S // 128) * 128
-            blk = next(b for b in (1024, 512, 256, 128) if s128 % b == 0)
-            _time(f"{shape}_parent_b{blk}",
-                  lambda q, k, v: parent._mha(q, k, v, causal, scale, blk),
+            _time(f"{shape}_parent",
+                  lambda q, k, v: parent.mha(q, k, v, causal=causal),
                   x, flops, args.iters)
 
 
