@@ -5,11 +5,8 @@ Replaces the reference's fused attention-softmax CUDA kernels
 online-softmax blocked attention on the MXU: no [S, S] score matrix ever
 reaches HBM.
 
-Default implementation is the **in-tree** kernel (``pallas_flash.mha`` --
-fwd + custom-VJP bwd, causal, any sequence length via tile padding).  The
-upstream ``jax.experimental.pallas.ops.tpu.flash_attention`` kernel remains
-available through ``impl="upstream"`` for A/B benchmarking; it requires
-S % 128 == 0.
+The kernel is the **in-tree** one (``pallas_flash.mha`` -- fwd + custom-VJP
+bwd, causal, any sequence length via tile padding).
 """
 
 import functools
@@ -17,57 +14,24 @@ import functools
 import jax
 import jax.numpy as jnp
 
-# upstream kernel's dkv pass tiles by 128-lane sub-blocks
-MIN_SEQ_BLOCK = 128
 
-
-def flash_attention_supported(q_shape, dtype=None, impl="pallas"):
-    """True when the selected kernel handles this [B, S, N, D] shape +
-    dtype (fwd AND bwd).  Checked *before* dispatch so grad tracing never
-    reaches an unsupported kernel."""
-    _, S, _, D = q_shape
+def flash_attention_supported(q_shape, dtype=None):
+    """True when the kernel handles this [B, S, N, D] shape + dtype (fwd AND
+    bwd).  Checked *before* dispatch so grad tracing never reaches an
+    unsupported kernel."""
+    D = q_shape[3]
     if dtype is not None and jnp.dtype(dtype) not in (
             jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)):
         return False
-    if impl == "upstream":
-        return S % MIN_SEQ_BLOCK == 0 and D % 8 == 0
-    # in-tree kernel: any S (padded to the 128 tile internally)
+    # any S (padded to the 128 tile internally)
     return D % 8 == 0
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale", "impl"))
-def flash_attention(q, k, v, causal=True, scale=None, impl="pallas"):
+@functools.partial(jax.jit, static_argnames=("causal", "scale"))
+def flash_attention(q, k, v, causal=True, scale=None):
     """[B, S, N, D] q/k/v -> [B, S, N, D]; bf16/fp32 in, same dtype out."""
-    B, S, N, D = q.shape
+    from .pallas_flash import mha
+
     if scale is None:
-        scale = float(D) ** -0.5
-    if impl == "pallas":
-        from .pallas_flash import mha
-
-        return mha(q, k, v, causal=causal, scale=scale)
-
-    from jax.experimental.pallas.ops.tpu.flash_attention import (
-        BlockSizes,
-        flash_attention as jax_flash,
-    )
-
-    if not flash_attention_supported(q.shape, impl="upstream"):
-        raise ValueError(
-            f"upstream flash kernel requires seq_len % {MIN_SEQ_BLOCK} == 0 "
-            f"(got S={S}); the default impl='pallas' handles any S")
-    # upstream kernel wants [B, N, S, D]
-    qt = jnp.swapaxes(q, 1, 2)
-    kt = jnp.swapaxes(k, 1, 2)
-    vt = jnp.swapaxes(v, 1, 2)
-    # largest multiple-of-128 divisor of S up to 512 (kernel needs block | seq
-    # and block >= the 128-lane sub-tile)
-    blk = max(d for d in range(MIN_SEQ_BLOCK, min(512, S) + 1, MIN_SEQ_BLOCK)
-              if S % d == 0)
-    block_sizes = BlockSizes(
-        block_q=blk, block_k_major=blk, block_k=blk, block_b=1,
-        block_q_major_dkv=blk, block_k_major_dkv=blk, block_k_dkv=blk,
-        block_q_dkv=blk, block_k_major_dq=blk, block_k_dq=blk, block_q_dq=blk,
-    )
-    out = jax_flash(qt, kt, vt, causal=causal, sm_scale=scale,
-                    block_sizes=block_sizes)
-    return jnp.swapaxes(out, 1, 2)
+        scale = float(q.shape[3]) ** -0.5
+    return mha(q, k, v, causal=causal, scale=scale)

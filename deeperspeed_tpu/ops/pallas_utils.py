@@ -123,11 +123,9 @@ def rowwise_call(kernel, out_shapes, arrays, block_rows, extra_in_specs=(),
             for o, (kind, _) in zip(outs, out_shapes)]
 
 
-def elementwise_call(kernel, out_dtypes, arrays, block_rows,
-                     extra_in_specs=(), extra_args=()):
+def elementwise_call(kernel, out_dtypes, arrays, block_rows):
     """Run an elementwise ``kernel`` over flattened (rows, 128) tiles of
-    same-shape ``arrays``; returns outputs reshaped to the input shape.
-    ``extra_args`` (e.g. SMEM scalars) are passed before the tiled arrays."""
+    same-shape ``arrays``; returns outputs reshaped to the input shape."""
     shape = arrays[0].shape
     n = arrays[0].size
     rows = -(-n // LANES)
@@ -137,6 +135,5 @@ def elementwise_call(kernel, out_dtypes, arrays, block_rows,
         return jnp.pad(flat, (0, rows * LANES - n)).reshape(rows, LANES)
 
     outs = rowwise_call(kernel, [("row", dt) for dt in out_dtypes],
-                        [to2d(a) for a in arrays], block_rows,
-                        extra_in_specs=extra_in_specs, extra_args=extra_args)
+                        [to2d(a) for a in arrays], block_rows)
     return [o.reshape(-1)[:n].reshape(shape) for o in outs]

@@ -40,6 +40,7 @@ from ..utils.timer import (
     ThroughputTimer,
 )
 from ..utils.tree import tree_cast, tree_global_norm, tree_size, tree_zeros_like
+from . import grad_reduce
 from .config import DeeperSpeedConfig
 from .lr_schedules import get_lr_schedule_fn
 from .optimizers import build_optimizer
@@ -53,13 +54,6 @@ from .precision import (
 from .zero.sharding import build_sharding_plan
 
 BATCH_AXES = topo.BATCH_AXES
-
-
-def _is_reduce_plan_leaf(x):
-    """Leaf predicate for ``zero.sharding.deferred_reduce_plan`` pytrees:
-    ``(collective, scatter_dim, axes)`` triples."""
-    return (isinstance(x, tuple) and len(x) == 3
-            and x[0] in ("all_reduce", "reduce_scatter"))
 
 
 def _clip_by_global_norm(grads, norm, clip):
@@ -288,83 +282,6 @@ class DeeperSpeedEngine:
             base_lr = 0.0
         self.optimizer = self.tx  # reference name
 
-        # ---- 1-bit Adam (reference runtime/comm/nccl.py:51 + onebit/adam.py):
-        # local update stays exact Adam; the dp grad reduction switches to
-        # error-feedback sign compression after freeze_step.  Like the
-        # reference, incompatible with ZeRO (needs replicated masters) and
-        # fp16 loss scaling; pointless without data parallelism.
-        self._onebit = self.optimizer_name == "onebitadam"
-        if self._onebit:
-            if config.zero_config.stage > 0:
-                raise ValueError("onebitadam requires zero stage 0 "
-                                 "(reference: 1-bit Adam does not compose "
-                                 "with ZeRO partitioning)")
-            if self.precision.is_fp16:
-                raise ValueError("onebitadam supports fp32/bf16 only")
-            # sp OR tp compose: that axis stays in GSPMD auto mode inside
-            # the manual-dp shard_map (its grad reductions are exact psums
-            # over ICI; only the dp axis -- the slow/DCN link 1-bit exists
-            # for -- is sign-compressed).  ep/zshard still conflict: MoE
-            # routing and MiCS/hpZ subgrouping assume the ZeRO reduction
-            # paths the onebit loop bypasses.
-            if self.mesh.ep > 1 or self.mesh.zshard > 1:
-                raise ValueError("onebitadam compresses over the dp axis; "
-                                 "ep/zshard must be 1 (sp or tp compose)")
-            if self.mesh.sp > 1 and self.mesh.tp > 1:
-                # XLA's SPMD partitioner CHECK-fails expanding device groups
-                # for a manual-dp region with BOTH sp and tp auto axes
-                # (spmd_partitioner_util.cc:495 in this build); each axis
-                # works alone
-                raise NotImplementedError(
-                    "onebitadam supports sp OR tp alongside dp, not both "
-                    "(XLA SPMD device-group expansion limitation)")
-            if self.mesh.dp == 1:
-                logger.warning("onebitadam: dp=1, nothing to compress; "
-                               "running plain Adam")
-                self._onebit = False
-
-        # ---- qgZ quantized gradient reduction (ZeRO++ zero_quantized_gradients
-        # / comm.quantized block): the data-parallel gradient mean runs the
-        # hierarchical int8 schedule (quantize -> intra reduce-scatter ->
-        # requantize -> inter reduce -> all-gathers; comm/compressed.py)
-        # instead of GSPMD's full-precision psum.  Same manual-dp loop shape
-        # as 1-bit Adam, but zshard composes (it IS the intra hop).
-        cq = config.comm.quantized
-        self._qgz = bool(cq.enabled)
-        if config.zero_config.zero_quantized_gradients and not self._qgz:
-            if config.zero_config.stage == 0:
-                self._qgz = True
-            else:
-                # GSPMD emits the stage>=1 grad reduce-scatter itself; the
-                # manual qgZ loop needs replicated masters.  Accept the
-                # reference flag without failing stage 1-3 configs.
-                logger.warning(
-                    "zero_quantized_gradients: the manual qgZ grad loop "
-                    "requires stage 0 (stage %d keeps the GSPMD reduction); "
-                    "ignoring", config.zero_config.stage)
-        if self._qgz:
-            if getattr(self, "_onebit", False):
-                raise ValueError("comm.quantized and onebitadam are mutually "
-                                 "exclusive gradient compressions")
-            if cq.enabled and config.zero_config.stage > 0:
-                raise ValueError(
-                    "comm.quantized requires zero stage 0: the manual "
-                    "dp-loop needs replicated masters (stage>=1 reductions "
-                    "are emitted by GSPMD)")
-            if self.precision.is_fp16:
-                raise ValueError("comm.quantized supports fp32/bf16 only")
-            if self.mesh.ep > 1:
-                raise ValueError("comm.quantized: ep must be 1 (MoE routing "
-                                 "assumes the GSPMD reduction paths)")
-            if self.mesh.sp > 1 and self.mesh.tp > 1:
-                raise NotImplementedError(
-                    "comm.quantized supports sp OR tp alongside dp, not both "
-                    "(XLA SPMD device-group expansion limitation)")
-            if self.mesh.dp * self.mesh.zshard == 1:
-                logger.warning("comm.quantized: dp*zshard=1, nothing to "
-                               "quantize; running plain reduction")
-                self._qgz = False
-
         # ---- lr schedule
         if lr_scheduler is not None and callable(lr_scheduler):
             self._lr_fn = lr_scheduler
@@ -409,7 +326,6 @@ class DeeperSpeedEngine:
             raise NotImplementedError(
                 "host_update does not compose with compression_training "
                 "(the QAT transform runs on the device compute path)")
-        self._check_onebit_feature_conflicts()
 
         # ---- dataloader
         self.training_dataloader = None
@@ -483,7 +399,6 @@ class DeeperSpeedEngine:
         # device-prefetching input pipeline, XLA latency-hiding flags (the
         # last applied in initialize(), before the engine exists).
         ov = config.comm.overlap
-        self._overlap = ov
         self._prefetcher = None
         self._prefetch_depth = 0
         if ov.enabled and ov.prefetch_depth > 0:
@@ -499,85 +414,29 @@ class DeeperSpeedEngine:
                     "donation is active (bounded buffer pool)")
                 depth = 2
             self._prefetch_depth = depth
-        self._deferred_reduce = False
-        self._sched_plan = None
-        self._planned_bucket_mb = None
-        self._schedule_mode = ov.schedule.mode if ov.enabled else "off"
         from ..comm import schedule as comm_schedule
 
-        comm_schedule.set_active_mode(self._schedule_mode)
+        comm_schedule.set_active_mode(ov.schedule.mode if ov.enabled
+                                      else "off")
         # memory-movement planning (comm/memplan.py): the same cost model,
-        # applied to parameter/optimizer state motion.  Calibration (one
-        # profiled step, persisted by the autotuner in the tuner cache)
-        # replaces the analytic compute term in BOTH planners when present.
+        # applied to parameter/optimizer state motion
         from ..comm import memplan as comm_memplan
 
         self._memory_mode = ov.schedule.memory if ov.enabled else "off"
         self._hbm_budget_bytes = (ov.schedule.hbm_budget_bytes
                                   if ov.enabled else None)
-        self._calibration = comm_memplan.load_calibration()
         comm_memplan.set_active_memory_mode(self._memory_mode)
         self.memory_plan = None
-        # the deferred loop is a manual-dp shard_map: model compute runs
-        # locally per dp shard, so any axis whose parallelism lives in
-        # GSPMD sharding constraints (tp/sp/ep/pp) would silently
-        # replicate compute instead.  The 1-bit/qgZ engines already
-        # reduce once per batch (their loops ARE the deferred layout).
-        blockers = []
-        if self.mesh.tp > 1 or self.mesh.sp > 1 or self.mesh.pp > 1:
-            blockers.append("tp/sp/pp > 1 (manual-dp loop would "
-                            "replicate model-parallel compute)")
-        if self.mesh.ep > 1:
-            blockers.append("ep > 1 (MoE routing needs the GSPMD paths)")
-        if self._compression is not None:
-            blockers.append("compression_training (QAT transform runs "
-                            "on the GSPMD compute path)")
-        if self._qwz:
-            blockers.append("zero_quantized_weights (quantized weight "
-                            "regather needs GSPMD resharding)")
-        deferrable = (ov.enabled and ov.deferred_reduction
-                      and not self._onebit and not self._qgz)
-        eligible = (deferrable and not blockers
-                    and self.mesh.dp * self.mesh.zshard > 1)
-        if self._schedule_mode == "auto":
-            # compiler-driven scheduling (comm/schedule.py): score the
-            # grad-reduce schedule candidates with the wire/ICI cost model;
-            # blocked regimes get a PLANNED per-microbatch + jaxpr-hoist
-            # schedule, not a fallback warning
-            n_red = 1
-            for axis in BATCH_AXES:
-                n_red *= self.mesh.mesh.shape.get(axis, 1)
-            wire_dt = self.precision.reduce_dtype or self.precision.accum_dtype
-            grad_bytes = (tree_size(self.state["master_params"])
-                          * jnp.dtype(wire_dt).itemsize)
-            self._sched_plan = comm_schedule.plan_schedule(
-                grad_bytes=grad_bytes,
-                gas=self.gradient_accumulation_steps(),
-                n_ranks=n_red,
-                deferred_allowed=eligible,
-                blockers=tuple(blockers),
-                bucket_mb=ov.bucket_mb,
-                qgz=self._qgz or self._onebit,
-                compute_s=(self._calibration.compute_s
-                           if self._calibration is not None
-                           and self._calibration.compute_s > 0 else None))
-            if self._sched_plan.grad_schedule == "deferred" and eligible:
-                self._deferred_reduce = True
-                self._planned_bucket_mb = self._sched_plan.bucket_mb
-            log_dist("comm.schedule[auto]: "
-                     + self._sched_plan.describe(), ranks=[0])
-        elif self._schedule_mode == "manual" and deferrable:
-            if blockers:
-                from ..utils.logging import warning_once
 
-                warning_once(
-                    "comm.overlap.deferred_reduction disabled: "
-                    + "; ".join(blockers)
-                    + " -- falling back to the per-microbatch reduction "
-                    "schedule (comm.overlap.schedule.mode=auto plans these "
-                    "regimes instead)")
-            elif eligible:
-                self._deferred_reduce = True
+        # ---- how the batch's gradient is accumulated and reduced: chosen
+        # once, by the module that owns the choice; what the reduction
+        # carries across steps (1-bit Adam's error feedback) joins the state
+        self._reduction = grad_reduce.select(self)
+        for key, value in self._reduction.init_carried(
+                self.state["master_params"]).items():
+            self.state[key] = value
+            self._state_shardings[key] = jax.tree_util.tree_map(
+                lambda x: x.sharding, value)
 
         if self._memory_mode != "off" and self.zero_optimization_stage() >= 3:
             # stage-3 compute params: every leaf gathered at its use site.
@@ -800,21 +659,6 @@ class DeeperSpeedEngine:
         return True so no model/user loss_fn is required."""
         return False
 
-    def _check_onebit_feature_conflicts(self):
-        """The onebit grads path bypasses _compute_params / LTD injection --
-        combining silently would fake those features (same guard class as
-        the compiled pipeline's NotImplementedErrors)."""
-        if not (getattr(self, "_onebit", False) or getattr(self, "_qgz", False)):
-            return
-        which = "onebitadam" if getattr(self, "_onebit", False) else "comm.quantized"
-        if self._compression is not None:
-            raise NotImplementedError(
-                f"{which} + compression_training is not supported (the "
-                "compressed-reduction path bypasses the QAT transform)")
-        if self.random_ltd_scheduler is not None:
-            raise NotImplementedError(
-                f"{which} + random-LTD is not supported")
-
     # ------------------------------------------------- data-efficiency stack
     def _init_data_efficiency(self):
         """Instantiate the config-gated data-efficiency schedulers.
@@ -967,8 +811,7 @@ class DeeperSpeedEngine:
             params = jax.device_put(params, self._master_dev_shardings)
 
         def loss_closure(p):
-            loss = self._loss_fn(p, mb, None)
-            return loss[0] if isinstance(loss, tuple) else loss
+            return grad_reduce.split_loss(self._loss_fn(p, mb, None))[0]
 
         return ev.compute_eigenvalue(loss_closure, params, rng=rng)
 
@@ -1046,19 +889,6 @@ class DeeperSpeedEngine:
             "step": jax.device_put(jnp.zeros((), jnp.int32), self._repl),
             "loss_scale": jax.device_put(scale_state, self._repl),
         }
-        if getattr(self, "_onebit", False):
-            # per-rank error feedback: leading dp axis, one slice per replica
-            # (volatile: reset on checkpoint resume, like the reference's
-            # worker/server error buffers)
-            dp = self.mesh.dp
-
-            def err_zeros(p):
-                sh = NamedSharding(self.mesh.mesh,
-                                   P(topo.DP_AXIS, *([None] * p.ndim)))
-                return jax.device_put(
-                    jnp.zeros((dp, *p.shape), jnp.float32), sh)
-
-            state["onebit_error"] = jax.tree_util.tree_map(err_zeros, master)
         return state
 
     def _shardings_like_state(self):
@@ -1070,9 +900,6 @@ class DeeperSpeedEngine:
             "step": self._repl,
             "loss_scale": jax.tree_util.tree_map(lambda _: self._repl, self.state["loss_scale"]),
         }
-        if getattr(self, "_onebit", False):
-            shardings["onebit_error"] = jax.tree_util.tree_map(
-                lambda e: e.sharding, self.state["onebit_error"])
         return shardings
 
     def _no_cast_mask(self, abstract):
@@ -1172,8 +999,8 @@ class DeeperSpeedEngine:
         point, and the rewritten (bit-exact) program jitted.  Host-offload
         steps keep the plain jit -- their device_put memory-space moves
         must not be replayed through eval_jaxpr."""
-        if (self._schedule_mode == "auto" and self._sched_plan is not None
-                and self._sched_plan.hoist and not self._offload_optimizer
+        plan = self._reduction.plan
+        if (plan is not None and plan.hoist and not self._offload_optimizer
                 and self._host_adam is None):
             from ..comm.schedule import ScheduledStepFn
 
@@ -1182,13 +1009,6 @@ class DeeperSpeedEngine:
                 plan_memory=(self._memory_mode == "auto"
                              and self.zero_optimization_stage() >= 3))
         return jax.jit(fn, **jit_kwargs)
-
-    @property
-    def _grad_schedule_tag(self):
-        """Telemetry label of the grad-reduce schedule actually in effect."""
-        if self._sched_plan is not None:
-            return self._sched_plan.tag
-        return "deferred" if self._deferred_reduce else "per_microbatch"
 
     def _state_jit_kwargs(self, rest_in, donate=True, state_out=True):
         """jit sharding kwargs for state-consuming steps.
@@ -1241,455 +1061,21 @@ class DeeperSpeedEngine:
             return jax.lax.with_sharding_constraint(params,
                                                     self.param_shardings)
 
-    def _micro_loss_and_grads(self, master, microbatch, rng, scale,
-                              ltd_tokens=None, step=None):
-        params = self._compute_params(master, step=step)
+    @property
+    def _grads_for_batch(self):
+        """``(master, batch, rng, scale, ltd_tokens=, step=, carried=)`` ->
+        (mean-loss grads still multiplied by ``scale``, reduced over the
+        data-parallel replicas; mean loss; the model's own numbers of the
+        step averaged over the microbatches, {} from a model that reports
+        none): the reduction in effect (``runtime/grad_reduce.py``).
+        ``carried`` holds what the reduction carries across steps, by state
+        key; the reduction replaces its entries with the step's.  A property,
+        not a method that forwards: a Python frame between the step and the
+        microbatch scan is not free (PERF.md §6, PR 31).
 
-        def scaled_loss(p):
-            if ltd_tokens is not None:
-                loss = self._loss_fn(p, microbatch, rng,
-                                     random_ltd_tokens=ltd_tokens)
-            else:
-                loss = self._loss_fn(p, microbatch, rng)
-            # a model may return (loss, {name: number}): what its step says
-            # of itself (a looped model's exit shares and counters)
-            stats = {}
-            if isinstance(loss, tuple):
-                if len(loss) > 1 and isinstance(loss[1], dict):
-                    stats = loss[1]
-                loss = loss[0]
-            return (loss * scale).astype(jnp.float32), (loss, stats)
-
-        (_, (loss, stats)), grads = jax.value_and_grad(
-            scaled_loss, has_aux=True)(params)
-        # communication_data_type (reference ``engine.py:1142-1144``): the
-        # cross-replica grad reduction runs in this dtype -- XLA places the
-        # psum/reduce-scatter where the grad's sharded layout is demanded,
-        # so casting HERE (before the caller's sharding constraint) sets the
-        # collective's wire dtype; accumulation re-casts after.
-        wire = self.precision.reduce_dtype or self.precision.accum_dtype
-        with jax.named_scope("grad_accumulate"):
-            grads = tree_cast(grads, wire)
-        return loss, grads, stats
-
-    def _grad_reduce_plan(self, master):
-        """Per-leaf (collective, dim, axes) for the dp grad reduction --
-        shared by the deferred path (which executes it) and the wire
-        recorder (which prices it)."""
-        from .zero.sharding import ZERO_AXES, deferred_reduce_plan
-
-        return deferred_reduce_plan(self.plan.grad_specs, master, self.mesh,
-                                    ZERO_AXES)
-
-    def _record_grad_reduce_wire(self, master, gas, schedule="per_microbatch",
-                                 n_buckets=1):
-        """Trace-time analytic record of the data-parallel grad reduction
-        (the one collective no ``comm/comm.py`` call mediates: per-microbatch
-        mode's sharding constraint makes GSPMD place it; deferred mode's
-        manual psum/psum_scatter emit it directly).  Prices the ACTUAL
-        schedule: per-leaf all-reduce vs reduce-scatter classification from
-        the grad specs, issued once per microbatch (``per_microbatch``) or
-        once per batch (``deferred``), in ``n_buckets`` collective groups.
-        No-op unless the comms logger is capturing (first train_batch with
-        telemetry enabled)."""
-        if not dist.comms_logger._capturing:
-            return
-        n = 1
-        for axis in BATCH_AXES:
-            n *= self.mesh.mesh.shape.get(axis, 1)
-        if n <= 1:
-            return
-        from ..telemetry.wire import plain_wire_bytes
-
-        wire = self.precision.reduce_dtype or self.precision.accum_dtype
-        itemsize = jnp.dtype(wire).itemsize
-        plan_flat = jax.tree_util.tree_leaves(
-            self._grad_reduce_plan(master), is_leaf=_is_reduce_plan_leaf)
-        rs_bytes = ar_bytes = 0
-        for p, leaf in zip(plan_flat, jax.tree_util.tree_leaves(master)):
-            nb = int(np.prod(leaf.shape)) * itemsize
-            if p[0] == "reduce_scatter":
-                rs_bytes += nb
-            else:
-                ar_bytes += nb
-        issues = 1 if schedule == "deferred" else gas
-        total = (plain_wire_bytes("reduce_scatter", rs_bytes, n)
-                 + plain_wire_bytes("all_reduce", ar_bytes, n)) * issues
-        dist.comms_logger.record_traced(
-            "grad_reduce_dp", total, n,
-            variant=jnp.dtype(wire).name, count=issues * max(n_buckets, 1),
-            schedule=self._grad_schedule_tag)
-
-    def _grads_for_batch(self, master, batch, rng, scale, ltd_tokens=None,
-                         step=None):
-        """Mean-loss grads (still multiplied by ``scale``) over gas
-        microbatches -> (grads, mean loss, the model's own numbers of the
-        step averaged over the microbatches: {} from a model that reports
-        none, and on the deferred and pipeline paths).
-
-        Subclasses re-express this: the pipeline engine replaces the microbatch
-        scan with the compiled pipeline over the pp axis."""
-        gas = self.gradient_accumulation_steps()
-        if self._deferred_reduce:
-            return (*self._grads_for_batch_deferred(
-                master, batch, rng, scale, ltd_tokens=ltd_tokens), {})
-        self._record_grad_reduce_wire(master, gas)
-
-        def micro(carry, mb):
-            acc = carry
-            sub_rng = jax.random.fold_in(rng, acc[1])
-            loss, grads, stats = self._micro_loss_and_grads(
-                master, mb, sub_rng, scale, ltd_tokens=ltd_tokens, step=step)
-            # reduction happens into this constrained layout, in the wire
-            # dtype chosen by _micro_loss_and_grads; accumulate in accum_dtype
-            with jax.named_scope("grad_accumulate"):
-                with jax.named_scope("zero3_reduce"):
-                    grads = jax.lax.with_sharding_constraint(
-                        grads, self.grad_shardings)
-                grads = tree_cast(grads, self.precision.accum_dtype)
-                new_acc = jax.tree_util.tree_map(jnp.add, acc[0], grads)
-            return (new_acc, acc[1] + 1), (loss, stats)
-
-        with jax.named_scope("grad_accumulate"):
-            zero_grads = jax.tree_util.tree_map(
-                lambda x: jnp.zeros(x.shape, self.precision.accum_dtype),
-                master)
-            zero_grads = jax.lax.with_sharding_constraint(
-                zero_grads, self.grad_shardings)
-        (grads, _), (losses, stats) = jax.lax.scan(
-            micro, (zero_grads, jnp.int32(0)), batch)
-        with jax.named_scope("grad_accumulate"):
-            grads = jax.tree_util.tree_map(lambda g: g / gas, grads)
-        stats = jax.tree_util.tree_map(
-            lambda s: jnp.mean(s.astype(jnp.float32), axis=0), stats)
-        return grads, jnp.mean(losses), stats
-
-    def _grads_for_batch_deferred(self, master, batch, rng, scale,
-                                  ltd_tokens=None):
-        """Mean-loss grads with the dp reduction DEFERRED to once per batch.
-
-        The per-microbatch path constrains grads to the reduced layout
-        inside the scan, so GSPMD inserts a psum/reduce-scatter per
-        microbatch -- gas x the necessary wire traffic.  Here the microbatch
-        loop runs inside a manual-dp shard_map (mirroring the 1-bit path):
-        each dp shard accumulates its LOCAL unreduced grads across the scan,
-        then one reduction realizes the ZeRO grad layout -- ``psum_scatter``
-        for leaves whose grad spec is dp-sharded (stage 2/3 kernels),
-        ``psum`` for the rest (stage 0/1, embeddings, 1-D leaves) -- cutting
-        bytes-on-wire by gas x.  ``overlap.bucket_mb`` splits the reduction
-        into byte-bounded leaf groups issued in leaf order, so XLA's
-        latency-hiding scheduler can overlap the tail of backward with the
-        first buckets' collectives; within a bucket the psum leaves fuse
-        into one flattened collective.
-
-        Numerics: local loss is the mean over the LOCAL batch shard, so
-        local grads are n_dp x the global-mean contribution; dividing the
-        psum by ``gas * n_dp`` recovers the per-microbatch result exactly
-        (up to accumulation-order rounding in the wire/accum dtypes).
-        """
-        from ..comm.overlap import bucketize
-
-        gas = self.gradient_accumulation_steps()
-        mesh = self.mesh
-        reduce_axes = tuple(a for a in BATCH_AXES if mesh.sizes[a] > 1)
-        n_red = 1
-        for a in reduce_axes:
-            n_red *= mesh.sizes[a]
-        wire = self.precision.reduce_dtype or self.precision.accum_dtype
-        acc_dt = self.precision.accum_dtype
-        plan_flat = jax.tree_util.tree_leaves(
-            self._grad_reduce_plan(master), is_leaf=_is_reduce_plan_leaf)
-        master_flat = jax.tree_util.tree_leaves(master)
-        itemsize = jnp.dtype(wire).itemsize
-        # auto mode: the scheduling pass's cost-model-chosen bucket size
-        # overrides the hand-configured one (comm/schedule.py plan_schedule)
-        bucket_mb = (self._planned_bucket_mb
-                     if self._planned_bucket_mb is not None
-                     else self._overlap.bucket_mb)
-        buckets = bucketize(
-            [int(np.prod(l.shape)) * itemsize for l in master_flat],
-            bucket_mb)
-        self._record_grad_reduce_wire(master, gas, schedule="deferred",
-                                      n_buckets=len(buckets))
-
-        def local_fn(master_l, batch_l, rng_l, scale_l):
-            def micro(carry, mb):
-                acc, i = carry
-                sub_rng = jax.random.fold_in(rng_l, i)
-                params = self.precision.cast_for_compute(master_l,
-                                                         self._no_cast)
-
-                def scaled_loss(p):
-                    if ltd_tokens is not None:
-                        loss = self._loss_fn(p, mb, sub_rng,
-                                             random_ltd_tokens=ltd_tokens)
-                    else:
-                        loss = self._loss_fn(p, mb, sub_rng)
-                    if isinstance(loss, tuple):
-                        loss = loss[0]
-                    return (loss * scale_l).astype(jnp.float32), loss
-
-                (_, loss), grads = jax.value_and_grad(
-                    scaled_loss, has_aux=True)(params)
-                # accumulate in accum_dtype in the LOCAL layout: no layout
-                # constraint here means no GSPMD reduction per microbatch
-                grads = tree_cast(grads, acc_dt)
-                return (jax.tree_util.tree_map(jnp.add, acc, grads),
-                        i + 1), loss
-
-            zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, acc_dt), master_l)
-            (gsum, _), losses = jax.lax.scan(micro, (zeros, jnp.int32(0)),
-                                             batch_l)
-
-            flat, gdef = jax.tree_util.tree_flatten(gsum)
-            inv = 1.0 / (gas * n_red)
-            out = list(flat)
-            with jax.named_scope("zero3_reduce"):
-                for bucket in buckets:
-                    ar = [i for i in bucket if plan_flat[i][0] == "all_reduce"]
-                    rs = [i for i in bucket
-                          if plan_flat[i][0] == "reduce_scatter"]
-                    if ar:
-                        # fuse the bucket's replicated-layout leaves into one
-                        # flattened all-reduce (wire dtype set by the cast)
-                        vecs = [(out[i] * inv).astype(wire).reshape(-1)
-                                for i in ar]
-                        vec = jnp.concatenate(vecs) if len(vecs) > 1 else vecs[0]
-                        vec = jax.lax.psum(vec, reduce_axes)
-                        sizes = np.cumsum([flat[i].size for i in ar])[:-1]
-                        for i, piece in zip(ar, jnp.split(vec, sizes)):
-                            out[i] = piece.reshape(flat[i].shape).astype(acc_dt)
-                    for i in rs:
-                        _, dim, axes = plan_flat[i]
-                        g = (out[i] * inv).astype(wire)
-                        g = jax.lax.psum_scatter(
-                            g, axes if len(axes) > 1 else axes[0],
-                            scatter_dimension=dim, tiled=True)
-                        # grad-spec axes may be a subgroup (MiCS/hpZ): finish
-                        # the reduction over the remaining batch axes
-                        rest = tuple(a for a in reduce_axes if a not in axes)
-                        if rest:
-                            g = jax.lax.psum(g, rest)
-                        out[i] = g.astype(acc_dt)
-            grads = jax.tree_util.tree_unflatten(gdef, out)
-            loss = jnp.mean(losses)
-            if reduce_axes:
-                loss = jax.lax.pmean(loss, reduce_axes)
-            return grads, loss
-
-        def batch_spec(x):
-            if x.ndim < 2:  # per-microbatch scalars (e.g. pld_theta)
-                return P(*([None] * x.ndim))
-            return P(*([None, reduce_axes] + [None] * (x.ndim - 2)))
-
-        def grad_out_spec(p, leaf):
-            kind, dim, axes = p
-            if kind == "reduce_scatter":
-                entry = axes if len(axes) > 1 else axes[0]
-                return P(*[entry if d == dim else None
-                           for d in range(leaf.ndim)])
-            return P()
-
-        base = jax.tree_util.tree_map(lambda _: P(), master)
-        out_grad_specs = jax.tree_util.tree_map(
-            grad_out_spec, self._grad_reduce_plan(master), master,
-            is_leaf=_is_reduce_plan_leaf)
-        fn = jax.shard_map(
-            local_fn, mesh=mesh.mesh,
-            in_specs=(base, jax.tree_util.tree_map(batch_spec, batch),
-                      P(), P()),
-            out_specs=(out_grad_specs, P()),
-            # full-manual for the same reason as the onebit path below
-            axis_names=set(mesh.mesh.axis_names),
-            check_vma=False,
-        )
-        grads, loss = fn(master, batch, rng, scale)
-        # realize the engine's grad layout (free: psum leaves are
-        # replicated, scatter leaves already landed sharded)
-        grads = jax.lax.with_sharding_constraint(grads, self.grad_shardings)
-        # match the per-microbatch contract: grads are summed/gas'd means
-        # still carrying ``scale``; division by gas*n_dp happened pre-psum
-        return grads, loss
-
-    def _grads_for_batch_onebit(self, master, batch, rng, error, step):
-        """Mean grads with the dp reduction compressed to sign bits + scale
-        after ``freeze_step`` (1-bit Adam compression stage; reference
-        ``compressed_allreduce`` ``runtime/comm/nccl.py:51``).
-
-        Runs the microbatch loop inside a shard_map that is *manual* over dp
-        (local grads never see an automatic psum) and auto over tp; every
-        leaf is then reduced by either ``lax.pmean`` (warmup) or
-        ``onebit_all_reduce`` with per-rank error feedback.
-        """
-        from ..comm.compressed import onebit_all_reduce
-
-        gas = self.gradient_accumulation_steps()
-        freeze = self.config.optimizer.params.freeze_step
-
-        def local_fn(master_l, batch_l, rng_l, error_l, step_l):
-            error_l = jax.tree_util.tree_map(lambda e: e[0], error_l)
-
-            def micro(carry, mb):
-                acc, i = carry
-                sub_rng = jax.random.fold_in(rng_l, i)
-                params = self.precision.cast_for_compute(master_l, self._no_cast)
-
-                def loss_of(p):
-                    loss = self._loss_fn(p, mb, sub_rng)
-                    return loss[0] if isinstance(loss, tuple) else loss
-
-                loss, grads = jax.value_and_grad(loss_of)(params)
-                grads = tree_cast(grads, jnp.float32)
-                return (jax.tree_util.tree_map(jnp.add, acc, grads), i + 1), loss
-
-            zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), master_l)
-            (gsum, _), losses = jax.lax.scan(micro, (zeros, jnp.int32(0)),
-                                             batch_l)
-            gmean = jax.tree_util.tree_map(lambda g: g / gas, gsum)
-
-            def reduce_leaf(g, err):
-                def warm(args):
-                    gg, ee = args
-                    return jax.lax.pmean(gg, topo.DP_AXIS), ee
-
-                def compressed(args):
-                    gg, ee = args
-                    return onebit_all_reduce(gg, topo.DP_AXIS, ee)
-
-                return jax.lax.cond(step_l < freeze, warm, compressed,
-                                    (g, err))
-
-            reduced = jax.tree_util.tree_map(reduce_leaf, gmean, error_l)
-            is_pair = lambda x: isinstance(x, tuple)
-            grads = jax.tree_util.tree_map(lambda r: r[0], reduced,
-                                           is_leaf=is_pair)
-            new_err = jax.tree_util.tree_map(lambda r: r[1][None], reduced,
-                                             is_leaf=is_pair)
-            loss = jax.lax.pmean(jnp.mean(losses), topo.DP_AXIS)
-            return grads, loss, new_err
-
-        def batch_spec(x):
-            return P(*([None, topo.DP_AXIS] + [None] * (x.ndim - 2)))
-
-        err_spec = jax.tree_util.tree_map(
-            lambda e: P(topo.DP_AXIS, *([None] * (e.ndim - 1))), error)
-        base = jax.tree_util.tree_map(lambda _: P(), master)
-        fn = jax.shard_map(
-            local_fn, mesh=self.mesh.mesh,
-            in_specs=(base, jax.tree_util.tree_map(batch_spec, batch),
-                      P(), err_spec, P()),
-            out_specs=(base, P(), err_spec),
-            # manual over ALL mesh axes, not just dp: a >1-size auto axis
-            # (sp/tp here) alongside the manual-dp scan + collectives trips
-            # an SPMD-partitioner manual-subgroup check in this jax (hard
-            # abort).  Non-dp operands are replicated, so full-manual is
-            # semantically identical.
-            axis_names=set(self.mesh.mesh.axis_names),
-            check_vma=False,
-        )
-        return fn(master, batch, rng, error, step)
-
-    def _grads_for_batch_qgz(self, master, batch, rng):
-        """Mean grads with the data-parallel reduction on the hierarchical
-        int8 qgZ schedule (``comm.all_reduce_quantized``): quantize -> intra
-        (zshard) reduce-scatter -> requantize -> inter (dp) reduce ->
-        quantized all-gathers.  Manual over dp (x zshard); auto over sp/tp
-        like the onebit path.  Leaves below the quantization granule reduce
-        with an exact pmean -- their relative int8 error is largest and
-        their wire cost is negligible.
-        """
-        from ..comm.comm import CommGroup, all_reduce_quantized, ReduceOp
-
-        cq = self.config.comm.quantized
-        gas = self.gradient_accumulation_steps()
-        axes = (topo.DP_AXIS, topo.ZSHARD_AXIS) if self.mesh.zshard > 1 \
-            else (topo.DP_AXIS,)
-        group = CommGroup(axes)
-        intra_group = CommGroup((cq.intra_axis,)) if cq.intra_axis else None
-        # below one quantization group per participant the padding overhead
-        # dominates and the blockwise error is worst: stay exact
-        min_elems = cq.group_size * group.size()
-        # comm.overlap composition: group the quantized reduces into
-        # bucket_mb-sized flattened collectives issued leaf-group-by-group
-        # (one qgZ schedule per bucket instead of per leaf; fewer pad+launch
-        # overheads, and the scheduler can overlap buckets with backward)
-        bucketed = self._overlap.enabled
-
-        def local_fn(master_l, batch_l, rng_l):
-            def micro(carry, mb):
-                acc, i = carry
-                sub_rng = jax.random.fold_in(rng_l, i)
-                params = self.precision.cast_for_compute(master_l, self._no_cast)
-
-                def loss_of(p):
-                    loss = self._loss_fn(p, mb, sub_rng)
-                    return loss[0] if isinstance(loss, tuple) else loss
-
-                loss, grads = jax.value_and_grad(loss_of)(params)
-                grads = tree_cast(grads, jnp.float32)
-                return (jax.tree_util.tree_map(jnp.add, acc, grads), i + 1), loss
-
-            zeros = jax.tree_util.tree_map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), master_l)
-            (gsum, _), losses = jax.lax.scan(micro, (zeros, jnp.int32(0)),
-                                             batch_l)
-
-            def reduce_leaf(g):
-                g = g / gas
-                if g.size < min_elems:
-                    return jax.lax.pmean(g, axes)
-                return all_reduce_quantized(
-                    g, op=ReduceOp.AVG, group=group, intra_group=intra_group,
-                    group_size=cq.group_size, impl=cq.impl,
-                    wire_dtype=cq.wire_dtype)
-
-            if not bucketed:
-                grads = jax.tree_util.tree_map(reduce_leaf, gsum)
-            else:
-                from ..comm.overlap import bucketize
-                from .zero.quantized import fused_flat_reduce
-
-                flat, gdef = jax.tree_util.tree_flatten(gsum)
-                out = list(flat)
-                small = [i for i, g in enumerate(flat) if g.size < min_elems]
-                large = [i for i, g in enumerate(flat) if g.size >= min_elems]
-                if small:
-                    # sub-granule leaves fuse into ONE exact pmean
-                    for i, r in zip(small, fused_flat_reduce(
-                            [flat[i] for i in small],
-                            lambda v: jax.lax.pmean(v, axes), divisor=gas)):
-                        out[i] = r
-                for b in bucketize([flat[i].size * 4 for i in large],
-                                   self._overlap.bucket_mb):
-                    idx = [large[j] for j in b]
-                    for i, r in zip(idx, fused_flat_reduce(
-                            [flat[i] for i in idx],
-                            lambda v: all_reduce_quantized(
-                                v, op=ReduceOp.AVG, group=group,
-                                intra_group=intra_group,
-                                group_size=cq.group_size, impl=cq.impl,
-                                wire_dtype=cq.wire_dtype),
-                            divisor=gas)):
-                        out[i] = r
-                grads = jax.tree_util.tree_unflatten(gdef, out)
-            loss = jax.lax.pmean(jnp.mean(losses), axes)
-            return grads, loss
-
-        def batch_spec(x):
-            return P(*([None, axes] + [None] * (x.ndim - 2)))
-
-        base = jax.tree_util.tree_map(lambda _: P(), master)
-        fn = jax.shard_map(
-            local_fn, mesh=self.mesh.mesh,
-            in_specs=(base, jax.tree_util.tree_map(batch_spec, batch), P()),
-            out_specs=(base, P()),
-            # full-manual for the same reason as the onebit path above
-            axis_names=set(self.mesh.mesh.axis_names),
-            check_vma=False,
-        )
-        return fn(master, batch, rng)
+        Subclasses re-express this as a method: the pipeline engine replaces
+        the microbatch scan with the compiled pipeline over the pp axis."""
+        return self._reduction.grads
 
     @jax.named_scope("grad_norm_clip")
     def _unscale_and_clip(self, grads, inv, clip, fp16):
@@ -1731,23 +1117,16 @@ class DeeperSpeedEngine:
             master = dev["master_params"]
             scale = state["loss_scale"].scale if fp16 is not None else jnp.float32(1.0)
 
-            new_error, model_stats = None, {}
-            if self._onebit:
-                grads, loss_mean, new_error = self._grads_for_batch_onebit(
-                    master, batch, rng, state["onebit_error"], state["step"])
-            elif self._qgz:
-                grads, loss_mean = self._grads_for_batch_qgz(master, batch, rng)
-            else:
-                grads, loss_mean, model_stats = self._grads_for_batch(
-                    master, batch, rng, scale, ltd_tokens=ltd_tokens,
-                    step=state["step"])
+            carried = {k: state[k] for k in self._reduction.carries}
+            grads, loss_mean, model_stats = self._grads_for_batch(
+                master, batch, rng, scale, ltd_tokens=ltd_tokens,
+                step=state["step"], carried=carried)
             grads, overflow, grad_norm = self._unscale_and_clip(
                 grads, 1.0 / scale, clip, fp16)
             new_state, lr = self._optimizer_pass(state, dev, master, grads,
                                                  overflow, fp16)
             new_scale = new_state["loss_scale"]
-            if new_error is not None:
-                new_state["onebit_error"] = new_error
+            new_state.update(carried)
             metrics = {
                 "loss": loss_mean,
                 "grad_norm": grad_norm,
@@ -1770,10 +1149,9 @@ class DeeperSpeedEngine:
                 step=state["step"])
 
             def micro(_, mb):
-                loss = self._loss_fn(params, mb, None)  # eval: deterministic
-                if isinstance(loss, tuple):
-                    loss = loss[0]
-                return 0, loss
+                # eval: deterministic
+                return 0, grad_reduce.split_loss(
+                    self._loss_fn(params, mb, None))[0]
 
             _, losses = jax.lax.scan(micro, 0, batch)
             return jnp.mean(losses)
@@ -1788,10 +1166,12 @@ class DeeperSpeedEngine:
 
         def micro_step(state, microbatch, rng):
             scale = state["loss_scale"].scale if self.precision.is_fp16 else jnp.float32(1.0)
-            loss, grads, _ = self._micro_loss_and_grads(
-                self._materialize_state(state)["master_params"], microbatch,
-                rng, scale, step=state["step"]
-            )
+            params = self._compute_params(
+                self._materialize_state(state)["master_params"],
+                step=state["step"])
+            loss, grads, _ = grad_reduce.micro_loss_and_grads(
+                self, params, microbatch, rng, scale,
+                wire=grad_reduce.wire_dtype(self))
             grads = jax.lax.with_sharding_constraint(grads, self.grad_shardings)
             # reduction ran in the wire dtype; the engine-side accumulation
             # buffer (backward()) must sum in accum_dtype
@@ -2026,7 +1406,7 @@ class DeeperSpeedEngine:
             new_state = self.state
             metrics = {"loss": loss_dev, "grad_norm": norm, "lr": lr,
                        "overflow": False, "loss_scale": 1.0}
-        elif self._opt_swapper is not None and not self._onebit:
+        elif self._opt_swapper is not None and not self._reduction.carries:
             # NVMe split step (VERDICT r3 Weak #4: the whole-state blocking
             # disk roundtrip serialized with the step): dispatch the
             # grads-only half first -- it needs no optimizer state, so the
@@ -2293,7 +1673,8 @@ class DeeperSpeedEngine:
                 est["overlapped_s"], step=step)
             tele.scalar("comm/exposed_vs_overlapped").record(
                 est["overlap_frac"], step=step, device_kind=kind)
-        if self._sched_plan is not None:
+        plan = self._reduction.plan
+        if plan is not None:
             # compiler-driven scheduling pass stats (comm/schedule.py):
             # what the planner chose + what the hoist pass moved
             hoisted = ncoll = 0
@@ -2305,7 +1686,7 @@ class DeeperSpeedEngine:
                 all_sites.extend(getattr(fn, "sites", ()))
             tele.scalar("comm/schedule/hoisted_collectives").record(
                 hoisted, step=step, collectives=ncoll,
-                schedule=self._sched_plan.tag, mode=self._schedule_mode)
+                schedule=plan.tag, mode=plan.mode)
             if all_sites:
                 # GSPMD-materialized (sharding_constraint) collectives: the
                 # sites find_collectives classified from layout transitions;
@@ -2315,12 +1696,12 @@ class DeeperSpeedEngine:
 
                 n_impl, impl_bytes = implicit_wire_summary(
                     all_sites, axis_sizes=dict(self.mesh.mesh.shape))
-                self._sched_plan.implicit_sites = n_impl
-                self._sched_plan.implicit_wire_bytes = impl_bytes
+                plan.implicit_sites = n_impl
+                plan.implicit_wire_bytes = impl_bytes
                 if n_impl:
                     tele.scalar("comm/gspmd_implicit/bytes_on_wire").record(
                         impl_bytes, step=step, sites=n_impl,
-                        schedule=self._sched_plan.tag)
+                        schedule=plan.tag)
             if self.memory_plan:
                 from ..comm.memplan import movement_summary
 

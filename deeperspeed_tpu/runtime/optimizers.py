@@ -2,11 +2,9 @@
 ``_configure_basic_optimizer`` + the fork's mu-optimizers at
 ``engine.py:1336-1350``).
 
-Built on optax transformations.  The Adam update itself can be routed to the
-Pallas fused-Adam kernel on TPU (see ``ops/adam``) -- the factory exposes the
-same decision the reference makes between FusedAdam/CPUAdam/torch Adam
-(``engine.py:1259-1334``), except "fused" here means one Pallas kernel per
-flat-leaf instead of a multi-tensor CUDA launch.
+Built on optax transformations.  The reference's choice between
+FusedAdam/CPUAdam/torch Adam (``engine.py:1259-1334``) is one optax chain
+here: "fused" on TPU is XLA's fusion of that chain (see ``build_optimizer``).
 """
 
 import jax
@@ -55,15 +53,9 @@ def scale_by_mup(multipliers):
     return optax.GradientTransformation(init_fn, update_fn)
 
 
-def _adam_like(params_cfg, adamw=False, mup_multipliers=None, use_fused=False):
-    b1, b2 = params_cfg.betas[0], params_cfg.betas[1]
-    if use_fused:
-        from ..ops.adam.fused_adam import scale_by_fused_adam
-
-        core = scale_by_fused_adam(b1=b1, b2=b2, eps=params_cfg.eps)
-    else:
-        core = optax.scale_by_adam(b1=b1, b2=b2, eps=params_cfg.eps)
-    chain = [core]
+def _adam_like(params_cfg, adamw=False, mup_multipliers=None):
+    chain = [optax.scale_by_adam(b1=params_cfg.betas[0], b2=params_cfg.betas[1],
+                                 eps=params_cfg.eps)]
     if mup_multipliers is not None:
         chain.append(scale_by_mup(mup_multipliers))
     if params_cfg.weight_decay and adamw:
@@ -84,25 +76,21 @@ def build_optimizer(name, params_cfg, mup_multipliers=None):
     over the schedule) so the on-device schedule stays a pure fn of step.
     """
     name = name.lower()
-    if name in (ADAM_OPTIMIZER, FUSED_ADAM_OPTIMIZER, CPU_ADAM_OPTIMIZER, ONEBIT_ADAM_OPTIMIZER):
+    if name in (ADAM_OPTIMIZER, FUSED_ADAM_OPTIMIZER, CPU_ADAM_OPTIMIZER,
+                ONEBIT_ADAM_OPTIMIZER, MUADAM_OPTIMIZER):
         # onebitadam: the LOCAL update is exact Adam -- the 1-bit part is the
         # gradient *reduction*, which the engine swaps in (error-feedback
         # sign compression over the dp axis after freeze_step; see
-        # engine._grads_for_batch_onebit and comm/compressed.py).
+        # grad_reduce.OneBit and comm/compressed.py).
         #
         # "Fused" on TPU means XLA's fusion of the whole optax chain: measured
-        # on v5e (tools/profile_bench.py, r3), the per-leaf Pallas kernel runs
+        # on v5e (tools/profile_bench.py, r3), a per-leaf Pallas kernel ran
         # at ~160 GB/s vs ~280 GB/s for the XLA elementwise fusion -- grid-step
-        # overhead on (512,128) blocks loses to XLA's own loop fusion, so the
-        # Pallas path is opt-in via type "FusedAdam", not the TPU default.
-        return _adam_like(params_cfg, adamw=False, mup_multipliers=mup_multipliers,
-                          use_fused=name == FUSED_ADAM_OPTIMIZER)
-    if name == ADAMW_OPTIMIZER:
-        return _adam_like(params_cfg, adamw=True, mup_multipliers=mup_multipliers,
-                          use_fused=False)
-    if name == MUADAM_OPTIMIZER:
+        # overhead on (512,128) blocks loses to XLA's own loop fusion.  The
+        # Pallas Adam and Lion kernels went for that reason (PR 31); the
+        # names "FusedAdam" / "FusedLion" stay accepted and build this chain.
         return _adam_like(params_cfg, adamw=False, mup_multipliers=mup_multipliers)
-    if name == MUADAMW_OPTIMIZER:
+    if name in (ADAMW_OPTIMIZER, MUADAMW_OPTIMIZER):
         return _adam_like(params_cfg, adamw=True, mup_multipliers=mup_multipliers)
     if name == SGD_OPTIMIZER:
         chain = [optax.trace(decay=params_cfg.momentum)] if params_cfg.momentum else []
@@ -124,13 +112,8 @@ def build_optimizer(name, params_cfg, mup_multipliers=None):
             optax.scale_by_trust_ratio(min_norm=0.0),
         )
     if name in (LION_OPTIMIZER, FUSED_LION_OPTIMIZER):
-        if name == FUSED_LION_OPTIMIZER:  # same opt-in rule as FusedAdam (see above)
-            from ..ops.lion import scale_by_fused_lion
-
-            core = scale_by_fused_lion(b1=params_cfg.betas[0], b2=params_cfg.betas[1])
-        else:
-            core = optax.scale_by_lion(b1=params_cfg.betas[0], b2=params_cfg.betas[1])
-        chain = [core]
+        chain = [optax.scale_by_lion(b1=params_cfg.betas[0],
+                                     b2=params_cfg.betas[1])]
         if params_cfg.weight_decay:
             chain.append(optax.add_decayed_weights(params_cfg.weight_decay,
                                                    mask=default_weight_decay_mask))

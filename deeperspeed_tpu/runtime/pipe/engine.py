@@ -17,6 +17,7 @@ import jax.numpy as jnp
 
 from ... import comm as dist
 from ...utils.logging import log_dist
+from .. import grad_reduce
 from ..engine import DeeperSpeedEngine
 from .compiled import make_pipeline_loss_fn
 from .module import PipelineModule
@@ -92,7 +93,7 @@ class PipelineEngine(DeeperSpeedEngine):
         return self._pipeline_grads
 
     def _grads_for_batch(self, master, batch, rng, scale, ltd_tokens=None,
-                         step=None):
+                         step=None, carried=None):
         # grads are taken w.r.t. the fp32 master directly; the compute-dtype
         # cast lives inside the pipeline's manual region (see compiled.py /
         # compiled_1f1b.py)
@@ -102,7 +103,7 @@ class PipelineEngine(DeeperSpeedEngine):
         self._record_pipe_wire(batch)
         # the pipeline reduces grads once over the whole batch (the sharding
         # constraint below), not per microbatch
-        self._record_grad_reduce_wire(master, 1)
+        grad_reduce.record_plain_wire(self, master, 1, self._reduction.tag)
         from ...utils.tree import tree_cast
 
         if self.config.pipeline.schedule == "1f1b":

@@ -1,5 +1,6 @@
-"""Fused Adam numerics vs optax reference (pattern of reference
-``tests/unit/ops/adam/test_cpu_adam.py``)."""
+"""The fused optimizer names build optax's own chain (the Pallas Adam and
+Lion kernels lost to XLA's fusion of it and went, PR 31): ``FusedAdam`` and
+``FusedLion`` give optax's update bit for bit, and train through the engine."""
 
 import jax
 import jax.numpy as jnp
@@ -7,46 +8,55 @@ import numpy as np
 import optax
 import pytest
 
-from deeperspeed_tpu.ops.adam.fused_adam import (
-    _adam_leaf_update_jnp,
-    scale_by_fused_adam,
-)
+from deeperspeed_tpu.runtime.config import OptimizerParams
+from deeperspeed_tpu.runtime.optimizers import build_optimizer
+
+
+def _same_updates(ours, ref, steps):
+    rng = np.random.RandomState(0)
+    params = {"w": jnp.asarray(rng.randn(64, 32).astype(np.float32)),
+              "b": jnp.asarray(rng.randn(4096).astype(np.float32))}
+    grads = jax.tree_util.tree_map(
+        lambda p: jnp.asarray(rng.randn(*p.shape).astype(np.float32)), params)
+    s1, s2 = ours.init(params), ref.init(params)
+    for _ in range(steps):
+        u1, s1 = jax.jit(ours.update)(grads, s1, params)
+        u2, s2 = jax.jit(ref.update)(grads, s2, params)
+        for k in params:
+            assert np.array_equal(np.asarray(u1[k]), np.asarray(u2[k]))
+        grads = jax.tree_util.tree_map(lambda g: g * 0.7, grads)
 
 
 def test_fused_adam_matches_optax():
-    params = {"w": jnp.ones((32, 16)), "b": jnp.zeros((16,))}
-    grads = {
-        "w": jax.random.normal(jax.random.PRNGKey(0), (32, 16)),
-        "b": jax.random.normal(jax.random.PRNGKey(1), (16,)),
-    }
-    ours = scale_by_fused_adam(b1=0.9, b2=0.999, eps=1e-8)
-    ref = optax.scale_by_adam(b1=0.9, b2=0.999, eps=1e-8)
-    s1, s2 = ours.init(params), ref.init(params)
-    for _ in range(5):
-        u1, s1 = ours.update(grads, s1, params)
-        u2, s2 = ref.update(grads, s2, params)
-    for k in params:
-        np.testing.assert_allclose(np.asarray(u1[k]), np.asarray(u2[k]), rtol=1e-5)
+    ours = build_optimizer("FusedAdam", OptimizerParams(
+        betas=[0.9, 0.999], eps=1e-8))
+    _same_updates(ours, optax.scale_by_adam(b1=0.9, b2=0.999, eps=1e-8), 5)
 
 
-def test_pallas_adam_interpret_matches_jnp():
-    """Run the Pallas kernel in interpret mode on CPU and compare to jnp math."""
-    import deeperspeed_tpu.ops.adam.pallas_adam as pa
-    from jax.experimental import pallas as pl
+def test_fused_lion_matches_optax():
+    ours = build_optimizer("FusedLion", OptimizerParams(betas=[0.9, 0.99]))
+    _same_updates(ours, optax.scale_by_lion(b1=0.9, b2=0.99), 3)
 
-    g = jax.random.normal(jax.random.PRNGKey(0), (1000,), jnp.float32)
-    m = jax.random.normal(jax.random.PRNGKey(1), (1000,)) * 0.1
-    v = jnp.abs(jax.random.normal(jax.random.PRNGKey(2), (1000,))) * 0.01
-    count = jnp.float32(3.0)
 
-    orig = pl.pallas_call
-    try:
-        pl.pallas_call = lambda *a, **kw: orig(*a, **{**kw, "interpret": True})
-        # re-jit with interpretation enabled
-        u, m2, v2 = pa.fused_adam_kernel.__wrapped__(g, m, v, count, 0.9, 0.999, 1e-8)
-    finally:
-        pl.pallas_call = orig
-    ur, mr, vr = _adam_leaf_update_jnp(g, m, v, count, 0.9, 0.999, 1e-8)
-    np.testing.assert_allclose(np.asarray(u), np.asarray(ur), rtol=1e-4, atol=1e-7)
-    np.testing.assert_allclose(np.asarray(m2), np.asarray(mr), rtol=1e-5, atol=1e-8)
-    np.testing.assert_allclose(np.asarray(v2), np.asarray(vr), rtol=1e-5, atol=1e-8)
+def test_lion_trains_via_engine():
+    _trains_via_engine("Lion")
+
+
+@pytest.mark.parametrize("name", ["FusedLion", "FusedAdam"])
+def test_fused_names_train_via_engine(name):
+    _trains_via_engine(name)
+
+
+def _trains_via_engine(name):
+    import deeperspeed_tpu as dst
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+
+    model = GPTNeoX(GPTNeoXConfig.tiny())
+    cfg = {"train_batch_size": 8, "gradient_accumulation_steps": 1,
+           "optimizer": {"type": name,
+                         "params": {"lr": 1e-4, "betas": [0.9, 0.99],
+                                    "weight_decay": 0.1}}}
+    engine, _, _, _ = dst.initialize(model=model, config=cfg)
+    batch = model.example_batch(batch_size=8, seq_len=32)
+    losses = [float(engine.train_batch(batch=batch)) for _ in range(5)]
+    assert losses[-1] < losses[0]

@@ -24,7 +24,6 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from deeperspeed_tpu.ops import pallas_utils
-from deeperspeed_tpu.ops.adam import pallas_adam
 from deeperspeed_tpu.ops.attention import core as attn_core
 from deeperspeed_tpu.ops.attention import paged, pallas_flash
 from deeperspeed_tpu.ops.quantizer import fused as qfused
@@ -177,13 +176,6 @@ def test_rms_norm_fwd_bwd(one_chip, dtype):
     fn = functools.partial(normalize.rms_norm, use_pallas=True)
     _compile(fn, x, g)
     _compile(_sum_grad(fn, 2), x, g)
-
-
-def test_fused_adam_kernel(one_chip):
-    w = _sds((50304, 1024), jnp.float32, one_chip)
-    count = _sds((), jnp.float32, one_chip)
-    _compile(functools.partial(pallas_adam.fused_adam_kernel.__wrapped__,
-                               b1=0.9, b2=0.999, eps=1e-8), w, w, w, count)
 
 
 def _pool_shapes(one_chip, pool_dtype):

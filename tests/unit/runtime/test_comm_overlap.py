@@ -57,7 +57,7 @@ def test_deferred_parity(mesh8, baseline_losses, stage, gas):
         gas=gas,
         zero_optimization={"stage": stage, "param_persistence_threshold": 1},
         comm={"overlap": {"enabled": True}}))
-    assert engine._deferred_reduce
+    assert engine._reduction.name == "deferred"
     np.testing.assert_allclose(losses, baseline_losses[gas], rtol=2e-4)
 
 
@@ -69,7 +69,7 @@ def test_deferred_bucketed_parity(mesh8, baseline_losses, stage):
         gas=2,
         zero_optimization={"stage": stage, "param_persistence_threshold": 1},
         comm={"overlap": {"enabled": True, "bucket_mb": 1e-4}}))
-    assert engine._deferred_reduce
+    assert engine._reduction.name == "deferred"
     np.testing.assert_allclose(losses, baseline_losses[2], rtol=2e-4)
 
 
@@ -81,7 +81,7 @@ def test_qgz_bucketed_parity(mesh8):
     _, plain = _train(_cfg(gas=2, comm=qgz))
     engine, bucketed = _train(_cfg(
         gas=2, comm={**qgz, "overlap": {"enabled": True, "bucket_mb": 1e-4}}))
-    assert engine._qgz and not engine._deferred_reduce
+    assert engine._reduction.name == "qgz"
     np.testing.assert_allclose(bucketed, plain, rtol=2e-2)
 
 
@@ -100,10 +100,10 @@ def test_auto_schedule_bitexact_vs_manual(mesh8, baseline_losses, stage, gas):
     engine, auto = _train(_cfg(
         gas=gas, zero_optimization=zero,
         comm={"overlap": {"enabled": True, "schedule": {"mode": "auto"}}}))
-    assert engine._sched_plan is not None
-    assert not engine._sched_plan.fallback
-    assert engine._sched_plan.grad_schedule == "deferred"
-    assert engine._deferred_reduce
+    assert engine._reduction.plan is not None
+    assert not engine._reduction.plan.fallback
+    assert engine._reduction.plan.grad_schedule == "deferred"
+    assert engine._reduction.name == "deferred"
     assert auto == manual, (auto, manual)
     np.testing.assert_allclose(auto, baseline_losses[gas], rtol=2e-4)
 
@@ -135,9 +135,9 @@ def test_auto_schedule_plans_model_parallel(reset_mesh, tmp_path):
         return engine, losses
 
     manual_engine, manual = run("manual")
-    assert not manual_engine._deferred_reduce
+    assert manual_engine._reduction.name == "per_microbatch"
     auto_engine, auto = run("auto")
-    plan = auto_engine._sched_plan
+    plan = auto_engine._reduction.plan
     assert plan is not None and not plan.fallback
     assert plan.grad_schedule == "per_microbatch" and plan.hoist
     assert auto == manual, (auto, manual)
@@ -199,7 +199,7 @@ def test_deferred_cuts_wire_bytes_by_gas(mesh8, tmp_path, stage):
                    zero_optimization={"stage": stage},
                    comm={"overlap": {"enabled": overlap}})
         engine, _ = _train(cfg, steps=1)
-        assert engine._deferred_reduce is overlap
+        assert (engine._reduction.name == "deferred") is overlap
         return _grad_reduce_bytes(engine)
 
     per_mb_bytes, per_mb_calls = bytes_for(False)
@@ -257,7 +257,7 @@ def test_deferred_falls_back_on_model_parallel(reset_mesh):
                 "optimizer": {"type": "Adam", "params": {"lr": 1e-2}},
                 "mesh": {"model_parallel_size": 2},
                 "comm": {"overlap": {"enabled": True}}})
-    assert not engine._deferred_reduce
+    assert engine._reduction.name == "per_microbatch"
 
 
 def test_prefetch_checkpoint_position(mesh8, tmp_path):

@@ -35,7 +35,7 @@ def test_warmup_matches_plain_adam_exactly(mesh8, onebit_trajectories):
     _, base, _ = onebit_trajectories
     ob, engine = _run(_cfg(freeze_step=100), steps=3)
     np.testing.assert_allclose(ob, base[:3], rtol=1e-6, atol=1e-7)
-    assert engine._onebit
+    assert engine._reduction.name == "onebit"
 
 
 @pytest.fixture(scope="module")

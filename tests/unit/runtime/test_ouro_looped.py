@@ -355,19 +355,40 @@ def one_device():
     return MeshTopology(devices=jax.devices()[:1])
 
 
-def looped_engine(**model_kw):
+def looped_engine(replicas=1, config=None, **model_kw):
     model = tiny_model(dtype=jnp.bfloat16, remat=True, **model_kw)
-    engine, _, _, _ = dst.initialize(model=model, mesh=one_device(), config={
-        "train_batch_size": 4, "train_micro_batch_size_per_gpu": 2,
-        "gradient_accumulation_steps": 2,
-        "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
-        "bf16": {"enabled": True}, "zero_optimization": {"stage": 0},
-        "gradient_clipping": 1.0, "steps_per_print": 10 ** 9})
+    engine, _, _, _ = dst.initialize(
+        model=model, mesh=MeshTopology(devices=jax.devices()[:replicas]),
+        config={
+            "train_batch_size": 4,
+            "train_micro_batch_size_per_gpu": 2 // replicas,
+            "gradient_accumulation_steps": 2,
+            "optimizer": {"type": "Adam", "params": {"lr": 1e-3}},
+            "bf16": {"enabled": True}, "zero_optimization": {"stage": 0},
+            "gradient_clipping": 1.0, "steps_per_print": 10 ** 9,
+            **(config or {})})
     return engine, model.example_batch(batch_size=4, seq_len=32)
 
 
-def test_engine_trains_the_looped_model_and_publishes_its_counters():
-    engine, batch = looped_engine()
+#: every reduction of ``runtime/grad_reduce.py`` runs the one micro body, so
+#: each hands on what the model says of its step: (replicas, config on top)
+REDUCTIONS = {
+    "per_microbatch": (1, {}),
+    "deferred": (2, {"comm": {"overlap": {"enabled": True}}}),
+    "onebit": (2, {"optimizer": {"type": "OneBitAdam", "params": {
+        "lr": 1e-3, "freeze_step": 3}}}),
+    "qgz": (2, {"comm": {"quantized": {"enabled": True}}}),
+}
+
+
+@pytest.mark.parametrize("reduction", list(REDUCTIONS))
+def test_engine_trains_the_looped_model_and_publishes_its_counters(
+        reset_mesh, reduction):
+    from deeperspeed_tpu.telemetry import trace
+
+    trace._STEP_COUNTERS.clear()
+    engine, batch = looped_engine(*REDUCTIONS[reduction])
+    assert engine._reduction.name == reduction
     losses = [float(engine.train_batch(batch=batch)) for _ in range(6)]
     assert losses[-1] < losses[0] - 0.5
     counters = telemetry.step_counters()["train_step"]

@@ -660,28 +660,140 @@ def scenario_slow_step(workdir, writer=None):
     return results
 
 
-def scenario_flood(workdir, writer=None):
-    """An admission burst far beyond capacity: shedding must engage (with
-    capped-exponential retry-after), the front end must end the flood
-    serving again with zero leaks, and goodput-under-deadline must beat
-    the no-shedding baseline."""
+class VirtualClock:
+    """The serving modules' clock, in the harness's hands: while installed
+    it stands in for the ``time`` module of the front end, its admission
+    and ladder, the scheduler and the replica pool, so every deadline,
+    retry-after, heartbeat and probe cool-down is reckoned on ONE clock
+    that moves only when the scenario says so (``advance``) or the code
+    under test sleeps.  A loaded host then changes how long a scenario
+    takes, never what it asserts.  The policies themselves run untouched."""
+
+    def __init__(self, start=1000.0):
+        self.now = float(start)
+        self._saved = {}
+
+    def monotonic(self):
+        return self.now
+
+    def sleep(self, seconds):
+        self.now += float(seconds)
+
+    advance = sleep
+
+    def __enter__(self):
+        from deeperspeed_tpu.inference.v2 import (frontend, replica,
+                                                  resilience, scheduler)
+
+        for mod in (frontend, replica, resilience, scheduler):
+            self._saved[mod] = mod.time
+            mod.time = self
+        return self
+
+    def __exit__(self, *exc):
+        for mod, real in self._saved.items():
+            mod.time = real
+        self._saved.clear()
+
+
+def scenario_flood(workdir, writer=None, n_requests=48, prompt_len=24,
+                   decode_tokens=32, round_s=0.01):
+    """An admission burst far beyond capacity, on the front end's own clock
+    (``VirtualClock``, every serving round ``round_s`` long): shedding must
+    engage, the retry-after hints must grow capped-exponentially along a
+    streak of sheds, every request the shedding front end admits must
+    finish inside its deadline (an admission is a promise) while the same
+    burst on a front end that admits everything lets admitted work expire,
+    and the flood must end with the front end serving again and zero
+    leaks."""
+    import numpy as np
+
     _force_cpu()
-    from tools.bench_inference import run_flood_bench
+    from deeperspeed_tpu.inference.v2 import RequestState
+    from tools.bench_inference import _flood_frontend
+
+    rng = np.random.default_rng(0)
+    prompts = [list(rng.integers(0, 256, size=prompt_len))
+               for _ in range(n_requests + 1)]
+
+    def turn(front, clock):
+        front.step()
+        clock.advance(round_s)
+
+    def flood(shed, clock):
+        """-> (front end, tickets, deadline): the deadline is 1.5x what one
+        uncontended request takes on this clock; 3 arrivals a round."""
+        front = _flood_frontend(shed=shed,
+                                max_ctx=prompt_len + decode_tokens + 8)
+        t0 = clock.now
+        alone = front.submit(prompts[-1], max_new_tokens=decode_tokens)
+        while front.has_work:
+            turn(front, clock)
+        assert alone.state is RequestState.DONE
+        deadline_s = 1.5 * (clock.now - t0)
+        tickets = []
+        for i in range(0, n_requests, 3):
+            tickets += [front.submit(p, deadline_s=deadline_s,
+                                     max_new_tokens=decode_tokens)
+                        for p in prompts[i:i + 3]]
+            turn(front, clock)
+        while front.has_work:
+            turn(front, clock)
+        return front, tickets, deadline_s
 
     results = []
     reg, restore = _serving_registry()
     try:
-        bench = run_flood_bench()
-        assert bench["shed_count"] > 0, "flood never shed a request"
-        assert bench["retry_after_max_s"] > 0, "sheds carried no retry-after"
-        assert bench["goodput_shed"] > bench["goodput_noshed"], \
-            (f"shedding did not improve goodput-under-deadline: "
-             f"{bench['goodput_shed']} <= {bench['goodput_noshed']}")
-        assert reg.counter("infer/shed_count").total > 0
+        with VirtualClock() as clock:
+            fe, tickets, deadline_s = flood(True, clock)
+            shed = [t for t in tickets if t.state is RequestState.SHED]
+            admitted = [t for t in tickets if t.state is not RequestState.SHED]
+            assert shed, "flood never shed a request"
+            assert admitted, "flood shed everything"
+            late = [t.uid for t in admitted if not t.met_deadline]
+            assert not late, \
+                f"admitted under shedding, yet past {deadline_s:.2f}s: {late}"
+            # retry-after: base * 2^(n-1) clamped to the cap, n the length
+            # of the shed streak so far, within the configured jitter
+            cfg = fe.config
+            base, cap, jit = (cfg.retry_after_base_s, cfg.retry_after_cap_s,
+                              cfg.retry_after_jitter_frac)
+            streak = longest = 0
+            for t in tickets:
+                if t.state is not RequestState.SHED:
+                    streak = 0
+                    continue
+                streak += 1
+                longest = max(longest, streak)
+                nominal = min(cap, base * 2.0 ** (streak - 1))
+                assert ((1 - jit) * nominal - 1e-9 <= t.retry_after_s
+                        <= min(cap, (1 + jit) * nominal) + 1e-9), \
+                    (f"shed {streak} of a streak hinted {t.retry_after_s}s, "
+                     f"nominal {nominal}s")
+            assert longest >= 3 and (
+                max(t.retry_after_s for t in shed)
+                >= 2 * min(t.retry_after_s for t in shed)), \
+                "retry-after never grew along a shed streak"
+            assert fe.admission.shed_count == len(shed)
+            assert reg.counter("infer/shed_count").total >= len(shed)
+            assert fe.expired_count == 0
+            assert_serving_recovered(fe, "flood")
+
+            base_fe, base_tickets, _ = flood(False, clock)
+            assert not any(t.state is RequestState.SHED
+                           for t in base_tickets)
+            in_time = [t for t in base_tickets if t.met_deadline]
+            assert base_fe.expired_count > 0 and \
+                len(in_time) + base_fe.expired_count == n_requests, \
+                (f"admitting everything: {len(in_time)} in time, "
+                 f"{base_fe.expired_count} expired of {n_requests}")
+            assert_serving_recovered(base_fe, "flood (no shedding)")
         results.append(
-            f"flood: shed {bench['shed_count']} requests, goodput "
-            f"{bench['goodput_shed']} vs {bench['goodput_noshed']} tokens "
-            f"without shedding")
+            f"flood: shed {len(shed)} of {n_requests} (retry-after up to "
+            f"{max(t.retry_after_s for t in shed):.2f}s over a streak of "
+            f"{longest}), all {len(admitted)} admitted finished inside "
+            f"{deadline_s:.2f}s on the front end's clock; with everything "
+            f"admitted {len(in_time)} did and {base_fe.expired_count} expired")
     finally:
         restore()
     return results
@@ -977,46 +1089,68 @@ def scenario_replica_slow(workdir, writer=None):
     return results
 
 
-def scenario_replica_flap(workdir, writer=None):
-    """A replica that dies, recovers, and dies again: every flap must fail
-    its work over cleanly, and the probe backoff must GROW across quick
-    re-ejections (flap damping) instead of resetting."""
+def scenario_replica_flap(workdir, writer=None, cooldown_s=0.01,
+                          cooldown_cap_s=1.0):
+    """A replica that dies, recovers, and dies again, on the pool's own
+    clock (``VirtualClock``): every flap must fail its work over cleanly,
+    each ejection must sit out its probe cool-down before ONE probe
+    re-admits the replica, and the cool-down must GROW across the quick
+    re-ejection (flap damping) instead of resetting."""
     from deeperspeed_tpu.inference.v2 import ReplicaState, RequestState
+    from deeperspeed_tpu.inference.v2.resilience import capped_exponential
 
     results = []
     reg, restore = _serving_registry()
     try:
-        fe, _ = _replica_pool(
-            n=2, pool={"probe_cooldown_s": 0.01,
-                       "probe_cooldown_cap_s": 1.0,
-                       "flap_window_s": 60.0})
-        victim = fe.replicas[0]
-        done = []
-        for episode in range(2):
-            t = fe.submit([episode + 1, 2, 3, 4, 5], max_new_tokens=4,
-                          deadline_s=60.0)
-            done.append(t)
-            if fe._entries[t.uid].replica is not victim:
-                fe.step()   # make sure the victim has SOME work first
-            victim.fault = "kill"
-            fe.run_until_idle()
-            assert victim.state is ReplicaState.EJECTED
-            victim.fault = None
-            fe.run_until_settled()
-            assert victim.state is ReplicaState.HEALTHY, \
-                f"episode {episode}: not re-admitted ({victim.state})"
-        assert victim.eject_count == 2
-        # flap damping: probe attempts carried across the quick re-eject,
-        # so the second episode probed at a LONGER cooldown
-        assert victim.probe_attempts >= 2, \
-            (f"probe backoff reset across flaps "
-             f"(attempts {victim.probe_attempts})")
-        for t in done:
-            assert t.state is RequestState.DONE, f"{t.uid} ended {t.state}"
-        _pool_clean(fe, "replica_flap")
+        with VirtualClock() as clock:
+            fe, _ = _replica_pool(
+                n=2, pool={"probe_cooldown_s": cooldown_s,
+                           "probe_cooldown_cap_s": cooldown_cap_s,
+                           "flap_window_s": 60.0})
+            victim = fe.replicas[0]
+            done, cooldowns = [], []
+            for episode in range(2):
+                t = fe.submit([episode + 1, 2, 3, 4, 5], max_new_tokens=4,
+                              deadline_s=60.0)
+                done.append(t)
+                assert fe._entries[t.uid].replica is victim, \
+                    f"episode {episode}: the idle pool routed past replica 0"
+                victim.fault = "kill"
+                fe.run_until_idle()
+                assert victim.state is ReplicaState.EJECTED
+                assert victim.eject_count == episode + 1
+                assert t.state is RequestState.DONE, \
+                    f"{t.uid} ended {t.state} after its replica died"
+                assert fe.failover_count == episode + 1
+                victim.fault = None
+                # flap damping: the attempts of the first episode carried
+                # across the quick re-eject, so this cool-down is longer
+                cooldown = capped_exponential(cooldown_s, cooldown_cap_s,
+                                              episode + 1)
+                cooldowns.append(cooldown)
+                clock.advance(0.9 * cooldown)
+                fe.step()
+                assert (victim.state is ReplicaState.EJECTED
+                        and victim.probe_attempts == episode), \
+                    (f"episode {episode}: probed {0.9 * cooldown:.4f}s after "
+                     f"the ejection, inside its {cooldown:.4f}s cool-down")
+                clock.advance(0.2 * cooldown)
+                fe.step()
+                assert victim.state is ReplicaState.PROBING, \
+                    f"episode {episode}: no probe after the cool-down"
+                fe.run_until_settled()
+                assert victim.state is ReplicaState.HEALTHY, \
+                    f"episode {episode}: not re-admitted ({victim.state})"
+                assert victim.probe_attempts == episode + 1, \
+                    (f"probe backoff reset across flaps "
+                     f"(attempts {victim.probe_attempts})")
+                assert fe.readmitted_count == episode + 1
+            assert cooldowns[1] == 2 * cooldowns[0]
+            _pool_clean(fe, "replica_flap")
         results.append(
-            f"2 flaps survived: eject_count={victim.eject_count}, "
-            f"probe backoff grew to attempt {victim.probe_attempts}")
+            f"2 flaps survived: eject_count={victim.eject_count}, one probe "
+            f"each after cool-downs of {cooldowns[0]:.3f}s then "
+            f"{cooldowns[1]:.3f}s on the pool's clock")
     finally:
         restore()
     return results
