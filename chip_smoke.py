@@ -17,6 +17,7 @@ Without a TPU it exits non-zero and prints no such line.
 """
 
 import argparse
+import dataclasses
 import gc
 import json
 import math
@@ -153,8 +154,10 @@ def check_losses(losses):
 
 # ------------------------------------------------------------------- train
 def phase_train(model, params):
+    # with remat, as the benchmark's train-410m cell runs the model
+    model = type(model)(dataclasses.replace(model.config, remat=True))
     engine, hlo, facts = run_train(model, params, MICRO_BATCH, zero_stage=0)
-    from deeperspeed_tpu.telemetry import kernel_paths
+    from deeperspeed_tpu.telemetry import count_kernel_passes, kernel_paths
 
     calls = kernel_calls(hlo)
     problems = check_losses(facts["losses"])
@@ -166,8 +169,17 @@ def phase_train(model, params):
     paths = kernel_paths()
     if set(paths.get("flash_attention", ())) != {"in_place_2"}:
         problems.append(f"flash attention not in place: {paths}")
+    # no profiler session here, so ``telemetry.kernel_passes()`` is empty:
+    # its count, from the step's text.  A recomputed block keeps the flash
+    # kernel's output and lse and must not run the forward kernel again
+    layers = model.config.num_layers
+    passes = count_kernel_passes(hlo)
+    if passes.get("flash_attention") != dict(forward=layers, recomputed=0,
+                                             backward=layers):
+        problems.append(f"flash attention's passes under remat: {passes}")
     emit("train", ok=not problems, problems=problems, kernel_paths=paths,
-         model="pythia_410m", layers=model.config.num_layers,
+         kernel_passes=passes, remat=True,
+         model="pythia_410m", layers=layers,
          hidden=model.config.hidden_size, seq=SEQ, batch=MICRO_BATCH,
          steps=TRAIN_STEPS, zero_stage=0, dtype="bfloat16",
          pallas_calls={k: len(v) for k, v in calls.items()},

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
+from ..ops.attention.pallas_flash import SAVED_BY_REMAT
 from ..ops.transformer.cross_entropy import chunked_linear_cross_entropy
 from ..parallel.topology import BATCH_AXES
 
@@ -509,7 +510,14 @@ class GPTNeoX(nn.Module):
                          name="embed_in")(input_ids).astype(cfg.dtype)
         block = GPTNeoXBlock
         if cfg.remat:
-            block = nn.remat(GPTNeoXBlock, static_argnums=(3,))
+            # a recomputed block keeps the flash kernel's own two residuals
+            # (its output and a float a row of lse: one more [B, S, H] a
+            # layer), so the backward pass does not run the forward kernel
+            # again; everything else of the block is recomputed as before
+            block = nn.remat(
+                GPTNeoXBlock, static_argnums=(3,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *SAVED_BY_REMAT))
         moe_layers = set(cfg.moe_layer_indices())
         for i in range(L):
             blk = block(cfg, use_moe=i in moe_layers, decode=self.decode,

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention.core import dot_product_attention
+from ..ops.attention.pallas_flash import SAVED_BY_REMAT
 from ..ops.transformer.rope import apply_rotary_pos_emb, rotary_tables
 from ..parallel.topology import BATCH_AXES
 from .gpt_neox import ModelLayerNorm, maybe_constrain
@@ -417,7 +418,13 @@ class Llama(nn.Module):
                     name="embed_positions")(positions).astype(cfg.dtype)
         block = LlamaBlock
         if cfg.remat:
-            block = nn.remat(LlamaBlock, static_argnums=(3,))
+            # as GPT-NeoX: a dense model uses a layer once a step, so keeping
+            # the flash kernel's output and lse costs one more [B, S, H] a
+            # layer and saves the forward kernel's second run
+            block = nn.remat(
+                LlamaBlock, static_argnums=(3,),
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    *SAVED_BY_REMAT))
         for i in range(cfg.num_layers):
             x = block(cfg, decode=self.decode, paged=self.paged,
                       name=f"layers_{i}")(

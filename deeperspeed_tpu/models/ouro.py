@@ -153,6 +153,11 @@ class Ouro(nn.Module):
         cfg = self.config
         self.embed_tokens = nn.Embed(cfg.vocab_size, cfg.hidden_size,
                                      dtype=jnp.float32)
+        # no policy, unlike the dense models (gpt_neox.py, llama.py): T
+        # passes over L layers would keep T * L kernel outputs (32 x 67.1 MB
+        # = 2.15 GB at [4, 4096, 2048] on the 14.38 GB the 2.6B cell holds,
+        # 16.5 GB of a 16.9 GB chip), and the scan over passes cannot save
+        # some passes and not others: the forward kernel runs again instead
         block = nn.remat(OuroBlock) if cfg.remat else OuroBlock
         self.layers = [block(cfg) for _ in range(cfg.num_layers)]
         self.final_norm = _Norm(cfg.block_config())

@@ -23,8 +23,9 @@ at D = 64: a program then runs both heads' walks on full-width operands, the
 row-side operand zeroed outside the head's own lanes (see ``_own_lanes``).
 Other shapes (``_heads_to_a_block`` says which, from N and D alone) are
 folded to ``[B*N, S, D]`` by a copy each way, and run the same kernels with
-one group a batch row.  The statistics (LSE, and the two-pass backward's
-delta) are the kernels' own: ``[B*N, S, 128]``, a row per head.
+one group a batch row.  The LSE is the kernels' own: float32
+``[B' * G, heads, S]``, one value a row with the rows on lanes, a row of
+statistics per head and the heads of a lane block together.
 
 Two-level tiling: the block the grid *loads* and the tile the kernel
 *computes* are separate sizes, chosen from the shapes by ``tile_plan``.
@@ -69,9 +70,19 @@ the causal mask a valid row never sees a padded column).
 Arbitrary sequence lengths are handled by padding S up to the 128-lane tile
 and masking padded *columns* out of the softmax (padded rows cost dead FLOPs
 but keep >=1 valid column, so no NaNs; their dO is zero so they contribute
-nothing to dK/dV).  LSE is stored lane-replicated ([B*N, S, 128] fp32) --
-the upstream TPU kernel's idiom -- so the backward reads it as a
-sublane-aligned column with no relayout.
+nothing to dK/dV).
+
+The LSE is lane-replicated only in VMEM (the running m and l, and the copy
+the backward's tiles read as a sublane-aligned column).  To HBM it goes as
+one float a row: the forward transposes each row group's statistics onto
+lanes once, the backward transposes a head's back once, before its walk
+(``_rows_onto_lanes``, ``_rows_off_lanes``: 128 x 128 transposes on the XLU,
+which the softmax's row reductions also use: the forward call alone moved by
+-0.8 to +5 %, BENCH_KERNELS.md).  Replicated it was four times the bytes of
+``o`` at D = 64, which is what made it too dear to keep: ``_mha_fwd`` names
+``o`` and the LSE for ``jax.checkpoint`` policies (``SAVED_BY_REMAT``), and a
+model whose remat wrap saves those two recomputes a block without running
+the forward kernel a second time (``models/gpt_neox.py``).
 """
 
 import functools
@@ -79,6 +90,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 
 from ..pallas_utils import LANES, NEG_INF, interpret_mode
@@ -91,6 +103,13 @@ _TN = (((0,), (0,)), ((), ()))      # a.T @ b
 # plan splits it, and the most a call may state as its scoped limit
 _VMEM_BUDGET = 48 << 20
 _VMEM_LIMIT = 100 << 20
+
+# ``jax.ad_checkpoint`` names of the two residuals the forward kernel itself
+# writes (its output and the lse: one bf16 [B, S, N*D] and a float a row).
+# A model whose remat wrap saves exactly these
+# (``jax.checkpoint_policies.save_only_these_names(*SAVED_BY_REMAT)``)
+# recomputes a block without the kernel; q, k, v are still recomputed.
+SAVED_BY_REMAT = ("flash_attention_out", "flash_attention_lse")
 
 
 class Plan(NamedTuple):
@@ -159,11 +178,12 @@ def tile_plan(S, D, dtype, block=None, N=1):
 
 
 def _bwd_resident_bytes(sp, w, itemsize, heads=1):
-    """VMEM the one-kernel backward holds per program: q, do, o and each
-    head's lse double-buffered, the fp32 dq accumulator, the dq output
-    block."""
-    return (2 * 3 * sp * w * itemsize + 2 * heads * sp * LANES * 4
-            + sp * w * 4 + 2 * sp * w * itemsize)
+    """VMEM the one-kernel backward holds per program: q, do, o and the
+    heads' lse (a sublane tile of rows on lanes) double-buffered, each
+    head's lse again as the tiles read it (lane-replicated), the fp32 dq
+    accumulator, the dq output block."""
+    return (2 * 3 * sp * w * itemsize + 2 * 8 * sp * 4
+            + heads * sp * LANES * 4 + sp * w * 4 + 2 * sp * w * itemsize)
 
 
 def _edge_tiles(block, sub, causal):
@@ -283,6 +303,24 @@ def _each_its_lanes(parts, width):
     return out
 
 
+# ----------------------------------------------- the statistics' two layouts
+# In VMEM a row statistic (m, l, the lse) is a sublane-aligned column,
+# replicated over the 128 lanes, so a score tile reads it with no relayout.
+# In HBM the lse is one float a row, rows on lanes: ``[B' * G, heads, S]``
+# (replicated it was 128 times the bytes, four times ``o``'s, written by
+# every forward call, read by every backward call and kept by a remat policy
+# that saves it).  The two functions below pass between the layouts, once
+# per row group and head, never per score tile.
+def _rows_onto_lanes(x):
+    """``[rows, 1]``, or ``[rows, 128]`` lane-replicated -> ``[1, rows]``."""
+    return jnp.broadcast_to(x, (x.shape[0], LANES)).T[:1]
+
+
+def _rows_off_lanes(x):
+    """``[1, rows]`` -> ``[rows, 128]`` lane-replicated."""
+    return jnp.broadcast_to(x, (LANES, x.shape[1])).T
+
+
 # --------------------------------------------------------------------- fwd
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 causal, pad, s_valid, block, sub, n, heads):
@@ -330,8 +368,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
                 for p, (cols, _) in zip(ps, pieces))
             if n == 1:
                 accs.append(acc / l_new)
-                lse_ref[h, rows, :] = jnp.broadcast_to(
-                    m_new + jnp.log(l_new), (sub, LANES))
+                lse_ref[0, pl.ds(h, 1), rows] = _rows_onto_lanes(
+                    m_new + jnp.log(l_new))
                 continue
             alpha = jnp.exp(m_prev - m_new)
             accs.append(acc)
@@ -370,12 +408,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     def _finalize():
         l = _each_its_lanes([l_scr[h, :, :1] for h in range(heads)], width)
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
-        lse_ref[:] = m_scr[:] + jnp.log(l_scr[:])
+        for h in range(heads):
+            for r in range(0, block, sub):
+                rows = pl.ds(r, sub)
+                lse_ref[0, pl.ds(h, 1), rows] = _rows_onto_lanes(
+                    m_scr[h, rows, :] + jnp.log(l_scr[h, rows, :]))
 
 
 # ---------------------------------------------------------------------- bwd
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                dq_ref, dk_ref, dv_ref, dk_scr, dv_scr, *dq_scr,
+                dq_ref, dk_ref, dv_ref, lse_scr, dk_scr, dv_scr, *dq_scr,
                 causal, pad, s_valid, block, sub, rows, n, heads):
     """One k/v block against its heads' resident q side: dk and dv of the
     block, and the block's share of the heads' dq (all of it when a head
@@ -385,8 +427,14 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     if n > 1:
         dq_scr, = dq_scr
 
-        @pl.when(kj == 0)
-        def _init_head():
+    @pl.when(kj == 0)
+    def _init_head():
+        # the heads' lse as the tiles read it, once for all their k/v blocks
+        for h in range(heads):
+            for r in range(0, n * block, rows):
+                lse_scr[h, pl.ds(r, rows), :] = _rows_off_lanes(
+                    lse_ref[0, pl.ds(h, 1), pl.ds(r, rows)])
+        if n > 1:
             dq_scr[:] = jnp.zeros_like(dq_scr)
 
     dk_scr[:] = jnp.zeros_like(dk_scr)
@@ -400,7 +448,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         for h in range(heads):
             q = _own_lanes(q_ref[0, rows, :], h, heads)
             do = _own_lanes(do_ref[0, rows, :], h, heads)
-            lse = lse_ref[h, rows, :1]
+            lse = lse_scr[h, rows, :1]
             # delta = rowsum(dO * O), recomputed per tile: [nrows, D] of
             # work beside the tile's [nrows, ncols]
             delta = jnp.sum(do.astype(jnp.float32)
@@ -547,8 +595,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # ------------------------------------------------------------------ calls
 # Operands are ``[B', S, G * width]``: ``G`` column groups of ``width``
 # lanes, each ``heads`` heads side by side.  In place B' = B and
-# G = N / heads; folded B' = B * N and G = 1.  The statistics (lse, delta)
-# are the kernels' own, ``[B' * G * heads, S, 128]``: a row per head.
+# G = N / heads; folded B' = B * N and G = 1.  The lse is the kernels' own,
+# ``[B' * G, heads, S]``; the two-pass backward's kernels read it and their
+# delta lane-replicated, ``[B' * G * heads, S, 128]``: a row per head.
 def _pad_seq(x, block):
     s = x.shape[1]
     sp = -(-s // block) * block
@@ -577,6 +626,20 @@ def _params(*semantics, vmem=None):
         dimension_semantics=semantics, vmem_limit_bytes=vmem))
 
 
+def _cost(q, plan, causal, matmuls, tensors):
+    """What a call costs, for XLA's scheduler: it decides what to run beside
+    a kernel (the copies that bring the next operands) by this, and without
+    it by the call's bytes, which the compact lse cut by half.  ``matmuls``
+    of ``2 * S * S * D`` FLOPs a head, ``tensors`` of q's size and the lse
+    through HBM."""
+    b, sp, hw = q.shape
+    heads = hw // (plan.width // max(plan.group, 1))
+    square = b * heads * sp * sp // (2 if causal else 1)
+    return pl.CostEstimate(
+        flops=2 * matmuls * square * (hw // heads), transcendentals=square,
+        bytes_accessed=tensors * q.size * q.dtype.itemsize + 4 * b * heads * sp)
+
+
 def _fwd_call(q, k, v, causal, s_valid, plan):
     b, sp, hw = q.shape
     block, span, w = plan.block, plan.span, plan.width
@@ -595,7 +658,8 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
     itemsize = q.dtype.itemsize
     need = (4 * span * w * itemsize             # k, v, double-buffered
             + 4 * block * w * itemsize          # q, o
-            + 4 * heads * block * LANES * 4     # lse out; m, l
+            + 2 * heads * block * LANES * 4     # m, l
+            + 2 * 8 * block * 4                 # lse out, a sublane tile
             + block * w * 4                     # acc
             + 3 * plan.sub * block * 4)         # a score tile, its exp, slack
     kernel = functools.partial(
@@ -610,19 +674,19 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
                   pl.BlockSpec((1, span, w), kv_index)],
         out_specs=[
             owned,
-            pl.BlockSpec((heads, block, LANES),
-                         lambda b, g, i, j: (b * groups + g, i, 0)),
+            pl.BlockSpec((1, heads, block),
+                         lambda b, g, i, j: (b * groups + g, 0, i)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, sp, hw), q.dtype),
-            jax.ShapeDtypeStruct((b * groups * heads, sp, LANES),
-                                 jnp.float32),
+            jax.ShapeDtypeStruct((b * groups, heads, sp), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((heads, block, LANES), jnp.float32),
             pltpu.VMEM((heads, block, LANES), jnp.float32),
             pltpu.VMEM((block, w), jnp.float32),
         ] if n > 1 else [],
+        cost_estimate=_cost(q, plan, causal, matmuls=2, tensors=4),
         interpret=interpret_mode(),
         **_params("parallel", "parallel", "parallel", "arbitrary",
                   vmem=_vmem_limit(need)),
@@ -639,7 +703,7 @@ def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
     from jax.experimental.pallas import tpu as pltpu
 
     head = pl.BlockSpec((1, sp, w), lambda b, g, j: (b, 0, g))
-    head_stat = pl.BlockSpec((heads, sp, LANES),
+    head_stat = pl.BlockSpec((1, heads, sp),
                              lambda b, g, j: (b * groups + g, 0, 0))
     owned = pl.BlockSpec((1, block, w), lambda b, g, j: (b, j, g))
     out = jax.ShapeDtypeStruct((b, sp, hw), q.dtype)
@@ -657,9 +721,11 @@ def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
         in_specs=[head, owned, owned, head, head, head_stat],
         out_specs=[head, owned, owned],
         out_shape=[out, out, out],
-        scratch_shapes=[pltpu.VMEM((block, w), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((heads, sp, LANES), jnp.float32),
+                        pltpu.VMEM((block, w), jnp.float32),
                         pltpu.VMEM((block, w), jnp.float32)]
         + [pltpu.VMEM((sp, w), jnp.float32)] * (n > 1),
+        cost_estimate=_cost(q, plan, causal, matmuls=5, tensors=8),
         interpret=interpret_mode(),
         **_params("parallel", "parallel", "arbitrary",
                   vmem=_vmem_limit(need)),
@@ -672,7 +738,9 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     b, sp, hw = q.shape
     w = plan.width
     heads, groups = max(plan.group, 1), hw // w
-    # a row of statistics per head, as the lse has it
+    # its kernels read both statistics lane-replicated, a row per head
+    lse = jnp.broadcast_to(lse.reshape(b * groups * heads, sp, 1),
+                           (b * groups * heads, sp, LANES))
     delta = jnp.sum((do.astype(jnp.float32) * o.astype(jnp.float32))
                     .reshape(b, sp, groups * heads, w // heads), axis=-1)
     delta = jnp.broadcast_to(
@@ -734,6 +802,10 @@ def _mha_fwd(q, k, v, causal, scale, plan):
     # score tile inside the kernels; dq is post-scaled in _mha_bwd
     qp = qp * jnp.asarray(scale, qp.dtype)
     o, lse = _fwd_call(qp, kp, vp, causal, s_valid, plan)
+    # what a remat policy keeps (``SAVED_BY_REMAT``) so that a recomputed
+    # block does not run the forward kernel again for its own residuals
+    o, lse = (checkpoint_name(t, name) for t, name in
+              zip((o, lse), SAVED_BY_REMAT))
     return o[:, :s_valid], (qp, kp, vp, o, lse)
 
 

@@ -28,8 +28,9 @@ from ..accelerator import get_accelerator
 from ..monitor.monitor import MonitorMaster
 from ..parallel import topology as topo
 from ..telemetry.trace import (TraceSessionWatch, compile_stats,
-                               publish_step_counters, publish_step_scopes,
-                               span, step_scopes, step_span)
+                               publish_kernel_passes, publish_step_counters,
+                               publish_step_scopes, span, step_scopes,
+                               step_span)
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -1347,12 +1348,15 @@ class DeeperSpeedEngine:
 
     def _publish_scopes(self, fn, *args):
         """``telemetry.step_scopes()`` gets the scope of every instruction
-        of ``fn``'s compiled program.  With the live arguments of the call
-        about to be made the executable comes from jit's in-memory cache:
-        nothing compiles and nothing is loaded."""
+        of ``fn``'s compiled program, ``telemetry.kernel_passes()`` its
+        kernel calls by pass.  With the live arguments of the call about to
+        be made the executable comes from jit's in-memory cache: nothing
+        compiles and nothing is loaded."""
         t0 = time.perf_counter()
         try:
-            name = publish_step_scopes(fn.lower(*args).compile().as_text())
+            text = fn.lower(*args).compile().as_text()
+            name = publish_step_scopes(text)
+            publish_kernel_passes(text)
         except Exception as e:
             logger.warning(f"telemetry: step scopes not published ({e})")
             return

@@ -21,8 +21,8 @@ Four pieces (see README "Observability"):
   and the :class:`FlightRecorder` postmortem ring; :func:`span`, the one
   primitive every live span goes through (a ``dst:`` event on the
   ``jax.profiler`` timeline, and a ring record when the tracer is on),
-  :func:`compile_stats`, :func:`step_scopes`, :func:`step_counters` and
-  :func:`kernel_paths`;
+  :func:`compile_stats`, :func:`step_scopes`, :func:`step_counters`,
+  :func:`kernel_paths` and :func:`kernel_passes`;
 * :mod:`aggregate` -- mergeable registry snapshots + the pool-side
   :class:`MetricsAggregator` (counters sum, histograms merge bucket-wise,
   quantiles interpolate post-merge);
@@ -41,8 +41,9 @@ from .registry import (LATENCY_BUCKETS_S, CounterChannel, HistogramChannel,
                        set_registry)
 from .slo import SLOAlert, SLOBurnEvaluator
 from .trace import (FlightRecorder, Span, TraceContext, Tracer, compile_stats,
-                    get_tracer, kernel_paths, set_tracer, slo_percentiles,
-                    span, step_counters, step_scopes, tracer_from_config)
+                    count_kernel_passes, get_tracer, kernel_passes,
+                    kernel_paths, set_tracer, slo_percentiles, span,
+                    step_counters, step_scopes, tracer_from_config)
 from .watchdog import StallWatchdog
 from .wire import plain_wire_bytes, q_bytes, quantized_variant, wire_bytes
 from . import serving  # noqa: F401  (typed serving-resilience events)
@@ -54,6 +55,7 @@ __all__ = [
     "Tracer", "TraceContext", "Span", "FlightRecorder", "get_tracer",
     "set_tracer", "tracer_from_config", "slo_percentiles", "span",
     "compile_stats", "step_scopes", "step_counters", "kernel_paths",
+    "kernel_passes", "count_kernel_passes",
     "StallWatchdog", "step_cost", "compiled_cost",
     "utilization", "device_peaks", "TPU_PEAK_SPECS", "wire_bytes", "q_bytes",
     "plain_wire_bytes", "quantized_variant", "serving",

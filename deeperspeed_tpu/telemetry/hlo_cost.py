@@ -90,16 +90,18 @@ def pallas_kernel_calls(hlo_text):
     did not give way to its XLA reference -- and on what size of operand."""
     import re
 
+    from .trace import _KERNEL_CALL, _KERNEL_OP_NAME
+
     found = {}
     for line in hlo_text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' not in line:
+        if _KERNEL_CALL not in line:
             continue
-        scope = re.search(r'op_name="[^"]*?(\w+)/pallas_call', line)
+        scope = _KERNEL_OP_NAME.search(line)
         operands = line.split("operand_layout_constraints={", 1)[-1]
         operands = re.split(r"}, \w+=", operands, maxsplit=1)[0]
         shapes = [tuple(int(d) for d in dims.split(",") if d)
                   for dims in re.findall(r"\w+\[([\d,]*)\]", operands)]
-        found.setdefault(scope.group(1) if scope else "?", []).append(shapes)
+        found.setdefault(scope.group(2) if scope else "?", []).append(shapes)
     return found
 
 

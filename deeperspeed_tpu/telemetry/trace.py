@@ -680,6 +680,110 @@ def kernel_paths():
     return {kernel: dict(paths) for kernel, paths in _KERNEL_PATHS.items()}
 
 
+# ---------------------------------------------------------- kernel passes
+# ``kernel_paths`` counts at trace time and cannot see a recomputation: a
+# remat wrap re-runs a jaxpr, it does not re-trace the kernel's caller.  The
+# compiled program can: each Pallas kernel is one ``tpu_custom_call`` whose
+# ``op_name`` says which pass it was traced for.
+_KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+_KERNEL_OP_NAME = re.compile(r'\bop_name="([^"]*?(\w+)/pallas_call)"')
+_WHILE = re.compile(r"\bwhile\(.*\bcondition=%?([\w.\-]+), body=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"\b(?:true_computation|false_computation)=%?([\w.\-]+)"
+                       r"|\bbranch_computations=\{([^}]*)\}")
+_LIMIT = re.compile(r"= s32\[\][^ ]* constant\((\d+)\)")
+_COUNTS_UP = re.compile(r"^\s+ROOT .* compare\(.*direction=LT")
+_KERNEL_PASSES = {}
+
+
+def _pass_of(op_name):
+    if "rematted_computation" in op_name:
+        return "recomputed"
+    return "backward" if "transpose(jvp(" in op_name else "forward"
+
+
+def count_kernel_passes(hlo_text):
+    """``{kernel: {"forward": n, "recomputed": n, "backward": n}}``: the
+    Pallas kernel calls a compiled program's text makes in one run, by the
+    scope each kernel is dispatched under (``flash_attention``,
+    ``fused_norm``) and by the pass its ``op_name`` places it in: under a
+    remat wrap's ``rematted_computation`` it is the forward pass run again
+    for the backward's sake, under ``transpose(jvp(...))`` alone the
+    backward, else the forward.  A kernel in the body of a ``while`` whose
+    condition is ``counter < constant`` (what a ``lax.scan`` compiles to)
+    counts that many times; under any other loop, and in each branch of a
+    conditional, once."""
+    kernels, children, limits, counts_up = {}, {}, {}, set()
+    inside = entry = None
+    for line in hlo_text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head is not None:
+            inside = head.group(1)
+            if line.startswith("ENTRY"):
+                entry = inside
+            continue
+        if inside is None:
+            continue
+        if _KERNEL_CALL in line:
+            named = _KERNEL_OP_NAME.search(line)
+            key = ((named.group(2), _pass_of(named.group(1))) if named
+                   else ("?", "forward"))
+            own = kernels.setdefault(inside, {})
+            own[key] = own.get(key, 0) + 1
+            continue
+        loop = _WHILE.search(line)
+        if loop is not None:
+            children.setdefault(inside, []).append(loop.groups())
+            continue
+        for found in _CALLED.finditer(line):
+            children.setdefault(inside, []).append((None, found.group(1)))
+        for one, many in _BRANCHES.findall(line):
+            for name in [one] if one else re.findall(r"[\w.\-]+", many):
+                children.setdefault(inside, []).append((None, name))
+        limit = _LIMIT.search(line)
+        if limit is not None:
+            limits.setdefault(inside, []).append(int(limit.group(1)))
+        if _COUNTS_UP.match(line):
+            counts_up.add(inside)
+
+    def trips(condition):
+        if condition in counts_up and len(limits.get(condition, ())) == 1:
+            return limits[condition][0]
+        return 1
+
+    totals = {}
+
+    def total(computation):
+        if computation not in totals:
+            counts = dict(kernels.get(computation, {}))
+            for condition, callee in children.get(computation, ()):
+                times = trips(condition) if condition else 1
+                for key, n in total(callee).items():
+                    counts[key] = counts.get(key, 0) + times * n
+            totals[computation] = counts
+        return totals[computation]
+
+    passes = {}
+    for (kernel, which), n in total(entry).items():
+        passes.setdefault(kernel, dict(forward=0, recomputed=0, backward=0))[
+            which] = n
+    return passes
+
+
+def publish_kernel_passes(hlo_text):
+    """Keep :func:`count_kernel_passes` of a step program's compiled text."""
+    _KERNEL_PASSES.clear()
+    _KERNEL_PASSES.update(count_kernel_passes(hlo_text))
+
+
+def kernel_passes():
+    """``{kernel: {"forward": n, "recomputed": n, "backward": n}}`` of the
+    step program published last (with :func:`step_scopes`, by the first
+    ``train_batch`` after a profiler session): Pythia-410M with remat reads
+    ``{"flash_attention": {"forward": 24, "recomputed": 0, "backward":
+    24}}``, since its recomputed blocks keep the kernel's output and lse."""
+    return {kernel: dict(passes) for kernel, passes in _KERNEL_PASSES.items()}
+
+
 class TraceSessionWatch:
     """Tells a loop when a profiler session that covered one of its steps
     has ended: ``ended()`` is one ``TraceAnnotation.is_enabled()`` a step,

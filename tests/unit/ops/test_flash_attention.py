@@ -139,6 +139,70 @@ def test_bf16_fwd_and_grads_by_layout(B, S, N, D):
                for g in _grads(lambda *a: mha(*a, causal=True), q, k, v))
 
 
+def _lse_residual(q, k, v, causal, **plan_changes):
+    """The lse the forward rule keeps for the backward, as ``[B, N, S]``."""
+    B, S, N, D = q.shape
+    plan = tile_plan(S, D, q.dtype, N=N)._replace(**plan_changes)
+    if plan.group:
+        operands = [t.reshape(B, S, N * D) for t in (q, k, v)]
+    else:
+        operands = [jnp.swapaxes(t, 1, 2).reshape(B * N, S, D)
+                    for t in (q, k, v)]
+    _, (_, _, _, o, lse) = pallas_flash._mha_fwd(
+        *operands, causal, float(D) ** -0.5, plan)
+    sp = o.shape[1]
+    # one float a row, rows on lanes: a row of statistics per head, heads of
+    # one lane block together
+    heads = max(plan.group, 1)
+    assert lse.dtype == jnp.float32
+    assert lse.shape == (B * N // heads, heads, sp)
+    return lse.reshape(B, N, sp)[:, :, :S]
+
+
+def _reference_lse(q, k, v, causal):
+    S, D = q.shape[1], q.shape[3]
+    s = jnp.einsum("bqnd,bknd->bnqk", q.astype(jnp.float32),
+                   k.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST) * float(D) ** -0.5
+    if causal:
+        s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1)
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("B,S,N,D,changes", [
+    # the layouts of test_fwd_and_grads_by_layout
+    (2, 256, 4, 64, {}), (1, 384, 16, 64, {}), (1, 256, 12, 64, {}),
+    (1, 1000, 2, 64, {}), (1, 512, 2, 128, {}), (1, 256, 2, 256, {}),
+    (1, 256, 4, 32, {}), (1, 256, 2, 96, {}), (2, 256, 3, 64, {}),
+    # one block that is on the diagonal, padded in rows and columns at once
+    (1, 40, 2, 16, {}),
+    # running statistics over several spans (the lse leaves in the last
+    # one), row groups, one edge tile: folded, two heads to a block, a head
+    # a block
+    (1, 1000, 2, 16, dict(block=256, sub=128, rows=128, span=256)),
+    (1, 1000, 2, 16, dict(block=512, sub=256, rows=256)),
+    (1, 1000, 2, 16, dict(block=256, sub=256, rows=256)),
+    (2, 1000, 2, 64, dict(block=256, sub=128, rows=128, span=256)),
+    (2, 1000, 1, 128, dict(block=256, sub=128, rows=128, span=256)),
+], ids=lambda t: str(t))
+def test_lse_is_one_float_a_row(B, S, N, D, changes, causal):
+    """What the forward kernel writes beside its output: the log-sum-exp of
+    each row's scaled, masked scores, one float32 a row (128 lane-replicated
+    copies of it are four times the bytes of ``o`` at D = 64)."""
+    q, k, v = _qkv(S=S, B=B, N=N, D=D)
+    np.testing.assert_allclose(
+        np.asarray(_lse_residual(q, k, v, causal, **changes)),
+        np.asarray(_reference_lse(q, k, v, causal)), rtol=2e-5, atol=2e-5)
+
+
+def test_lse_of_bf16_operands_is_float32_and_close():
+    q, k, v = _qkv(S=1000, B=1, N=4, D=64, dtype=jnp.bfloat16)
+    np.testing.assert_allclose(
+        np.asarray(_lse_residual(q, k, v, True)),
+        np.asarray(_reference_lse(q, k, v, True)), rtol=2e-2, atol=2e-2)
+
+
 @pytest.mark.parametrize("causal", [True, False])
 def test_grads_when_the_edge_tile_is_the_padded_one(causal):
     """S = 40: one 128-row block whose single tile is on the diagonal, holds
@@ -214,9 +278,12 @@ def test_long_sequence_plans_in_place(changes, N, D, causal):
 
 def _primitives(jaxpr):
     """Names of every primitive of a jaxpr, those of nested jaxprs (a jit,
-    a custom VJP's forward) included."""
+    a custom VJP's forward) included; a kernel's body is not the program's
+    (the lse's 128 x 128 transposes in VMEM are no transpose in HBM)."""
     for eqn in jaxpr.eqns:
         yield eqn.primitive.name
+        if eqn.primitive.name == "pallas_call":
+            continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(sub, "jaxpr", sub)

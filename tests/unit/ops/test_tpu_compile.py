@@ -98,8 +98,10 @@ def _sum_grad(fn, n_diff):
     # the benchmark cells' own calls: train-410m, train-160m
     ((8, 2048, 16, 64), jnp.bfloat16),
     ((16, 1024, 12, 64), jnp.bfloat16),
-    # train-ouro-2.6b-loop4's heads of 128: a head is a lane block
+    # train-ouro-2.6b-loop4's heads of 128: a head is a lane block (half
+    # its micro-batch, and its own call)
     ((2, 4096, 16, 128), jnp.bfloat16),
+    ((4, 4096, 16, 128), jnp.bfloat16),
     # heads of 96 (NeoX-20B) and the longest length whose backward still
     # keeps a head's q side in VMEM; fp32 once; a padded length
     ((4, 2048, 8, 96), jnp.bfloat16),
@@ -129,6 +131,71 @@ def test_flash_mha_fwd_bwd(one_chip, shape, dtype):
     sp = -(-S // plan.block) * plan.block
     want = (B, sp, N * D) if plan.group else (B * N, sp, D)
     assert all(want in operands for operands in calls), (want, calls)
+    # the backward reads the lse as the forward wrote it: one float a row,
+    # rows on lanes, the heads of a lane block together
+    heads = max(plan.group, 1)
+    stats = [shape for operands in calls for shape in operands
+             if shape != want]
+    assert stats == [(B * N // heads, heads, sp)], stats
+
+
+@pytest.fixture
+def on_the_chip(topo, monkeypatch):
+    """Models dispatch to the Pallas kernels, as on the chip."""
+    from deeperspeed_tpu.accelerator import get_accelerator
+
+    monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
+                        lambda self: True)
+
+
+def _model_gradient_passes(model, loss, one_chip):
+    """``count_kernel_passes`` of the gradient of ``loss(params, ids)``,
+    compiled for the chip, over [2, 256] tokens."""
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+
+    ids = jnp.zeros((2, 256), jnp.int32)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(1), ids)))
+    return count_kernel_passes(_compile(
+        jax.grad(loss), params, _sds(ids.shape, ids.dtype, one_chip)))
+
+
+def test_recomputed_gpt_neox_keeps_the_flash_kernels_residuals(
+        one_chip, on_the_chip):
+    """What ``telemetry.kernel_passes()`` reads in the compiled step: with
+    remat each layer's kernel runs once forward and once backward, the
+    recomputed block has the kernel's output and lse (``gpt_neox.py``'s
+    remat policy); the fused norms are still recomputed."""
+    from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
+
+    model = GPTNeoX(GPTNeoXConfig(
+        vocab_size=256, hidden_size=256, num_heads=4, num_layers=2,
+        max_seq_len=256, remat=True, dtype=jnp.bfloat16))
+    passes = _model_gradient_passes(
+        model, lambda p, ids: model.apply(p, ids).astype(jnp.float32).mean(),
+        one_chip)
+    assert passes["flash_attention"] == dict(forward=2, recomputed=0,
+                                             backward=2)
+    assert passes["fused_norm"]["recomputed"] == 4
+
+
+def test_recomputed_looped_model_runs_the_forward_kernel_again(
+        one_chip, on_the_chip):
+    """``Ouro`` keeps its policy-less wrap: T * L = 3 * 2 recomputed calls,
+    counted through the scan over passes."""
+    from deeperspeed_tpu.models.ouro import Ouro, OuroConfig
+
+    model = Ouro(OuroConfig.tiny(
+        hidden_size=256, num_heads=2, num_kv_heads=2, intermediate_size=256,
+        max_seq_len=256, ce_chunk_tokens=256, total_ut_steps=3, remat=True,
+        dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    passes = _model_gradient_passes(
+        model, lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
+    assert passes["flash_attention"] == dict(forward=6, recomputed=6,
+                                             backward=6)
 
 
 def test_flash_mha_long_sequence_two_pass(one_chip):

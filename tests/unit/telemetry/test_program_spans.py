@@ -18,8 +18,9 @@ from deeperspeed_tpu import telemetry
 from deeperspeed_tpu.inference.v2 import InferenceEngineV2
 from deeperspeed_tpu.models.gpt_neox import GPTNeoX, GPTNeoXConfig
 from deeperspeed_tpu.telemetry.trace import (Tracer, TraceSessionWatch,
-                                             get_tracer, instruction_scopes,
-                                             set_tracer, span)
+                                             count_kernel_passes, get_tracer,
+                                             instruction_scopes, set_tracer,
+                                             span)
 
 TRAIN_PHASES = {"dst:train/input", "dst:train/dispatch", "dst:train/fence",
                 "dst:train/readback", "dst:train/report"}
@@ -235,6 +236,105 @@ ENTRY %main (a: f32[4]) -> f32[4] {
 
 
 # ---------------------------------------------------------- the serve round
+def _kernel_call(name, op_name):
+    """A Pallas kernel's instruction as the TPU compiler prints it (a
+    two-layer GPT-NeoX's gradient compiled for a described v5e), its
+    operands and the kernel's body cut."""
+    return (f'  %{name} = (bf16[2,256,256]{{2,1,0:T(8,128)(2,1)S(1)}}, '
+            f'f32[4,2,256]{{2,1,0:T(2,128)S(1)}}) custom-call(%copy.58, '
+            f'%copy.59, %copy.60), custom_call_target="tpu_custom_call", '
+            f'operand_layout_constraints={{bf16[2,256,256]{{2,1,0}}}}, '
+            f'frontend_attributes={{kernel_metadata={{}}}}, '
+            f'metadata={{op_name="{op_name}" stack_frame_id=59}}, '
+            f'backend_config={{"flag_configs":[]}}')
+
+
+_ATTENTION = ("attention/attention/jit(flash_attention)/flash_attention/"
+              "pallas_call")
+_FORWARD = _kernel_call(
+    "flash_attention.6", f"jit(loss)/jvp(GPTNeoX)/layers_0/{_ATTENTION}")
+_RECOMPUTED = _kernel_call(
+    "flash_attention.8", "jit(loss)/transpose(jvp(GPTNeoX))/jvp(GPTNeoX)/"
+    f"checkpoint/rematted_computation/layers_1/{_ATTENTION}")
+_BACKWARD = _kernel_call(
+    "flash_attention.9", "jit(loss)/transpose(jvp(GPTNeoX))/jvp(GPTNeoX)/"
+    f"checkpoint/layers_1/{_ATTENTION}")
+_NORM = _kernel_call(
+    "fused_norm.2", "jit(loss)/jvp(GPTNeoX)/layers_0/attention/"
+    "input_layernorm/fused_norm/pallas_call")
+
+
+def _program(entry, *computations):
+    return "\n".join(
+        ["HloModule jit_train_step, entry_computation_layout={()}", ""]
+        + [text + "\n" for text in computations]
+        + ["ENTRY %main.1 (a: f32[4]) -> f32[4] {",
+           "  %a = f32[4]{0} parameter(0)", *entry,
+           "  ROOT %copy.4 = f32[4]{0} copy(%a)", "}", ""])
+
+
+# the passes of a scan: its condition counts up to a constant
+_SCAN = """%cond.1 (t: (s32[], f32[4])) -> pred[] {
+  %constant.7 = s32[]{:T(128)} constant(4)
+  %t = (s32[]{:T(128)}, f32[4]{0}) parameter(0)
+  %i = s32[]{:T(128)} get-tuple-element(%t), index=0
+  ROOT %lt.6 = pred[]{:T(512)} compare(%i, %constant.7), direction=LT
+}
+
+%body.1 (t: (s32[], f32[4])) -> (s32[], f32[4]) {
+  %t = (s32[]{:T(128)}, f32[4]{0}) parameter(0)
+BODY
+  ROOT %r = (s32[]{:T(128)}, f32[4]{0}) tuple(%t)
+}"""
+_WHILE = ("  %while.3 = (s32[]{:T(128)}, f32[4]{0}) while(%tuple.2), "
+          "condition=%cond.1, body=%body.1")
+
+
+# a kernel in each branch of a conditional: each counts once
+_BRANCH = """%%branch.%d (p: f32[4]) -> f32[4] {
+  %%p = f32[4]{0} parameter(0)
+%s
+  ROOT %%c = f32[4]{0} copy(%%p)
+}"""
+_CONDITIONAL = ("  %conditional.5 = f32[4]{0} conditional(%i, %a, %a), "
+                "branch_computations={%branch.0, %branch.1}")
+
+
+@pytest.mark.parametrize("text,passes", [
+    (_program([_FORWARD]), dict(forward=1, recomputed=0, backward=0)),
+    (_program([_RECOMPUTED]), dict(forward=0, recomputed=1, backward=0)),
+    (_program([_BACKWARD]), dict(forward=0, recomputed=0, backward=1)),
+    # a recomputed layer as it was before the remat wrap saved the kernel's
+    # residuals, and as it is
+    (_program([_FORWARD, _RECOMPUTED, _BACKWARD]),
+     dict(forward=1, recomputed=1, backward=1)),
+    (_program([_FORWARD, _FORWARD, _BACKWARD, _BACKWARD]),
+     dict(forward=2, recomputed=0, backward=2)),
+    # two layers in the body of a scan over four passes
+    (_program([_WHILE], _SCAN.replace(
+        "BODY", "\n".join([_RECOMPUTED, _BACKWARD] * 2))),
+     dict(forward=0, recomputed=8, backward=8)),
+    (_program([_CONDITIONAL], _BRANCH % (0, _FORWARD), _BRANCH % (1, _FORWARD)),
+     dict(forward=2, recomputed=0, backward=0)),
+], ids=["forward", "recomputed", "backward", "layer_recomputed", "two_layers",
+        "scan", "conditional"])
+def test_kernel_passes_from_a_compiled_programs_text(text, passes):
+    assert count_kernel_passes(text) == {"flash_attention": passes}
+
+
+def test_kernel_passes_are_by_kernel_and_published_by_the_step():
+    text = _program([_NORM, _FORWARD, _BACKWARD])
+    assert count_kernel_passes(text) == {
+        "fused_norm": dict(forward=1, recomputed=0, backward=0),
+        "flash_attention": dict(forward=1, recomputed=0, backward=1)}
+    assert count_kernel_passes(_program([])) == {}
+    from deeperspeed_tpu.telemetry.trace import publish_kernel_passes
+    publish_kernel_passes(text)
+    assert telemetry.kernel_passes() == count_kernel_passes(text)
+    publish_kernel_passes(_program([]))    # the step program published last
+    assert telemetry.kernel_passes() == {}
+
+
 def test_round_stats_against_a_hand_counted_schedule(ring):
     engine = InferenceEngineV2(
         GPTNeoX(GPTNeoXConfig.tiny(max_seq_len=64)),
