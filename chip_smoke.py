@@ -7,7 +7,7 @@ heads, vocab 50304, sequence 2048, bf16) with weights made from a seed, and
 checks what comes out.  No speed is claimed; it is the quickest proof that
 the system still starts on the chip.
 
-    python chip_smoke.py             # one chip: device, train, serve
+    python chip_smoke.py             # one chip: device, train, hybrid, serve
     python chip_smoke.py --chips 4   # four chips: ZeRO-3 dp=4 vs one device
 
 One process, no subprocess, no network, no git.  Each phase prints one JSON
@@ -184,6 +184,48 @@ def phase_train(model, params):
          steps=TRAIN_STEPS, zero_stage=0, dtype="bfloat16",
          pallas_calls={k: len(v) for k, v in calls.items()},
          peak_bytes_in_use=peak_bytes(), **facts, **device_facts())
+    del engine
+    gc.collect()
+    return not problems
+
+
+# ------------------------------------------------------------------ hybrid
+def phase_hybrid():
+    """One short step of the hybrid model (``models/nemotron_h.py``) at the
+    published widths and a chip's share of every layer: an expert layer, a
+    Mamba-2 layer and an attention layer, 2048 tokens.  Fails unless no
+    routed slot was dropped and every kind of layer counted itself."""
+    import jax
+    import jax.numpy as jnp
+
+    import deeperspeed_tpu as dst
+    from deeperspeed_tpu import telemetry
+    from deeperspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+
+    model = NemotronH(NemotronHConfig.nemotron_3_super(
+        pattern="EM*", mamba_num_heads=32, n_groups=2, num_heads=8,
+        num_kv_heads=1, experts_held=8, vocab_size=16384, max_seq_len=SEQ,
+        remat=True, dtype=jnp.bfloat16))
+    engine, _, _, _ = dst.initialize(model=model,
+                                     config=train_config(1, 1, 0))
+    batch = model.example_batch(batch_size=1, seq_len=SEQ, seed=SEED)
+    losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
+    told = telemetry.step_counters().get("train_step", {})
+    problems = []
+    if not all(math.isfinite(x) for x in losses):
+        problems.append("non-finite loss")
+    if told.get("moe_slots_dropped") != 0:
+        problems.append(f"routed slots dropped: {told}")
+    for counter in ("ssm_layer_applications", "moe_layer_applications",
+                    "attention_layer_applications"):
+        if told.get(counter) != 1:
+            problems.append(f"{counter} is not 1: {told}")
+    if not told.get("moe_slots_held", 0) > 0:
+        problems.append(f"no slot routed to the experts held: {told}")
+    emit("hybrid", ok=not problems, problems=problems, counters=told,
+         model="nemotron_3_super share, pattern EM*", seq=SEQ,
+         params=model.num_params(), losses=[round(x, 4) for x in losses],
+         peak_bytes_in_use=peak_bytes(), **device_facts())
     del engine
     gc.collect()
     return not problems
@@ -434,6 +476,7 @@ def main():
         ok = phase_four_chips(model, params)
     else:
         ok = phase_train(model, params)
+        ok = phase_hybrid() and ok
         ok = phase_serve(model, params) and ok
     if not ok:
         emit("result", ok=False)

@@ -652,12 +652,14 @@ def publish_step_counters(program, counters):
     _STEP_COUNTERS[program] = counters
 
 
-def step_counters():
+def step_counters(read=True):
     """``{program name: {counter: number or list}}`` of the last step each
     program ran: the counters a model returns beside its loss (``(loss,
     {name: value})``), averaged over the step's microbatches.  Reading waits
-    for that step."""
-    return {program: {name: np.asarray(value).tolist()
+    for that step; ``read=False`` gives the device arrays as they are and
+    waits for nothing (a caller that keeps every step's and reads them
+    after the last)."""
+    return {program: {name: np.asarray(value).tolist() if read else value
                       for name, value in counters.items()}
             for program, counters in _STEP_COUNTERS.items()}
 

@@ -198,6 +198,30 @@ def test_recomputed_looped_model_runs_the_forward_kernel_again(
                                              backward=6)
 
 
+def test_recomputed_hybrid_model_compiles_with_its_kernels(
+        one_chip, on_the_chip):
+    """``NemotronH`` (a Mamba-2, an expert and an attention layer, remat):
+    the chip's compiler takes the chunked scan and the dropless walk (a
+    loop as long as the slots routed here need, forward and backward), and
+    the attention layer keeps the flash kernel's residuals (one forward, one
+    backward)."""
+    from deeperspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
+
+    model = NemotronH(NemotronHConfig.tiny(
+        pattern="EM*", hidden_size=256, mamba_num_heads=4, mamba_head_dim=64,
+        n_groups=2, ssm_state_size=128, chunk_size=128, num_heads=2,
+        num_kv_heads=1, head_dim=128, moe_latent_size=128,
+        moe_intermediate_size=256, moe_shared_expert_intermediate_size=256,
+        max_seq_len=256, ce_chunk_tokens=256,
+        remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    passes = _model_gradient_passes(
+        model, lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
+    assert passes["flash_attention"] == dict(forward=1, recomputed=0,
+                                             backward=1)
+
+
 def test_flash_mha_long_sequence_two_pass(one_chip):
     """S = 16k: the forward still holds the whole k/v of a head; the
     backward's q side no longer fits and it goes two-pass (three calls)."""

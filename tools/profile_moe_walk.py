@@ -1,0 +1,133 @@
+"""Time the routed experts' walk alone on the chip, by load:
+``moe.dropless.routed_experts`` forward and backward on ``tokens`` rows of
+``latent`` floats over ``held`` experts, for a list of loads (slots an
+expert) and of chunk sizes.  The loads are data, so one program a chunk size
+serves them all; the device's busiest operations of the last load are listed
+from a profiler session.
+
+    python tools/profile_moe_walk.py --rows 256 512 --loads 0 40x1 40 704 8000x1+704
+
+A load ``n`` gives every held expert ``n`` slots, ``nxk`` gives ``k`` experts
+``n`` each, and ``+`` joins such parts (experts in order).  One JSON line a
+(chunk size, load): ms a call, which is what one expert layer of a step pays
+for its routed part (the walk keeps only its inputs, so a recomputed layer
+runs it once forward and once backward).
+"""
+
+import argparse
+import collections
+import glob
+import gzip
+import json
+import os
+import sys
+import tempfile
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from deeperspeed_tpu.moe import dropless
+
+
+def counts_of(load, held):
+    """``"8000x1+704"`` -> [8000, 704, 704, ...] for ``held`` experts."""
+    counts = []
+    for part in load.split("+"):
+        n, _, k = part.partition("x")
+        counts += [int(n)] * (int(k) if k else held - len(counts))
+    return (counts + [0] * held)[:held]
+
+
+def routing(counts, tokens, seed=1):
+    """Each expert's slots on tokens drawn without order -> (weights,
+    chosen) [tokens, held]."""
+    rng = np.random.default_rng(seed)
+    chosen = np.zeros((tokens, len(counts)), bool)
+    for e, n in enumerate(counts):
+        chosen[rng.permutation(tokens)[:n], e] = True
+    weights = np.where(chosen, rng.uniform(0.1, 1.0, chosen.shape), 0.0)
+    return jnp.asarray(weights, jnp.float32), jnp.asarray(chosen)
+
+
+def busiest(run, calls, top):
+    """Device ms a call by operation, the ``top`` largest, from a profiler
+    session around ``calls`` runs."""
+    with tempfile.TemporaryDirectory() as where:
+        with jax.profiler.trace(where):
+            for _ in range(calls):
+                out = run()
+            jax.block_until_ready(out)
+        path = glob.glob(where + "/plugins/profile/*/*.trace.json.gz")[0]
+        events = json.load(gzip.open(path))["traceEvents"]
+    thread = {(e["pid"], e["tid"]): e["args"]["name"] for e in events
+              if e.get("ph") == "M" and e.get("name") == "thread_name"}
+    total, count = collections.Counter(), collections.Counter()
+    for e in events:
+        if e.get("ph") == "X" and thread.get(
+                (e["pid"], e["tid"])) == "XLA Ops":
+            total[e["name"]] += e["dur"]
+            count[e["name"]] += 1
+    return [{"op": name, "ms": round(us / calls / 1e3, 3),
+             "calls": count[name] // calls}
+            for name, us in total.most_common(top)]
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--tokens", type=int, default=16384)
+    ap.add_argument("--latent", type=int, default=1024)
+    ap.add_argument("--intermediate", type=int, default=2688)
+    ap.add_argument("--held", type=int, default=8)
+    ap.add_argument("--rows", type=int, nargs="+",
+                    default=[dropless.ROWS_PER_CHUNK])
+    ap.add_argument("--loads", nargs="+",
+                    default=["0", "40x1", "40", "704", "8000x1+704"])
+    ap.add_argument("--calls", type=int, default=20)
+    ap.add_argument("--top", type=int, default=12)
+    args = ap.parse_args(argv)
+
+    T, L, F, H = args.tokens, args.latent, args.intermediate, args.held
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    x = jax.random.normal(keys[0], (T, L), jnp.bfloat16)
+    w_in = (0.02 * jax.random.normal(keys[1], (H, L, F))).astype(jnp.bfloat16)
+    w_out = (0.02 * jax.random.normal(keys[2], (H, F, L))).astype(jnp.bfloat16)
+    g = jax.random.normal(keys[3], (T, L), jnp.float32)
+
+    def loss(x, held_w, w_in, w_out, is_chosen):
+        out, counted = dropless.routed_experts(x, held_w, is_chosen, w_in,
+                                               w_out)
+        return jnp.sum(out * g), counted
+
+    print(json.dumps({"device": jax.devices()[0].device_kind, "tokens": T,
+                      "latent": L, "intermediate": F, "held": H}), flush=True)
+    for rows in args.rows:
+        dropless.ROWS_PER_CHUNK = rows       # read as the program is traced
+        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
+                                          has_aux=True))
+        for load in args.loads:
+            held_w, is_chosen = routing(counts_of(load, H), T)
+
+            def run():
+                return step(x, held_w, w_in, w_out, is_chosen)
+
+            (_, counted), _ = jax.block_until_ready(run())
+            start = time.perf_counter()
+            for _ in range(args.calls):
+                out = run()
+            jax.block_until_ready(out)
+            print(json.dumps({
+                "rows_per_chunk": rows, "load": load,
+                "slots": int(counted["slots"]), "done": int(counted["done"]),
+                "ms": round(1e3 * (time.perf_counter() - start) / args.calls,
+                            3)}), flush=True)
+        print(json.dumps({"rows_per_chunk": rows, "load": load,
+                          "busiest": busiest(run, 5, args.top)}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())
