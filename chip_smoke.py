@@ -194,7 +194,11 @@ def phase_hybrid():
     """One short step of the hybrid model (``models/nemotron_h.py``) at the
     published widths and a chip's share of every layer: an expert layer, a
     Mamba-2 layer and an attention layer, 2048 tokens.  Fails unless no
-    routed slot was dropped and every kind of layer counted itself."""
+    routed slot was dropped, every kind of layer counted itself, and the
+    Mamba layer's scan is the repo's kernel pair: every traced call on the
+    ``pallas`` path, and one forward, one recomputed and one backward kernel
+    call in the compiled step (``ops/pallas_ssd.py`` keeps nothing across
+    the remat wrap)."""
     import jax
     import jax.numpy as jnp
 
@@ -222,7 +226,18 @@ def phase_hybrid():
             problems.append(f"{counter} is not 1: {told}")
     if not told.get("moe_slots_held", 0) > 0:
         problems.append(f"no slot routed to the experts held: {told}")
+    paths = telemetry.kernel_paths().get("ssd_scan", {})
+    if set(paths) != {"pallas"}:
+        problems.append(f"the scan did not take its kernels: {paths}")
+    # the step's text, lowered from the engine's own step function: the
+    # same program, so a cache hit
+    passes = telemetry.count_kernel_passes(engine._get_train_step(None).lower(
+        engine.state, engine._stack_microbatches(batch),
+        jax.random.PRNGKey(0)).compile().as_text()).get("ssd_scan")
+    if passes != dict(forward=1, recomputed=1, backward=1):
+        problems.append(f"the scan's kernel passes under remat: {passes}")
     emit("hybrid", ok=not problems, problems=problems, counters=told,
+         kernel_paths=paths, kernel_passes=passes,
          model="nemotron_3_super share, pattern EM*", seq=SEQ,
          params=model.num_params(), losses=[round(x, 4) for x in losses],
          peak_bytes_in_use=peak_bytes(), **device_facts())

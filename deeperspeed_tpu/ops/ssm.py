@@ -13,9 +13,16 @@ like attention with a decay in place of a softmax; across chunks a state of
 ``[P, N]`` a head is carried by a short scan.  Every matmul has the chunk
 or the state on its contraction, so it runs on the MXU in the operands' type
 with float32 sums; the decays (``dt A``, their running sums, every
-``exp``) and the carried state stay float32 whatever the operands are.  The
-backward pass is this form's own transpose, recomputed from the operands
+``exp``) and the carried state stay float32 whatever the operands are.
+
+One algorithm, two implementations, chosen from the shapes alone
+(``pallas_ssd.scan_plan``): where chunk, head width and state width are whole
+128-lane blocks and the backend has Pallas kernels, the repo's own kernel
+pair (``ops/pallas_ssd.py``: a chunk's ``[Q, Q]`` products and the state stay
+in VMEM, forward and backward); anything else runs the plain ``jnp`` form
+below, whose backward pass is its own transpose recomputed from the operands
 (``jax.checkpoint``): nothing of size ``[chunks, heads, Q, Q]`` is kept.
+``telemetry.kernel_paths()["ssd_scan"]`` says which a traced call took.
 
 Heads are independent, so a chip that holds a range of heads (with the
 groups that serve them) calls these functions on its range and gets its
@@ -45,12 +52,15 @@ def gated_group_rms_norm(y, gate, scale, groups, eps):
     """``GroupRMSNorm(y * silu(gate))``: the gated product normalised over
     each of ``groups`` equal runs of the last axis apart (gate before norm),
     times ``scale`` [C]; float32 inside, ``y``'s type out."""
-    lead, width = y.shape[:-1], y.shape[-1]
     g = (y.astype(jnp.float32) * jax.nn.silu(gate.astype(jnp.float32)))
-    g = g.reshape(*lead, groups, width // groups)
-    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
-    return (g.reshape(*lead, width) * scale.astype(jnp.float32)).astype(
-        y.dtype)
+    # a run at a time, as slices of the last axis: a reshape to [..., groups,
+    # width / groups] puts a dimension of ``groups`` second to last, which on
+    # the TPU is another tiling and a copy of the float32 array each way
+    g = jnp.concatenate(
+        [run * jax.lax.rsqrt(jnp.mean(jnp.square(run), -1, keepdims=True)
+                             + eps) for run in jnp.split(g, groups, axis=-1)],
+        axis=-1)
+    return (g * scale.astype(jnp.float32)).astype(y.dtype)
 
 
 def _ssd(x, dt, a, b, c, chunk):
@@ -118,15 +128,48 @@ def ssd_scan(x, dt, a, b, c, d=None, chunk=128):
     -> y [B, S, Hd, P] in ``x``'s type.  Any ``S``: the tail chunk is padded
     with ``dt = 0`` steps, which leave the state as it is and whose outputs
     are dropped."""
-    S = x.shape[1]
+    from ..accelerator import get_accelerator
+    from ..telemetry.trace import count_kernel_path
+    from . import pallas_ssd
+
+    B, S, Hd, P = x.shape
+    G, N = b.shape[2:]
+    f32 = jnp.float32
     pad = -S % chunk
-    dt = dt.astype(jnp.float32)
+    dt, a = dt.astype(f32), a.astype(f32)
     ops = (x, dt, b, c)
     if pad:
         ops = tuple(jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
                     for t in ops)
-    y = jax.checkpoint(_ssd, static_argnums=5)(
-        ops[0], ops[1], a.astype(jnp.float32), ops[2], ops[3], chunk)[:, :S]
-    if d is not None:
-        y = y + x.astype(jnp.float32) * d.astype(jnp.float32)[:, None]
-    return y.astype(x.dtype)
+    plan = pallas_ssd.scan_plan(Hd, P, G, N, chunk,
+                                (x.dtype, b.dtype, c.dtype))
+    if plan is None or not get_accelerator().use_pallas_kernels():
+        count_kernel_path("ssd_scan", "plain")
+        y = jax.checkpoint(_ssd, static_argnums=5)(
+            ops[0], ops[1], a, ops[2], ops[3], chunk)[:, :S]
+        if d is not None:
+            y = y + x.astype(f32) * d.astype(f32)[:, None]
+        return y.astype(x.dtype)
+    count_kernel_path("ssd_scan", "pallas")
+    return _through_the_kernels(ops[0], ops[1], a, ops[2], ops[3], d, plan)[
+        :, :S]
+
+
+def _through_the_kernels(x, dt, a, b, c, d, plan):
+    """Whole chunks through ``pallas_ssd``: the operands flat, as the
+    kernels read them (reshapes of contiguous dimensions); under a mesh each
+    device runs the kernels on its own batch rows."""
+    from ..parallel.topology import BATCH_AXES
+    from . import pallas_ssd
+    from .pallas_utils import shard_kernel
+
+    B, Sp, Hd, P = x.shape
+    d = jnp.zeros_like(a) if d is None else d.astype(jnp.float32)
+    rows, whole = (BATCH_AXES, None, None), (None,)
+    with jax.named_scope("ssm_scan"):
+        y = shard_kernel(
+            lambda *t: pallas_ssd.ssd_scan_kernel(*t, plan),
+            (x.reshape(B, Sp, Hd * P), dt, a, b.reshape(B, Sp, -1),
+             c.reshape(B, Sp, -1), d),
+            (rows, rows, whole, rows, rows, whole))
+        return y.reshape(B, Sp, Hd, P)

@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from deeperspeed_tpu.ops import pallas_utils
+from deeperspeed_tpu.ops import pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
 from deeperspeed_tpu.ops.attention import paged, pallas_flash
 from deeperspeed_tpu.ops.quantizer import fused as qfused
@@ -32,7 +32,7 @@ from deeperspeed_tpu.ops.transformer import normalize
 from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
-_BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk)
+_BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd)
 
 
 @pytest.fixture(scope="module")
@@ -201,10 +201,12 @@ def test_recomputed_looped_model_runs_the_forward_kernel_again(
 def test_recomputed_hybrid_model_compiles_with_its_kernels(
         one_chip, on_the_chip):
     """``NemotronH`` (a Mamba-2, an expert and an attention layer, remat):
-    the chip's compiler takes the chunked scan and the dropless walk (a
-    loop as long as the slots routed here need, forward and backward), and
-    the attention layer keeps the flash kernel's residuals (one forward, one
-    backward)."""
+    the chip's compiler takes the dropless walk (a loop as long as the slots
+    routed here need, forward and backward); the attention layer keeps the
+    flash kernel's residuals (one forward, one backward); the Mamba layer's
+    scan is the kernel pair, which keeps nothing across the remat wrap: a
+    forward call, the recomputed one (it writes the states) and the backward
+    call for each M layer."""
     from deeperspeed_tpu.models.nemotron_h import NemotronH, NemotronHConfig
 
     model = NemotronH(NemotronHConfig.tiny(
@@ -220,6 +222,47 @@ def test_recomputed_hybrid_model_compiles_with_its_kernels(
             p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
     assert passes["flash_attention"] == dict(forward=1, recomputed=0,
                                              backward=1)
+    m_layers = model.config.pattern.count("M")
+    assert passes["ssd_scan"] == dict(forward=m_layers, recomputed=m_layers,
+                                      backward=m_layers)
+
+
+@pytest.mark.parametrize("heads,head_dim,groups", [
+    (32, 64, 2),        # train-nemotron3-super-ep64-8k: two heads a lane block
+    (8, 128, 2),        # a head a lane block
+    (128, 64, 8),       # the whole layer: four head blocks of two groups
+])
+def test_ssd_scan_fwd_bwd(one_chip, heads, head_dim, groups):
+    """The scan's kernel pair at the hybrid cell's length and batch (``x``
+    [2, 8192, 2048], ``b``, ``c`` [2, 8192, 256], chunk 128 in its first
+    case) inside the default scoped-VMEM limit: an undifferentiated forward
+    is one call that writes no states; forward + backward are two calls,
+    and the states between them are the operands' type."""
+    B, S, N, chunk = 2, 8192, 128, 128
+    dtype = jnp.bfloat16
+    plan = pallas_ssd.scan_plan(heads, head_dim, groups, N, chunk,
+                                (dtype,) * 3)
+    assert plan is not None
+    assert pallas_flash._vmem_limit(
+        pallas_ssd._vmem_need(plan, 2, wide_tensors=3)) is None
+
+    scan = functools.partial(ssm._through_the_kernels, plan=plan)
+
+    args = (_sds((B, S, heads, head_dim), dtype, one_chip),
+            _sds((B, S, heads), jnp.float32, one_chip),
+            _sds((heads,), jnp.float32, one_chip),
+            _sds((B, S, groups, N), dtype, one_chip),
+            _sds((B, S, groups, N), dtype, one_chip),
+            _sds((heads,), jnp.float32, one_chip))
+    flat = (B, S, heads * head_dim)
+    calls = _kernel_operand_shapes(_compile(scan, *args))
+    assert len(calls) == 1 and flat in calls[0], calls
+    assert (B, S // chunk, heads * head_dim, N) not in calls[0]
+    calls = _kernel_operand_shapes(_compile(_sum_grad(scan, 6), *args))
+    assert len(calls) == 2, calls
+    assert all(flat in operands for operands in calls), calls
+    assert sum((B, S // chunk, heads * head_dim, N) in operands
+               for operands in calls) == 1, calls
 
 
 def test_flash_mha_long_sequence_two_pass(one_chip):
@@ -333,6 +376,29 @@ def test_flash_dispatch_partitions_over_dp(dp4):
     for operands in calls:
         assert (2, 2048, 16 * 64) in operands, operands
         assert (8, 2048, 16 * 64) not in operands, operands
+    assert "all-gather" not in text
+
+
+def test_ssd_scan_dispatch_partitions_over_dp(dp4, on_the_chip):
+    """Through ``ssd_scan`` every chip runs the scan's kernels on its own
+    quarter of the batch, forward and backward; the decay rates and the
+    skip are whole on every chip."""
+    B, S, heads, p, groups, n = 8, 1024, 4, 64, 2, 128
+    rows = NamedSharding(dp4, P("dp", None, None, None))
+    whole = NamedSharding(dp4, P())
+    args = (_sds((B, S, heads, p), jnp.bfloat16, rows),
+            _sds((B, S, heads), jnp.float32,
+                 NamedSharding(dp4, P("dp", None, None))),
+            _sds((heads,), jnp.float32, whole),
+            _sds((B, S, groups, n), jnp.bfloat16, rows),
+            _sds((B, S, groups, n), jnp.bfloat16, rows),
+            _sds((heads,), jnp.float32, whole))
+    text = _compile(_sum_grad(ssm.ssd_scan, 6), *args)
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) == 2, calls
+    for operands in calls:
+        assert (B // 4, S, heads * p) in operands, operands
+        assert (B, S, heads * p) not in operands, operands
     assert "all-gather" not in text
 
 
