@@ -1,0 +1,53 @@
+"""Model FLOP/s utilisation of the training step of a model with window and
+full attention layers and a mixture of experts, at the median step time, at
+the shares this chip holds.  FLOPs a token by
+``reference/mellum_ref.flops_per_token``: 6 x the matmul weights a token
+passes, a routed expert counted per slot -- the slots from the program's own
+counter ``moe_slots_held``, the mean over the window's steps, kept in the
+run's record by the runner -- plus attention's scores and values: ``12 heads
+D S`` a full layer (the customary count of ``core.model_flops_per_token``)
+and that times the band's share of the triangle a windowed layer; times
+tokens per step over the median step, over chips x the published bf16 peak.
+Recomputed operations do not count.
+The layers of each kind are checked against what the program counted on the
+device, and a dropped slot refuses the number: where the program has no such
+counters, or they say otherwise, there is no number."""
+
+from benchmarks import core
+from benchmarks.reference import mellum_ref as ref
+
+COUNTED = (("window_layer_applications", "sliding_attention"),
+           ("full_layer_applications", "full_attention"))
+
+
+def flops_per_token(cfg, seq_len, tokens_per_step, counters):
+    """-> FLOPs a token, or None where the counters disagree with the
+    configuration's layers or a slot was dropped."""
+    kinds = ref.layer_kinds(cfg)
+    if any(counters.get(name) != kinds.count(kind) for name, kind in COUNTED):
+        return None
+    if counters.get("moe_layer_applications") != len(kinds):
+        return None
+    if counters.get("moe_slots_dropped") != 0:
+        return None
+    return ref.flops_per_token(
+        cfg, seq_len, counters["moe_slots_held"] / tokens_per_step)
+
+
+def compute(record, trace):
+    ready = record.get("step_ready_at")
+    cfg = record.get("model_config", {})
+    if not ready or len(ready) < 3 or "layer_types" not in cfg:
+        return None
+    counters = record.get("step_counters")
+    if not counters:
+        return None
+    tokens_per_step = record["tokens"] / record["attempted"]
+    per_token = flops_per_token(cfg, record["seq_len"], tokens_per_step,
+                                counters)
+    if per_token is None:
+        return None
+    peak = core.device_peaks(record["device_kind"])["bf16_flops_per_s"]
+    step_s = core.median([b - a for a, b in zip(ready[:-1], ready[1:])])
+    return core.mfu_pct(per_token, tokens_per_step / step_s, record["chips"],
+                        peak)

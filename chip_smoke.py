@@ -7,7 +7,7 @@ heads, vocab 50304, sequence 2048, bf16) with weights made from a seed, and
 checks what comes out.  No speed is claimed; it is the quickest proof that
 the system still starts on the chip.
 
-    python chip_smoke.py             # one chip: device, train, hybrid, serve
+    python chip_smoke.py             # one chip: device, train, hybrid, windowed, serve
     python chip_smoke.py --chips 4   # four chips: ZeRO-3 dp=4 vs one device
 
 One process, no subprocess, no network, no git.  Each phase prints one JSON
@@ -189,6 +189,19 @@ def phase_train(model, params):
     return not problems
 
 
+def step_kernel_passes(engine, batch):
+    """``telemetry.count_kernel_passes`` of the engine's step program: its
+    text lowered from the engine's own step function (the same program, so
+    a cache hit)."""
+    import jax
+
+    from deeperspeed_tpu import telemetry
+
+    return telemetry.count_kernel_passes(engine._get_train_step(None).lower(
+        engine.state, engine._stack_microbatches(batch),
+        jax.random.PRNGKey(0)).compile().as_text())
+
+
 # ------------------------------------------------------------------ hybrid
 def phase_hybrid():
     """One short step of the hybrid model (``models/nemotron_h.py``) at the
@@ -199,7 +212,6 @@ def phase_hybrid():
     ``pallas`` path, and one forward, one recomputed and one backward kernel
     call in the compiled step (``ops/pallas_ssd.py`` keeps nothing across
     the remat wrap)."""
-    import jax
     import jax.numpy as jnp
 
     import deeperspeed_tpu as dst
@@ -229,16 +241,70 @@ def phase_hybrid():
     paths = telemetry.kernel_paths().get("ssd_scan", {})
     if set(paths) != {"pallas"}:
         problems.append(f"the scan did not take its kernels: {paths}")
-    # the step's text, lowered from the engine's own step function: the
-    # same program, so a cache hit
-    passes = telemetry.count_kernel_passes(engine._get_train_step(None).lower(
-        engine.state, engine._stack_microbatches(batch),
-        jax.random.PRNGKey(0)).compile().as_text()).get("ssd_scan")
+    passes = step_kernel_passes(engine, batch).get("ssd_scan")
     if passes != dict(forward=1, recomputed=1, backward=1):
         problems.append(f"the scan's kernel passes under remat: {passes}")
     emit("hybrid", ok=not problems, problems=problems, counters=told,
          kernel_paths=paths, kernel_passes=passes,
          model="nemotron_3_super share, pattern EM*", seq=SEQ,
+         params=model.num_params(), losses=[round(x, 4) for x in losses],
+         peak_bytes_in_use=peak_bytes(), **device_facts())
+    del engine
+    gc.collect()
+    return not problems
+
+
+# ---------------------------------------------------------------- windowed
+def phase_windowed():
+    """One short step of Mellum 2 (``models/mellum.py``) at the published
+    widths and a chip's share: a period of three sliding-window layers and a
+    full one, every MLP 16 of the 64 gated experts, 2048 tokens.  Fails
+    unless every windowed call of the model took the kernel (only the
+    in-place path under ``flash_attention_window``, and in the compiled step
+    three forward and three backward kernel calls of that name beside one
+    pair of the full kernel's, nothing recomputed: what the layers' pattern
+    says), every kind of layer counted itself, and no routed slot was
+    dropped."""
+    import jax.numpy as jnp
+
+    import deeperspeed_tpu as dst
+    from deeperspeed_tpu import telemetry
+    from deeperspeed_tpu.models.mellum import Mellum, MellumConfig
+
+    model = Mellum(MellumConfig.mellum2_12b(
+        layers_held=4, first_layer_held=12, routed_experts_held=16,
+        vocab_rows_held=24576, max_seq_len=SEQ, remat=True,
+        dtype=jnp.bfloat16))
+    engine, _, _, _ = dst.initialize(model=model,
+                                     config=train_config(1, 1, 0))
+    batch = model.example_batch(batch_size=1, seq_len=SEQ, seed=SEED)
+    losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
+    told = telemetry.step_counters().get("train_step", {})
+    problems = []
+    if not all(math.isfinite(x) for x in losses):
+        problems.append("non-finite loss")
+    if told.get("moe_slots_dropped") != 0:
+        problems.append(f"routed slots dropped: {told}")
+    for counter, want in (("window_layer_applications", 3),
+                          ("full_layer_applications", 1),
+                          ("moe_layer_applications", 4)):
+        if told.get(counter) != want:
+            problems.append(f"{counter} is not {want}: {told}")
+    if not told.get("moe_slots_held", 0) > 0:
+        problems.append(f"no slot routed to the experts held: {told}")
+    paths = telemetry.kernel_paths().get("flash_attention_window", {})
+    if set(paths) != {"in_place_1"}:
+        problems.append(f"a windowed call left the in-place kernel: {paths}")
+    passes = step_kernel_passes(engine, batch)
+    want = {"flash_attention_window": dict(forward=3, recomputed=0,
+                                           backward=3),
+            "flash_attention": dict(forward=1, recomputed=0, backward=1)}
+    got = {k: passes.get(k) for k in want}
+    if got != want:
+        problems.append(f"the attention kernels' passes under remat: {got}")
+    emit("windowed", ok=not problems, problems=problems, counters=told,
+         kernel_paths=paths, kernel_passes=got,
+         model="mellum2_12b share, one period, 16 experts", seq=SEQ,
          params=model.num_params(), losses=[round(x, 4) for x in losses],
          peak_bytes_in_use=peak_bytes(), **device_facts())
     del engine
@@ -492,6 +558,7 @@ def main():
     else:
         ok = phase_train(model, params)
         ok = phase_hybrid() and ok
+        ok = phase_windowed() and ok
         ok = phase_serve(model, params) and ok
     if not ok:
         emit("result", ok=False)

@@ -184,15 +184,13 @@ class LlamaAttention(nn.Module):
 
         with jax.named_scope("attention_layout"):   # GQA's copy of k and v
             k, v = self._repeat_kv(k), self._repeat_kv(v)
+        # the window goes to the kernel, which skips what lies left of the
+        # band (``pallas_flash.mha``); only a padding mask takes the plain path
         mask = None
-        if cfg.sliding_window is not None:
-            qpos = jnp.arange(S)[:, None]
-            kpos = jnp.arange(S)[None, :]
-            mask = (kpos > qpos - cfg.sliding_window)[None, None]
         if attention_mask is not None:
-            am = attention_mask[:, None, None, :].astype(bool)
-            mask = am if mask is None else (mask & am)
-        out = dot_product_attention(q, k, v, mask=mask, causal=True)
+            mask = attention_mask[:, None, None, :].astype(bool)
+        out = dot_product_attention(q, k, v, mask=mask, causal=True,
+                                    window=cfg.sliding_window)
         with jax.named_scope("attention_layout"):
             out = out.reshape(B, S, H)
         return nn.Dense(H, use_bias=False, dtype=cfg.dtype,

@@ -18,7 +18,7 @@ from ..pallas_utils import shard_kernel
 
 
 def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rng=None,
-                         dropout_rate=0.0):
+                         dropout_rate=0.0, window=None):
     """jnp reference path: [B, S, N, D] q/k/v -> [B, S, N, D]."""
     *_, seq_q, num_heads, head_dim = q.shape
     seq_k = k.shape[-3]
@@ -29,6 +29,11 @@ def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rn
     if causal:
         causal_mask = jnp.tril(jnp.ones((seq_q, seq_k), bool), k=seq_k - seq_q)
         logits = jnp.where(causal_mask[None, None], logits, jnp.finfo(jnp.float32).min)
+    if window is not None:
+        # row i sees the columns j with i - window < j (and, causal, j <= i)
+        seen = (jnp.arange(seq_k)[None, :] + (seq_q - seq_k)
+                > jnp.arange(seq_q)[:, None] - window)
+        logits = jnp.where(seen[None, None], logits, jnp.finfo(jnp.float32).min)
     if mask is not None:
         logits = jnp.where(mask, logits, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
@@ -39,8 +44,13 @@ def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rn
 
 
 def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rng=None,
-                          dropout_rate=0.0, use_pallas=None):
-    """Multi-head attention over [batch, seq, heads, head_dim] tensors."""
+                          dropout_rate=0.0, use_pallas=None, window=None):
+    """Multi-head attention over [batch, seq, heads, head_dim] tensors.
+    ``window``: a causal call's sliding window in rows (row i sees the
+    columns ``i - window < j <= i``); the flash kernel skips what lies left
+    of the band, the plain path masks it."""
+    if window is not None and not causal:
+        raise ValueError("a window belongs to a causal call")
     if use_pallas is None:
         use_pallas = get_accelerator().use_pallas_kernels()
     if use_pallas and mask is None and dropout_rate == 0.0:
@@ -51,10 +61,12 @@ def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_r
             # rows and heads (heads over sp is the Ulysses layout)
             spec = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
             return shard_kernel(
-                functools.partial(flash_attention, causal=causal, scale=scale),
+                functools.partial(flash_attention, causal=causal, scale=scale,
+                                  window=window),
                 (q, k, v), (spec, spec, spec))
     return _reference_attention(q, k, v, mask=mask, causal=causal, scale=scale,
-                                dropout_rng=dropout_rng, dropout_rate=dropout_rate)
+                                dropout_rng=dropout_rng, dropout_rate=dropout_rate,
+                                window=window)
 
 
 causal_attention = functools.partial(dot_product_attention, causal=True)

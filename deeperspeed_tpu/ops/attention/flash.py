@@ -27,11 +27,12 @@ def flash_attention_supported(q_shape, dtype=None):
     return D % 8 == 0
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "scale"))
-def flash_attention(q, k, v, causal=True, scale=None):
-    """[B, S, N, D] q/k/v -> [B, S, N, D]; bf16/fp32 in, same dtype out."""
+@functools.partial(jax.jit, static_argnames=("causal", "scale", "window"))
+def flash_attention(q, k, v, causal=True, scale=None, window=None):
+    """[B, S, N, D] q/k/v -> [B, S, N, D]; bf16/fp32 in, same dtype out.
+    ``window``: a causal call's sliding window in rows (``mha``)."""
     from .pallas_flash import mha
 
     if scale is None:
         scale = float(q.shape[3]) ** -0.5
-    return mha(q, k, v, causal=causal, scale=scale)
+    return mha(q, k, v, causal=causal, scale=scale, window=window)

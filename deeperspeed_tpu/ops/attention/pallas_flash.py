@@ -121,6 +121,7 @@ class Plan(NamedTuple):
     resident_bwd: bool  # one-kernel backward (q side of a head fits VMEM)
     group: int          # heads to a lane block of [B, S, N*D]; 0 = folded
     width: int          # lanes of the block a program loads: group * D, or D
+    window: int = 0     # causal window in rows (row i sees i-window < j <= i); 0 = none
 
 
 def _heads_to_a_block(N, D):
@@ -139,11 +140,13 @@ def _heads_to_a_block(N, D):
     return 0
 
 
-def tile_plan(S, D, dtype, block=None, N=1):
+def tile_plan(S, D, dtype, block=None, N=1, window=None):
     """Tile sizes from what the call can see.  ``block`` overrides the
     owner block (tests); everything else follows from the shapes: the tiles
     from S and D, the layout the kernels take (``group``, ``width``) from
-    the head count N and D alone (``_heads_to_a_block``).
+    the head count N and D alone (``_heads_to_a_block``).  ``window`` (a
+    causal call's, in rows) changes no size: the same tiles, fewer of them
+    (``_band_tiles``); one that reaches the whole length is no window.
 
     Measured on the v5e for causal bf16 at D = 64, S = 1024 / 2048 (the
     benchmark's cells) and at D = 96 / 128, S = 2048-8192 (PERF.md section 6,
@@ -174,7 +177,8 @@ def tile_plan(S, D, dtype, block=None, N=1):
                and 4 * c * block * width * itemsize <= _VMEM_BUDGET // 2)
     return Plan(block, sub, rows, cps * block,
                 _bwd_resident_bytes(sp, width, itemsize, heads)
-                <= _VMEM_BUDGET, group, width)
+                <= _VMEM_BUDGET, group, width,
+                int(window) if window and window < S else 0)
 
 
 def _bwd_resident_bytes(sp, w, itemsize, heads=1):
@@ -205,6 +209,63 @@ def _pieces(ncols, sub, on_edge):
     return [(0, ncols - sub, False)][:ncols > sub] + [(ncols - sub, sub, True)]
 
 
+def _band(block, window):
+    """Which column chunks a row block's band touches, by their distance
+    ``d`` = row block - column chunk (0 is the chunk the diagonal crosses):
+    ``(full, partial)``.  Chunks at distances 1..``full`` are seen whole by
+    every row of the block; those at the distances in ``partial`` (at most
+    two) are crossed by the band's left edge; farther ones are not
+    visited."""
+    full = max(0, window // block - 1)
+    return full, list(range(full + 1, _reach(block, window) + 1))
+
+
+def _reach(block, window):
+    """The distance of the farthest column chunk a row block's band touches
+    (a window of one block reaches the chunk before the diagonal's)."""
+    return (window + block - 2) // block
+
+
+def _band_tiles(block, sub, window, d):
+    """The static walk of the chunk at distance ``d`` under a causal window,
+    as ``(row0, [(col0, ncols, mask)])``: the ``sub`` rows from ``row0`` of
+    the row block against column pieces of the chunk.  Row ``a`` of a group
+    sees the chunk's column ``c`` iff ``off + a < c`` (the band's left edge,
+    ``off = d * block + row0 - window``) and, in the chunk the diagonal
+    crosses, ``c <= row0 + a``.  Columns no row of the group sees are not
+    computed; ``mask`` is None where every row sees every column of a piece
+    and else ``(left, right)``, either None or the bound of a piece's own
+    columns against its rows (``_mask_band``): only the pieces an edge
+    crosses pay iota / compare / select, as on the diagonal alone without a
+    window.  All bounds are whole 128-lane tiles."""
+    tiles = []
+    for row0 in range(0, block, sub):
+        off = d * block + row0 - window
+        hi = block if d else row0 + sub
+        lo = max(0, off + 1) // LANES * LANES
+        if lo >= hi:
+            continue
+        # columns [lo, left_end) are hidden from some row, [diag, hi) too
+        left_end = min(hi, max(lo, -(-(off + sub) // LANES) * LANES))
+        diag = hi if d else row0
+        cuts = sorted({lo, min(left_end, hi), max(lo, diag), hi})
+        pieces = []
+        for c0, c1 in zip(cuts[:-1], cuts[1:]):
+            left = off - c0 if c0 < left_end else None
+            right = row0 - c0 if c1 > diag else None
+            pieces.append((c0, c1 - c0, None if left is None and right is None
+                           else (left, right)))
+        tiles.append((row0, pieces))
+    return tiles
+
+
+def band_pairs(S, window):
+    """(row, column) pairs a causal window lets see each other in a length
+    ``S``: the triangle's where ``window`` reaches the whole length."""
+    w = min(int(window), S) if window else S
+    return w * S - w * (w - 1) // 2
+
+
 def walk_counts(plan, S, causal=True):
     """What one head's walk computes, in ``sub x sub`` squares:
     ``(executed, masked, total)``.  The same enumeration the kernels run
@@ -229,12 +290,14 @@ def _mask(s, qi, ki, bq, bk, s_valid, causal):
     return _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad=True)
 
 
-def _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad):
+def _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad, window=0):
     if not causal and not pad:
         return s
     rows = qi * bq + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     cols = ki * bk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    if causal and pad:
+    if window:
+        valid = jnp.logical_and(cols <= rows, cols > rows - window)
+    elif causal and pad:
         valid = jnp.logical_and(cols < s_valid, cols <= rows)
     elif causal:
         valid = cols <= rows
@@ -254,6 +317,30 @@ def _mask_edge(s, causal, col0, s_valid):
     else:
         valid = cols < s_valid - col0
     return jnp.where(valid, s, NEG_INF)
+
+
+def _mask_band(s, left, right):
+    """Mask a score piece the band's edges cross: row ``a`` sees the
+    piece's column ``c`` iff ``c > left + a`` and ``c <= right + a`` (either
+    bound may be None: not in this piece).  Static bounds: one constant
+    mask."""
+    rows = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    valid = None if right is None else cols <= rows + right
+    if left is not None:
+        seen = cols > rows + left
+        valid = seen if valid is None else jnp.logical_and(valid, seen)
+    return jnp.where(valid, s, NEG_INF)
+
+
+def _masked(s, mask, causal, col0, s_valid):
+    """A score piece under its mask: none, the edge's (True: the diagonal's
+    constant triangle, or the padding), or a band's bounds."""
+    if not mask:
+        return s
+    if mask is True:
+        return _mask_edge(s, causal, col0, s_valid)
+    return _mask_band(s, *mask)
 
 
 def _ds(start, size):
@@ -323,7 +410,7 @@ def _rows_off_lanes(x):
 
 # --------------------------------------------------------------------- fwd
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
-                causal, pad, s_valid, block, sub, n, heads):
+                causal, pad, s_valid, block, sub, n, heads, window=0):
     """One q block against one resident span of its heads' k/v.  With one
     block to the head (``n == 1``) a tile is its rows' whole softmax: no
     running statistics, no scratch."""
@@ -339,13 +426,14 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             l_scr[:] = jnp.zeros_like(l_scr)
             acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def tile(row0, col0, ncols, on_edge=False):
+    def tile(row0, col0, pieces):
         """Softmax update of the ``sub`` rows from ``row0`` (static, within
-        the q block) by ``ncols`` columns from ``col0`` (within the span):
-        one update of the row statistics whatever the width."""
+        the q block) by the column ``pieces`` (``(col0, ncols, mask)``, as
+        ``_pieces`` and ``_band_tiles`` give them) of the chunk at ``col0``
+        (within the span): one update of the row statistics whatever the
+        width."""
         rows = pl.ds(row0, sub)
-        pieces = [(pl.ds(col0 + c0, nc), masked)
-                  for c0, nc, masked in _pieces(ncols, sub, on_edge)]
+        pieces = [(pl.ds(col0 + c0, nc), mask) for c0, nc, mask in pieces]
         accs, alphas = [], []
         for h in range(heads):
             # q arrives pre-scaled; no per-tile scale multiply
@@ -353,8 +441,8 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
             ss = [jax.lax.dot_general(q, k_ref[0, cols, :], _NT,
                                       preferred_element_type=jnp.float32)
                   for cols, _ in pieces]
-            ss = [_mask_edge(s, causal, n * block - sub, s_valid)
-                  if masked else s for s, (_, masked) in zip(ss, pieces)]
+            ss = [_masked(s, mask, causal, n * block - sub, s_valid)
+                  for s, (_, mask) in zip(ss, pieces)]
             m_new = functools.reduce(
                 jnp.maximum, [jnp.max(s, axis=1, keepdims=True) for s in ss])
             if n > 1:
@@ -387,21 +475,40 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
     def interior(c):
         col0 = pl.multiple_of((c - kj * cps) * block, block)
         for row0 in range(0, block, sub):
-            tile(row0, col0, block)
+            tile(row0, col0, _pieces(block, sub, False))
 
     # the chunk that ends this q block's walk: the one the diagonal crosses,
     # or (non-causal) the last of the head, where the padding is
     edge = qi if causal else n - 1
 
+    def band_chunk(d):
+        """The chunk ``d`` chunks left of the diagonal's, under a window."""
+        col0 = pl.multiple_of((edge - d - kj * cps) * block, block)
+        for row0, pieces in _band_tiles(block, sub, window, d):
+            tile(row0, col0, pieces)
+
     def edge_chunk():
+        if window:
+            return band_chunk(0)
         col0 = pl.multiple_of((edge - kj * cps) * block, block)
         for row0, ncols in _edge_tiles(block, sub, causal):
-            tile(row0, col0, ncols, on_edge=causal or pad)
+            tile(row0, col0, _pieces(ncols, sub, causal or pad))
 
     if n == 1:
         edge_chunk()
         return
-    _walk(kj * cps, jnp.minimum((kj + 1) * cps, edge), interior)
+    if window:
+        # only the chunks the block's band touches: those every row sees
+        # whole, then the one or two the band's left edge crosses
+        full, partial = _band(block, window)
+        if full:
+            _walk(jnp.maximum(kj * cps, edge - full),
+                  jnp.minimum((kj + 1) * cps, edge), interior)
+        for d in partial:
+            pl.when(jnp.logical_and(edge >= d, (edge - d) // cps == kj))(
+                functools.partial(band_chunk, d))
+    else:
+        _walk(kj * cps, jnp.minimum((kj + 1) * cps, edge), interior)
     pl.when(edge // cps == kj)(edge_chunk)
 
     @pl.when(kj == pl.num_programs(3) - 1)
@@ -418,7 +525,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 # ---------------------------------------------------------------------- bwd
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                 dq_ref, dk_ref, dv_ref, lse_scr, dk_scr, dv_scr, *dq_scr,
-                causal, pad, s_valid, block, sub, rows, n, heads):
+                causal, pad, s_valid, block, sub, rows, n, heads, window=0):
     """One k/v block against its heads' resident q side: dk and dv of the
     block, and the block's share of the heads' dq (all of it when a head
     is one block, ``n == 1``: then dq needs no accumulator)."""
@@ -440,9 +547,9 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     dk_scr[:] = jnp.zeros_like(dk_scr)
     dv_scr[:] = jnp.zeros_like(dv_scr)
 
-    def tile(row0, nrows, ncols, on_edge=False):
+    def tile(row0, nrows, pieces):
         """``nrows`` q rows from ``row0`` (within the head) against the
-        first ``ncols`` columns of the k/v block."""
+        column ``pieces`` (``(col0, ncols, mask)``) of the k/v block."""
         rows = _ds(row0, nrows)
         dqs = []
         for h in range(heads):
@@ -455,13 +562,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                             * o_ref[0, rows, :].astype(jnp.float32),
                             axis=1, keepdims=True)
             dq = None
-            for c0, nc, masked in _pieces(ncols, sub, on_edge):
+            for c0, nc, mask in pieces:
                 cols = pl.ds(c0, nc)
                 k, v = k_ref[0, cols, :], v_ref[0, cols, :]
                 s = jax.lax.dot_general(q, k, _NT,
                                         preferred_element_type=jnp.float32)
-                if masked:
-                    s = _mask_edge(s, causal, n * block - sub, s_valid)
+                s = _masked(s, mask, causal, n * block - sub, s_valid)
                 p = jnp.exp(s - lse)
                 # dV += P^T dO   (contracting the q rows)
                 dv_scr[cols, :] += jax.lax.dot_general(
@@ -486,12 +592,27 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
     def interior(c, on_edge=False):
         for r in range(0, block, rows):
-            tile(c * block + r, rows, block, on_edge)
+            tile(c * block + r, rows, _pieces(block, sub, on_edge))
 
-    if causal:
+    def band_chunk(d):
+        """The q chunk ``d`` chunks below the diagonal's, under a window."""
+        for row0, pieces in _band_tiles(block, sub, window, d):
+            tile((kj + d) * block + row0, sub, pieces)
+
+    if window:
+        # the chunk the diagonal crosses, the q chunks whose every row sees
+        # the whole block, then the one or two the band's left edge crosses;
+        # no row farther down sees a column of this block
+        full, partial = _band(block, window)
+        band_chunk(0)
+        if full:
+            _walk(kj + 1, jnp.minimum(kj + 1 + full, n), interior)
+        for d in partial:
+            pl.when(kj + d < n)(functools.partial(band_chunk, d))
+    elif causal:
         # the chunk the diagonal crosses, then every q chunk below it
         for row0, ncols in _edge_tiles(block, sub, True):
-            tile(kj * block + row0, sub, ncols, on_edge=True)
+            tile(kj * block + row0, sub, _pieces(ncols, sub, True))
         _walk(kj + 1, n, interior)
     elif pad:
         # only the head's last k/v block holds padded columns
@@ -511,8 +632,17 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
 
 
 # ------------------------------------------------- two-pass bwd (long S only)
+def _band_of_tiles(d, block, window):
+    """Of a one-level grid's tile at distance ``d`` (traced) under a window
+    -> (every row sees it whole, an edge of the band crosses it)."""
+    full, _ = _band(block, window)
+    return (jnp.logical_and(d >= 1, d <= full),
+            jnp.logical_or(d == 0, jnp.logical_and(
+                d > full, d <= _reach(block, window))))
+
+
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, causal, pad, s_valid, bq, bk, heads):
+               dq_scr, *, causal, pad, s_valid, bq, bk, heads, window=0):
     qi, ki = pl.program_id(2), pl.program_id(3)
     nk = pl.num_programs(3)
 
@@ -529,7 +659,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             s = jax.lax.dot_general(q, k, _NT,
                                     preferred_element_type=jnp.float32)
             if masked:
-                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
+                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad,
+                               window)
             p = jnp.exp(s - lse_ref[h][:, :1])
             dp = jax.lax.dot_general(do, v, _NT,
                                      preferred_element_type=jnp.float32)
@@ -539,7 +670,11 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
                 preferred_element_type=jnp.float32))
         dq_scr[:] += _each_its_lanes(dqs, dq_scr.shape[1])
 
-    if causal:
+    if window:
+        whole, crossed = _band_of_tiles(qi - ki, bq, window)
+        pl.when(whole)(lambda: _tile(False))
+        pl.when(crossed)(lambda: _tile(True))
+    elif causal:
         pl.when(ki < qi)(lambda: _tile(False))
         pl.when(ki == qi)(lambda: _tile(True))
     else:
@@ -552,7 +687,7 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, pad, s_valid, bq, bk, heads):
+                *, causal, pad, s_valid, bq, bk, heads, window=0):
     ki, qi = pl.program_id(2), pl.program_id(3)
     nq = pl.num_programs(3)
 
@@ -569,7 +704,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             s = jax.lax.dot_general(q, k, _NT,
                                     preferred_element_type=jnp.float32)
             if masked:
-                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad)
+                s = _tile_mask(s, qi, ki, bq, bk, s_valid, causal, pad,
+                               window)
             p = jnp.exp(s - lse_ref[h][:, :1])
             dv_scr[:] += jax.lax.dot_general(
                 p.astype(do.dtype), do, _TN,
@@ -580,7 +716,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             dk_scr[:] += jax.lax.dot_general(
                 ds, q, _TN, preferred_element_type=jnp.float32)
 
-    if causal:
+    if window:
+        whole, crossed = _band_of_tiles(qi - ki, bq, window)
+        pl.when(whole)(lambda: _tile(False))
+        pl.when(crossed)(lambda: _tile(True))
+    elif causal:
         pl.when(qi > ki)(lambda: _tile(False))
         pl.when(qi == ki)(lambda: _tile(True))
     else:
@@ -635,6 +775,8 @@ def _cost(q, plan, causal, matmuls, tensors):
     b, sp, hw = q.shape
     heads = hw // (plan.width // max(plan.group, 1))
     square = b * heads * sp * sp // (2 if causal else 1)
+    if plan.window:
+        square = b * heads * band_pairs(sp, plan.window)
     return pl.CostEstimate(
         flops=2 * matmuls * square * (hw // heads), transcendentals=square,
         bytes_accessed=tensors * q.size * q.dtype.itemsize + 4 * b * heads * sp)
@@ -646,7 +788,14 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
     heads, groups, n = max(plan.group, 1), hw // w, sp // block
     from jax.experimental.pallas import tpu as pltpu
 
-    if causal:
+    if plan.window:
+        # nor is a span wholly left of the band
+        reach = _reach(block, plan.window)
+
+        def kv_index(b, g, i, j):
+            return (b, jnp.clip(j, jnp.maximum(i - reach, 0) * block // span,
+                                (i * block) // span), g)
+    elif causal:
         # a span wholly above the diagonal is not walked: name the last
         # needed one again, so it is not loaded either
         def kv_index(b, g, i, j):
@@ -664,7 +813,7 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
             + 3 * plan.sub * block * 4)         # a score tile, its exp, slack
     kernel = functools.partial(
         _fwd_kernel, causal=causal, pad=s_valid != sp, s_valid=s_valid,
-        block=block, sub=plan.sub, n=n, heads=heads)
+        block=block, sub=plan.sub, n=n, heads=heads, window=plan.window)
     owned = pl.BlockSpec((1, block, w), lambda b, g, i, j: (b, i, g))
     o, lse = pl.pallas_call(
         kernel,
@@ -716,7 +865,8 @@ def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
     return pl.pallas_call(
         functools.partial(_bwd_kernel, causal=causal, pad=s_valid != sp,
                           s_valid=s_valid, block=block, sub=plan.sub,
-                          rows=plan.rows, n=n, heads=heads),
+                          rows=plan.rows, n=n, heads=heads,
+                          window=plan.window),
         grid=(b, groups, n),
         in_specs=[head, owned, owned, head, head, head_stat],
         out_specs=[head, owned, owned],
@@ -751,10 +901,22 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     from jax.experimental.pallas import tpu as pltpu
 
     static = dict(causal=causal, pad=s_valid != sp, s_valid=s_valid,
-                  bq=bq, bk=bk, heads=heads)
+                  bq=bq, bk=bk, heads=heads, window=plan.window)
     out = jax.ShapeDtypeStruct((b, sp, hw), q.dtype)
+    # under a window a tile outside the band is neither computed nor loaded:
+    # the walked side names the nearest tile of the band again
+    reach = _reach(bq, plan.window) if plan.window else None
+
+    def near(i, j, ahead):
+        """The walked side's tile ``j`` for the owner tile ``i``."""
+        if reach is None:
+            return j
+        return (jnp.clip(j, i, jnp.minimum(i + reach, nq - 1)) if ahead
+                else jnp.clip(j, jnp.maximum(i - reach, 0), i))
+
     q_spec_i = pl.BlockSpec((1, bq, w), lambda b, g, i, j: (b, i, g))
-    k_spec_j = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (b, j, g))
+    k_spec_j = pl.BlockSpec((1, bk, w),
+                            lambda b, g, i, j: (b, near(i, j, False), g))
     stat_i = pl.BlockSpec((heads, bq, LANES),
                           lambda b, g, i, j: (b * groups + g, i, 0))
 
@@ -770,10 +932,12 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     )(q, k, v, do, lse, delta)
 
     # dk/dv: grid's 3rd dim walks k tiles, 4th dim scans q tiles
-    q_spec_j = pl.BlockSpec((1, bq, w), lambda b, g, i, j: (b, j, g))
+    q_spec_j = pl.BlockSpec((1, bq, w),
+                            lambda b, g, i, j: (b, near(i, j, True), g))
     k_spec_i = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (b, i, g))
     stat_j = pl.BlockSpec((heads, bq, LANES),
-                          lambda b, g, i, j: (b * groups + g, j, 0))
+                          lambda b, g, i, j: (b * groups + g,
+                                              near(i, j, True), 0))
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, **static),
         grid=(b, groups, nk, nq),
@@ -788,14 +952,25 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     return dq, dk, dv
 
 
+def kernel_name(plan):
+    """The scope a call's kernels run under, which names their events in a
+    device trace and their counts in ``telemetry.kernel_paths()`` /
+    ``kernel_passes()``: windowed calls apart from full ones."""
+    return "flash_attention_window" if plan.window else "flash_attention"
+
+
 # ------------------------------------------------------------- public API
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _mha(q, k, v, causal, scale, plan):
     return _mha_fwd(q, k, v, causal, scale, plan)[0]
 
 
-@jax.named_scope("flash_attention")
 def _mha_fwd(q, k, v, causal, scale, plan):
+    with jax.named_scope(kernel_name(plan)):
+        return _mha_fwd_scoped(q, k, v, causal, scale, plan)
+
+
+def _mha_fwd_scoped(q, k, v, causal, scale, plan):
     s_valid = q.shape[1]
     qp, kp, vp = (_pad_seq(t, plan.block) for t in (q, k, v))
     # pre-scale q once (one elementwise multiply) instead of scaling every
@@ -809,8 +984,12 @@ def _mha_fwd(q, k, v, causal, scale, plan):
     return o[:, :s_valid], (qp, kp, vp, o, lse)
 
 
-@jax.named_scope("flash_attention")
 def _mha_bwd(causal, scale, plan, res, do):
+    with jax.named_scope(kernel_name(plan)):
+        return _mha_bwd_scoped(causal, scale, plan, res, do)
+
+
+def _mha_bwd_scoped(causal, scale, plan, res, do):
     qp, kp, vp, o, lse = res
     s_valid = do.shape[1]
     dop = _pad_seq(do, plan.block)
@@ -829,12 +1008,20 @@ def _mha_fwd_rule(q, k, v, causal, scale, plan):
 _mha.defvjp(_mha_fwd_rule, _mha_bwd)
 
 
-def mha(q, k, v, causal=True, scale=None, block=None):
+def mha(q, k, v, causal=True, scale=None, block=None, window=None):
     """Blocked multi-head attention: [B, S, N, D] q/k/v -> [B, S, N, D].
 
     Any S (padded to the 128 tile internally); D should be a multiple of 8.
     Differentiable (custom VJP, FlashAttention-2 backward).  Tile sizes come
     from ``tile_plan``; ``block`` overrides the owner block only.
+
+    ``window`` (static, causal calls only): row i sees the columns j with
+    ``i - window < j <= i``.  The forward and both backward forms visit only
+    the column chunks a row block's band touches and mask the band's left
+    edge in the one or two chunks it crosses, as the diagonal is masked in
+    its own (``_band_tiles``); a window that reaches the whole length is the
+    full call.  Windowed calls run under the scope ``flash_attention_window``
+    and count under that name.
 
     Where ``tile_plan`` finds that heads are whole lane blocks of the
     projections' output (``Plan.group``), the kernels take ``[B, S, N*D]``
@@ -848,8 +1035,10 @@ def mha(q, k, v, causal=True, scale=None, block=None):
     B, S, N, D = q.shape
     if scale is None:
         scale = float(D) ** -0.5
-    plan = tile_plan(S, D, q.dtype, block, N)
-    count_kernel_path("flash_attention",
+    if window is not None and (not causal or window < 1):
+        raise ValueError("a window belongs to a causal call and is >= 1 row")
+    plan = tile_plan(S, D, q.dtype, block, N, window)
+    count_kernel_path(kernel_name(plan),
                       f"in_place_{plan.group}" if plan.group else "folded")
     if plan.group:
         # a reshape of contiguous dimensions; whatever the compiler still

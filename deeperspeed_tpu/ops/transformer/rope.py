@@ -9,16 +9,46 @@ better, so this is the canonical XLA-fused implementation (the
 compiler fusion).
 """
 
+import math
+
 import jax.numpy as jnp
 
 
-def rotary_tables(positions, rot_dim, base=10000, dtype=jnp.float32):
-    """cos/sin tables [..., seq, 1, rot_dim] for integer positions [..., seq]."""
-    inv_freq = 1.0 / (base ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
+def yarn_inv_freq(rot_dim, base, factor, original_max_position, beta_fast=32,
+                  beta_slow=1):
+    """YaRN's inverse frequencies [rot_dim / 2] (Peng et al. 2023, as the
+    ``transformers`` library computes them): dimensions that turn more than
+    ``beta_fast`` times over the original context keep their frequency,
+    those that turn less than ``beta_slow`` times have it divided by
+    ``factor``, and a linear ramp joins the two."""
+    def correction(rotations):
+        return (rot_dim * math.log(original_max_position
+                                   / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(correction(beta_fast)), 0)
+    high = min(math.ceil(correction(beta_slow)), rot_dim - 1)
+    if low == high:
+        high += 0.001
+    i = jnp.arange(rot_dim // 2, dtype=jnp.float32)
+    freq = base ** (2 * i / rot_dim)
+    ramp = jnp.clip((i - low) / (high - low), 0.0, 1.0)
+    return ramp / (factor * freq) + (1.0 - ramp) / freq
+
+
+def rotary_tables(positions, rot_dim, base=10000, dtype=jnp.float32,
+                  inv_freq=None, scale=None):
+    """cos/sin tables [..., seq, 1, rot_dim] for integer positions [..., seq].
+    ``inv_freq`` [rot_dim / 2] replaces the plain ``base^(-2i/d)`` (YaRN's);
+    ``scale`` multiplies both tables (YaRN's attention factor)."""
+    if inv_freq is None:
+        inv_freq = 1.0 / (base ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32) / rot_dim))
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq
     emb = jnp.concatenate([freqs, freqs], axis=-1)
-    return (jnp.cos(emb)[..., None, :].astype(dtype),
-            jnp.sin(emb)[..., None, :].astype(dtype))
+    cos, sin = jnp.cos(emb), jnp.sin(emb)
+    if scale is not None:
+        cos, sin = cos * scale, sin * scale
+    return (cos[..., None, :].astype(dtype), sin[..., None, :].astype(dtype))
 
 
 def _rotate_half(x):

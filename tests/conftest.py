@@ -54,6 +54,18 @@ def pytest_collection_modifyitems(config, items):
             item.add_marker(skip)
 
 
+@pytest.fixture(autouse=True)
+def _no_step_counters_left_behind():
+    """What a train step published of itself (``telemetry.step_counters()``)
+    is process-global: a test that trains a model with counters would leave
+    them for whichever test the worker runs next, and a reader's test that
+    starts from "the program published nothing" then fails by the order."""
+    yield
+    from deeperspeed_tpu.telemetry import trace
+
+    trace._STEP_COUNTERS.clear()
+
+
 @pytest.fixture
 def mesh8():
     """Fresh pure-DP 8-device mesh, installed as the process-global mesh."""

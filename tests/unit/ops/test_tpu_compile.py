@@ -286,6 +286,73 @@ def test_flash_mha_two_heads_two_pass(one_chip):
     assert len(_kernel_operand_shapes(text)) == 3
 
 
+@pytest.mark.parametrize("shape,window", [
+    # train-mellum2-ep4-8k's windowed layers (half its micro-batch, and its
+    # own call): 32 query heads of 128 on GQA's copy of k and v
+    ((2, 8192, 32, 128), 1024), ((4, 8192, 32, 128), 1024),
+    # Mistral's window; a window within a block at two heads a lane block;
+    # a padded length and a window that is no multiple of a tile
+    ((1, 8192, 8, 128), 4096), ((2, 2048, 16, 64), 256),
+    ((2, 1000, 8, 64), 200),
+])
+def test_flash_mha_window_fwd_bwd(one_chip, shape, window):
+    """A windowed call is the same two kernel calls on the same operands as
+    a full one, under a scope of its own (which names its events in a
+    device trace), within the scoped-VMEM limit the full call states."""
+    B, S, N, D = shape
+    q = _sds(shape, jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(functools.partial(
+        pallas_flash.mha, window=window), 3), q, q, q)
+    plan = pallas_flash.tile_plan(S, D, jnp.bfloat16, N=N, window=window)
+    assert plan.window == window and plan.resident_bwd
+    assert plan._replace(window=0) == pallas_flash.tile_plan(
+        S, D, jnp.bfloat16, N=N)
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) == 2
+    sp = -(-S // plan.block) * plan.block
+    assert all((B, sp, N * D) in operands for operands in calls), calls
+    # both kernels are dispatched under the windowed scope, none under the
+    # full call's (by pass they are counted in a model's program, below)
+    names = [line.split('op_name="')[1].split('"')[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 2 and all(
+        "flash_attention_window" in n for n in names), names
+
+
+def test_flash_mha_window_long_sequence_two_pass(one_chip):
+    """S = 16k under a window: the two-pass backward's three calls."""
+    q = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(functools.partial(
+        pallas_flash.mha, window=1024), 3), q, q, q)
+    assert len(_kernel_operand_shapes(text)) == 3
+
+
+def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
+        one_chip, on_the_chip):
+    """``Mellum`` (three windowed layers and a full one, remat): every
+    windowed layer's attention is a ``flash_attention_window`` kernel call
+    forward and one backward, the full layer's a ``flash_attention`` pair;
+    the remat wrap keeps the kernels' residuals (nothing recomputed); and
+    the chip's compiler takes the dropless walk with softmax scoring and
+    gated experts."""
+    from deeperspeed_tpu.models.mellum import Mellum, MellumConfig
+
+    model = Mellum(MellumConfig.tiny(
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        hidden_size=256, num_heads=2, num_kv_heads=1, head_dim=128,
+        sliding_window=128, moe_intermediate_size=128, max_seq_len=256,
+        ce_chunk_tokens=256, remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    passes = _model_gradient_passes(
+        model, lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
+    assert passes["flash_attention_window"] == dict(forward=3, recomputed=0,
+                                                    backward=3)
+    assert passes["flash_attention"] == dict(forward=1, recomputed=0,
+                                             backward=1)
+
+
 def test_flash_mha_non_causal(one_chip):
     q = _sds((2, 1000, 8, 64), jnp.bfloat16, one_chip)
     fn = functools.partial(pallas_flash.mha, causal=False)

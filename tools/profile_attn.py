@@ -10,7 +10,9 @@ takes and returns ``[B, S, N, D]`` arrays in the layout the compiler gives a
 program's arguments, so ``around`` is an upper bound of what a model pays,
 where producers and consumers are fused; the kernel's time is the kernel's.
 
-``--parent DIR`` (a second checkout of this repo) times that checkout's
+``--windows 0 1024`` times each shape under those causal windows too (0 is the
+full call; a windowed call's kernels are ``flash_attention_window`` events and
+its TFLOP/s credit the band's pairs only).  ``--parent DIR`` (a second checkout of this repo) times that checkout's
 ``mha`` the same way, on the same chip in the same process; ``--plans``
 adds ``block:sub:rows`` triples beside the plan ``tile_plan`` picks.
 
@@ -110,6 +112,8 @@ def main():
     ap.add_argument("--plans", nargs="*", default=[],
                     help="block:sub:rows triples to time beside tile_plan's")
     ap.add_argument("--parent", default=None)
+    ap.add_argument("--windows", type=int, nargs="*", default=[0],
+                    help="causal windows in rows to time; 0 is the full call")
     ap.add_argument("--dtype", default="bfloat16")
     ap.add_argument("--non-causal", action="store_true")
     ap.add_argument("--iters", type=int, default=20)
@@ -135,14 +139,20 @@ def main():
             emit(f"{shape}_{label}_plan", **plan._asdict(),
                  executed_share=round(executed / total, 4),
                  masked_share=round(masked / total, 4))
-            # mha asks tile_plan; hand it this plan for the call
-            pf.tile_plan = lambda *a, plan=plan, **kw: plan
-            try:
-                _time(f"{shape}_{label}",
-                      lambda q, k, v: pf.mha(q, k, v, causal=causal),
-                      x, flops, args.iters)
-            finally:
-                pf.tile_plan = planned
+            for window in args.windows:
+                windowed = plan._replace(window=window if window < S else 0)
+                share = (pf.band_pairs(S, windowed.window)
+                         / pf.band_pairs(S, None)) if causal else 1.0
+                # mha asks tile_plan; hand it this plan for the call
+                pf.tile_plan = lambda *a, plan=windowed, **kw: plan
+                try:
+                    _time(f"{shape}_{label}" + (f"_w{window}" if window
+                                                else ""),
+                          lambda q, k, v: pf.mha(q, k, v, causal=causal),
+                          x, {m: f * share for m, f in flops.items()},
+                          args.iters)
+                finally:
+                    pf.tile_plan = planned
         if parent is not None:
             _time(f"{shape}_parent",
                   lambda q, k, v: parent.mha(q, k, v, causal=causal),

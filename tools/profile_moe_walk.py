@@ -7,6 +7,20 @@ from a profiler session.
 
     python tools/profile_moe_walk.py --rows 256 512 --loads 0 40x1 40 704 8000x1+704
 
+The defaults are the hybrid cell's layer (8 experts of a 1024-wide latent,
+relu2).  Mellum's cell (``train-mellum2-ep4-8k``: 16 gated experts on rows of
+2,304, 1,024-8,192 slots an expert at micro-batch 2-4 and beyond) is
+
+    python tools/profile_moe_walk.py --tokens 32768 --latent 2304 \
+        --intermediate 896 --held 16 --gated --rows 256 1024 \
+        --loads 1024 2048 4096 8192
+
+and with ``--blocks`` the form that cell's layers take (``dropless.walk_form``:
+blocks of ``--rows`` tokens against each held expert, the same time at any
+load).  ``dropless.BLOCKS_FROM_SHARE`` stands on both ways at both models'
+shapes: these two commands, and the defaults with and without ``--blocks
+--rows 2048`` (PERF.md section 6, PR 38).
+
 A load ``n`` gives every held expert ``n`` slots, ``nxk`` gives ``k`` experts
 ``n`` each, and ``+`` joins such parts (experts in order).  One JSON line a
 (chunk size, load): ms a call, which is what one expert layer of a step pays
@@ -16,6 +30,7 @@ runs it once forward and once backward).
 
 import argparse
 import collections
+import functools
 import glob
 import gzip
 import json
@@ -82,6 +97,13 @@ def main(argv=None):
     ap.add_argument("--latent", type=int, default=1024)
     ap.add_argument("--intermediate", type=int, default=2688)
     ap.add_argument("--held", type=int, default=8)
+    ap.add_argument("--gated", action="store_true",
+                    help="gated experts (silu(gate) * up on a fused gate | "
+                         "up matrix) in place of relu2")
+    ap.add_argument("--blocks", action="store_true",
+                    help="walk blocks of --rows consecutive tokens against "
+                         "each held expert (the heavily loaded form) in "
+                         "place of an expert's slots")
     ap.add_argument("--rows", type=int, nargs="+",
                     default=[dropless.ROWS_PER_CHUNK])
     ap.add_argument("--loads", nargs="+",
@@ -93,21 +115,25 @@ def main(argv=None):
     T, L, F, H = args.tokens, args.latent, args.intermediate, args.held
     keys = jax.random.split(jax.random.PRNGKey(0), 4)
     x = jax.random.normal(keys[0], (T, L), jnp.bfloat16)
-    w_in = (0.02 * jax.random.normal(keys[1], (H, L, F))).astype(jnp.bfloat16)
+    activation = dropless.gated_silu if args.gated else dropless.relu2
+    w_in = (0.02 * jax.random.normal(
+        keys[1], (H, L, 2 * F if args.gated else F))).astype(jnp.bfloat16)
     w_out = (0.02 * jax.random.normal(keys[2], (H, F, L))).astype(jnp.bfloat16)
     g = jax.random.normal(keys[3], (T, L), jnp.float32)
 
-    def loss(x, held_w, w_in, w_out, is_chosen):
+    def loss(rows, x, held_w, w_in, w_out, is_chosen):
         out, counted = dropless.routed_experts(x, held_w, is_chosen, w_in,
-                                               w_out)
+                                               w_out, activation, rows,
+                                               args.blocks)
         return jnp.sum(out * g), counted
 
     print(json.dumps({"device": jax.devices()[0].device_kind, "tokens": T,
-                      "latent": L, "intermediate": F, "held": H}), flush=True)
+                      "latent": L, "intermediate": F, "held": H,
+                      "gated": args.gated, "blocks": args.blocks}),
+          flush=True)
     for rows in args.rows:
-        dropless.ROWS_PER_CHUNK = rows       # read as the program is traced
-        step = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3),
-                                          has_aux=True))
+        step = jax.jit(jax.value_and_grad(functools.partial(loss, rows),
+                                          argnums=(0, 1, 2, 3), has_aux=True))
         for load in args.loads:
             held_w, is_chosen = routing(counts_of(load, H), T)
 
