@@ -18,6 +18,8 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTH_WORDS = ("hidden", "intermediate", "latent", "state", "head_dim",
                "_dim", "_rank", "experts_per_tok")
+#: the rate a training cell reports, which a claim on its step has to move
+STEP_RATE = "train_tokens_per_s_chip"
 
 
 def test_top_level_keys_and_sizes():
@@ -26,7 +28,8 @@ def test_top_level_keys_and_sizes():
     assert 1 <= MANIFEST["run_seconds"] <= 51
     assert 1 <= len(MANIFEST["workloads"]) <= 24
     assert len(json.dumps(MANIFEST)) < 64 * 1024
-    assert MANIFEST["command"][1].startswith(MANIFEST["paths"][0] + "/")
+    assert any(MANIFEST["command"][1].startswith(path + "/")
+               for path in MANIFEST["paths"])
     for path in MANIFEST["paths"]:
         assert os.path.isdir(os.path.join(core.ROOT, path))
 
@@ -117,36 +120,92 @@ def test_every_cell_reports_setup_another_metric_and_a_layer_metric():
         assert core.metrics_for(MANIFEST, w["name"], "per_layer")
 
 
-def test_layer_metrics_have_readers_and_move_what_their_cells_report():
-    e2e = {m["name"]: m for m in MANIFEST["end_to_end"]}
-    cells = [w["name"] for w in MANIFEST["workloads"]]
+def test_layer_metrics_have_readers_and_move_what_their_cells_report(listed):
+    manifest, bench_dir = listed
+    e2e = {m["name"]: m for m in manifest["end_to_end"]}
+    cells = [w["name"] for w in manifest["workloads"]]
     layers = {}
-    for m in MANIFEST["per_layer"]:
-        reader = core.layer_metric_reader(m["name"])
+    for m in manifest["per_layer"]:
+        reader = core.layer_metric_reader(m["name"], bench_dir)
         assert callable(reader.compute)
         assert m["moves"] in e2e and m["moves"] != "setup_s"
         for cell in m.get("workloads", []):
             assert cell in cells
             assert m["moves"] in [x["name"] for x in core.metrics_for(
-                MANIFEST, cell, "end_to_end")]
+                manifest, cell, "end_to_end")]
         if "roofline" in m["name"] or "mfu" in m["name"]:
             assert m["unit"] == "%"
         layers.setdefault(m["layer"].lower(), set()).add(m["layer"])
     assert all(len(v) == 1 for v in layers.values())   # letter for letter
 
 
-def test_a_reader_with_nothing_to_read_returns_nothing():
-    for m in MANIFEST["per_layer"]:
-        assert core.layer_metric_reader(m["name"]).compute({}, None) is None
+def test_a_reader_with_nothing_to_read_returns_nothing(listed):
+    manifest, bench_dir = listed
+    for m in manifest["per_layer"]:
+        assert core.layer_metric_reader(m["name"], bench_dir).compute(
+            {}, None) is None
 
 
-@pytest.fixture
-def bench_copy(tmp_path):
-    """The benchmark alone in a temporary copy: BENCHMARK.json and paths."""
-    shutil.copy(os.path.join(core.ROOT, "BENCHMARK.json"), tmp_path)
-    shutil.copytree(core.BENCH_DIR, tmp_path / "benchmarks",
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    return tmp_path
+def test_no_reader_waits_unlisted_and_no_manifest_beside_the_manifest(listed):
+    """A reader that ``per_layer`` does not list is never asked, and a list
+    of entries beside BENCHMARK.json is how a metric goes missing.  What else
+    a later PR keeps here (a module its readers share, named ``_...``) is its
+    own affair."""
+    manifest, bench_dir = listed
+    held = os.listdir(os.path.join(bench_dir, "layer_metrics"))
+    assert not [f for f in held if f.endswith(".json")]
+    assert {f[:-3] for f in held if f.endswith(".py")
+            and not f.startswith("_")} <= {m["name"]
+                                           for m in manifest["per_layer"]}
+
+
+def cells_reporting(manifest, metric):
+    return [w["name"] for w in manifest["workloads"]
+            if metric in [m["name"] for m in core.metrics_for(
+                manifest, w["name"], "end_to_end")]]
+
+
+def shares_of_the_peak(manifest, cell):
+    """The whole step's shares of the chip's peak that a cell prints: the
+    per-layer entries with ``mfu`` in their names that move the rate the cell
+    reports.  A claim on the cell's step stands only where one bounds it:
+    with none, a kernel taken off the path leaves nothing to hold the gain
+    to.  A later PR may list another (the step count by the device); which
+    one bounds a claim, and how it is compared, PERF.md section 3 says: one
+    that counts routed slots (``train.swa_moe_mfu_pct``) reads the seed's
+    load with the step and is compared in pairs on one seed only."""
+    return [m["name"] for m in core.metrics_for(manifest, cell, "per_layer")
+            if "mfu" in m["name"] and m["moves"] == STEP_RATE]
+
+
+@pytest.mark.parametrize("cell", cells_reporting(MANIFEST, STEP_RATE))
+def test_every_training_cell_has_a_share_of_the_whole_steps_peak(cell, listed):
+    manifest, _ = listed
+    assert shares_of_the_peak(manifest, cell), cell
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in MANIFEST["per_layer"]
+                                  if "roofline" in m["name"]])
+def test_every_roofline_is_read_in_some_cell(name):
+    assert any(name in [m["name"] for m in core.metrics_for(
+        MANIFEST, w["name"], "per_layer")] for w in MANIFEST["workloads"])
+
+
+@pytest.mark.parametrize("cell", cells_reporting(MANIFEST, STEP_RATE))
+def test_a_cell_without_its_share_of_the_peak_is_refused(cell, bench_copy):
+    """The rule above on a copy of the manifest: whole it holds; with the
+    cell's shares taken out it fails for every cell they served and for no
+    other."""
+    whole = core.load_manifest(bench_copy)
+    gone = shares_of_the_peak(whole, cell)
+    assert gone
+    removed = core.load_manifest(bench_copy)
+    removed["per_layer"] = [m for m in removed["per_layer"]
+                            if m["name"] not in gone]
+    assert not shares_of_the_peak(removed, cell)
+    for other in cells_reporting(whole, STEP_RATE):
+        kept = set(shares_of_the_peak(whole, other)) - set(gone)
+        assert set(shares_of_the_peak(removed, other)) == kept
 
 
 def test_new_cell_metric_and_mix_are_files_plus_entries(bench_copy):

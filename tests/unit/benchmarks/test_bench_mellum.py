@@ -443,17 +443,16 @@ def test_scope_readers_on_a_fixture(monkeypatch):
                 record, object()) is None
 
 
-#: readers of this cell that BENCHMARK.json cannot list yet
-UNLISTED = ["train.swa_moe_mfu_pct", "train.scope_ms.attention_window",
-            "train.scope_ms.attention_full",
-            "flash_attention_window_roofline",
-            "flash_attention_full_roofline"]
+#: this cell's own readers
+OWN = ["train.swa_moe_mfu_pct", "train.scope_ms.attention_window",
+       "train.scope_ms.attention_full", "flash_attention_window_roofline",
+       "flash_attention_full_roofline"]
 
 
-def test_the_cell_lists_the_readers_that_serve_it():
-    manifest = core.load_manifest()
+def test_the_cell_lists_the_readers_that_serve_it(listed):
+    manifest, bench_dir = listed
     names = {m["name"] for m in core.metrics_for(manifest, NAME, "per_layer")}
-    assert names == {
+    assert names >= set(OWN) | {
         "train.scope_ms.moe_route", "train.scope_ms.moe_experts",
         "train.hybrid_unattributed_pct", "train.moe_load_max_over_mean",
         "train.step_ms", "device.idle_pct.train", "train.scope_ms.mlp",
@@ -461,23 +460,19 @@ def test_the_cell_lists_the_readers_that_serve_it():
         "train.scope_ms.head_ce", "train.scope_ms.optimizer",
         "train.idle_ms.fence", "train.idle_ms.input",
         "train.idle_ms.dispatch", "train.idle_ms.outside"}
-    # this cell's own readers wait in ``layer_metrics/unlisted.json``: an
-    # accepted test pins ``ssd_scan_roofline`` as the last ``per_layer``
-    # entry and only a ``benchmark`` PR may move it, so nothing is appended;
-    # each waiting entry is whole, names this cell and has its reader
-    assert manifest["per_layer"][-1]["name"] == "ssd_scan_roofline"
-    waiting = core.load_json(core.BENCH_DIR
-                             + "/layer_metrics/unlisted.json")["per_layer"]
-    assert [m["name"] for m in waiting] == UNLISTED
-    listed = {m["name"] for m in manifest["per_layer"]}
+    # each of its own entries is whole, names this cell alone, moves what the
+    # cell reports, sits in a layer the manifest has and has its reader
+    by_name = {m["name"]: m for m in manifest["per_layer"]}
     e2e = {m["name"] for m in manifest["end_to_end"]}
-    layers = {m["layer"] for m in manifest["per_layer"]}
-    for m in waiting:
+    layers = {m["layer"] for m in manifest["per_layer"]
+              if m["name"] not in OWN}
+    for name in OWN:
+        m = by_name[name]
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert m["name"] not in listed and m["workloads"] == [NAME]
+        assert m["workloads"] == [NAME]
         assert m["moves"] in e2e and m["layer"] in layers
-        assert callable(core.layer_metric_reader(m["name"]).compute)
+        assert callable(core.layer_metric_reader(name, bench_dir).compute)
     # readers that would print a wrong number here are not asked: the full
     # kernel's take every ``flash_attention`` event at one cost
     assert not names & {"train.mfu_pct", "train.looped_mfu_pct",
@@ -490,10 +485,11 @@ def test_the_cell_lists_the_readers_that_serve_it():
                                                    "per_layer")}
         assert not any("swa" in n or "window" in n or n.endswith("_full")
                        for n in old)
-    assert [m["name"] for m in core.metrics_for(
-        manifest, NAME, "end_to_end")] == ["train_tokens_per_s_chip",
-                                           "setup_s"]
-    cell, config, traffic = core.find_cell(manifest, NAME)
+    assert {m["name"] for m in core.metrics_for(
+        manifest, NAME, "end_to_end")} == {"train_tokens_per_s_chip",
+                                           "setup_s"}
+    cell, config, traffic = core.find_cell(manifest, NAME,
+                                           os.path.dirname(bench_dir))
     assert cell["chips"] == 1 and traffic["runner"] == "train_swa_moe"
     assert traffic["seq_len"] == 8192 and traffic["remat"] is True
     assert traffic["scheduler"]["type"] == "WarmupLR"

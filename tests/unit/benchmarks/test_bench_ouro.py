@@ -321,8 +321,8 @@ def test_exit_gate_reader_on_a_fixture(monkeypatch):
     assert reader.compute({}, None) is None
 
 
-def test_the_cell_lists_the_readers_that_serve_it():
-    manifest = core.load_manifest()
+def test_the_cell_lists_the_readers_that_serve_it(listed):
+    manifest, _ = listed
     names = {m["name"] for m in core.metrics_for(
         manifest, "train-ouro-2.6b-loop4", "per_layer")}
     assert {"train.looped_mfu_pct", "train.scope_ms.exit_gate",

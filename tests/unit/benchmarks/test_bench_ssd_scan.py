@@ -94,12 +94,12 @@ def test_the_reader_asks_the_program_for_its_passes():
         reader.kernel_passes()) == set(PASSES)
 
 
-def test_only_the_hybrid_cell_lists_the_metric():
-    manifest = core.load_manifest()
+def test_only_the_hybrid_cell_lists_the_metric(listed):
+    manifest, _ = listed
     entry = [m for m in manifest["per_layer"]
              if m["name"] == "ssd_scan_roofline"]
     assert entry == [{"name": "ssd_scan_roofline", "unit": "%",
                       "better": "higher", "source": "device_trace",
                       "layer": "kernels", "moves": "train_tokens_per_s_chip",
                       "workloads": [NAME]}]
-    assert manifest["per_layer"][-1] == entry[0]
+    assert entry[0] in manifest["per_layer"]
