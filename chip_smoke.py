@@ -263,14 +263,19 @@ def phase_windowed():
     in-place path under ``flash_attention_window``, and in the compiled step
     three forward and three backward kernel calls of that name beside one
     pair of the full kernel's, nothing recomputed: what the layers' pattern
-    says), every kind of layer counted itself, and no routed slot was
-    dropped."""
+    says), every expert layer walked the grouped form (``grouped_matmul``:
+    eight kernel calls forward, 24 backward, none recomputed, a buffer
+    handed over unwritten a layer and pass; its kernels multiplied the slots
+    held and no more than a tile an expert and a chunk beside them), every
+    kind of layer counted itself, and no routed slot was dropped."""
     import jax.numpy as jnp
 
     import deeperspeed_tpu as dst
     from deeperspeed_tpu import telemetry
     from deeperspeed_tpu.models.mellum import Mellum, MellumConfig
+    from deeperspeed_tpu.ops import pallas_gmm
 
+    walked = dict(telemetry.kernel_paths().get("grouped_matmul", {}))
     model = Mellum(MellumConfig.mellum2_12b(
         layers_held=4, first_layer_held=12, routed_experts_held=16,
         vocab_rows_held=24576, max_seq_len=SEQ, remat=True,
@@ -292,18 +297,31 @@ def phase_windowed():
             problems.append(f"{counter} is not {want}: {told}")
     if not told.get("moe_slots_held", 0) > 0:
         problems.append(f"no slot routed to the experts held: {told}")
+    held, computed = told.get("moe_slots_held", 0), told.get(
+        "moe_rows_computed", 0)
+    # 16 experts and at most two chunks of the 2048 x 8 sorted slots
+    if not held <= computed <= held + pallas_gmm.TILE_ROWS * (16 + 2):
+        problems.append(f"the grouped matmul's rows are not the slots': "
+                        f"{told}")
     paths = telemetry.kernel_paths().get("flash_attention_window", {})
     if set(paths) != {"in_place_1"}:
         problems.append(f"a windowed call left the in-place kernel: {paths}")
+    walks = {form: n - walked.get(form, 0) for form, n in
+             telemetry.kernel_paths().get("grouped_matmul", {}).items()}
+    if not walks.get("pallas") or walks.get("slots"):
+        problems.append(f"an expert layer left the grouped form: {walks}")
     passes = step_kernel_passes(engine, batch)
     want = {"flash_attention_window": dict(forward=3, recomputed=0,
                                            backward=3),
-            "flash_attention": dict(forward=1, recomputed=0, backward=1)}
+            "flash_attention": dict(forward=1, recomputed=0, backward=1),
+            "grouped_matmul": dict(forward=8, recomputed=0, backward=24),
+            "unwritten": dict(forward=4, recomputed=0, backward=4)}
     got = {k: passes.get(k) for k in want}
     if got != want:
-        problems.append(f"the attention kernels' passes under remat: {got}")
+        problems.append(f"the kernels' passes under remat: {got}")
     emit("windowed", ok=not problems, problems=problems, counters=told,
-         kernel_paths=paths, kernel_passes=got,
+         kernel_paths={"flash_attention_window": paths,
+                       "grouped_matmul": walks}, kernel_passes=got,
          model="mellum2_12b share, one period, 16 experts", seq=SEQ,
          params=model.num_params(), losses=[round(x, 4) for x in losses],
          peak_bytes_in_use=peak_bytes(), **device_facts())

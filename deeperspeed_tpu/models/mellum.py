@@ -267,11 +267,12 @@ class Mellum(nn.Module):
         block = MellumBlock
         if cfg.remat:
             # a recomputed layer keeps the flash kernel's own two residuals
-            # (windowed calls name theirs alike), as the dense models' do
+            # (windowed calls name theirs alike), as the dense models' do,
+            # and the grouped walk's plan: its sorts are made once a step
             block = nn.remat(
                 MellumBlock,
                 policy=jax.checkpoint_policies.save_only_these_names(
-                    *SAVED_BY_REMAT))
+                    *SAVED_BY_REMAT, dropless.PLAN_SAVED_BY_REMAT))
         told = []
         for i, kind in enumerate(cfg.kinds):
             x, said = block(cfg, kind, name=f"layers_{i}")(x)

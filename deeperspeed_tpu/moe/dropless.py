@@ -24,20 +24,39 @@ is one expert's, so its matmuls are plain ones, its tokens are all different
 and in order (the gathers and scatter-adds are told so), and an expert's
 weight gradient is added to in place.
 
-The walk is one loop, one expert body and one custom VJP; what differs by
-the shapes alone (``walk_form``) is how a chunk finds its rows.  By *slots*
-(above) a chunk is some of ONE expert's slots, read and added to by index,
-and the walk is as long as the load: the way of a lightly loaded share (a few
-per cent of the (token, held expert) pairs chosen).  By *blocks* a chunk is
-``rows`` consecutive tokens against one held expert, ALL the block's tokens
-with weight zero where the token did not choose the expert, so its rows are
-slices and its results are added in place, with no gather, no scatter-add and
-no sort, at the price of the unchosen pairs' matmuls; the walk is ``held x
-tokens / rows`` chunks whatever the load, and its time does not move with
-it.  On a v5e a scatter-add of a chunk's rows costs a pass over the whole
-``[tokens, width]`` float32 table it adds to plus 0.63 us a row (1.55 ms for
-1024 rows into [32768, 2304]), twice a chunk; both ways are measured at both
-models' shapes beside ``BLOCKS_FROM_SHARE`` (PERF.md, PR 38).
+Two forms, chosen by the shapes alone (``walk_form``).  By *slots* (above)
+a chunk is some of ONE expert's slots, read by index and added to the output
+by XLA's scatter-add: on a v5e that is a pass over the whole ``[tokens,
+width]`` float32 table plus 0.63 us a row (1.55 ms for 1024 rows into [32768,
+2304]), twice a chunk.  Its fixed costs are small, so it is the way of a
+lightly loaded share (a few per cent of the (token, held expert) pairs
+chosen: the hybrid model's top-22 of 512).  The *grouped* form is the way
+of a heavily loaded one (Mellum's top-8 of 64 over 16 held): the sorted
+slots are walked ``rows`` at a time ACROSS experts, a chunk's rows gathered
+in that order and multiplied by a grouped matmul (``ops/pallas_gmm.py``: a
+row tile by the matrix of the expert it falls in by the counts, a tile that
+straddles two experts visited once for each, no expert padded to a
+capacity; only the tiles under the slots routed here are visited), and
+each chunk's results are written, unweighted and in the rows' own type, to
+their places in a buffer of ``tokens x min(k, held)`` rows in sorted order
+(handed over unwritten: a slot's place is written before it is read).
+The output is then a *gather*: a token has at most ``min(k, held)`` slots,
+so it reads its own slots' rows by their sorted positions (a zero row where
+a choice is not held), weights them and sums them in float32: nothing is
+scatter-added into a ``[tokens, width]`` table, and only rows that are
+written are touched.  The backward pass is the transpose: a token's
+cotangent gathered in sorted order, the rows and the hidden recomputed a
+chunk at a time, the matrices' gradients by the transposed grouped matmul
+(an expert's rows' outer products, float32, in place), ``d_x`` by the same
+gather-and-sum over a buffer of the chunks' ``d_rows``.  The same sums in
+the same types either way; the memory is a chunk's plus that one buffer.
+What the grouped form costs a program's set-up is held down on purpose
+(PERF.md section 6, PRs 40 and 41): its forward, its backward and its plan
+are each behind one ``jax.jit``, so that a model's layers of one shape are
+one trace and one body of the lowered step; and every sort but one is in
+the plan, which a recomputed layer keeps (``PLAN_SAVED_BY_REMAT``): a sort
+is how the TPU permutes narrow rows, and each is a megabyte or two of the
+step's executable, which is loaded from the compile cache in every process.
 
 Two things of a layer are arguments of the one walk.  The scoring:
 ``sigmoid_topk`` is DeepSeek-V3's, as the Nemotron-H family uses it
@@ -51,10 +70,19 @@ chosen).  And an expert's body between its two matrices, ``activation``:
 """
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
+
+from ..ops import pallas_gmm
+from ..telemetry.trace import count_kernel_path
+
+# a grouped chunk is whole tiles of the kernels' rows, and the fewest rows a
+# tile has (a bfloat16 tile's 16 sublanes)
+_ROWS_STEP = 128
 
 #: Slots a chunk of the routed walk, all of one expert.  An expert with a
 #: few slots costs a chunk all the same, and a chunk costs by its rows: on a
@@ -64,32 +92,39 @@ import numpy as np
 #: experts with 40 slots each cost 3.4 / 5.9 / 7.3 ms and 8 with 704 each
 #: 9.7 / 11.5 / 7.3 ms (PERF.md, PR 34).
 ROWS_PER_CHUNK = 256
-#: Tokens a block, where a chunk of the walk is a block of consecutive tokens
-#: against one held expert, and the share of the (token, held expert) pairs
-#: that even routing must choose (``k / num_experts``) for a layer's walk to
-#: go by blocks.  Both ways measured on a v5e at both models' shapes, forward
-#: and backward, ms a layer (PERF.md, PR 38).  Mellum's layer (32,768 tokens
-#: of 2,304 floats, 16 gated experts of 896): by blocks 275 / 225 / 199 at
-#: 512 / 1024 / 2048 tokens a block, at any load; by slots, 1024 a chunk,
-#: 132 at 6.25 % of the pairs chosen and 241 at Mellum's own 12.5 % (top-8 of
-#: 64): they cross at 10 %.  The hybrid model's layer (16,384 tokens of 1,024
-#: floats, 8 relu2 experts of 2,688): by blocks 43.6 at any load; by slots,
-#: 256 a chunk, 4.8 at 0.24 % and 11.2 at its own 4.3 % (top-22 of 512):
-#: they would cross near 25 %.
-ROWS_PER_BLOCK = 2048
-BLOCKS_FROM_SHARE = 1 / 10
+#: Sorted slots a chunk of the grouped walk (rows of any experts), and the
+#: share of the (token, held expert) pairs that even routing must choose
+#: (``k / num_experts``) for a layer's walk to take the grouped form.
+#: Both forms on a v5e at both models' shapes, forward and backward, ms a
+#: layer (`tools/profile_moe_walk.py`; PERF.md, PR 40).  Mellum's layer
+#: (32,768 tokens of 2,304 floats, 16 gated experts of 896) at 2,048 / 4,096 /
+#: 6,250 slots an expert (6.25 % of the pairs chosen, its own 12.5 %, 19 %):
+#: grouped 26.3 / 43.2 / 64.2 in chunks of 8,192 rows (4,096 and 16,384 rows
+#: read 4 % less and 8 % more before the tokens were taken by load; tiles of
+#: 256, 512 and 1,024 rows alike), of it the kernels 9.6 / 18.8 / 31.1, 81-88 %
+#: of the MXU's peak at the slots' rows; by slots, 1,024 a chunk, 131.7 /
+#: 240.7.  The hybrid model's layer (16,384 tokens of 1,024 floats, 8 relu2
+#: experts of 2,688) at 40 / 704 slots an expert (0.24 %, its own 4.3 %):
+#: grouped 5.15 / 6.22, by slots, 256 a chunk, 4.57 / 10.88.  They cross
+#: under 5 % at either model's shapes; the threshold is held at 5 % (ISSUE
+#: 40), which keeps the hybrid model's walk what it was.
+ROWS_PER_GROUPED_CHUNK = 8192
+GROUPED_FROM_SHARE = 1 / 20
+#: The name a ``jax.checkpoint`` policy keeps the grouped walk's plan by
+#: (``save_only_these_names``): a few int32 a token (4.3 MB of Mellum's
+#: layer) in place of the plan's sorts again in the recomputed layer.
+PLAN_SAVED_BY_REMAT = "moe_grouped_plan"
 
 
-def walk_form(tokens, k, num_experts):
-    """-> (whether the walk goes by blocks of tokens, rows a chunk), from
-    the shapes alone: blocks where even routing chooses a tenth or more of
-    the pairs and the tokens divide into blocks; else an expert's slots,
-    ``ROWS_PER_CHUNK`` a chunk."""
-    if k / num_experts >= BLOCKS_FROM_SHARE:
-        rows = next((r for r in (ROWS_PER_BLOCK, 1024, 512, 256, 128)
-                     if tokens % r == 0), tokens)
-        if rows <= ROWS_PER_BLOCK:
-            return True, rows
+def walk_form(tokens, k, num_experts, widths=()):
+    """-> (whether the walk is the grouped one, rows a chunk), from the
+    shapes alone: grouped where even routing chooses a twentieth or more of
+    the pairs and the kernels take the ``widths`` (the tokens', the two
+    matrices'); else an expert's slots, ``ROWS_PER_CHUNK`` a chunk."""
+    if k / num_experts >= GROUPED_FROM_SHARE and pallas_gmm.takes(*widths):
+        # no more rows a chunk than the slots there can be, in whole tiles
+        most = -(-tokens * k // _ROWS_STEP) * _ROWS_STEP
+        return True, min(ROWS_PER_GROUPED_CHUNK, most)
     return False, min(ROWS_PER_CHUNK, tokens)
 
 
@@ -180,19 +215,6 @@ def _slots_chunk(plan, c, rows, tokens):
     return e, pair, token, n
 
 
-def _block_chunk(plan, c, rows, tokens):
-    """Chunk ``c`` of the walk by blocks -> (its expert, the first of its
-    pairs in the ``[held * tokens]`` pair order, its first token, how many
-    of its tokens chose the expert): expert by expert, each expert's blocks
-    in token order, so that an expert's matrices stay put while its gradient
-    adds up."""
-    is_slot, _ = plan
-    e, j = c // (tokens // rows), c % (tokens // rows)
-    pair = e * tokens + j * rows
-    return e, pair, j * rows, jnp.sum(
-        jax.lax.dynamic_slice_in_dim(is_slot, pair, rows))
-
-
 #: what every gather and scatter-add of a chunk may be told of its indices
 _ONE_EXPERTS_TOKENS = dict(unique_indices=True, indices_are_sorted=True)
 
@@ -216,27 +238,6 @@ class _Slots:
     def add(table, index, values):
         return table.at[index].add(values.astype(table.dtype), mode="drop",
                                    **_ONE_EXPERTS_TOKENS)
-
-
-class _Blocks:
-    """A chunk is ``rows`` consecutive tokens against one held expert: its
-    rows are a slice from ``lo`` on, added to in place.  ``held * tokens /
-    rows`` chunks whatever the load."""
-    chunk = staticmethod(_block_chunk)
-
-    @staticmethod
-    def chunks(plan, rows, tokens, held):
-        return held * (tokens // rows)
-
-    @staticmethod
-    def read(table, lo, rows):
-        return jax.lax.dynamic_slice_in_dim(table, lo, rows, axis=0)
-
-    @staticmethod
-    def add(table, lo, values):
-        there = _Blocks.read(table, lo, values.shape[0])
-        return jax.lax.dynamic_update_slice_in_dim(
-            table, there + values.astype(table.dtype), lo, axis=0)
 
 
 def _add_to_expert(table, e, d):
@@ -324,53 +325,344 @@ def _routed_bwd(activation, rows, form, kept, cotangents):
 _routed.defvjp(_routed_fwd, _routed_bwd)
 
 
+# ------------------------------------------------------- the grouped form
+class _Plan(NamedTuple):
+    """What the grouped walk needs of the routing (``_grouped_plan``)."""
+    pairs: jax.Array        # [held * T + rows] ``slot_plan``'s sorted pairs
+    counts: jax.Array       # [held] slots an expert
+    pos: jax.Array          # [T, most a token] sorted places of its slots
+    expert: jax.Array       # [T, most a token] and their experts
+    rank: jax.Array         # [T] the token's place by falling number of slots
+    by_load: jax.Array      # [T, most a token] ``pos``, its rows in that order
+
+
+def _sorted_chunk(plan, c, rows, tokens):
+    """Chunk ``c`` of the walk over the sorted slots, ``rows`` of them from
+    ``c * rows`` on, of whichever experts -> (the (expert, token) pair and
+    the token of each row, the rows each held expert has in the chunk, how
+    many rows are slots).  Rows that are no slots point past the arrays'
+    ends."""
+    order, counts = plan.pairs, plan.counts
+    lo = c * rows
+    ends = jnp.cumsum(counts)
+    sizes = jnp.clip(ends - lo, 0, rows) - jnp.clip(ends - counts - lo, 0,
+                                                    rows)
+    n = jnp.sum(sizes)
+    r = jnp.arange(rows, dtype=jnp.int32)
+    pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+    pair = jnp.where(r < n, pair, order.shape[0] + r)
+    return pair, jnp.where(r < n, pair % tokens, tokens + r), sizes, n
+
+
+def _rows_of(table, index):
+    """``table[index]``, a zero row where the index points past the end."""
+    return table.at[index].get(mode="fill", fill_value=0)
+
+
+def _tokens_a_block(tokens):
+    return next((b for b in (1024, 512, 256, 128, 64, 32, 16, 8)
+                 if tokens % b == 0), tokens)
+
+
+def _by_load(rank, table):
+    """A table of a row a token, [T, n] -> its rows by the tokens' ``rank``
+    (a sort: XLA's gather of narrow rows by index is a loop of a row a turn
+    on the TPU, its sort is not)."""
+    key = jnp.broadcast_to(rank, table.shape[::-1])
+    return jax.lax.sort((key, table.T), dimension=1, num_keys=1)[1].T
+
+
+def _sum_of_slots(table, plan, rows, weight=None):
+    """``out[t] = sum_j weight[t, j] * table[pos[t, j]]`` in float32: each
+    token GATHERS the rows of its own slots from the sorted ``table`` -- what
+    takes the place of a scatter-add of rows into ``[tokens, width]``.  The
+    tokens are taken by falling number of slots (``plan.by_load``), a block
+    of them at a time, and a block reads a row a token for as many slots as
+    its first token has, so the rows read follow the load and not ``tokens x
+    min(k, held)``; a last gather of ``tokens`` whole rows puts them in the
+    tokens' own order.  -> [tokens, width] in ``table``'s type where no
+    ``weight`` is given (the sums still float32), else float32."""
+    tokens, most = plan.pos.shape
+    block = _tokens_a_block(tokens)
+    width = table.shape[1]
+    tables = (plan.by_load,) if weight is None else (
+        plan.by_load, _by_load(plan.rank, weight))
+
+    def one(args):
+        def add_slot(j, acc):
+            rows = _rows_of(table, jax.lax.dynamic_index_in_dim(
+                args[0], j, axis=1, keepdims=False)).astype(jnp.float32)
+            if weight is not None:
+                rows = rows * jax.lax.dynamic_index_in_dim(args[1], j, axis=1)
+            return acc + rows
+
+        slots = jnp.sum(args[0][0] < _room(plan.pos, rows))
+        out = jax.lax.fori_loop(0, slots, add_slot,
+                                jnp.zeros((block, width), jnp.float32))
+        return out if weight is not None else out.astype(table.dtype)
+
+    out = jax.lax.map(one, tuple(t.reshape(-1, block, most)
+                                 for t in tables)).reshape(tokens, width)
+    # whole rows by index are the gather XLA is good at
+    return out[plan.rank]
+
+
+def _grouped_chunks(plan, rows):
+    return -(-jnp.sum(plan.counts) // rows)
+
+
+def _grouped_walk(plan, rows, body, carry):
+    """``body(c, carry)`` for every chunk of the sorted slots."""
+    return jax.lax.fori_loop(0, _grouped_chunks(plan, rows), body, carry)
+
+
+def _room(pos, rows):
+    """Rows of the sorted slots' buffer: the most slots the tokens can have
+    (``pos`` [T, most a token]), in whole chunks."""
+    return -(-pos.shape[0] * pos.shape[1] // rows) * rows
+
+
+def _tile(rows):
+    """Rows a tile of the kernels, for chunks of ``rows``."""
+    return next(t for t in (pallas_gmm.TILE_ROWS, 256, 128, 64, 32, 16, 8, 1)
+                if rows % t == 0)
+
+
+def _gmm(visits, tile):
+    """The grouped matmul of a chunk's visits.  One form of call for both
+    passes: calls of one signature share a trace and a lowering."""
+    return functools.partial(pallas_gmm.grouped_matmul, visits=visits,
+                             tile_rows=tile)
+
+
+def _slot_weights(held_w, plan, rows):
+    """The routing weight of each token's slots, [T, most] float32, zero
+    where a token has fewer."""
+    mine = plan.expert[..., None] == jnp.arange(held_w.shape[1])
+    return jnp.where(plan.pos < _room(plan.pos, rows), jnp.sum(
+        jnp.where(mine, held_w[:, None, :], 0.0), axis=-1), 0.0)
+
+
+# The walk's two halves and its plan are jitted: a model's layers of one
+# shape, and their replay under ``remat``, share ONE trace of each and the
+# step's lowering emits each body once and calls it.  Traced in Python under
+# flax, ``remat`` and the custom VJP for every layer and pass, the same walk
+# cost the cell seconds of set-up (PERF.md section 6, PRs 40 and 41).
+@functools.partial(jax.jit, static_argnames=("activation", "rows"))
+def _grouped_forward(x, held_w, w_in, w_out, plan, activation, rows):
+    """-> (out [T, L] float32, the slots the chunks counted, the rows the
+    kernels multiplied)."""
+    tile, room = _tile(rows), _room(plan.pos, rows)
+    with jax.named_scope("moe_route"):
+        w_in_, w_out_ = w_in.astype(x.dtype), w_out.astype(x.dtype)
+
+    def body(c, carry):
+        ys, done, computed = carry
+        with jax.named_scope("moe_route"):
+            _, token, sizes, n = _sorted_chunk(plan, c, rows, x.shape[0])
+            visits = pallas_gmm.visit_plan(sizes, rows, tile)
+            x_rows = _rows_of(x, token)
+        gmm = _gmm(visits, tile)
+        with jax.named_scope("moe_experts"):
+            hidden = gmm(x_rows, w_in_)
+            y = gmm(activation(hidden), w_out_)
+        with jax.named_scope("moe_route"):
+            return (jax.lax.dynamic_update_slice_in_dim(ys, y, c * rows, 0),
+                    done + n, computed + visits.count * tile)
+
+    with jax.named_scope("moe_route"):
+        slots = pallas_gmm.unwritten((room, x.shape[1]), x.dtype, after=x)
+    ys, done, computed = _grouped_walk(plan, rows, body, (
+        slots, jnp.int32(0), jnp.int32(0)))
+    with jax.named_scope("moe_route"):
+        out = _sum_of_slots(ys, plan, rows,
+                            _slot_weights(held_w, plan, rows))
+    return out, done, computed
+
+
+@functools.partial(jax.jit, static_argnames=("activation", "rows"))
+def _grouped_backward(x, held_w, w_in, w_out, plan, d_out, activation, rows):
+    """The chunks again: a chunk's rows and hidden are recomputed, its
+    cotangents gathered in sorted order, the matrices' gradients summed in
+    float32 by the transposed grouped matmul, and ``d_rows`` and the slots'
+    weights' gradients written to their sorted places; then every token
+    gathers its own.  -> the gradients of ``x``, ``held_w``, ``w_in`` and
+    ``w_out``."""
+    pos, expert = plan.pos, plan.expert
+    tile, room = _tile(rows), _room(pos, rows)
+    with jax.named_scope("moe_route"):
+        w_in_, w_out_ = w_in.astype(x.dtype), w_out.astype(x.dtype)
+        pair_w = held_w.T.reshape(-1)
+        # every slot's place in the first two is written before it is read
+        sums = (pallas_gmm.unwritten((room, x.shape[1]), x.dtype,
+                                     after=d_out),
+                jnp.zeros(room, jnp.float32),
+                jnp.zeros(w_in.shape, jnp.float32),
+                jnp.zeros(w_out.shape, jnp.float32))
+
+    def body(c, grads):
+        d_xs, d_ws, d_w_in, d_w_out = grads
+        with jax.named_scope("moe_route"):
+            pair, token, sizes, _ = _sorted_chunk(plan, c, rows, x.shape[0])
+            visits = pallas_gmm.visit_plan(sizes, rows, tile)
+            x_rows = _rows_of(x, token)
+            d_y = _rows_of(d_out, token)
+            weight = _rows_of(pair_w, pair)
+        gmm = _gmm(visits, tile)
+        with jax.named_scope("moe_experts"):
+            hidden = gmm(x_rows, w_in_)
+            act, transpose = jax.vjp(activation, hidden)
+            y = gmm(act, w_out_)
+        with jax.named_scope("moe_route"):
+            d_weight = jnp.sum(d_y * y.astype(jnp.float32), axis=-1)
+            d_y = (d_y * weight[:, None]).astype(y.dtype)
+        with jax.named_scope("moe_experts"):
+            d_hidden, = transpose(gmm(d_y, w_out_, transpose_rhs=True))
+            d_rows = gmm(d_hidden, w_in_, transpose_rhs=True)
+            d_w_in = pallas_gmm.grouped_outer(x_rows, d_hidden, visits,
+                                              d_w_in, tile_rows=tile)
+            d_w_out = pallas_gmm.grouped_outer(act, d_y, visits, d_w_out,
+                                               tile_rows=tile)
+        with jax.named_scope("moe_route"):
+            at = c * rows
+            return (jax.lax.dynamic_update_slice_in_dim(d_xs, d_rows, at, 0),
+                    jax.lax.dynamic_update_slice_in_dim(d_ws, d_weight, at, 0),
+                    d_w_in, d_w_out)
+
+    d_xs, d_ws, d_w_in, d_w_out = _grouped_walk(plan, rows, body, sums)
+    with jax.named_scope("moe_route"):
+        d_w_in, d_w_out = d_w_in.astype(w_in.dtype), d_w_out.astype(
+            w_out.dtype)
+        d_x = _sum_of_slots(d_xs, plan, rows)
+        # a slot's weight is its token's weight for the slot's expert; a
+        # pair nobody chose has no slot and no gradient
+        d_slot = _rows_of(d_ws, pos)
+        d_held_w = jnp.sum(jnp.where(
+            expert[..., None] == jnp.arange(held_w.shape[1]),
+            d_slot[..., None], 0.0), axis=1)
+    return d_x, d_held_w.astype(held_w.dtype), d_w_in, d_w_out
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _grouped(x, held_w, w_in, w_out, plan, activation, rows):
+    return _grouped_forward(x, held_w, w_in, w_out, plan, activation, rows)
+
+
+def _grouped_fwd(x, held_w, w_in, w_out, plan, activation, rows):
+    """Keeps the inputs and the plan, and nothing of a chunk."""
+    return (_grouped_forward(x, held_w, w_in, w_out, plan, activation, rows),
+            (x, held_w, w_in, w_out, plan))
+
+
+def _grouped_bwd(activation, rows, kept, cotangents):
+    no_gradient = jax.tree_util.tree_map(
+        lambda a: np.zeros(jnp.shape(a), jax.dtypes.float0), kept[-1])
+    return _grouped_backward(*kept, cotangents[0], activation, rows) + (
+        no_gradient,)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "per_token"))
+def _grouped_plan(is_chosen, rows, per_token):
+    """What the grouped walk needs of the routing, once a layer and step
+    (``PLAN_SAVED_BY_REMAT``): the sorted pairs and the counts
+    (``slot_plan``); for every token the sorted positions of its own slots
+    and their experts, [T, per_token], by expert, a place past the slots'
+    buffer (``_room``) where the token has fewer; each token's place among
+    the tokens by falling number of slots, and the positions in that order.
+    Every sort of the walk but one is here (a sort is the TPU's way to
+    permute narrow rows, and a megabyte or two of the step's executable)."""
+    T, held = is_chosen.shape
+    order, counts = slot_plan(is_chosen)
+    room = -(-T * per_token // rows) * rows
+    # the sorted order IS the pairs' own order, so a pair's place is the
+    # number of chosen pairs before it
+    place = jnp.cumsum(is_chosen.T.reshape(-1).astype(jnp.int32)) - 1
+    place = jnp.where(is_chosen, place.reshape(held, T).T, room)
+    pos, expert = jax.lax.sort(
+        (place, jnp.broadcast_to(jnp.arange(held, dtype=jnp.int32),
+                                 place.shape)), dimension=1, num_keys=1)
+    pos, expert = pos[:, :per_token], expert[:, :per_token]
+    # a token has 0 .. held slots, so its place by falling number of slots
+    # (tokens of one number in their own order) is a count, not a sort: the
+    # tokens with more slots, and the earlier ones with as many
+    slots = jnp.sum(is_chosen.astype(jnp.int32), axis=1)
+    same = (slots[None, :] == jnp.arange(held, -1, -1, dtype=jnp.int32)[
+        :, None]).astype(jnp.int32)                     # [held + 1, T]
+    earlier = jnp.cumsum(same, axis=1) - same
+    as_many = jnp.sum(same, axis=1)
+    more = jnp.cumsum(as_many) - as_many
+    rank = jnp.sum(same * (earlier + more[:, None]), axis=0)
+    return _Plan(
+        jnp.concatenate([order, jnp.full(rows, held * T, jnp.int32)]),
+        counts, pos, expert, rank, _by_load(rank, pos))
+
+
 def routed_experts(x, held_w, is_chosen, w_in, w_out, activation=relu2,
-                   rows=None, blocks=False):
+                   rows=None, grouped=False, per_token=None):
     """``sum_e held_w[t, e] * act(x[t] @ w_in[e]) @ w_out[e]`` over the
     experts held here, for the chosen (token, expert) pairs only.
 
     ``x`` [T, L] tokens (in the experts' own width), ``held_w`` / ``is_chosen``
     [T, held] from ``held_weights``, ``w_in`` [held, L, F] (``[held, L, 2 F]``
     for a gated ``activation``), ``w_out`` [held, F, L]; ``rows`` slots a
-    chunk (``ROWS_PER_CHUNK`` unless given), or with ``blocks`` the tokens
-    of a block (which must divide the tokens): the two ways the one walk
-    finds a chunk's rows (the module docstring), the same sums either way
+    chunk (``ROWS_PER_CHUNK`` unless given): one expert's, or with
+    ``grouped`` sorted slots of any experts through the grouped matmul
+    (whole tiles of its rows; ``per_token`` is the most slots a token can
+    have here, ``min(k, held)``, all the held experts unless given): the
+    two forms of the module docstring, the same sums either way
     -> (out [T, L] float32, counters: ``slots`` routed here, ``done``
     slots the walk's chunks counted as they computed them, ``counts`` [held]
-    per expert).  ``slots - done`` is what was dropped: zero, because the
-    walk is as long as the slots need.  The backward pass walks the chunks
-    again (a custom VJP): a chunk's rows live only while it is computed,
-    forward and backward."""
+    per expert and, of the grouped form, ``computed``: the rows its kernels
+    multiplied, straddled and partly filled tiles included).  ``slots -
+    done`` is what was dropped: zero, because the walk is as long as the
+    slots need.  The backward pass walks the chunks again (a custom VJP): a
+    chunk's rows live only while it is computed, forward and backward."""
     T, held = held_w.shape
+    count_kernel_path("grouped_matmul", "pallas" if grouped else "slots")
+    if grouped:
+        rows = rows or ROWS_PER_GROUPED_CHUNK
+        with jax.named_scope("moe_route"):
+            plan = _Plan(*(checkpoint_name(a, PLAN_SAVED_BY_REMAT)
+                           for a in _grouped_plan(
+                               is_chosen, rows, min(per_token or held, held))))
+        out, done, computed = _grouped(x, held_w, w_in, w_out, plan,
+                                       activation, rows)
+        return out, {"slots": jnp.sum(plan.counts), "done": done,
+                     "counts": plan.counts, "computed": computed}
     rows = min(rows or ROWS_PER_CHUNK, T)
     with jax.named_scope("moe_route"):
-        if blocks:
-            counts = jnp.sum(is_chosen.astype(jnp.int32), axis=0)
-            plan = (is_chosen.T.reshape(-1).astype(jnp.int32), counts)
-        else:
-            order, counts = slot_plan(is_chosen)
-            # a chunk's rows are read ``rows`` at a time from any slot on
-            plan = (jnp.concatenate(
-                [order, jnp.full(rows, held * T, jnp.int32)]), counts)
+        order, counts = slot_plan(is_chosen)
+        # a chunk's rows are read ``rows`` at a time from any slot on
+        plan = (jnp.concatenate(
+            [order, jnp.full(rows, held * T, jnp.int32)]), counts)
         pair_w = held_w.T.reshape(-1)            # as the plan counts pairs
     out, done = _routed(x, pair_w, w_in, w_out, plan, activation, rows,
-                        _Blocks if blocks else _Slots)
+                        _Slots)
     return out, {"slots": jnp.sum(counts), "done": done, "counts": counts}
 
 
 def load_counters(per_layer):
     """What a step says of its expert layers, from each layer's
     ``routed_experts`` counters: the mean number of slots a layer held, the
-    fullest held expert over the mean one (the largest over the layers), and
-    the slots dropped (zero)."""
+    fullest held expert over the mean one (the largest over the layers), the
+    slots dropped (zero) and, where every layer walked the grouped form, the
+    mean number of rows a layer's kernels multiplied (over
+    ``moe_slots_held``: what straddled and partly filled tiles cost)."""
     slots = jnp.stack([c["slots"] for c in per_layer]).astype(jnp.float32)
     done = jnp.stack([c["done"] for c in per_layer]).astype(jnp.float32)
     counts = jnp.stack([c["counts"] for c in per_layer]).astype(jnp.float32)
     skew = jnp.max(counts, axis=1) / jnp.maximum(jnp.mean(counts, axis=1),
                                                  1.0)
-    return {"moe_slots_held": jnp.mean(slots),
+    told = {"moe_slots_held": jnp.mean(slots),
             "moe_load_max_over_mean": jnp.max(skew),
             "moe_slots_dropped": jnp.sum(slots - done)}
+    if all("computed" in c for c in per_layer):
+        told["moe_rows_computed"] = jnp.mean(jnp.stack(
+            [c["computed"] for c in per_layer]).astype(jnp.float32))
+    return told
 
 
 def dropless_moe(x, logits, w_in, w_out, *, k, first_expert, experts_held,
@@ -386,7 +678,10 @@ def dropless_moe(x, logits, w_in, w_out, *, k, first_expert, experts_held,
         chosen, weights = scoring(logits, k, selection_bias, normalize, scale)
         held_w, is_chosen = held_weights(chosen, weights, first_expert,
                                          experts_held)
-    blocks, rows = walk_form(x.shape[0], k, logits.shape[-1])
+    grouped, rows = walk_form(
+        x.shape[0], k, logits.shape[-1],
+        (x.shape[-1], w_in.shape[-1], w_out.shape[-2]))
     out, counters = routed_experts(x, held_w, is_chosen, w_in, w_out,
-                                   activation, rows, blocks)
+                                   activation, rows, grouped,
+                                   min(k, experts_held))
     return out, counters, is_chosen

@@ -3,10 +3,13 @@
 log-probabilities, routed sets and gradients for each kind of layer alone
 and for a period; the four expert shares add up to the uncut reference's
 layer (router counted once); YaRN's frequencies against numbers worked by
-hand; every windowed call of the model goes to the kernel; and the model
-through ``dst.initialize`` / ``engine.train_batch`` under a warm-up."""
+hand; every windowed call of the model goes to the kernel; the grouped
+walk's bodies in the lowered gradient program do not grow with the layers;
+and the model through ``dst.initialize`` / ``engine.train_batch`` under a
+warm-up."""
 
 import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -260,6 +263,57 @@ def test_every_windowed_call_of_the_model_takes_the_kernel(monkeypatch):
     # four attention layers, four kernel calls: none took the masked path
     assert kernels(jax.make_jaxpr(
         lambda p: model.apply({"params": p}, ids)[0])(params).jaxpr) == 4
+
+
+# ---------------------------------------------------------------- the set-up
+@pytest.fixture(scope="module")
+def lowered_gradient_programs():
+    """The recomputed model's gradient program, lowered and not compiled,
+    with two layers and with four -> {layers: (bodies, calls) by function
+    name}.  Widths of whole lane blocks, so that the expert layers walk the
+    grouped form (its kernels in interpret mode here)."""
+    def counted(layers):
+        model = Mellum(MellumConfig.tiny(
+            layer_types=(SLIDING,) * (layers - 1) + (FULL,), hidden_size=128,
+            num_heads=2, num_kv_heads=1, head_dim=64,
+            moe_intermediate_size=128, remat=True, dtype=jnp.bfloat16))
+        loss = model.loss_fn()
+        batch = model.example_batch(2, 64)
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), batch["input_ids"]))
+        text = jax.jit(jax.grad(
+            lambda p, b: loss(p["params"], b)[0])).lower(
+                params, batch).as_text()
+
+        def by_name(pattern):
+            names = [re.sub(r"_\d+$", "", n)
+                     for n in re.findall(pattern, text)]
+            return {n: names.count(n) for n in set(names)}
+
+        return (by_name(r"func\.func private @(\w+)\("),
+                by_name(r"call @(\w+)\("))
+
+    return {layers: counted(layers) for layers in (2, 4)}
+
+
+@pytest.mark.parametrize("body", ["_grouped_forward", "_grouped_backward",
+                                  "_grouped_plan"])
+def test_the_grouped_walk_is_lowered_once_for_all_the_layers(
+        lowered_gradient_programs, body):
+    """What a cell's set-up pays for the walk must not grow with the layers
+    (PERF.md section 6, PRs 40 and 41: traced in Python for every layer and
+    pass, the walk cost the cell 17 % of ``setup_s``): the jitted forward,
+    backward and plan are each ONE body of the lowered text whether the
+    model has two layers or four, and every layer calls each once (the plan
+    is kept for the recomputed layer, ``dropless.PLAN_SAVED_BY_REMAT``: its
+    sorts are not made again)."""
+    (two, calls_two), (four, calls_four) = (lowered_gradient_programs[2],
+                                            lowered_gradient_programs[4])
+    assert two[body] == four[body] == 1
+    assert calls_two[body] == 2 and calls_four[body] == 4
+    # and so are the kernels' jitted entry points inside them
+    for kernel in ("grouped_matmul", "grouped_outer"):
+        assert two[kernel] == four[kernel] > 0
 
 
 # ------------------------------------------------------------ the engine

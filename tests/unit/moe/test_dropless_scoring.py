@@ -1,8 +1,10 @@
 """The two arguments of the one routed walk (``moe/dropless.py``):
 ``softmax_topk`` scoring and the gated expert body (``gated_silu`` on a fused
 gate | up matrix), through the walk against a dense loop, values and
-gradients; dropless when every token picks the same experts; and the chunk
-size chosen from what even routing would give an expert."""
+gradients; dropless when every token picks the same experts; and the two
+forms of the walk (an expert's slots, or the sorted slots through the grouped
+matmul, whose kernels run in interpret mode here at widths they tile), chosen
+from the share of the pairs that even routing would choose."""
 
 import jax
 import jax.numpy as jnp
@@ -10,16 +12,22 @@ import numpy as np
 import pytest
 
 from deeperspeed_tpu.moe import dropless
+from deeperspeed_tpu.ops import pallas_gmm
 
 T, L, F, E, K = 96, 24, 20, 16, 3
+# widths the grouped matmul's kernels tile (whole blocks of 128 lanes)
+WIDE = (256, 128)
 
 
-def _layer(seed=0, gated=True):
+def _layer(seed=0, gated=True, widths=(L, F)):
+    L, F = widths
     ks = jax.random.split(jax.random.PRNGKey(seed), 4)
     x = jax.random.normal(ks[0], (T, L))
     logits = jax.random.normal(ks[1], (T, E))
     w_in = 0.3 * jax.random.normal(ks[2], (E, L, 2 * F if gated else F))
     w_out = 0.3 * jax.random.normal(ks[3], (E, F, L))
+    if widths == WIDE:
+        w_in, w_out = w_in / 3, w_out / 3
     return x, logits, w_in, w_out
 
 
@@ -31,6 +39,7 @@ def _dense(x, logits, w_in, w_out, first, held, normalize=True):
     if normalize:
         top = top / jnp.sum(top, -1, keepdims=True)
     out = jnp.zeros_like(x)
+    F = w_out.shape[1]
     for e in range(first, first + held):
         w = jnp.sum(jnp.where(chosen == e, top, 0.0), -1)
         h = x @ w_in[e]
@@ -108,99 +117,229 @@ def test_gradients_through_the_walk_are_the_dense_loops():
 @pytest.mark.parametrize("form", [(False, 8), (False, 64), (True, 32), None])
 def test_dropless_when_every_token_picks_the_same_experts(form, monkeypatch):
     """Every token the same scores: all T tokens pick the same K experts,
-    and a share that holds them computes T x K slots, none dropped, the
-    fullest held expert ``held / K`` times the mean; by slots and by
-    blocks."""
+    and a share that holds them computes T x K slots (the worst case the
+    shapes allow), none dropped, the fullest held expert ``held / K`` times
+    the mean; by slots, and by the grouped form at widths it tiles."""
+    grouped = bool(form and form[0])
     if form:
         monkeypatch.setattr(dropless, "walk_form", lambda *_: form)
-    x, _, w_in, w_out = _layer(2)
+    x, _, w_in, w_out = _layer(2, widths=WIDE if grouped else (L, F))
     logits = jnp.tile(jnp.arange(E, dtype=jnp.float32)[None], (T, 1))
     out, counters, is_chosen = _walk(x, logits, w_in, w_out, E - 4, 4)
     assert int(counters["slots"]) == int(counters["done"]) == T * K
+    assert ("computed" in counters) == grouped
     np.testing.assert_array_equal(np.asarray(counters["counts"]),
                                   [0, T, T, T])
     told = dropless.load_counters([counters])
     assert float(told["moe_slots_dropped"]) == 0.0
     assert float(told["moe_load_max_over_mean"]) == pytest.approx(4 / 3)
+    assert ("moe_rows_computed" in told) == grouped
     np.testing.assert_allclose(
         out, _dense(x, logits, w_in, w_out, E - 4, 4), rtol=2e-5, atol=2e-5)
 
 
 def test_the_walks_form_follows_the_share_even_routing_chooses():
     # the hybrid cell: top-22 of 512 chooses 4.3 % of the pairs: an expert's
-    # slots, 256 a chunk, as before
+    # slots, 256 a chunk, as before, whatever the widths
     assert dropless.walk_form(16384, 22, 512) == (False, 256)
-    # Mellum's: top-8 of 64 chooses an eighth: blocks of 2048 tokens
-    assert dropless.walk_form(16384, 8, 64) == (True, 2048)
-    assert dropless.walk_form(32768, 8, 64) == (True, 2048)
-    assert dropless.walk_form(3072, 8, 64) == (True, 1024)
-    # blocks divide the tokens; a few tokens are one block
-    assert dropless.walk_form(1536, 8, 64) == (True, 512)
-    assert dropless.walk_form(80, 3, 16) == (True, 80)
-    # tokens no block divides walk slots
-    assert dropless.walk_form(8191, 8, 64) == (False, 256)
-    assert dropless.walk_form(80, 1, 16) == (False, 80)
+    assert dropless.walk_form(16384, 22, 512, (1024, 2688, 2688)) == (
+        False, 256)
+    # Mellum's: top-8 of 64 chooses an eighth: the sorted slots through the
+    # grouped matmul, at its own widths and where none are given
+    rows = dropless.ROWS_PER_GROUPED_CHUNK
+    assert dropless.walk_form(32768, 8, 64, (2304, 1792, 896)) == (True, rows)
+    assert dropless.walk_form(16384, 8, 64) == (True, rows)
+    assert dropless.walk_form(8191, 8, 64) == (True, rows)
+    # no more rows a chunk than the slots there can be, in whole tiles
+    assert dropless.walk_form(80, 3, 16, WIDE + (128,)) == (True, 256)
+    # a width the kernels cannot tile (the CPU rehearsal's 48) walks slots
+    assert dropless.walk_form(32768, 8, 64, (2304, 96, 48)) == (False, 256)
+    assert dropless.walk_form(80, 3, 16, (24, 40, 20)) == (False, 80)
+    # from a twentieth of the pairs chosen on (the forms cross lower, at
+    # either model's shapes; the threshold is held there)
+    assert dropless.walk_form(32768, 4, 64) == (True, rows)
+    assert dropless.walk_form(80, 1, 20) == (True, 128)
+    assert dropless.walk_form(80, 1, 21) == (False, 80)
+    # two forms and no third
+    assert not hasattr(dropless, "_Blocks")
+    assert not hasattr(dropless, "ROWS_PER_BLOCK")
 
 
-@pytest.mark.parametrize("rows", [16, 32, 96])
-def test_blocks_and_slots_are_the_same_sums(rows):
-    """The two chunk forms of the one walk: outputs, counters and all four
-    gradients agree, for a share and for all the experts."""
-    x, logits, w_in, w_out = _layer(3)
-    g = jax.random.normal(jax.random.PRNGKey(5), (T, L))
-    chosen, weights = dropless.softmax_topk(logits, K)
-    held_w, is_chosen = dropless.held_weights(chosen, weights, 4, 8)
+def _through(x, held_w, is_chosen, w_in, w_out, g, rows, grouped):
+    """Outputs, counters and all four gradients of one form of the walk."""
+    def loss(x, held_w, w_in, w_out):
+        out, counters = dropless.routed_experts(
+            x, held_w, is_chosen, w_in, w_out, dropless.gated_silu, rows,
+            grouped, K)
+        return jnp.sum(out * g), (out, counters)
+    (_, (out, counters)), grads = jax.value_and_grad(
+        loss, argnums=(0, 1, 2, 3), has_aux=True)(x, held_w, w_in, w_out)
+    return out, counters, grads
 
-    def run(blocks):
-        def loss(x, held_w, w_in, w_out):
-            out, counters = dropless.routed_experts(
-                x, held_w, is_chosen, w_in[4:12], w_out[4:12],
-                dropless.gated_silu, rows, blocks)
-            return jnp.sum(out * g), (out, counters)
-        (_, (out, counters)), grads = jax.value_and_grad(
-            loss, argnums=(0, 1, 2, 3), has_aux=True)(x, held_w, w_in, w_out)
-        return out, counters, grads
 
-    out_b, counted_b, grads_b = run(True)
-    out_s, counted_s, grads_s = run(False)
-    np.testing.assert_allclose(out_b, out_s, rtol=2e-5, atol=2e-5)
+def _same(got, want, is_chosen):
+    out_g, counted_g, grads_g = got
+    out_s, counted_s, grads_s = want
+    np.testing.assert_allclose(out_g, out_s, rtol=2e-5, atol=2e-5)
     for name in ("slots", "done", "counts"):
-        np.testing.assert_array_equal(counted_b[name], counted_s[name])
-    for a, b, name in zip(grads_b, grads_s, ("x", "held_w", "w_in", "w_out")):
+        np.testing.assert_array_equal(counted_g[name], counted_s[name])
+    for a, b, name in zip(grads_g, grads_s, ("x", "held_w", "w_in", "w_out")):
         if name == "held_w":
-            # by blocks a pair nobody chose has the derivative its zero
-            # weight would have; ``held_weights`` drops it (a ``where``)
+            # a pair nobody chose has no slot: its weight's gradient is
+            # exactly zero, in both forms
+            assert float(jnp.max(jnp.abs(a[~is_chosen]))) == 0.0
             assert float(jnp.max(jnp.abs(b[~is_chosen]))) == 0.0
-            a = jnp.where(is_chosen, a, 0.0)
         scale = max(float(jnp.max(jnp.abs(b))), 1e-8)
         np.testing.assert_allclose(np.asarray(a) / scale,
                                    np.asarray(b) / scale, rtol=0, atol=3e-5,
                                    err_msg=name)
 
 
-@pytest.mark.parametrize("form", [dropless._Slots, dropless._Blocks])
-def test_done_is_what_the_walks_chunks_counted(form, monkeypatch):
-    """``done`` is added up chunk by chunk inside the walk, by slots and by
-    blocks: a walk cut one chunk short reports the slots it left out, so
+@pytest.mark.parametrize("rows", [16, 32, 96])
+def test_blocks_and_slots_are_the_same_sums(rows):
+    """The two forms of the walk (the name is from when the second was by
+    blocks of tokens; it is the grouped matmul over the sorted slots):
+    outputs, counters and all four gradients agree, for a share and for all
+    the experts, with counts that are no multiples of a tile."""
+    x, logits, w_in, w_out = _layer(3, widths=WIDE)
+    g = jax.random.normal(jax.random.PRNGKey(5), x.shape)
+    chosen, weights = dropless.softmax_topk(logits, K)
+    for first, held in ((4, 8), (0, E)):
+        held_w, is_chosen = dropless.held_weights(chosen, weights, first,
+                                                  held)
+        assert any(int(n) % dropless._tile(rows) for n in is_chosen.sum(0))
+        args = (x, held_w, is_chosen, w_in[first:first + held],
+                w_out[first:first + held], g, rows)
+        _same(_through(*args, True), _through(*args, False), is_chosen)
+
+
+@pytest.mark.parametrize("form", ["slots", "grouped"])
+def test_done_is_what_the_walks_chunks_counted(form, monkeypatch, request):
+    """``done`` is added up chunk by chunk inside the walk, in both forms:
+    a walk cut one chunk short reports the slots it left out, so
     ``moe_slots_dropped`` = 0 says that every chunk ran."""
-    x, logits, w_in, w_out = _layer(4)
+    x, logits, w_in, w_out = _layer(4, widths=WIDE)
     chosen, weights = dropless.softmax_topk(logits, K)
     held_w, is_chosen = dropless.held_weights(chosen, weights, 4, 8)
-    blocks = form is dropless._Blocks
+    grouped = form == "grouped"
 
     def counters():
         return dropless.routed_experts(x, held_w, is_chosen, w_in[4:12],
                                        w_out[4:12], dropless.gated_silu, 32,
-                                       blocks)[1]
+                                       grouped, K)[1]
 
     whole = counters()
     assert int(whole["slots"]) == int(whole["done"]) > 0
-    chunks = form.chunks
-    monkeypatch.setattr(form, "chunks", staticmethod(
-        lambda *a: chunks(*a) - 1))
+    if grouped:
+        chunks = dropless._grouped_chunks
+        monkeypatch.setattr(dropless, "_grouped_chunks",
+                            lambda *a: chunks(*a) - 1)
+        # the grouped walk is jitted: neither the whole walk's trace may
+        # serve the short one, nor the short one's a later test
+        jax.clear_caches()
+        request.addfinalizer(jax.clear_caches)
+    else:
+        chunks = dropless._Slots.chunks
+        monkeypatch.setattr(dropless._Slots, "chunks", staticmethod(
+            lambda *a: chunks(*a) - 1))
     short = counters()
     assert int(short["slots"]) == int(whole["slots"])
-    # the last chunk is the last held expert's last tokens
-    left_out = (int(jnp.sum(is_chosen[-32:, -1])) if blocks
-                else (int(whole["counts"][-1]) - 1) % 32 + 1)
-    assert int(short["slots"]) - int(short["done"]) == left_out > 0
+    # the last chunk is the last sorted slots: the last held expert's last
+    # tokens by slots, the last of them all by the grouped form
+    last = int(whole["slots"]) if grouped else int(whole["counts"][-1])
+    assert int(short["slots"]) - int(short["done"]) == (last - 1) % 32 + 1
+
+
+@pytest.mark.parametrize("per_token", [3, 8])
+def test_the_grouped_plan_counts_the_tokens_places_by_load(per_token):
+    """The plan's one table by load: a token's place by falling number of
+    slots is counted (tokens of one number keep their own order: a stable
+    sort's places, without the sort), its sorted positions are brought into
+    that order, and a token's positions are its chosen pairs' places among
+    the sorted pairs."""
+    _, logits, _, _ = _layer(7)
+    chosen, weights = dropless.softmax_topk(logits, K)
+    _, is_chosen = dropless.held_weights(chosen, weights, 4, 8)
+    plan = dropless._grouped_plan(is_chosen, 32, per_token)
+    slots = np.asarray(is_chosen).sum(1)
+    order = np.argsort(-slots, kind="stable")
+    np.testing.assert_array_equal(np.asarray(plan.rank)[order],
+                                  np.arange(T))
+    np.testing.assert_array_equal(plan.by_load, np.asarray(plan.pos)[order])
+    # slot_plan's pairs are ``expert * T + token``, sorted
+    pairs = np.asarray(plan.pairs)
+    for t in (0, 17, T - 1):
+        mine = sorted(np.flatnonzero(pairs % T == t)[:slots[t]])
+        np.testing.assert_array_equal(
+            np.asarray(plan.pos)[t, :slots[t]],
+            [p for p in mine if pairs[p] < 8 * T])
+    assert int(jnp.sum(plan.counts)) == slots.sum()
+
+
+@pytest.mark.parametrize("case", ["nobody", "everybody"])
+def test_the_grouped_form_when_no_token_or_every_token_picks_an_expert(case):
+    """No held expert chosen by any token: no chunk, no kernel call, zeros
+    and zero gradients.  One held expert chosen by every token and no
+    other: one group, the others' matrices get no gradient."""
+    x, _, w_in, w_out = _layer(6, widths=WIDE)
+    g = jax.random.normal(jax.random.PRNGKey(7), x.shape)
+    is_chosen = jnp.zeros((T, 4), bool)
+    if case == "everybody":
+        is_chosen = is_chosen.at[:, 2].set(True)
+    held_w = jnp.where(is_chosen, 0.25 + jax.random.uniform(
+        jax.random.PRNGKey(8), (T, 4)), 0.0)
+    args = (x, held_w, is_chosen, w_in[:4], w_out[:4], g, 32)
+    got = _through(*args, True)
+    _same(got, _through(*args, False), is_chosen)
+    out, counters, grads = got
+    if case == "nobody":
+        assert int(counters["slots"]) == int(counters["computed"]) == 0
+        assert float(jnp.max(jnp.abs(out))) == 0.0
+        assert all(float(jnp.max(jnp.abs(d))) == 0.0 for d in grads)
+    else:
+        assert int(counters["slots"]) == int(counters["computed"]) == T
+        assert float(jnp.max(jnp.abs(grads[2][jnp.array([0, 1, 3])]))) == 0.0
+        assert float(jnp.max(jnp.abs(grads[2][2]))) > 0.0
+
+
+def _rows_the_kernels_multiply(counts, rows, tile):
+    """The padding's arithmetic: in every chunk of ``rows`` sorted slots a
+    held expert with rows there is visited once for each tile its rows
+    touch."""
+    ends = np.cumsum(counts)
+    visits = 0
+    for lo in range(0, int(ends[-1]), rows):
+        for start, end in zip(ends - counts, ends):
+            a, b = max(start, lo) - lo, min(end, lo + rows) - lo
+            if b > a:
+                visits += (b - 1) // tile - a // tile + 1
+    return visits * tile
+
+
+def test_rows_computed_over_slots_held_is_the_paddings_arithmetic(
+        monkeypatch):
+    """``moe_rows_computed`` counts whole tiles, a straddled tile once an
+    expert: by the arithmetic above at an uneven load, and within 15 % of
+    the slots at an even one where an expert's slots are many tiles."""
+    monkeypatch.setattr(pallas_gmm, "TILE_ROWS", 16)
+    tokens, held = 1024, 4
+    x = jax.random.normal(jax.random.PRNGKey(0), (tokens, WIDE[0]))
+    _, _, w_in, w_out = _layer(5, widths=WIDE)
+    rng = np.random.default_rng(0)
+    for share, most in (((0.5,) * held, 1.15), ((0.03, 0.6, 0.0, 0.21), 2.0)):
+        chosen = np.stack([rng.random(tokens) < p for p in share], axis=1)
+        is_chosen = jnp.asarray(chosen)
+        held_w = jnp.where(is_chosen, 0.5, 0.0)
+        out, counters = dropless.routed_experts(
+            x, held_w, is_chosen, w_in[:held], w_out[:held],
+            dropless.gated_silu, 256, True)
+        told = dropless.load_counters([counters])
+        assert float(told["moe_slots_dropped"]) == 0.0
+        assert float(told["moe_rows_computed"]) == _rows_the_kernels_multiply(
+            chosen.sum(0), 256, 16)
+        assert 1.0 <= float(told["moe_rows_computed"]) / float(
+            told["moe_slots_held"]) <= most
+        want, _ = dropless.routed_experts(
+            x, held_w, is_chosen, w_in[:held], w_out[:held],
+            dropless.gated_silu, 256)
+        np.testing.assert_allclose(out, want, rtol=2e-5, atol=2e-5)

@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from deeperspeed_tpu.ops import pallas_ssd, pallas_utils, ssm
+from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
 from deeperspeed_tpu.ops.attention import paged, pallas_flash
 from deeperspeed_tpu.ops.quantizer import fused as qfused
@@ -32,7 +32,8 @@ from deeperspeed_tpu.ops.transformer import normalize
 from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
-_BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd)
+_BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
+            pallas_gmm)
 
 
 @pytest.fixture(scope="module")
@@ -335,7 +336,10 @@ def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
     forward and one backward, the full layer's a ``flash_attention`` pair;
     the remat wrap keeps the kernels' residuals (nothing recomputed); and
     the chip's compiler takes the dropless walk with softmax scoring and
-    gated experts."""
+    gated experts in its grouped form: a layer's two grouped matmuls
+    forward, and backward those two again (a chunk's rows recomputed), the
+    two transposed ones and the two outer products; the recomputed layer's
+    forward walk is dead code (the walk keeps only its inputs)."""
     from deeperspeed_tpu.models.mellum import Mellum, MellumConfig
 
     model = Mellum(MellumConfig.tiny(
@@ -351,6 +355,44 @@ def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
                                                     backward=3)
     assert passes["flash_attention"] == dict(forward=1, recomputed=0,
                                              backward=1)
+    assert passes["grouped_matmul"] == dict(forward=4 * 2, recomputed=0,
+                                            backward=4 * 6)
+    # the sorted slots' buffer of each pass is handed over unwritten
+    assert passes["unwritten"] == dict(forward=4, recomputed=0, backward=4)
+
+
+@pytest.mark.parametrize("tokens,latent,inner,held,gated", [
+    (32768, 2304, 896, 16, True),    # train-mellum2-ep4-8k's layer
+    (16384, 2304, 896, 16, True),    # the same at micro-batch 2
+])
+def test_grouped_walk_fwd_bwd(one_chip, tokens, latent, inner, held, gated):
+    """The routed walk's grouped form at a cell's shapes, forward and
+    backward: two grouped matmuls into a buffer handed over unwritten, then
+    those again, two transposed and two outer products into float32
+    accumulators, every block inside the limit the calls state."""
+    from deeperspeed_tpu.moe import dropless
+
+    bf16 = jnp.bfloat16
+    activation = dropless.gated_silu if gated else dropless.relu2
+
+    def fn(x, held_w, w_in, w_out, is_chosen):
+        return dropless.routed_experts(
+            x, held_w, is_chosen, w_in, w_out, activation,
+            dropless.ROWS_PER_GROUPED_CHUNK, True, min(8, held))[0]
+
+    shapes = (_sds((tokens, latent), bf16, one_chip),
+              _sds((tokens, held), jnp.float32, one_chip),
+              _sds((held, latent, inner * (2 if gated else 1)), bf16,
+                   one_chip),
+              _sds((held, inner, latent), bf16, one_chip),
+              _sds((tokens, held), jnp.bool_, one_chip))
+    assert len(_kernel_operand_shapes(_compile(fn, *shapes))) == 3
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+    passes = count_kernel_passes(_compile(
+        jax.grad(lambda *a: jnp.sum(fn(*a)), argnums=(0, 1, 2, 3)), *shapes))
+    assert passes["grouped_matmul"] == dict(forward=0, recomputed=0,
+                                            backward=6)
+    assert passes["unwritten"] == dict(forward=0, recomputed=0, backward=1)
 
 
 def test_flash_mha_non_causal(one_chip):
