@@ -189,6 +189,23 @@ def phase_train(model, params):
     return not problems
 
 
+def last_step_phases():
+    """The newest step record's host phases (``telemetry.step_timeline()``)
+    beside its counters: ``{span: [wall ms, count]}`` and the step's own wall,
+    process-CPU and thread-CPU ms: an operator's one-line look at where a
+    live step's host time went, with no profiler."""
+    from deeperspeed_tpu import telemetry
+
+    last = telemetry.step_timeline()[-1]
+    return {"step": last["step"],
+            "wall_ms": round(1e3 * (last["t1"] - last["t0"]), 3),
+            "cpu_ms": round(1e3 * (last["cpu1"] - last["cpu0"]), 3),
+            "thread_cpu_ms": round(
+                1e3 * (last["thread_cpu1"] - last["thread_cpu0"]), 3),
+            **{name: [round(1e3 * wall, 3), n]
+               for name, (wall, n) in last["phases"].items()}}
+
+
 def step_kernel_passes(engine, batch):
     """``telemetry.count_kernel_passes`` of the engine's step program: its
     text lowered from the engine's own step function (the same program, so
@@ -227,6 +244,7 @@ def phase_hybrid():
     batch = model.example_batch(batch_size=1, seq_len=SEQ, seed=SEED)
     losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
     told = telemetry.step_counters().get("train_step", {})
+    phases = last_step_phases()
     problems = []
     if not all(math.isfinite(x) for x in losses):
         problems.append("non-finite loss")
@@ -245,7 +263,7 @@ def phase_hybrid():
     if passes != dict(forward=1, recomputed=1, backward=1):
         problems.append(f"the scan's kernel passes under remat: {passes}")
     emit("hybrid", ok=not problems, problems=problems, counters=told,
-         kernel_paths=paths, kernel_passes=passes,
+         step_phases=phases, kernel_paths=paths, kernel_passes=passes,
          model="nemotron_3_super share, pattern EM*", seq=SEQ,
          params=model.num_params(), losses=[round(x, 4) for x in losses],
          peak_bytes_in_use=peak_bytes(), **device_facts())
@@ -285,6 +303,7 @@ def phase_windowed():
     batch = model.example_batch(batch_size=1, seq_len=SEQ, seed=SEED)
     losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
     told = telemetry.step_counters().get("train_step", {})
+    phases = last_step_phases()
     problems = []
     if not all(math.isfinite(x) for x in losses):
         problems.append("non-finite loss")
@@ -320,6 +339,7 @@ def phase_windowed():
     if got != want:
         problems.append(f"the kernels' passes under remat: {got}")
     emit("windowed", ok=not problems, problems=problems, counters=told,
+         step_phases=phases,
          kernel_paths={"flash_attention_window": paths,
                        "grouped_matmul": walks}, kernel_passes=got,
          model="mellum2_12b share, one period, 16 experts", seq=SEQ,

@@ -1332,18 +1332,21 @@ class DeeperSpeedEngine:
         # after a profiler session that covered a step: publish the scopes
         # of the step program about to run, for whoever reads that trace
         publish = self._trace_watch.ended()
-        with step_span("train/step", self.global_steps):
-            return self._train_step_phases(data, capture, publish)
+        with step_span("train/step", self.global_steps, "train_step",
+                       profiled=self._trace_watch.profiled) as step:
+            return self._train_step_phases(step, data, capture, publish)
 
-    def _run_step(self, dispatch, publish, fn, *args):
+    def _run_step(self, step, dispatch, publish, fn, *args):
         """Call a step program inside its ``train/dispatch`` span, which
-        gets ``compiled=1`` if the call compiled anything."""
+        gets ``compiled=1`` if the call compiled anything, as the step's
+        record does."""
         if publish:
             self._publish_scopes(fn, *args)
         compiled = compile_stats().programs
         out = fn(*args)
         if compile_stats().programs != compiled:
             dispatch.set(compiled=1)
+            step.record["compiled"] = True
         return out
 
     def _publish_scopes(self, fn, *args):
@@ -1364,10 +1367,9 @@ class DeeperSpeedEngine:
                     f"{time.perf_counter() - t0:.3f}s "
                     f"({len(step_scopes()[name])} instructions)")
 
-    def _train_step_phases(self, data, capture, publish):
+    def _train_step_phases(self, step, data, capture, publish):
         """The step itself, phase by phase (``dst:train/<phase>``)."""
         lowered = None
-        t_start = time.perf_counter()
 
         self.tput_timer.start()
         self.timers(TRAIN_BATCH_TIMER).start()
@@ -1392,8 +1394,8 @@ class DeeperSpeedEngine:
                         grads_fn, self.state["master_params"], stacked, rng,
                         step_arr)
                 grads, loss_dev, norm = self._run_step(
-                    dispatch, publish, grads_fn, self.state["master_params"],
-                    stacked, rng, step_arr)
+                    step, dispatch, publish, grads_fn,
+                    self.state["master_params"], stacked, rng, step_arr)
             with span("train/readback"):
                 # one batched fetch: device_get overlaps the per-leaf D2H
                 # copies instead of serializing blocking np.asarray calls
@@ -1428,7 +1430,8 @@ class DeeperSpeedEngine:
                     lowered = self._lower_for_cost(grads_fn, sub_state,
                                                    stacked, rng)
                 grads, loss_mean, master_dev = self._run_step(
-                    dispatch, publish, grads_fn, sub_state, stacked, rng)
+                    step, dispatch, publish, grads_fn, sub_state, stacked,
+                    rng)
             with span("train/swap_in"):
                 self._ensure_opt_resident()
             with span("train/dispatch") as dispatch:
@@ -1436,7 +1439,7 @@ class DeeperSpeedEngine:
                     self._apply_batch_fn = self._make_apply(
                         divisor=1, device_master=True)
                 new_state, metrics = self._run_step(
-                    dispatch, publish, self._apply_batch_fn, self.state,
+                    step, dispatch, publish, self._apply_batch_fn, self.state,
                     grads, master_dev)
             metrics = {**metrics, "loss": loss_mean}
         else:
@@ -1450,7 +1453,8 @@ class DeeperSpeedEngine:
                     lowered = self._lower_for_cost(step_fn, self.state,
                                                    stacked, rng)
                 new_state, metrics = self._run_step(
-                    dispatch, publish, step_fn, self.state, stacked, rng)
+                    step, dispatch, publish, step_fn, self.state, stacked,
+                    rng)
         poisoned = False
         if self._sentinel is not None:
             with span("train/readback"):
@@ -1472,7 +1476,7 @@ class DeeperSpeedEngine:
                 self._spill_opt()
         self.timers(TRAIN_BATCH_TIMER).stop()
         self.tput_timer.stop(global_step=True)
-        step_time = time.perf_counter() - t_start
+        step_time = step.elapsed()
 
         if capture:
             self._comm_footprint = dist.comms_logger.end_trace_capture()
@@ -1492,7 +1496,8 @@ class DeeperSpeedEngine:
             self.global_samples += self.train_batch_size()
         self._last_metrics = metrics
         if "model" in metrics:
-            # kept as device arrays: whoever asks ``step_counters()`` waits
+            # into this step's record, as the device arrays they are: whoever
+            # asks ``step_counters()`` or ``step_timeline(read=True)`` waits
             publish_step_counters("train_step", metrics["model"])
         if self.precision.is_fp16 and not rolled_back:
             with span("train/readback"):

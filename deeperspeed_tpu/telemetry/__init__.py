@@ -20,8 +20,11 @@ Four pieces (see README "Observability"):
   :class:`TraceContext`), per-request SLO accounting, Chrome-trace export,
   and the :class:`FlightRecorder` postmortem ring; :func:`span`, the one
   primitive every live span goes through (a ``dst:`` event on the
-  ``jax.profiler`` timeline, and a ring record when the tracer is on),
-  :func:`compile_stats`, :func:`step_scopes`, :func:`step_counters`,
+  ``jax.profiler`` timeline, and a ring record when the tracer is on), and
+  what is kept whether or not a tracer or a profiler is on:
+  :func:`compile_stats`, :func:`step_scopes`, :func:`step_timeline` (one
+  record a train step: its clocks, its host phases by wall time, and the
+  model's counters), :func:`step_counters` (the newest record's),
   :func:`kernel_paths` and :func:`kernel_passes`;
 * :mod:`aggregate` -- mergeable registry snapshots + the pool-side
   :class:`MetricsAggregator` (counters sum, histograms merge bucket-wise,
@@ -43,7 +46,8 @@ from .slo import SLOAlert, SLOBurnEvaluator
 from .trace import (FlightRecorder, Span, TraceContext, Tracer, compile_stats,
                     count_kernel_passes, get_tracer, kernel_passes,
                     kernel_paths, set_tracer, slo_percentiles, span,
-                    step_counters, step_scopes, tracer_from_config)
+                    step_counters, step_scopes, step_timeline,
+                    tracer_from_config)
 from .watchdog import StallWatchdog
 from .wire import plain_wire_bytes, q_bytes, quantized_variant, wire_bytes
 from . import serving  # noqa: F401  (typed serving-resilience events)
@@ -54,7 +58,8 @@ __all__ = [
     "get_registry", "set_registry", "registry_from_config",
     "Tracer", "TraceContext", "Span", "FlightRecorder", "get_tracer",
     "set_tracer", "tracer_from_config", "slo_percentiles", "span",
-    "compile_stats", "step_scopes", "step_counters", "kernel_paths",
+    "compile_stats", "step_scopes", "step_counters", "step_timeline",
+    "kernel_paths",
     "kernel_passes", "count_kernel_passes",
     "StallWatchdog", "step_cost", "compiled_cost",
     "utilization", "device_peaks", "TPU_PEAK_SPECS", "wire_bytes", "q_bytes",

@@ -36,6 +36,14 @@ profiler session, and a ring record second, when the tracer is enabled.
 The module also keeps the two things only the program can tell a trace
 reader: what was compiled (:func:`compile_stats`) and which scope each
 instruction of a step program was traced under (:func:`step_scopes`).
+
+And one record a train step, always on, profiler or not
+(:func:`step_timeline`): :func:`step_span` opens it and reads the process's
+and the thread's CPU clocks at both ends, every span closed inside the step
+on the step's thread adds its wall time to it, and the model's counters of
+that step go with it (:func:`step_counters` is the
+newest record's).  Its ``step`` is the ``step_num`` the step's annotation
+carries, so a device trace joins it.
 """
 
 import json
@@ -56,7 +64,15 @@ from .registry import JsonlSink, _is_rank0, get_registry
 #: every program span is ``dst:<layer>/<phase>`` on the profiler's timeline
 #: (a harness's own are ``bench:<span>``)
 SPAN_PREFIX = "dst:"
-_THREAD = threading.local()
+
+
+class _ThreadState(threading.local):
+    #: the step record this thread has open (``step_span``); a class default,
+    #: so that a thread outside any step reads it at an attribute's price
+    step = None
+
+
+_THREAD = _ThreadState()
 
 
 def _open_spans():
@@ -114,9 +130,19 @@ class _SpanScope:
     ``dst:<name>`` on the profiler's clock and, when its tracer is enabled,
     the same interval as a ring :class:`Span` (same name, attributes and
     parent).  With no ``parent_id`` given the ring span nests under the
-    innermost span this thread has open."""
+    innermost span this thread has open.
 
-    __slots__ = ("_tracer", "name", "attrs", "_ids", "_annotation", "span")
+    Inside a step (``step_span`` open on this thread) the interval's wall
+    time is also added to the step's record under the span's own name: a
+    span nested in another (``train/prefetch`` in ``train/input``) is kept
+    under its name and lies inside the outer one's interval too.  A span on
+    another thread lands in no record.  No CPU clock is read here: on the
+    chip machine's host such a read is a system call of 6-18 us and the
+    clock ticks every 10 ms, so a phase of a few ms cannot be told from
+    its neighbours (PERF.md section 6, PR 42); the step reads them, twice."""
+
+    __slots__ = ("_tracer", "name", "attrs", "_ids", "_annotation", "span",
+                 "_step", "_entered")
 
     def __init__(self, tracer, name, trace_id, parent_id, attrs):
         self._tracer = tracer
@@ -129,6 +155,9 @@ class _SpanScope:
         self._annotation = jax.profiler.TraceAnnotation(
             SPAN_PREFIX + self.name, **self.attrs)
         self._annotation.__enter__()
+        self._step = _THREAD.step
+        if self._step is not None:
+            self._entered = time.perf_counter()
         if self._tracer.enabled:
             trace_id, parent_id = self._ids
             stack = _open_spans()
@@ -156,6 +185,14 @@ class _SpanScope:
             self.span.attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb):
+        if self._step is not None:
+            wall = time.perf_counter() - self._entered
+            phase = self._step["phases"].get(self.name)
+            if phase is None:
+                self._step["phases"][self.name] = [wall, 1]
+            else:
+                phase[0] += wall
+                phase[1] += 1
         if self.span is not None:
             if exc_type is not None:
                 self.span.attrs["error"] = exc_type.__name__
@@ -641,27 +678,141 @@ def publish_step_scopes(hlo_text):
     return name
 
 
-_STEP_COUNTERS = {}
+# ----------------------------------------------------------- step timeline
+class _StepTimeline:
+    """The last ``KEEP`` step records, oldest first, and the newest of each
+    program.  One writer a program (its train loop's thread) and no lock: a
+    ``deque.append`` and a ``dict`` store are each atomic."""
+
+    KEEP = 1024
+
+    def __init__(self):
+        self.records = deque(maxlen=self.KEEP)
+        self.newest = {}
+
+    def push(self, record):
+        self.records.append(record)
+        self.newest[record["program"]] = record
+
+    def clear(self):
+        self.records.clear()
+        self.newest.clear()
+
+
+_STEP_TIMELINE = _StepTimeline()
+#: the one store under the name it had while it held the last step's counters
+#: alone (tests empty it by this name)
+_STEP_COUNTERS = _STEP_TIMELINE
+
+
+def _step_record(program, step=None, profiled=None):
+    return {"step": step, "program": program, "t0": None, "t1": None,
+            "cpu0": None, "cpu1": None, "thread_cpu0": None,
+            "thread_cpu1": None, "phases": {}, "compiled": False,
+            "profiled": profiled, "counters": {}}
+
+
+class _StepScope:
+    """One live step (``step_span``): the profiler's step annotation and the
+    step's record, open on this thread until the step closes."""
+
+    __slots__ = ("record", "_annotation", "_outer")
+
+    def __init__(self, name, step_num, program, profiled):
+        self._annotation = jax.profiler.StepTraceAnnotation(
+            SPAN_PREFIX + name, step_num=step_num)
+        self.record = _step_record(program, step_num, profiled)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        self._outer = _THREAD.step
+        _THREAD.step = record = self.record
+        record["cpu0"] = time.process_time()
+        record["thread_cpu0"] = time.thread_time()
+        record["t0"] = time.perf_counter()
+        return self
+
+    def elapsed(self):
+        """Seconds since the step opened, on the record's clock."""
+        return time.perf_counter() - self.record["t0"]
+
+    def __exit__(self, exc_type, exc, tb):
+        record = self.record
+        record["t1"] = time.perf_counter()
+        record["thread_cpu1"] = time.thread_time()
+        record["cpu1"] = time.process_time()
+        _THREAD.step = self._outer
+        _STEP_TIMELINE.push(record)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+def _read_counters(records):
+    """Copies of ``records`` with their counters as numbers: one batched
+    fetch, which waits for the newest of those steps."""
+    told = jax.device_get([r["counters"] for r in records])
+    return [dict(r, counters={k: np.asarray(v).tolist()
+                              for k, v in counters.items()})
+            for r, counters in zip(records, told)]
+
+
+def step_timeline(read=False, steps=None):
+    """The records of the last 1,024 steps (``_StepTimeline.KEEP``), oldest
+    first, kept whether or not a profiler or the tracer is on; ``steps``
+    picks those whose ``step`` is among them.  A record:
+
+    * ``step``: the ``step_num`` of the step's ``dst:train/step`` annotation
+      (what joins it to a device trace), and ``program``, the step program's
+      name (``train_step``);
+    * ``t0``, ``t1``: ``time.perf_counter()`` where the step opened and
+      closed; ``cpu0``, ``cpu1``: ``time.process_time()`` there (every thread
+      of the process); ``thread_cpu0``, ``thread_cpu1``: the step's own
+      thread (``time.thread_time()``);
+    * ``phases``: ``{span name: [wall s, count]}`` of the spans closed inside
+      the step on its thread (``train/input``, ``train/dispatch``,
+      ``train/fence`` ...);
+    * ``compiled``: a dispatch of the step compiled something; ``profiled``:
+      a profiler session was on when the step began;
+    * ``counters``: what the step's model counted (``{name: device array}``).
+
+    Nothing is read from the device unless ``read`` is true: then the
+    counters come as numbers, after a wait for the newest step asked for.
+    The records are copies; the counters' arrays are the step's own."""
+    records = list(_STEP_TIMELINE.records)
+    if steps is not None:
+        wanted = set(steps)
+        records = [r for r in records if r["step"] in wanted]
+    return _read_counters(records) if read else [dict(r) for r in records]
 
 
 def publish_step_counters(program, counters):
-    """Keep what a step program's model reported of its own last step
+    """Keep what a step program's model reported of its own step
     (``{name: device array}``: a looped model's layer and head applications,
-    its exits' shares) under the program's name.  Nothing is read here: the
-    arrays stay on the device until :func:`step_counters` is asked."""
-    _STEP_COUNTERS[program] = counters
+    its exits' shares) in the step's record; called outside a step, in a
+    record of its own.  Nothing is read here: the arrays stay on the device
+    until :func:`step_counters` or :func:`step_timeline` is asked."""
+    record = _THREAD.step
+    if record is not None and record["program"] == program:
+        record["counters"] = counters
+        # the open record is the program's newest from here on
+        _STEP_TIMELINE.newest[program] = record
+    else:
+        record = _step_record(program)
+        record["counters"] = counters
+        _STEP_TIMELINE.push(record)
 
 
 def step_counters(read=True):
-    """``{program name: {counter: number or list}}`` of the last step each
-    program ran: the counters a model returns beside its loss (``(loss,
-    {name: value})``), averaged over the step's microbatches.  Reading waits
-    for that step; ``read=False`` gives the device arrays as they are and
-    waits for nothing (a caller that keeps every step's and reads them
-    after the last)."""
-    return {program: {name: np.asarray(value).tolist() if read else value
-                      for name, value in counters.items()}
-            for program, counters in _STEP_COUNTERS.items()}
+    """``{program name: {counter: number or list}}`` of the newest step each
+    program ran, where its model counted anything: the counters a model
+    returns beside its loss (``(loss, {name: value})``), averaged over the
+    step's microbatches.  A view of :func:`step_timeline`'s newest records.
+    Reading waits for that step; ``read=False`` gives the device arrays as
+    they are and waits for nothing."""
+    newest = [r for r in _STEP_TIMELINE.newest.values() if r["counters"]]
+    if read:
+        newest = _read_counters(newest)
+    return {r["program"]: dict(r["counters"]) for r in newest}
 
 
 _KERNEL_PATHS = {}
@@ -791,13 +942,16 @@ class TraceSessionWatch:
     has ended: ``ended()`` is one ``TraceAnnotation.is_enabled()`` a step,
     true once per session, on the first step after it."""
 
-    __slots__ = ("_covered",)
+    __slots__ = ("_covered", "profiled")
 
     def __init__(self):
         self._covered = False
+        #: what ``ended()`` last read: a session is on
+        self.profiled = False
 
     def ended(self):
-        if jax.profiler.TraceAnnotation.is_enabled():
+        self.profiled = jax.profiler.TraceAnnotation.is_enabled()
+        if self.profiled:
             self._covered = True
             return False
         covered, self._covered = self._covered, False
@@ -808,17 +962,22 @@ class TraceSessionWatch:
 def span(name, trace_id=None, parent_id=None, **attrs):
     """The program's one span primitive: ``with span("train/input"): ...``
     is a ``dst:train/input`` event on the profiler's timeline (collected by
-    whoever has a ``jax.profiler`` session open; under a microsecond
-    otherwise) and, when the process tracer is enabled, the same interval
-    in its ring.  Attributes arrive as the event's stats."""
+    whoever has a ``jax.profiler`` session open) and, when the process
+    tracer is enabled, the same interval in its ring; inside a step
+    (:func:`step_span`) its wall time also goes to the step's record.
+    Attributes arrive as the event's stats.  With no session and no tracer
+    an enter and exit cost 1.2-1.3 us outside a step and 1.6-1.7 us inside
+    one on the chip machine's host (PERF.md section 6, PR 42)."""
     return _SpanScope(get_tracer(), name, trace_id, parent_id, attrs)
 
 
-def step_span(name, step_num):
-    """A whole step (``jax.profiler.StepTraceAnnotation``), so that the
-    profiler's own tools group device work by step."""
-    return jax.profiler.StepTraceAnnotation(SPAN_PREFIX + name,
-                                            step_num=step_num)
+def step_span(name, step_num, program, profiled=None):
+    """A whole step of ``program``: a ``jax.profiler.StepTraceAnnotation``,
+    so that the profiler's own tools group device work by step, and the
+    step's record (:func:`step_timeline`), open on the calling thread until
+    the step closes; ``profiled`` is the caller's own reading of whether a
+    profiler session is on."""
+    return _StepScope(name, step_num, program, profiled)
 
 
 # ------------------------------------------------------------- process glue

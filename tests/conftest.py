@@ -56,14 +56,15 @@ def pytest_collection_modifyitems(config, items):
 
 @pytest.fixture(autouse=True)
 def _no_step_counters_left_behind():
-    """What a train step published of itself (``telemetry.step_counters()``)
-    is process-global: a test that trains a model with counters would leave
-    them for whichever test the worker runs next, and a reader's test that
-    starts from "the program published nothing" then fails by the order."""
+    """What a train step kept of itself (``telemetry.step_timeline()``, and
+    ``step_counters()``, its newest record's) is process-global: a test that
+    trains a model with counters would leave them for whichever test the
+    worker runs next, and a reader's test that starts from "the program
+    published nothing" then fails by the order."""
     yield
     from deeperspeed_tpu.telemetry import trace
 
-    trace._STEP_COUNTERS.clear()
+    trace._STEP_TIMELINE.clear()
 
 
 @pytest.fixture

@@ -386,7 +386,7 @@ def test_engine_trains_the_looped_model_and_publishes_its_counters(
         reset_mesh, reduction):
     from deeperspeed_tpu.telemetry import trace
 
-    trace._STEP_COUNTERS.clear()
+    trace._STEP_TIMELINE.clear()
     engine, batch = looped_engine(*REDUCTIONS[reduction])
     assert engine._reduction.name == reduction
     losses = [float(engine.train_batch(batch=batch)) for _ in range(6)]
@@ -409,7 +409,7 @@ def test_engine_trains_the_looped_model_and_publishes_its_counters(
 def test_a_model_without_counters_publishes_none():
     from deeperspeed_tpu.telemetry import trace
 
-    trace._STEP_COUNTERS.clear()
+    trace._STEP_TIMELINE.clear()
     model = GPTNeoX(GPTNeoXConfig.tiny())
     engine, _, _, _ = dst.initialize(model=model, mesh=one_device(), config={
         "train_batch_size": 4, "optimizer": {"type": "Adam", "params": {

@@ -152,6 +152,10 @@ def test_train_batch_emits_its_five_phases_inside_the_step(tmp_path):
     events = session.events()
     steps = [e for e in events if e[0] == "dst:train/step"]
     assert [e[3]["step_num"] for e in steps] == ["3", "4"]
+    # the step's record carries that number, and says a session was on
+    assert [(r["step"], r["profiled"]) for r in telemetry.step_timeline()
+            if r["program"] == "train_step"][-5:] == [
+        (0, False), (1, False), (2, False), (3, True), (4, True)]
     for _name, lo, hi, _stats in steps:
         inside = [e for e in events if lo <= e[1] and e[2] <= hi
                   and e[0] != "dst:train/step"]
