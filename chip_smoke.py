@@ -206,6 +206,20 @@ def last_step_phases():
                for name, (wall, n) in last["phases"].items()}}
 
 
+def head_ce_problem(before):
+    """What is wrong with the head + loss the training step traced since
+    ``before`` (``kernel_paths()["head_ce"]`` then): it has to be the fused
+    form, whose forward walk makes the gradient, and never the per-token
+    one, which recomputes the logits in the backward pass."""
+    from deeperspeed_tpu import telemetry
+
+    traced = {form: n - before.get(form, 0) for form, n in
+              telemetry.kernel_paths().get("head_ce", {}).items()}
+    if not traced.get("fused") or traced.get("per_token"):
+        return [f"the step's head + loss is not the fused form: {traced}"]
+    return []
+
+
 def step_kernel_passes(engine, batch):
     """``telemetry.count_kernel_passes`` of the engine's step program: its
     text lowered from the engine's own step function (the same program, so
@@ -223,7 +237,8 @@ def step_kernel_passes(engine, batch):
 def phase_hybrid():
     """One short step of the hybrid model (``models/nemotron_h.py``) at the
     published widths and a chip's share of every layer: an expert layer, a
-    Mamba-2 layer and an attention layer, 2048 tokens.  Fails unless no
+    Mamba-2 layer and an attention layer, 2048 tokens.  Fails unless the
+    step's head + loss is the fused form (``kernel_paths()["head_ce"]``), no
     routed slot was dropped, every kind of layer counted itself, and the
     Mamba layer's scan is the repo's kernel pair: every traced call on the
     ``pallas`` path, and one forward, one recomputed and one backward kernel
@@ -239,13 +254,14 @@ def phase_hybrid():
         pattern="EM*", mamba_num_heads=32, n_groups=2, num_heads=8,
         num_kv_heads=1, experts_held=8, vocab_size=16384, max_seq_len=SEQ,
         remat=True, dtype=jnp.bfloat16))
+    head_ce = dict(telemetry.kernel_paths().get("head_ce", {}))
     engine, _, _, _ = dst.initialize(model=model,
                                      config=train_config(1, 1, 0))
     batch = model.example_batch(batch_size=1, seq_len=SEQ, seed=SEED)
     losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
     told = telemetry.step_counters().get("train_step", {})
     phases = last_step_phases()
-    problems = []
+    problems = head_ce_problem(head_ce)
     if not all(math.isfinite(x) for x in losses):
         problems.append("non-finite loss")
     if told.get("moe_slots_dropped") != 0:
@@ -277,7 +293,8 @@ def phase_windowed():
     """One short step of Mellum 2 (``models/mellum.py``) at the published
     widths and a chip's share: a period of three sliding-window layers and a
     full one, every MLP 16 of the 64 gated experts, 2048 tokens.  Fails
-    unless every windowed call of the model took the kernel (only the
+    unless the step's head + loss is the fused form, every windowed call of
+    the model took the kernel (only the
     in-place path under ``flash_attention_window``, and in the compiled step
     three forward and three backward kernel calls of that name beside one
     pair of the full kernel's, nothing recomputed: what the layers' pattern
@@ -294,6 +311,7 @@ def phase_windowed():
     from deeperspeed_tpu.ops import pallas_gmm
 
     walked = dict(telemetry.kernel_paths().get("grouped_matmul", {}))
+    head_ce = dict(telemetry.kernel_paths().get("head_ce", {}))
     model = Mellum(MellumConfig.mellum2_12b(
         layers_held=4, first_layer_held=12, routed_experts_held=16,
         vocab_rows_held=24576, max_seq_len=SEQ, remat=True,
@@ -304,7 +322,7 @@ def phase_windowed():
     losses = [float(engine.train_batch(batch=batch)) for _ in range(2)]
     told = telemetry.step_counters().get("train_step", {})
     phases = last_step_phases()
-    problems = []
+    problems = head_ce_problem(head_ce)
     if not all(math.isfinite(x) for x in losses):
         problems.append("non-finite loss")
     if told.get("moe_slots_dropped") != 0:

@@ -23,7 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import dot_product_attention
 from ..ops.attention.pallas_flash import SAVED_BY_REMAT
-from ..ops.transformer.cross_entropy import chunked_linear_cross_entropy
+from ..ops.transformer.cross_entropy import mean_linear_cross_entropy
 from ..parallel.topology import BATCH_AXES
 
 
@@ -636,29 +636,18 @@ class GPTNeoX(nn.Module):
             is HBM-bound at bench shapes (XLA cost analysis: 75 GB
             accessed vs 12 TFLOPs -- PROFILE.md round 5), and the single
             largest tensor is the [B, S, V] logits + fp32 cast.  Head + CE
-            run chunk by chunk (``ops/transformer/cross_entropy.py``), each
-            chunk folded into the running (sum, count)."""
+            run chunk by chunk (``ops/transformer/cross_entropy.py``), the
+            tokens weighted ``-mask / count`` so that the walk makes the
+            head's gradient too."""
             deterministic, rngs, kwargs = _apply_setup(
                 batch, rng, deterministic, random_ltd_tokens)
             hidden = model.apply({"params": params}, batch["input_ids"],
                                  deterministic=deterministic, rngs=rngs,
                                  return_hidden=True, **kwargs)
-            w = params["embed_out"]["kernel"]          # [H, V]
-            B, S, H = hidden.shape
-            mask = batch.get("loss_mask")
-            mask = (jnp.ones((B * S,), jnp.float32) if mask is None
-                    else mask.reshape(-1).astype(jnp.float32))
-
-            def fold(carry, token_ll, mc):
-                num, den = carry
-                return num + jnp.sum(token_ll * mc), den + jnp.sum(mc)
-
             with jax.named_scope("head_ce"):
-                num, den = chunked_linear_cross_entropy(
-                    hidden.reshape(B * S, H), w, batch["labels"].reshape(-1),
-                    cfg.ce_chunk_tokens, extras=(mask,), fold=fold,
-                    init=(jnp.float32(0.0), jnp.float32(0.0)))
-                return -num / jnp.maximum(den, 1.0)
+                return mean_linear_cross_entropy(
+                    hidden, params["embed_out"]["kernel"], batch["labels"],
+                    batch.get("loss_mask"), cfg.ce_chunk_tokens)
 
         if cfg.ce_chunk_tokens > 0:
             if cfg.has_moe:

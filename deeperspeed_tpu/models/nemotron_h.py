@@ -53,7 +53,8 @@ from ..moe import dropless
 from ..ops.attention.core import dot_product_attention
 from ..ops.attention.pallas_flash import SAVED_BY_REMAT
 from ..ops.ssm import causal_depthwise_conv1d, gated_group_rms_norm, ssd_scan
-from ..ops.transformer.cross_entropy import chunked_linear_cross_entropy
+from ..ops.transformer.cross_entropy import (chunked_linear_cross_entropy,
+                                             mean_linear_cross_entropy)
 from ..ops.transformer.normalize import rms_norm
 from ..parallel.topology import BATCH_AXES
 from .gpt_neox import maybe_constrain
@@ -339,18 +340,12 @@ class NemotronH(nn.Module):
                                   self.config.vocab_size)
         return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}
 
-    def logprobs(self, params, input_ids, labels):
-        """The training path's forward -> (log-probability of ``labels``
-        [B, S] float32, which held experts each token chose in each E layer
-        [layers, B, S, held], counters of what ran on the device)."""
+    def _hidden(self, params, input_ids):
+        """The stack to the final norm -> (hidden [B, S, H], which held
+        experts each token chose in each E layer [layers, B, S, held],
+        counters of what ran on the device)."""
         cfg = self.config
         hidden, told = self.apply({"params": params}, input_ids)
-        # the head's gradient adds up over the chunks: in float32
-        head = params["lm_head_kernel"].astype(jnp.float32)
-        with jax.named_scope("head_ce"):
-            token_ll = chunked_linear_cross_entropy(
-                hidden.reshape(-1, cfg.hidden_size), head,
-                labels.reshape(-1), cfg.ce_chunk_tokens)
         counters = {
             "ssm_layer_applications": jnp.int32(cfg.layers("M")),
             "attention_layer_applications": jnp.int32(cfg.layers("*")),
@@ -360,20 +355,33 @@ class NemotronH(nn.Module):
                 [t["counters"] for t in told]))
             chosen = jnp.stack([t["chosen"] for t in told])
         else:
-            chosen = jnp.zeros((0,) + labels.shape + (cfg.experts_held,), bool)
+            chosen = jnp.zeros((0,) + input_ids.shape + (cfg.experts_held,),
+                               bool)
+        return hidden, chosen, counters
+
+    def logprobs(self, params, input_ids, labels):
+        """The training path's forward, for a check that wants every token's
+        value -> (log-probability of ``labels`` [B, S] float32, the chosen
+        held experts and the counters of ``_hidden``)."""
+        cfg = self.config
+        hidden, chosen, counters = self._hidden(params, input_ids)
+        with jax.named_scope("head_ce"):
+            token_ll = chunked_linear_cross_entropy(
+                hidden.reshape(-1, cfg.hidden_size), params["lm_head_kernel"],
+                labels.reshape(-1), cfg.ce_chunk_tokens)
         return token_ll.reshape(labels.shape), chosen, counters
 
     def loss_fn(self):
         """Mean next-token cross entropy -> (loss, the step's counters:
         layer applications by kind and the expert layers' load)."""
+        cfg = self.config
 
         def loss(params, batch, rng=None, **_):
-            token_ll, _, counters = self.logprobs(params, batch["input_ids"],
-                                                  batch["labels"])
+            hidden, _, counters = self._hidden(params, batch["input_ids"])
             with jax.named_scope("head_ce"):
-                mask = batch.get("loss_mask", jnp.ones_like(token_ll))
-                ce = -jnp.sum(token_ll * mask) / jnp.maximum(jnp.sum(mask),
-                                                              1.0)
+                ce = mean_linear_cross_entropy(
+                    hidden, params["lm_head_kernel"], batch["labels"],
+                    batch.get("loss_mask"), cfg.ce_chunk_tokens)
             return ce, jax.lax.stop_gradient(counters)
 
         return loss

@@ -32,7 +32,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.transformer.cross_entropy import chunked_linear_cross_entropy
+from ..ops.transformer.cross_entropy import (chunked_linear_cross_entropy,
+                                             weighted_linear_cross_entropy)
 from ..parallel.topology import BATCH_AXES
 from .gpt_neox import maybe_constrain
 from .llama import LlamaAttention, LlamaConfig, LlamaMLP, _Norm
@@ -198,16 +199,12 @@ class Ouro(nn.Module):
                                   self.config.vocab_size)
         return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}
 
-    def exits(self, params, input_ids, labels):
-        """The training path's forward: the stack traced ONCE and run T
-        times on shared parameters (a scan over passes), then every exit
-        through one chunked head + cross entropy.  -> (log-probability of
-        ``labels`` at every exit [T, B, S] float32, the exit distribution
-        [T, B, S] float32, counters of what ran on the device)."""
-        cfg = self.config
+    def _passes(self, params, input_ids):
+        """The stack traced ONCE and run T times on shared parameters (a
+        scan over passes) -> (every pass's closing norm's output
+        [T, B, S, H], the layer applications the device counted)."""
         variables = {"params": params}
         B, S = input_ids.shape
-        T = cfg.total_ut_steps
         positions = jnp.broadcast_to(jnp.arange(S), (B, S))
 
         def one_pass(carry, _):
@@ -217,15 +214,25 @@ class Ouro(nn.Module):
 
         h0 = self.apply(variables, input_ids, method="embed")
         (_, layers), hs = jax.lax.scan(one_pass, (h0, jnp.int32(0)), None,
-                                       length=T)
+                                       length=self.config.total_ut_steps)
+        return hs, layers
 
-        # the head's gradient adds up over chunks and exits: in float32
-        head = params["lm_head"]["kernel"].astype(jnp.float32)
+    def exits(self, params, input_ids, labels):
+        """The training path's forward, for a check that wants every token's
+        value at every exit: the passes, then every exit through one chunked
+        head + cross entropy.  -> (log-probability of ``labels`` at every
+        exit [T, B, S] float32, the exit distribution [T, B, S] float32,
+        counters of what ran on the device)."""
+        cfg = self.config
+        B, S = input_ids.shape
+        T = cfg.total_ut_steps
+        hs, layers = self._passes(params, input_ids)
         flat_labels = labels.reshape(-1)
 
         def one_exit(heads, h):
             return heads + 1, chunked_linear_cross_entropy(
-                h, head, flat_labels, cfg.ce_chunk_tokens)
+                h, params["lm_head"]["kernel"], flat_labels,
+                cfg.ce_chunk_tokens)
 
         with jax.named_scope("head_ce"):
             heads, token_ll = jax.lax.scan(one_exit, jnp.int32(0),
@@ -238,21 +245,37 @@ class Ouro(nn.Module):
     def loss_fn(self):
         """``mean_i [ sum_t p^t_i CE^t_i - beta H(p_i) ]`` -> (loss, what the
         step reports of itself: the counters, the batch mean of each exit's
-        share and of the entropy)."""
-        beta = self.config.exit_entropy_beta
+        share and of the entropy).  The exits' weights ``-p * mask / count``
+        are made first, so that all T x B x S exit-tokens go through ONE walk
+        of the head that makes its gradient as it goes (a walk an exit under
+        a scan would stack T float32 head gradients)."""
+        cfg = self.config
+        beta = cfg.exit_entropy_beta
 
         def loss(params, batch, rng=None, **_):
-            token_ll, p, stats = self.exits(params, batch["input_ids"],
-                                            batch["labels"])
-            with jax.named_scope("head_ce"), jax.named_scope("exit_gate"):
-                entropy = exit_entropy(p)
-                per_token = jnp.sum(p * -token_ll, axis=0) - beta * entropy
-                mask = batch.get("loss_mask", jnp.ones_like(per_token))
-                count = jnp.maximum(jnp.sum(mask), 1.0)
-                stats = jax.lax.stop_gradient(dict(
-                    stats, exit_share=jnp.sum(p * mask, axis=(1, 2)) / count,
-                    exit_entropy=jnp.sum(entropy * mask) / count))
-                return jnp.sum(per_token * mask) / count, stats
+            labels = batch["labels"]
+            T, tokens = cfg.total_ut_steps, labels.size
+            hs, layers = self._passes(params, batch["input_ids"])
+            with jax.named_scope("head_ce"):
+                with jax.named_scope("exit_gate"):
+                    p = exit_distribution(params["exit_gate"], hs)
+                    entropy = exit_entropy(p)
+                    mask = batch.get("loss_mask", jnp.ones_like(entropy))
+                    count = jnp.maximum(jnp.sum(mask), 1.0)
+                    weights = -p * mask / count
+                    mean_entropy = jnp.sum(entropy * mask) / count
+                    share = jnp.sum(p * mask, axis=(1, 2)) / count
+                chunk = min(cfg.ce_chunk_tokens, tokens)
+                ce, chunks = weighted_linear_cross_entropy(
+                    hs.reshape(T * tokens, -1), params["lm_head"]["kernel"],
+                    jnp.tile(labels.reshape(-1), T), weights.reshape(-1),
+                    chunk)
+                stats = jax.lax.stop_gradient({
+                    "layer_applications": layers,
+                    # the rows the walk covered over the rows an exit has
+                    "head_applications": chunks * chunk // tokens,
+                    "exit_share": share, "exit_entropy": mean_entropy})
+                return ce - beta * mean_entropy, stats
 
         return loss
 

@@ -1,9 +1,9 @@
 """The looped model (``models/ouro.py``) on the CPU at small sizes: against
 the plain reference in float32, what weight sharing means for the count of
 parameters and for the gradient, the exit distribution and the loss's parts,
-the lifted chunked cross entropy (``GPTNeoX``'s losses bit for bit what they
-were), the scopes and counters the step program publishes, and the scopes
-added to ``models/llama.py``, which change no program.
+the lifted chunked cross entropy (``GPTNeoX``'s losses what they were), the
+scopes and counters the step program publishes, and the scopes added to
+``models/llama.py``, which change no program.
 """
 
 import contextlib
@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 import deeperspeed_tpu as dst
 from benchmarks import core, program_trace
@@ -23,7 +24,7 @@ from deeperspeed_tpu.models.llama import Llama, LlamaConfig
 from deeperspeed_tpu.models.ouro import (Ouro, OuroBlock, OuroConfig,
                                          exit_distribution, exit_entropy)
 from deeperspeed_tpu.ops.transformer.cross_entropy import (
-    chunked_linear_cross_entropy)
+    chunked_linear_cross_entropy, weighted_linear_cross_entropy)
 from deeperspeed_tpu.parallel.topology import MeshTopology
 
 #: the tiny preset as a configuration file would state it
@@ -264,6 +265,95 @@ def test_chunked_cross_entropy_gives_the_unchunked_per_token_values(tokens,
         xb, w, labels, chunk)))(wb.astype(jnp.float32)).dtype == jnp.float32
 
 
+# ------------------------------- the weighted sum that makes its gradient
+def _plain_weighted_sum(x, w, labels, weights):
+    """The unchunked float32 expression, for autodiff to differentiate."""
+    logits = x.astype(jnp.float32) @ w.astype(jnp.float32)
+    return jnp.sum(weights * (
+        jnp.take_along_axis(logits, labels[:, None], -1)[:, 0]
+        - jax.nn.logsumexp(logits, -1)))
+
+
+def _head_operands(tokens, dtype, hidden=32, vocab=100):
+    rng = np.random.default_rng(tokens)
+    return (jnp.asarray(rng.normal(size=(tokens, hidden)), dtype),
+            jnp.asarray(rng.normal(size=(hidden, vocab)) / 4, dtype),
+            jnp.asarray(rng.integers(0, vocab, size=tokens), jnp.int32),
+            jnp.asarray(rng.normal(size=tokens), jnp.float32))
+
+
+#: float32 differs from autodiff in the order of its sums; bfloat16 rounds
+#: the logits and their cotangent to 8 bits where the plain expression, in
+#: float32 on the same bfloat16 inputs, rounds nothing
+TOLERANCE = {"float32": 1e-5, "bfloat16": 1e-2}
+
+
+@pytest.mark.parametrize("dtype", list(TOLERANCE))
+@pytest.mark.parametrize("tokens,chunk", [(40, 16), (48, 48), (30, 64)],
+                         ids=["tail_chunk", "one_chunk", "chunk_over_tokens"])
+def test_weighted_sum_and_its_gradients_are_autodiffs_of_the_plain_one(
+        tokens, chunk, dtype):
+    x, w, labels, weights = _head_operands(tokens, getattr(jnp, dtype))
+    tol = TOLERANCE[dtype]
+
+    def fused(x, w, weights):
+        total, chunks = weighted_linear_cross_entropy(x, w, labels, weights,
+                                                      chunk)
+        return 3.0 * total, chunks      # a cotangent that is not 1
+
+    # no gradient asked: the primal walk, which counts its chunks too
+    total, chunks = fused(x, w, weights)
+    assert total.dtype == jnp.float32 and int(chunks) == -(-tokens // chunk)
+    (got, chunks), grads = jax.jit(jax.value_and_grad(
+        fused, argnums=(0, 1, 2), has_aux=True))(x, w, weights)
+    assert int(chunks) == -(-tokens // chunk)
+    want, want_grads = jax.value_and_grad(
+        lambda *a: 3.0 * _plain_weighted_sum(a[0], a[1], labels, a[2]),
+        argnums=(0, 1, 2))(x, w, weights)
+    assert abs(float(got) - float(total)) <= 1e-6 * abs(float(total))
+    assert abs(float(got) - float(want)) <= tol * abs(float(want))
+    for a, b, like in zip(grads, want_grads, (x, w, weights)):
+        assert a.dtype == like.dtype and a.shape == like.shape
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b)
+
+
+def test_weighted_sum_ignores_a_token_of_weight_zero_and_pads_with_them():
+    x, w, labels, weights = _head_operands(30, jnp.float32)
+    weights = weights.at[7].set(0.0)
+    grad = jax.grad(lambda x: weighted_linear_cross_entropy(
+        x, w, labels, weights, 16)[0])(x)
+    assert np.all(np.asarray(grad[7]) == 0.0) and np.all(
+        np.abs(np.asarray(grad)).sum(-1)[np.arange(30) != 7] > 0)
+    x7 = x.at[7].set(100.0)
+    assert float(weighted_linear_cross_entropy(x7, w, labels, weights, 16)[0]
+                 ) == float(weighted_linear_cross_entropy(
+                     x, w, labels, weights, 16)[0])
+
+
+def test_weighted_sum_with_the_vocabulary_sharded_over_tp():
+    """The head as the models place it, ``P(None, "tp")``: plain ``jnp``
+    inside the scan, so the partitioner divides the walk; the same numbers
+    as on one device."""
+    from jax.sharding import Mesh, NamedSharding
+
+    x, w, labels, weights = _head_operands(40, jnp.float32, vocab=128)
+
+    def fused(x, w, weights):
+        return weighted_linear_cross_entropy(x, w, labels, weights, 16)[0]
+
+    want, want_grads = jax.value_and_grad(fused, argnums=(0, 1, 2))(
+        x, w, weights)
+    mesh = Mesh(np.array(jax.devices()[:2]), ("tp",))
+    w_tp = jax.device_put(w, NamedSharding(mesh, P(None, "tp")))
+    got, grads = jax.jit(jax.value_and_grad(fused, argnums=(0, 1, 2)))(
+        x, w_tp, weights)
+    assert grads[1].sharding.spec == P(None, "tp")
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-6)
+    for a, b in zip(grads, want_grads):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
 def _loss_chunked_as_it_was(model, cfg):
     """``GPTNeoX.loss_fn``'s chunked closure as PR 28 left it, kept here
     word for word as the yardstick of the lift."""
@@ -331,7 +421,12 @@ LOSSES_AS_THEY_WERE = {
 
 
 @pytest.mark.parametrize("dtype,chunk,masked", sorted(LOSSES_AS_THEY_WERE))
-def test_gpt_neox_losses_are_bit_for_bit_what_they_were(dtype, chunk, masked):
+def test_gpt_neox_losses_are_what_they_were(dtype, chunk, masked):
+    """The monolithic loss bit for bit.  The chunked one weights the tokens
+    ``-mask / count`` before the walk (PR 44) where PR 28 divided the sum
+    after it, and adds the head's gradient up in float32 where PR 28 rounded
+    a chunk's to the head's dtype first: the same loss to a float32 rounding,
+    the same gradient to the dtype's."""
     cfg = GPTNeoXConfig.tiny(ce_chunk_tokens=chunk, dtype=getattr(jnp, dtype))
     model = GPTNeoX(cfg)
     batch = model.example_batch(batch_size=3, seq_len=20, seed=3)
@@ -340,14 +435,19 @@ def test_gpt_neox_losses_are_bit_for_bit_what_they_were(dtype, chunk, masked):
             jnp.float32)
     params = model.init(jax.random.PRNGKey(1), batch["input_ids"])["params"]
     loss, grads = jax.jit(jax.value_and_grad(model.loss_fn()))(params, batch)
-    assert float(loss) == LOSSES_AS_THEY_WERE[dtype, chunk, masked]
-    if chunk:
-        was, was_grads = jax.jit(jax.value_and_grad(
-            _loss_chunked_as_it_was(model, cfg)))(params, batch)
-        assert np.array_equal(np.asarray(loss), np.asarray(was))
-        for a, b in zip(jax.tree_util.tree_leaves(grads),
-                        jax.tree_util.tree_leaves(was_grads)):
-            assert np.array_equal(np.asarray(a), np.asarray(b))
+    want = LOSSES_AS_THEY_WERE[dtype, chunk, masked]
+    if not chunk:
+        assert float(loss) == want
+        return
+    assert abs(float(loss) - want) <= 2e-7 * want
+    was, was_grads = jax.jit(jax.value_and_grad(
+        _loss_chunked_as_it_was(model, cfg)))(params, batch)
+    assert float(was) == want
+    tol = 1e-5 if dtype == "float32" else 1e-2
+    for (name, a), b in zip(jax.tree_util.tree_leaves_with_path(grads),
+                            jax.tree_util.tree_leaves(was_grads)):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        assert np.linalg.norm(a - b) <= tol * np.linalg.norm(b), name
 
 
 # -------------------------------------------- through the engine: tracing
