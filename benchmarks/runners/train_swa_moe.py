@@ -3,7 +3,11 @@ a softmax-routed mixture of gated experts (Mellum 2: one chip's share of the
 experts and of the vocabulary, one period of its layers): ``dst.initialize``
 -> ``engine.train_batch`` on a fresh seeded batch every step, under the
 learning-rate schedule the traffic file gives, exactly as the other train
-cells run.
+cells run.  Where the traffic file names a ``world`` (``traffic_gen``), the
+weights and the ids of every run are that world's and the run's seed only
+renames them: the step's time follows what the weights and ids route to the
+experts held, and a cell has to tell one run from another by something else
+than that draw.  ``calibrate`` reads a world of its own for every seed.
 
 What is model-free comes from ``runners/train.py`` (the engine's JSON
 config, the mesh, the comparison of a first step's gradient and update) and
@@ -26,6 +30,7 @@ The CPU rehearsal's limits are in ``limits/rehearsal-mellum.json`` (never
 ``python3 benchmarks/runners/train_swa_moe.py``.
 """
 
+import copy
 import os
 import sys
 
@@ -214,6 +219,21 @@ def plain_first_step(cfg, traffic, params, grads, master_dtype="float32"):
             "grad_norm": float(norm)}
 
 
+#: the tables that move with the ids under a world (``move_tables``)
+TABLE_ROWS, TABLE_COLUMNS = [("embed_tokens", "embedding")], [("lm_head_kernel",)]
+
+
+def seeded_params(cfg, batches):
+    """The float32 weights a run starts from, for the program and for the
+    reference alike: the seed's, or under a world the world's with both
+    tables moved to the run's names for the ids."""
+    params = ref.init_params(cfg, batches.world_seed)
+    if batches.order is None:
+        return params
+    return traffic_gen.move_tables(params, batches.inverse, TABLE_ROWS,
+                                   TABLE_COLUMNS)
+
+
 def start_engine(ctx, seed):
     """Seeded weights -> the engine, after its first step on the seed's
     first batch.  -> (engine, batches, first loss, what the step left)."""
@@ -221,7 +241,7 @@ def start_engine(ctx, seed):
 
     cfg, traffic = ctx.config, ctx.traffic
     batches = traffic_gen.TokenBatches(traffic, vocab(cfg), seed)
-    params = ref.init_params(cfg, seed)
+    params = seeded_params(cfg, batches)
     engine, _, _, _ = dst.initialize(
         model=program_model(cfg, traffic), model_parameters=params,
         mesh=train.cell_mesh(ctx), config=engine_config(traffic, seed))
@@ -261,9 +281,10 @@ def against_reference(ctx, seed, first_loss, left, controls=False):
     import jax.numpy as jnp
 
     cfg, traffic = ctx.config, ctx.traffic
-    first = traffic_gen.TokenBatches(traffic, vocab(cfg), seed).batch(0)
+    batches = traffic_gen.TokenBatches(traffic, vocab(cfg), seed)
+    first = batches.batch(0)
     ids, labels = jnp.asarray(first["input_ids"]), jnp.asarray(first["labels"])
-    params = ref.init_params(cfg, seed)
+    params = seeded_params(cfg, batches)
     model = program_model(cfg, traffic)
     prog_lp, prog_chosen, _ = jax.jit(model.logprobs)(
         hybrid.cast_for_compute(model, params, traffic), ids[:1], labels[:1])
@@ -320,14 +341,19 @@ def against_reference(ctx, seed, first_loss, left, controls=False):
 def calibrate(ctx, seeds, control_seeds=3):
     """Readings for the limits, many seeds in one process: the program's
     first step, and on the first ``control_seeds`` seeds the controls,
-    against the plain reference.  One JSON line per seed -> the readings."""
+    against the plain reference; under a world every seed is its own, so
+    the limits stand on as many sets of weights as there are seeds.  One
+    JSON line per seed -> the readings."""
     readings = []
     for n, seed in enumerate(seeds):
-        engine, _, first_loss, left = start_engine(ctx, seed)
+        # every seed a world of its own, under its own renaming
+        own = copy.copy(ctx)
+        own.traffic = traffic_gen.own_world(ctx.traffic, seed)
+        engine, _, first_loss, left = start_engine(own, seed)
         del engine
         live = train.free_device()
         readings.append(dict(seed=seed, **against_reference(
-            ctx, seed, first_loss, left, controls=n < control_seeds)))
+            own, seed, first_loss, left, controls=n < control_seeds)))
         ctx.log("calibrate", live_bytes_after_engine=live, **readings[-1])
     return readings
 
