@@ -163,21 +163,32 @@ def _dense(width, cfg, name):
 
 class MellumAttention(nn.Module):
     """Grouped-query causal attention of one layer kind: the window, and the
-    kind's rotary tables, are the kind's."""
+    kind's rotary tables, are the kind's.  What a sibling model's layers
+    have beside (``models/laguna.py``) is told per layer and absent here:
+    ``heads`` / ``kv_heads`` where they go by layer and not by the
+    configuration's one count, ``rotary_dim`` where only the first dims of a
+    head turn, and ``gated``, a sigmoid gate a head (``sigmoid(u W_g)``, ``W_g``
+    [H, heads]) on the attention output before the output projection."""
 
-    config: MellumConfig
+    config: Any
     kind: str = FULL
+    heads: Optional[int] = None
+    kv_heads: Optional[int] = None
+    rotary_dim: Optional[int] = None
+    gated: bool = False
 
     @nn.compact
     def __call__(self, u):
         cfg = self.config
         B, S, _ = u.shape
-        nq, kv, d = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
+        nq, kv, d = (self.heads or cfg.num_heads,
+                     self.kv_heads or cfg.num_kv_heads, cfg.head_dim)
         q = _dense(nq * d, cfg, "q_proj")(u).reshape(B, S, nq, d)
         k = _dense(kv * d, cfg, "k_proj")(u).reshape(B, S, kv, d)
         v = _dense(kv * d, cfg, "v_proj")(u).reshape(B, S, kv, d)
         rope = cfg.rope_full if self.kind == FULL else cfg.rope_sliding
-        cos, sin = rope.tables(jnp.arange(S)[None], d, cfg.dtype)
+        cos, sin = rope.tables(jnp.arange(S)[None], self.rotary_dim or d,
+                               cfg.dtype)
         q, k = apply_rotary_pos_emb(q, k, cos, sin)
         if kv != nq:
             with jax.named_scope("attention_layout"):  # GQA's copy of k, v
@@ -185,6 +196,11 @@ class MellumAttention(nn.Module):
         out = dot_product_attention(
             q, k, v, causal=True,
             window=cfg.sliding_window if self.kind == SLIDING else None)
+        if self.gated:
+            with jax.named_scope("attention_gate"):
+                gate = jax.nn.sigmoid(
+                    _dense(nq, cfg, "g_proj")(u).astype(jnp.float32))
+                out = out * gate[..., None].astype(out.dtype)
         with jax.named_scope("attention_layout"):
             out = out.reshape(B, S, nq * d)
         return _dense(cfg.hidden_size, cfg, "o_proj")(out)
@@ -195,7 +211,8 @@ class MellumMoE(nn.Module):
     [B, S, H], the walk's counters, which held experts each token chose
     [B, S, held])."""
 
-    config: MellumConfig
+    config: Any
+    scale: float = 1.0      # of the routed weights (a sibling model's)
 
     @nn.compact
     def __call__(self, u):
@@ -217,8 +234,8 @@ class MellumMoE(nn.Module):
         out, counters, is_chosen = dropless.dropless_moe(
             tokens, logits, gate_up, down, k=cfg.num_experts_per_tok,
             first_expert=cfg.first_expert_held, experts_held=held,
-            normalize=cfg.norm_topk_prob, scoring=dropless.softmax_topk,
-            activation=dropless.gated_silu)
+            normalize=cfg.norm_topk_prob, scale=self.scale,
+            scoring=dropless.softmax_topk, activation=dropless.gated_silu)
         return (out.reshape(B, S, H), counters,
                 is_chosen.reshape(B, S, held))
 
@@ -226,6 +243,9 @@ class MellumMoE(nn.Module):
 class MellumBlock(nn.Module):
     """``h = x + Attn(RMSNorm(x))``, ``y = h + MoE(RMSNorm(h))`` -> (y, what
     the routed walk counted and chose)."""
+
+    #: what a layer of ``MellumConfig.kinds`` may be
+    KINDS = frozenset(SCOPE_OF)
 
     config: MellumConfig
     kind: str = FULL
@@ -254,24 +274,29 @@ class Mellum(nn.Module):
     """Causal LM: tokens [B, S] -> (the closing norm's output [B, S, H],
     each layer's counters and chosen-here mask)."""
 
+    #: the class of a layer, made with (configuration, an entry of the
+    #: configuration's ``kinds``); a sibling model names its own
+    block_cls = MellumBlock
+
     config: MellumConfig
 
     @nn.compact
     def __call__(self, input_ids, **_):
         cfg = self.config
-        if set(cfg.kinds) - set(SCOPE_OF):
-            raise ValueError(f"layer_types {cfg.kinds!r}: {sorted(SCOPE_OF)}")
+        if set(cfg.kinds) - self.block_cls.KINDS:
+            raise ValueError(f"layer_types {cfg.kinds!r}: "
+                             f"{sorted(self.block_cls.KINDS)}")
         with jax.named_scope("embed"):
             x = nn.Embed(cfg.vocab_rows, cfg.hidden_size, dtype=cfg.dtype,
                          embedding_init=nn.initializers.normal(0.02),
                          name="embed_tokens")(input_ids)
-        block = MellumBlock
+        block = self.block_cls
         if cfg.remat:
             # a recomputed layer keeps the flash kernel's own two residuals
             # (windowed calls name theirs alike), as the dense models' do,
             # and the grouped walk's plan: its sorts are made once a step
             block = nn.remat(
-                MellumBlock,
+                block,
                 policy=jax.checkpoint_policies.save_only_these_names(
                     *SAVED_BY_REMAT, dropless.PLAN_SAVED_BY_REMAT))
         told = []

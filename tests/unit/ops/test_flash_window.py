@@ -76,6 +76,35 @@ def test_window_by_layout(N, D):
                  lambda *a: _masked_plain(*a, 160), q, k, v, 3e-5, (N, D))
 
 
+@pytest.mark.parametrize("two_pass", [False, True],
+                         ids=["one_kernel_bwd", "two_pass_bwd"])
+@pytest.mark.parametrize("heads", [24, 36], ids=["groups_of_6", "groups_of_9"])
+def test_window_512_on_gqa_copies_of_four_kv_heads(heads, two_pass):
+    """Laguna's calls (``models/laguna.py``): 24 | 36 query heads of 128 on
+    GQA's copy of 4 KV heads under a window of 512, a band of two chunks of
+    the walk here; outputs and gradients through the copy, against plain
+    attention under the mask, with both backward forms."""
+    S, D, kv, window = 1024, 128, 4, 512
+    plan = tile_plan(S, D, jnp.float32, block=256, N=heads, window=window)
+    assert plan.window == window and plan.group == 1
+    assert _band(plan.block, window) == (1, [2])
+    plan = plan._replace(resident_bwd=not two_pass)
+
+    def copied(fn):
+        return lambda q, k, v: fn(q, *(jnp.repeat(t, heads // kv, axis=2)
+                                       for t in (k, v)))
+
+    def kernel(q, k, v):
+        o = pallas_flash._mha(*(t.reshape(1, S, heads * D) for t in (q, k, v)),
+                              True, float(D) ** -0.5, plan)
+        return o.reshape(1, S, heads, D)
+
+    q, _, _ = _qkv(S=S, N=heads, D=D)
+    _, k, v = _qkv(S=S, N=kv, D=D, seed=1)
+    _assert_same(copied(kernel), copied(lambda *a: _masked_plain(*a, window)),
+                 q, k, v, 3e-5, f"heads={heads} two_pass={two_pass}")
+
+
 @pytest.mark.parametrize("changes", [
     dict(resident_bwd=False),                       # the two-pass backward
     dict(block=256, sub=128, rows=128, span=256),   # the forward over spans

@@ -107,6 +107,9 @@ def _sum_grad(fn, n_diff):
     # keeps a head's q side in VMEM; fp32 once; a padded length
     ((4, 2048, 8, 96), jnp.bfloat16),
     ((2, 8192, 8, 128), jnp.bfloat16),
+    # train-laguna-s-ep32-8k's full layers: 24 query heads of 128 (GQA
+    # groups of 6 over the 4 KV heads held)
+    ((2, 8192, 24, 128), jnp.bfloat16),
     ((2, 1024, 8, 64), jnp.float32),
     ((2, 1000, 8, 64), jnp.bfloat16),
     # two heads to a lane block over several owner blocks (running
@@ -295,6 +298,9 @@ def test_flash_mha_two_heads_two_pass(one_chip):
     # a padded length and a window that is no multiple of a tile
     ((1, 8192, 8, 128), 4096), ((2, 2048, 16, 64), 256),
     ((2, 1000, 8, 64), 200),
+    # train-laguna-s-ep32-8k's windowed layers: 36 query heads of 128 (GQA
+    # groups of 9 over the 4 KV heads held) under a window of a quarter block
+    ((2, 8192, 36, 128), 512),
 ])
 def test_flash_mha_window_fwd_bwd(one_chip, shape, window):
     """A windowed call is the same two kernel calls on the same operands as

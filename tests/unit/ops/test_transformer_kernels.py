@@ -168,6 +168,48 @@ class TestRope:
                                    rtol=1e-6, atol=1e-6)
 
 
+    @pytest.mark.parametrize("head_dim,rot,yarn", [
+        (128, 64, True),      # Laguna's full layers: half a head under YaRN
+        (128, 128, False),    # its sliding layers: the whole head, plain
+        (64, 16, False)])
+    def test_first_dims_turn_and_the_rest_pass(self, head_dim, rot, yarn):
+        """``apply_rotary_pos_emb`` turns the first ``rot`` dims of a head
+        (``cos.shape[-1]``) and passes the rest through; under YaRN the
+        frequencies are those of the ``rot`` dims that turn (factor 128 over
+        8192 at theta 500,000: c(32) = 9.04, c(1) = 17.49, so the first ten
+        of 32 are plain, those from the nineteenth on divided by 128) and
+        both tables carry the attention factor."""
+        from deeperspeed_tpu.ops.transformer.rope import yarn_inv_freq
+
+        rng = np.random.RandomState(7)
+        q = jnp.asarray(rng.randn(1, 8, 3, head_dim).astype(np.float32))
+        k = jnp.asarray(rng.randn(1, 8, 2, head_dim).astype(np.float32))
+        pos = jnp.arange(8)[None]
+        factor = 1.4852030263919618
+        if yarn:
+            inv = yarn_inv_freq(rot, 500000.0, 128.0, 8192, 32, 1)
+            plain = 500000.0 ** (-2 * np.arange(rot // 2) / rot)
+            np.testing.assert_allclose(inv[:10], plain[:10], rtol=1e-6)
+            np.testing.assert_allclose(inv[18:], plain[18:] / 128, rtol=1e-6)
+            np.testing.assert_allclose(      # i = 12: ramp 3 / 9
+                inv[12], plain[12] * (1 / 3 / 128 + 2 / 3), rtol=1e-6)
+            cos, sin = rotary_tables(pos, rot, 500000.0, inv_freq=inv,
+                                     scale=factor)
+        else:
+            cos, sin = rotary_tables(pos, rot)
+        assert cos.shape == (1, 8, 1, rot)
+        q2, k2 = apply_rotary_pos_emb(q, k, cos, sin)
+        for got, was in ((q2, q), (k2, k)):
+            np.testing.assert_array_equal(np.asarray(got[..., rot:]),
+                                          np.asarray(was[..., rot:]))
+            np.testing.assert_allclose(
+                np.linalg.norm(np.asarray(got[..., :rot]), axis=-1),
+                np.linalg.norm(np.asarray(was[..., :rot]), axis=-1)
+                * (factor if yarn else 1.0), rtol=1e-5)
+            assert float(jnp.min(jnp.abs(got[:, 1:, :, :rot]
+                                         - was[:, 1:, :, :rot]))) > 0
+
+
 class TestTransformerLayer:
     def test_layer_runs_and_differentiates(self):
         from deeperspeed_tpu.ops.transformer.transformer import (
