@@ -29,9 +29,16 @@ a chunk is some of ONE expert's slots, read by index and added to the output
 by XLA's scatter-add: on a v5e that is a pass over the whole ``[tokens,
 width]`` float32 table plus 0.63 us a row (1.55 ms for 1024 rows into [32768,
 2304]), twice a chunk.  Its fixed costs are small, so it is the way of a
-lightly loaded share (a few per cent of the (token, held expert) pairs
-chosen: the hybrid model's top-22 of 512).  The *grouped* form is the way
-of a heavily loaded one (Mellum's top-8 of 64 over 16 held): the sorted
+lightly loaded share of a narrow table (a few per cent of the (token, held
+expert) pairs chosen, rows of 1,024: the hybrid model's top-22 of 512).
+What it pays grows with the table and not with the rows, so the rule
+reckons, besides the pairs' share, the bytes one forward walk by slots would
+pass over under even routing (``slots_walk_bytes``: the held experts' chunks
+times the float32 table), and from ``GROUPED_FROM_TABLE_BYTES`` on the layer
+walks grouped however few the pairs (Laguna's top-10 of 256 chooses 3.9 %,
+and its 24 chunks would pass 4.83 GB of a ``[16384, 3072]`` table).  The
+*grouped* form is the way of a heavily loaded share (Mellum's top-8 of 64
+over 16 held) and of a wide one: the sorted
 slots are walked ``rows`` at a time ACROSS experts, a chunk's rows gathered
 in that order and multiplied by a grouped matmul (``ops/pallas_gmm.py``: a
 row tile by the matrix of the expert it falls in by the counts, a tile that
@@ -110,18 +117,60 @@ ROWS_PER_CHUNK = 256
 #: 40), which keeps the hybrid model's walk what it was.
 ROWS_PER_GROUPED_CHUNK = 8192
 GROUPED_FROM_SHARE = 1 / 20
+#: ... or the bytes one forward walk by slots would pass over under even
+#: routing (``slots_walk_bytes``) from which a layer takes the grouped form
+#: however few the pairs: what the walk by slots pays is the table, a pass
+#: over ``[tokens, width]`` float32 or three a chunk, and the share does not
+#: see the width.  The three models' layers reckon 24 x 67.1 MB = 1.61 GB
+#: (the hybrid: 16,384 x 1,024, top-22 of 512, 8 held), 24 x 201.3 MB = 4.83
+#: GB (Laguna: 16,384 x 3,072, top-10 of 256, 8 held) and 256 x 302.0 MB =
+#: 77.3 GB (Mellum: 32,768 x 2,304, top-8 of 64, 16 held).  Both forms on
+#: one v5e in one call, forward and backward, ms a layer
+#: (`tools/profile_moe_walk.py`; BENCH_KERNELS.md, PR 48).  Laguna's layer
+#: (8 gated experts of 1,024) at 80 / 400 / 640 / 1,200 slots an expert and
+#: at one expert's 3,200 over seven of 300 (a collapsed load, the lightest
+#: world's, even routing, the heaviest drift, a fullest expert five times the
+#: mean): grouped 9.87 / 11.07 / 11.60 / 16.14 / 12.01 in one chunk or two
+#: (the kernels 2.59-6.15 of it), by slots, 256 a chunk, 19.68 / 34.66 /
+#: 49.11 / 77.11 / 55.19: 2.0-2.5 ms a chunk where the hybrid's chunk is
+#: 0.45, and grouped ahead at every load.  The hybrid's layer at 40 / 350 /
+#: 704: grouped 5.02 / 5.68 / 6.27, by slots 4.42 / 7.68 / 10.73, as PR 40
+#: read them.  By slots a layer costs 6.7-10 ms for every GB reckoned here,
+#: grouped 5-6 ms at even routing on a narrow table, so UNDER EVEN ROUTING
+#: the forms cross near 0.8 GB, under all three models; the hybrid's load
+#: collapses to 44 slots an expert within its window, where the walk by
+#: slots is 0.6 ms a layer ahead, which no rule on shapes can see.  The
+#: constant is held between the hybrid's 1.61 GB and Laguna's 4.83 GB (ISSUE
+#: 48), which keeps the hybrid model's walk what it was.
+GROUPED_FROM_TABLE_BYTES = 3e9
 #: The name a ``jax.checkpoint`` policy keeps the grouped walk's plan by
 #: (``save_only_these_names``): a few int32 a token (4.3 MB of Mellum's
 #: layer) in place of the plan's sorts again in the recomputed layer.
 PLAN_SAVED_BY_REMAT = "moe_grouped_plan"
 
 
-def walk_form(tokens, k, num_experts, widths=()):
+def slots_walk_bytes(tokens, k, num_experts, width, experts_held):
+    """The bytes one forward walk by slots would pass over under even
+    routing, from the shapes alone: every chunk (each held expert's
+    ``tokens * k / num_experts`` slots, ``ROWS_PER_CHUNK`` a chunk) is a pass
+    over the float32 ``[tokens, width]`` table."""
+    chunks = experts_held * -(-tokens * k // (num_experts * ROWS_PER_CHUNK))
+    return chunks * tokens * width * 4
+
+
+def walk_form(tokens, k, num_experts, widths=(), experts_held=0):
     """-> (whether the walk is the grouped one, rows a chunk), from the
-    shapes alone: grouped where even routing chooses a twentieth or more of
-    the pairs and the kernels take the ``widths`` (the tokens', the two
-    matrices'); else an expert's slots, ``ROWS_PER_CHUNK`` a chunk."""
-    if k / num_experts >= GROUPED_FROM_SHARE and pallas_gmm.takes(*widths):
+    shapes alone: grouped where the kernels take the ``widths`` (the
+    tokens', the two matrices') and either even routing chooses a twentieth
+    or more of the pairs or the walk by slots would pass over
+    ``GROUPED_FROM_TABLE_BYTES`` (``slots_walk_bytes`` of the tokens' width
+    and the ``experts_held``); else an expert's slots, ``ROWS_PER_CHUNK`` a
+    chunk."""
+    loaded = k / num_experts >= GROUPED_FROM_SHARE
+    wide = bool(widths) and slots_walk_bytes(
+        tokens, k, num_experts, widths[0],
+        experts_held) >= GROUPED_FROM_TABLE_BYTES
+    if (loaded or wide) and pallas_gmm.takes(*widths):
         # no more rows a chunk than the slots there can be, in whole tiles
         most = -(-tokens * k // _ROWS_STEP) * _ROWS_STEP
         return True, min(ROWS_PER_GROUPED_CHUNK, most)
@@ -680,7 +729,7 @@ def dropless_moe(x, logits, w_in, w_out, *, k, first_expert, experts_held,
                                          experts_held)
     grouped, rows = walk_form(
         x.shape[0], k, logits.shape[-1],
-        (x.shape[-1], w_in.shape[-1], w_out.shape[-2]))
+        (x.shape[-1], w_in.shape[-1], w_out.shape[-2]), experts_held)
     out, counters = routed_experts(x, held_w, is_chosen, w_in, w_out,
                                    activation, rows, grouped,
                                    min(k, experts_held))

@@ -6,8 +6,11 @@ five mechanisms left out is another model; the expert shares and the head
 shares of a layer add up to the uncut reference's layer (router, norms,
 shared expert and dense MLP counted once); rotary turns the first half of a
 full layer's head and the whole of a sliding layer's; the cut's arithmetic;
-every attention call of the model goes to the kernel; and the model through
-``dst.initialize`` / ``engine.train_batch`` under a warm-up."""
+every attention call of the model goes to the kernel; a wide layer's routed
+walk takes the grouped matmul; and the model through ``dst.initialize`` /
+``engine.train_batch`` under a warm-up."""
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -380,6 +383,47 @@ def test_every_attention_call_of_the_model_takes_the_kernel(monkeypatch):
         before, "flash_attention_window")
     assert calls(after, "flash_attention") > calls(before, "flash_attention")
     np.testing.assert_allclose(got, plain, rtol=2e-4, atol=2e-4)
+
+
+def test_a_wide_layers_walk_takes_the_grouped_matmul(monkeypatch):
+    """The routed walk's form is chosen by the shapes: this tiny model's
+    expert layers (top-3 of 64 chooses 4.7 % of the pairs, widths the
+    kernels tile) walk by slots until the bytes that walk would pass over
+    reach ``GROUPED_FROM_TABLE_BYTES``, as the cell's ``[16384, 3072]`` layers'
+    do; from there a step counts ``grouped_matmul: pallas`` a sparse layer
+    (the kernels in interpret mode here), the rows the kernels multiplied
+    beside the slots held, no slot dropped, and the same loss."""
+    from deeperspeed_tpu.moe import dropless
+
+    model = Laguna(LagunaConfig.tiny(
+        hidden_size=128, moe_intermediate_size=128, num_experts=64,
+        routed_experts_held=8, first_expert_held=4))
+    batch = model.example_batch(2, 40)
+    params = model.init(jax.random.PRNGKey(3), batch["input_ids"])["params"]
+    # nobody routes here by chance at this size: lean towards the held
+    lean = jnp.zeros(64).at[4:12].set(0.5)
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: leaf + lean if "router_kernel" in
+        jax.tree_util.keystr(path) else leaf, params)
+
+    def step():
+        before = collections.Counter(
+            telemetry.kernel_paths().get("grouped_matmul"))
+        loss, told = model.loss_fn()(params, batch)
+        return float(loss), told, dict(collections.Counter(
+            telemetry.kernel_paths()["grouped_matmul"]) - before)
+
+    by_slots, told_slots, paths = step()
+    assert paths == {"slots": 2} and "moe_rows_computed" not in told_slots
+    monkeypatch.setattr(
+        dropless, "GROUPED_FROM_TABLE_BYTES", dropless.slots_walk_bytes(
+            2 * 40, 3, 64, 128, 8))
+    grouped, told, paths = step()
+    assert paths == {"pallas": 2}
+    assert told["moe_rows_computed"] >= told["moe_slots_held"] > 0
+    assert told["moe_slots_held"] == told_slots["moe_slots_held"]
+    assert told["moe_slots_dropped"] == 0
+    assert grouped == pytest.approx(by_slots, rel=1e-5)
 
 
 # ------------------------------------------------------------ the engine

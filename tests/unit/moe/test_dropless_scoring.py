@@ -4,7 +4,10 @@ gate | up matrix), through the walk against a dense loop, values and
 gradients; dropless when every token picks the same experts; and the two
 forms of the walk (an expert's slots, or the sorted slots through the grouped
 matmul, whose kernels run in interpret mode here at widths they tile), chosen
-from the share of the pairs that even routing would choose."""
+from the share of the pairs that even routing would choose and from the bytes
+the walk by slots would pass over."""
+
+import collections
 
 import jax
 import jax.numpy as jnp
@@ -13,6 +16,7 @@ import pytest
 
 from deeperspeed_tpu.moe import dropless
 from deeperspeed_tpu.ops import pallas_gmm
+from deeperspeed_tpu.telemetry import kernel_paths
 
 T, L, F, E, K = 96, 24, 20, 16, 3
 # widths the grouped matmul's kernels tile (whole blocks of 128 lanes)
@@ -138,29 +142,69 @@ def test_dropless_when_every_token_picks_the_same_experts(form, monkeypatch):
         out, _dense(x, logits, w_in, w_out, E - 4, 4), rtol=2e-5, atol=2e-5)
 
 
-def test_the_walks_form_follows_the_share_even_routing_chooses():
-    # the hybrid cell: top-22 of 512 chooses 4.3 % of the pairs: an expert's
-    # slots, 256 a chunk, as before, whatever the widths
-    assert dropless.walk_form(16384, 22, 512) == (False, 256)
-    assert dropless.walk_form(16384, 22, 512, (1024, 2688, 2688)) == (
-        False, 256)
+_ROWS = dropless.ROWS_PER_GROUPED_CHUNK
+# the three models' layers: (tokens, k, experts, widths, held)
+HYBRID = (16384, 22, 512, (1024, 2688, 2688), 8)
+MELLUM = (32768, 8, 64, (2304, 1792, 896), 16)
+LAGUNA = (16384, 10, 256, (3072, 2048, 1024), 8)
+
+
+@pytest.mark.parametrize("shape,form", [
+    # the hybrid cell: top-22 of 512 chooses 4.3 % of the pairs and its
+    # table is narrow: an expert's slots, 256 a chunk, as before, whatever
+    # the widths and with the experts held told
+    ((16384, 22, 512), (False, 256)),
+    ((16384, 22, 512, (1024, 2688, 2688)), (False, 256)),
+    (HYBRID, (False, 256)),
     # Mellum's: top-8 of 64 chooses an eighth: the sorted slots through the
     # grouped matmul, at its own widths and where none are given
-    rows = dropless.ROWS_PER_GROUPED_CHUNK
-    assert dropless.walk_form(32768, 8, 64, (2304, 1792, 896)) == (True, rows)
-    assert dropless.walk_form(16384, 8, 64) == (True, rows)
-    assert dropless.walk_form(8191, 8, 64) == (True, rows)
+    ((32768, 8, 64, (2304, 1792, 896)), (True, _ROWS)),
+    (MELLUM, (True, _ROWS)),
+    ((16384, 8, 64), (True, _ROWS)),
+    ((8191, 8, 64), (True, _ROWS)),
+    # Laguna's: top-10 of 256 chooses 3.9 %, but by slots every chunk would
+    # pass a [16384, 3072] float32 table: grouped, one chunk a layer's slots;
+    # not so where the share held is not told, or is one expert's
+    (LAGUNA, (True, _ROWS)),
+    (LAGUNA[:4], (False, 256)),
+    (LAGUNA[:4] + (1,), (False, 256)),
     # no more rows a chunk than the slots there can be, in whole tiles
-    assert dropless.walk_form(80, 3, 16, WIDE + (128,)) == (True, 256)
-    # a width the kernels cannot tile (the CPU rehearsal's 48) walks slots
-    assert dropless.walk_form(32768, 8, 64, (2304, 96, 48)) == (False, 256)
-    assert dropless.walk_form(80, 3, 16, (24, 40, 20)) == (False, 80)
+    ((80, 3, 16, WIDE + (128,)), (True, 256)),
+    # a width the kernels cannot tile (the CPU rehearsal's 48) walks slots,
+    # however many bytes that passes over
+    ((32768, 8, 64, (2304, 96, 48)), (False, 256)),
+    ((80, 3, 16, (24, 40, 20)), (False, 80)),
+    ((16384, 10, 256, (3072, 2048, 1000), 8), (False, 256)),
+    (MELLUM[:3] + ((2304, 96, 48), 16), (False, 256)),
     # from a twentieth of the pairs chosen on (the forms cross lower, at
     # either model's shapes; the threshold is held there)
-    assert dropless.walk_form(32768, 4, 64) == (True, rows)
-    assert dropless.walk_form(80, 1, 20) == (True, 128)
-    assert dropless.walk_form(80, 1, 21) == (False, 80)
-    # two forms and no third
+    ((32768, 4, 64), (True, _ROWS)),
+    ((80, 1, 20), (True, 128)),
+    ((80, 1, 21), (False, 80)),
+    # ... or from ``GROUPED_FROM_TABLE_BYTES`` on: Laguna's layer at the
+    # hybrid's width passes the hybrid's bytes and walks by slots; the
+    # hybrid's at Laguna's width walks grouped
+    ((16384, 10, 256, (1024, 2048, 1024), 8), (False, 256)),
+    ((16384, 22, 512, (3072, 2688, 2688), 8), (True, _ROWS)),
+])
+def test_the_walks_form_follows_the_share_even_routing_chooses(shape, form):
+    assert dropless.walk_form(*shape) == form
+
+
+@pytest.mark.parametrize("shape,gigabytes", [
+    (HYBRID, 1.61), (LAGUNA, 4.83), (MELLUM, 77.3)])
+def test_the_bytes_the_walk_by_slots_would_pass_over(shape, gigabytes):
+    """Held x ceil(an expert's slots by even routing / 256) chunks, each a
+    pass over the float32 ``[tokens, width]`` table: 24 x 67.1 MB, 24 x
+    201.3 MB, 256 x 302.0 MB; the constant lies between the first two."""
+    tokens, k, experts, widths, held = shape
+    got = dropless.slots_walk_bytes(tokens, k, experts, widths[0], held)
+    assert got / 1e9 == pytest.approx(gigabytes, rel=2e-3)
+    assert (got >= dropless.GROUPED_FROM_TABLE_BYTES) == (shape != HYBRID)
+    assert 1.61e9 < dropless.GROUPED_FROM_TABLE_BYTES < 4.83e9
+
+
+def test_two_forms_and_no_third():
     assert not hasattr(dropless, "_Blocks")
     assert not hasattr(dropless, "ROWS_PER_BLOCK")
 
@@ -177,13 +221,13 @@ def _through(x, held_w, is_chosen, w_in, w_out, g, rows, grouped):
     return out, counters, grads
 
 
-def _same(got, want, is_chosen):
+def _same(got, want, is_chosen, names=("x", "held_w", "w_in", "w_out")):
     out_g, counted_g, grads_g = got
     out_s, counted_s, grads_s = want
     np.testing.assert_allclose(out_g, out_s, rtol=2e-5, atol=2e-5)
     for name in ("slots", "done", "counts"):
         np.testing.assert_array_equal(counted_g[name], counted_s[name])
-    for a, b, name in zip(grads_g, grads_s, ("x", "held_w", "w_in", "w_out")):
+    for a, b, name in zip(grads_g, grads_s, names):
         if name == "held_w":
             # a pair nobody chose has no slot: its weight's gradient is
             # exactly zero, in both forms
@@ -211,6 +255,52 @@ def test_blocks_and_slots_are_the_same_sums(rows):
         args = (x, held_w, is_chosen, w_in[first:first + held],
                 w_out[first:first + held], g, rows)
         _same(_through(*args, True), _through(*args, False), is_chosen)
+
+
+def test_lagunas_layer_is_the_same_sums_in_the_form_it_now_takes(monkeypatch):
+    """A tiny layer of Laguna's kind (a scaled softmax top-10 of 256, 8 gated
+    experts held, widths the kernels tile): even routing chooses 3.9 % of
+    the pairs, so it is the bytes the walk by slots would pass over that
+    send it to the grouped form; outputs, counters and all four gradients
+    agree with the walk by slots, which it took before."""
+    tokens, width, inner = 128, 256, 128
+    experts, k, first, held = 256, 10, 40, 8
+    ks = jax.random.split(jax.random.PRNGKey(11), 5)
+    x = jax.random.normal(ks[0], (tokens, width))
+    # the held experts a little likelier than the rest: a few hundred slots
+    logits = jax.random.normal(ks[1], (tokens, experts)).at[
+        :, first:first + held].add(2.0)
+    w_in = 0.1 * jax.random.normal(ks[2], (held, width, 2 * inner))
+    w_out = 0.1 * jax.random.normal(ks[3], (held, inner, width))
+    g = jax.random.normal(ks[4], (tokens, width))
+    shape = (tokens, k, experts, (width, 2 * inner, inner), held)
+    passed = dropless.slots_walk_bytes(tokens, k, experts, width, held)
+
+    def through(from_bytes):
+        monkeypatch.setattr(dropless, "GROUPED_FROM_TABLE_BYTES", from_bytes)
+        before = collections.Counter(kernel_paths().get("grouped_matmul"))
+
+        def loss(x, logits, w_in, w_out):
+            out, counters, is_chosen = dropless.dropless_moe(
+                x, logits, w_in, w_out, k=k, first_expert=first,
+                experts_held=held, scale=2.5, scoring=dropless.softmax_topk,
+                activation=dropless.gated_silu)
+            return jnp.sum(out * g), (out, counters, is_chosen)
+        (_, (out, counters, is_chosen)), grads = jax.value_and_grad(
+            loss, argnums=(0, 1, 2, 3), has_aux=True)(x, logits, w_in, w_out)
+        return (out, counters, grads), is_chosen, dict(
+            collections.Counter(kernel_paths()["grouped_matmul"]) - before)
+
+    by_slots, chosen, path_s = through(float("inf"))
+    assert dropless.walk_form(*shape) == (False, tokens)
+    grouped, _, path_g = through(passed)
+    assert dropless.walk_form(*shape) == (True, 1280)
+    assert (path_s, path_g) == ({"slots": 1}, {"pallas": 1})
+    counted = grouped[1]
+    assert int(counted["slots"]) == int(counted["done"]) == int(
+        chosen.sum()) > 200
+    assert int(counted["computed"]) >= int(counted["slots"])
+    _same(grouped, by_slots, chosen, ("x", "logits", "w_in", "w_out"))
 
 
 @pytest.mark.parametrize("form", ["slots", "grouped"])

@@ -370,6 +370,7 @@ def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
 @pytest.mark.parametrize("tokens,latent,inner,held,gated", [
     (32768, 2304, 896, 16, True),    # train-mellum2-ep4-8k's layer
     (16384, 2304, 896, 16, True),    # the same at micro-batch 2
+    (16384, 3072, 1024, 8, True),    # train-laguna-s-ep32-8k's layer
 ])
 def test_grouped_walk_fwd_bwd(one_chip, tokens, latent, inner, held, gated):
     """The routed walk's grouped form at a cell's shapes, forward and

@@ -18,10 +18,22 @@ relu2).  Mellum's cell (``train-mellum2-ep4-8k``: 16 gated experts on rows of
 and with ``--grouped`` the form that cell's layers take (``dropless.walk_form``:
 the sorted slots ``--rows`` at a time through the grouped matmul,
 ``ops/pallas_gmm.py``; ``--per-token`` is the most slots a token has, ``min(k,
-held)``, and ``--tile`` the kernels' rows a tile).  ``dropless.
-GROUPED_FROM_SHARE`` stands on both forms at both models' shapes: the command
-above with ``--rows 1024`` and with ``--grouped --rows 8192``, and the defaults
-with and without ``--grouped --rows 8192`` (PERF.md section 6, PR 40).  Of the
+held)``, and ``--tile`` the kernels' rows a tile).  Laguna's cell
+(``train-laguna-s-ep32-8k``: 8 gated experts of 1,024 on rows of 3,072, a
+collapsed load, the lightest world's, even routing, the heaviest drift and one
+expert five times the mean) is
+
+    python tools/profile_moe_walk.py --tokens 16384 --latent 3072 \
+        --intermediate 1024 --held 8 --gated --grouped --rows 8192 \
+        --per-token 8 --loads 80 400 640 1200 3200x1+300
+
+and without ``--grouped``, ``--rows 256``: the form its layers took until PR
+48.  ``walk_form``'s two constants stand on both forms at the three models'
+shapes: ``dropless.GROUPED_FROM_SHARE`` on Mellum's command above with
+``--rows 1024`` and with ``--grouped --rows 8192`` and the defaults with and
+without ``--grouped --rows 8192`` (PERF.md section 6, PR 40), ``dropless.
+GROUPED_FROM_TABLE_BYTES`` on Laguna's both ways beside the defaults both
+ways at ``--loads 40 350 704`` (BENCH_KERNELS.md, PR 48).  Of the
 grouped form each line also gives the kernels alone (``kernel_ms``, the
 ``grouped_matmul`` events of a profiler session) and their share of the
 MXU's peak at the slots' rows (forward, the recomputed forward and the four
