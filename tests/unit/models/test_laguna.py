@@ -372,6 +372,10 @@ def test_every_attention_call_of_the_model_takes_the_kernel(monkeypatch):
     plain = model.logprobs(params, ids, labels)[0]
     monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
                         lambda self: True)
+    # ``count_kernel_path`` counts when a call is TRACED: whatever this
+    # process traced before (another model's test, the same shapes) must
+    # not answer for the counted call from jit's cache
+    jax.clear_caches()
     before = telemetry.kernel_paths()
     got = model.logprobs(params, ids, labels)[0]
     after = telemetry.kernel_paths()

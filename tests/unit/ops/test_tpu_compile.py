@@ -25,7 +25,7 @@ from jax.sharding import SingleDeviceSharding
 
 from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
-from deeperspeed_tpu.ops.attention import paged, pallas_flash
+from deeperspeed_tpu.ops.attention import eva, paged, pallas_eva, pallas_flash
 from deeperspeed_tpu.ops.quantizer import fused as qfused
 from deeperspeed_tpu.ops.sampling import topk
 from deeperspeed_tpu.ops.transformer import normalize
@@ -33,7 +33,7 @@ from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
 _BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
-            pallas_gmm)
+            pallas_gmm, pallas_eva)
 
 
 @pytest.fixture(scope="module")
@@ -365,6 +365,74 @@ def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
                                             backward=4 * 6)
     # the sorted slots' buffer of each pass is handed over unwritten
     assert passes["unwritten"] == dict(forward=4, recomputed=0, backward=4)
+
+
+@pytest.mark.parametrize("shape", [
+    (1, 16384, 16, 128),      # train-evabyte-tp2-16k's call: 16 heads held
+    (1, 4096, 2, 128),        # two windows
+])
+def test_eva_attention_fwd_bwd(one_chip, shape):
+    """EVA attention at the cell's shape (eight windows of 2048 bytes, 1024
+    summaries of 16-byte chunks, heads of 128): the summaries are plain
+    ``jnp``, the attention exactly two kernel calls under the scope
+    ``eva_attention`` on the projections' own ``[B, S, N*D]`` and the
+    summaries' ``[B, S / 16, N*D]``; the backward reads the lse as the
+    forward wrote it, one float a row; and no buffer of the program holds a
+    score matrix of the sequence's length (the float32 scores ``[16, 16384,
+    2048 + 896]`` would be 3.1 GB, those inside the mask 1.5 GB)."""
+    B, S, N, D = shape
+    W, C = 2048, 16
+    q = _sds(shape, jnp.bfloat16, one_chip)
+    mu = _sds((N, D), jnp.float32, one_chip)
+
+    def attend(q, k, v, mu, phi):
+        kb, vb = eva.chunk_summaries(k, v, mu, phi, C)
+        return eva.eva_attention(q, k, v, kb, vb, W, C, use_pallas=True)
+
+    compiled = jax.jit(_sum_grad(attend, 5)).lower(q, q, q, mu, mu).compile()
+    text = compiled.as_text()
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) == 2
+    for operands in calls:
+        assert (B, S, N * D) in operands and (B, S // C, N * D) in operands
+    # the backward reads the lse as the forward wrote it: one float a row
+    assert sum((B * N, 1, S) in operands for operands in calls) == 1
+    names = [line.split('op_name="')[1].split('"')[0]
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(names) == 2 and all("eva_attention" in n for n in names), names
+    # every temporary of forward and backward TOGETHER (the summaries'
+    # float32 pooling among them) is under a third of the bytes of the
+    # float32 scores inside the mask alone
+    scores = 4 * B * N * eva.pairs_needed(S, W, C)
+    assert compiled.memory_analysis().temp_size_in_bytes < scores / 3
+    assert pallas_eva.compiles_for_tpu(S, W, C, D)
+
+
+def test_recomputed_evabyte_keeps_the_eva_kernels_residuals(one_chip,
+                                                            on_the_chip):
+    """``EvaByte`` (two layers, remat, a float32 stream): each layer's
+    attention is one ``eva_attention`` kernel call forward and one backward;
+    the remat wrap keeps the kernel's output and lse (nothing recomputed)."""
+    from deeperspeed_tpu.models.evabyte import EvaByte, EvaByteConfig
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+
+    model = EvaByte(EvaByteConfig.tiny(
+        hidden_size=256, num_attention_heads=2, intermediate_size=256,
+        window_size=2048, chunk_size=16, max_seq_len=4096,
+        ce_chunk_tokens=2048, remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    ids = jnp.zeros((1, 4096), jnp.int32)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(1), ids)))
+    passes = count_kernel_passes(_compile(
+        jax.grad(lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0]),
+        params, _sds(ids.shape, ids.dtype, one_chip)))
+    assert passes["eva_attention"] == dict(forward=2, recomputed=0,
+                                           backward=2)
+    assert "flash_attention" not in passes
 
 
 @pytest.mark.parametrize("tokens,latent,inner,held,gated", [
