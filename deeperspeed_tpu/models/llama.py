@@ -182,10 +182,10 @@ class LlamaAttention(nn.Module):
                 return nn.Dense(H, use_bias=False, dtype=cfg.dtype,
                                 name="o_proj")(out.reshape(B, S, H))
 
-        with jax.named_scope("attention_layout"):   # GQA's copy of k and v
-            k, v = self._repeat_kv(k), self._repeat_kv(v)
-        # the window goes to the kernel, which skips what lies left of the
-        # band (``pallas_flash.mha``); only a padding mask takes the plain path
+        # k and v go at their KV heads, which the kernel addresses by the
+        # query head's group, and the window goes to it too: it skips what
+        # lies left of the band (``pallas_flash.mha``); only a padding mask
+        # takes the plain path
         mask = None
         if attention_mask is not None:
             mask = attention_mask[:, None, None, :].astype(bool)

@@ -190,9 +190,8 @@ class MellumAttention(nn.Module):
         cos, sin = rope.tables(jnp.arange(S)[None], self.rotary_dim or d,
                                cfg.dtype)
         q, k = apply_rotary_pos_emb(q, k, cos, sin)
-        if kv != nq:
-            with jax.named_scope("attention_layout"):  # GQA's copy of k, v
-                k, v = (jnp.repeat(t, nq // kv, axis=2) for t in (k, v))
+        # k and v go at their KV heads: the kernel addresses them by the
+        # query head's group and nothing copies them (``pallas_flash.mha``)
         out = dot_product_attention(
             q, k, v, causal=True,
             window=cfg.sliding_window if self.kind == SLIDING else None)

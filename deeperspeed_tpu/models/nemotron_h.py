@@ -211,9 +211,8 @@ class AttentionMixer(nn.Module):
         q = _dense(nq * d, cfg, "q_proj")(u).reshape(B, S, nq, d)
         k = _dense(kv * d, cfg, "k_proj")(u).reshape(B, S, kv, d)
         v = _dense(kv * d, cfg, "v_proj")(u).reshape(B, S, kv, d)
-        if kv != nq:
-            with jax.named_scope("attention_layout"):  # GQA's copy of k, v
-                k, v = (jnp.repeat(t, nq // kv, axis=2) for t in (k, v))
+        # k and v go at their KV heads: the kernel addresses them by the
+        # query head's group and nothing copies them (``pallas_flash.mha``)
         out = dot_product_attention(q, k, v, causal=True)
         with jax.named_scope("attention_layout"):
             out = out.reshape(B, S, nq * d)

@@ -14,14 +14,18 @@ import jax.numpy as jnp
 
 from ...accelerator import get_accelerator
 from ...parallel.topology import BATCH_AXES, SP_AXIS, TP_AXIS
-from ..pallas_utils import shard_kernel
+from ..pallas_utils import kernel_spec, shard_kernel
 
 
 def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rng=None,
                          dropout_rate=0.0, window=None):
-    """jnp reference path: [B, S, N, D] q/k/v -> [B, S, N, D]."""
+    """jnp reference path: [B, S, N, D] q/k/v -> [B, S, N, D]; k and v may
+    hold fewer heads than q (grouped-query: repeated here)."""
     *_, seq_q, num_heads, head_dim = q.shape
     seq_k = k.shape[-3]
+    if k.shape[-2] != num_heads:
+        k, v = (jnp.repeat(t, num_heads // t.shape[-2], axis=-2)
+                for t in (k, v))
     if scale is None:
         scale = 1.0 / jnp.sqrt(head_dim).astype(q.dtype)
     # [B, N, Sq, Sk]
@@ -43,9 +47,21 @@ def _reference_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rn
     return jnp.einsum("bnqk,bknd->bqnd", probs, v)
 
 
+def _kv_heads_of(q, k, v):
+    """k and v are KV heads of q's query heads: the same batch, length and
+    head dim, the query heads a multiple of theirs (grouped-query), which
+    the flash kernel takes as they are."""
+    return (k.shape == v.shape and q.ndim == k.ndim == 4
+            and q.shape[:2] + q.shape[3:] == k.shape[:2] + k.shape[3:]
+            and q.shape[2] % k.shape[2] == 0)
+
+
 def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_rng=None,
                           dropout_rate=0.0, use_pallas=None, window=None):
-    """Multi-head attention over [batch, seq, heads, head_dim] tensors.
+    """Multi-head attention over [batch, seq, heads, head_dim] tensors; k
+    and v may hold fewer heads than q (grouped-query: query head ``h`` on
+    KV head ``h // (heads // kv_heads)``), and are copied nowhere the flash
+    kernel can address them by group (``pallas_flash.mha``).
     ``window``: a causal call's sliding window in rows (row i sees the
     columns ``i - window < j <= i``); the flash kernel skips what lies left
     of the band, the plain path masks it."""
@@ -56,10 +72,19 @@ def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_r
     if use_pallas and mask is None and dropout_rate == 0.0:
         from .flash import flash_attention, flash_attention_supported
 
-        if flash_attention_supported(q.shape, q.dtype) and q.shape == k.shape:
+        if flash_attention_supported(q.shape, q.dtype) and _kv_heads_of(q, k, v):
             # each shard attends over the whole sequence for its own batch
             # rows and heads (heads over sp is the Ulysses layout)
             spec = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
+            if kernel_spec(spec, k.shape) != kernel_spec(spec, q.shape):
+                # the query heads lie over more devices than the KV heads
+                # divide into: a shard's query heads take their copies along
+                from .pallas_flash import (copy_kv_heads, kernel_name,
+                                           tile_plan)
+
+                k, v = copy_kv_heads(k, v, q.shape[2], kernel_name(tile_plan(
+                    q.shape[1], q.shape[3], q.dtype, N=q.shape[2],
+                    window=window)))
             return shard_kernel(
                 functools.partial(flash_attention, causal=causal, scale=scale,
                                   window=window),

@@ -60,6 +60,23 @@ Two-level tiling: the block the grid *loads* and the tile the kernel
   two-pass backward (a dq pass and a dk/dv pass over a one-level grid)
   takes over.
 
+Grouped-query heads (k and v with fewer heads than q, a KV head read by
+``rep`` query heads): where a head is a lane block of its own the kernels
+address a query head's KV head where it lies.  q, o, do and dq stay
+``[B, S, N*D]`` column groups; k, v, dk and dv are ``[B, S, N_kv*D]`` and
+column group ``g`` reads KV column group ``g // rep`` (``_kv_group``), so
+nothing copies k and v out to the query heads.  The one-kernel backward
+walks a KV head's query heads one after the other (grid: batch, KV head,
+query head of the group, k/v block) and adds each one's dk and dv of a block
+into fp32 sums of the whole KV head that stay in VMEM; the bf16 block leaves
+once, with the group's last query head, so no dk or dv of the query heads'
+width reaches HBM and nothing is summed after the kernel.  The two-pass
+backward's dk/dv pass walks the group between the k tile and the q tiles.
+``rep`` is read from the operands' widths; at ``rep`` 1 every call is the
+program it was.  The layouts that cannot address a KV head so (two heads of
+64 to a lane block, the folded one) take a copy of k and v (``copy_kv_heads``),
+and ``mha`` counts which it was (``grouped_<rep>`` | ``copied_<rep>``).
+
 At small head dim the matmuls are D-thin (they half-fill the MXU at D = 64)
 and the fp32 softmax ops on each score tile weigh as much, so the structure
 also minimizes VPU work per tile: q is pre-scaled once outside the kernel
@@ -140,13 +157,16 @@ def _heads_to_a_block(N, D):
     return 0
 
 
-def tile_plan(S, D, dtype, block=None, N=1, window=None):
+def tile_plan(S, D, dtype, block=None, N=1, window=None, kv_heads=None):
     """Tile sizes from what the call can see.  ``block`` overrides the
     owner block (tests); everything else follows from the shapes: the tiles
     from S and D, the layout the kernels take (``group``, ``width``) from
     the head count N and D alone (``_heads_to_a_block``).  ``window`` (a
     causal call's, in rows) changes no size: the same tiles, fewer of them
     (``_band_tiles``); one that reaches the whole length is no window.
+    ``kv_heads`` (k's and v's head count where it is not N) changes one
+    answer: the one-kernel backward of grouped-query heads also holds a KV
+    head's dk and dv sums (``_bwd_resident_bytes``).
 
     Measured on the v5e for causal bf16 at D = 64, S = 1024 / 2048 (the
     benchmark's cells) and at D = 96 / 128, S = 2048-8192 (PERF.md section 6,
@@ -175,19 +195,22 @@ def tile_plan(S, D, dtype, block=None, N=1, window=None):
     cps = next(c for c in range(n, 0, -1)
                if n % c == 0
                and 4 * c * block * width * itemsize <= _VMEM_BUDGET // 2)
+    grouped = group == 1 and N != (kv_heads or N)
     return Plan(block, sub, rows, cps * block,
-                _bwd_resident_bytes(sp, width, itemsize, heads)
+                _bwd_resident_bytes(sp, width, itemsize, heads, grouped)
                 <= _VMEM_BUDGET, group, width,
                 int(window) if window and window < S else 0)
 
 
-def _bwd_resident_bytes(sp, w, itemsize, heads=1):
+def _bwd_resident_bytes(sp, w, itemsize, heads=1, grouped=False):
     """VMEM the one-kernel backward holds per program: q, do, o and the
     heads' lse (a sublane tile of rows on lanes) double-buffered, each
     head's lse again as the tiles read it (lane-replicated), the fp32 dq
-    accumulator, the dq output block."""
+    accumulator, the dq output block; under grouped-query heads also the
+    KV head's fp32 dk and dv, summed over its query heads."""
     return (2 * 3 * sp * w * itemsize + 2 * 8 * sp * 4
-            + heads * sp * LANES * 4 + sp * w * 4 + 2 * sp * w * itemsize)
+            + heads * sp * LANES * 4 + sp * w * 4 + 2 * sp * w * itemsize
+            + grouped * 2 * sp * w * 4)
 
 
 def _edge_tiles(block, sub, causal):
@@ -524,15 +547,20 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *scratch,
 
 # ---------------------------------------------------------------------- bwd
 def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                dq_ref, dk_ref, dv_ref, lse_scr, dk_scr, dv_scr, *dq_scr,
-                causal, pad, s_valid, block, sub, rows, n, heads, window=0):
+                dq_ref, dk_ref, dv_ref, lse_scr, dk_scr, dv_scr, *more,
+                causal, pad, s_valid, block, sub, rows, n, heads, window=0,
+                rep=1):
     """One k/v block against its heads' resident q side: dk and dv of the
     block, and the block's share of the heads' dq (all of it when a head
-    is one block, ``n == 1``: then dq needs no accumulator)."""
-    kj = pl.program_id(2)
+    is one block, ``n == 1``: then dq needs no accumulator).  Grouped-query
+    heads (``rep`` > 1 query heads a KV head; the grid walks them one after
+    the other, each over all the k/v blocks): dk and dv of the block are
+    this query head's share, added to the KV head's fp32 sums (``more``'s
+    last two, whole heads), which leave with the group's last query head."""
+    kj = pl.program_id(2 + (rep > 1))
     width = q_ref.shape[2]
     if n > 1:
-        dq_scr, = dq_scr
+        dq_scr = more[0]
 
     @pl.when(kj == 0)
     def _init_head():
@@ -622,8 +650,24 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     else:
         _walk(0, n, interior)
 
-    dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-    dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    if rep == 1:
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    else:
+        r, block_rows = pl.program_id(2), _ds(kj * block, block)
+        for out, own, total in zip((dk_ref, dv_ref), (dk_scr, dv_scr),
+                                   more[-2:]):
+            @pl.when(r == 0)
+            def _first_of_group():
+                total[block_rows, :] = own[:]
+
+            @pl.when(jnp.logical_and(r > 0, r < rep - 1))
+            def _add_to_group():
+                total[block_rows, :] += own[:]
+
+            @pl.when(r == rep - 1)
+            def _leave_group():
+                out[0] = (total[block_rows, :] + own[:]).astype(out.dtype)
 
     if n > 1:
         @pl.when(kj == n - 1)
@@ -687,11 +731,18 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr,
-                *, causal, pad, s_valid, bq, bk, heads, window=0):
-    ki, qi = pl.program_id(2), pl.program_id(3)
-    nq = pl.num_programs(3)
+                *, causal, pad, s_valid, bq, bk, heads, window=0, rep=1):
+    """Grouped-query heads (``rep`` > 1): the grid walks a KV head's query
+    heads between the k tile and the q tiles, and the accumulators run over
+    both walks."""
+    walk = 3 + (rep > 1)
+    ki, qi = pl.program_id(2), pl.program_id(walk)
+    nq = pl.num_programs(walk)
+    first = qi == 0
+    if rep > 1:
+        first = jnp.logical_and(first, pl.program_id(3) == 0)
 
-    @pl.when(qi == 0)
+    @pl.when(first)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -726,7 +777,11 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     else:
         _tile(True)
 
-    @pl.when(qi == nq - 1)
+    last = qi == nq - 1
+    if rep > 1:
+        last = jnp.logical_and(last, pl.program_id(3) == rep - 1)
+
+    @pl.when(last)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
@@ -738,6 +793,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 # G = N / heads; folded B' = B * N and G = 1.  The lse is the kernels' own,
 # ``[B' * G, heads, S]``; the two-pass backward's kernels read it and their
 # delta lane-replicated, ``[B' * G * heads, S, 128]``: a row per head.
+# Grouped-query heads (in place at one head a group): k, v, dk and dv are
+# ``[B, S, G / rep * width]`` and query group ``g`` reads KV group
+# ``g // rep``, ``rep`` from the operands' widths.
 def _pad_seq(x, block):
     s = x.shape[1]
     sp = -(-s // block) * block
@@ -766,12 +824,38 @@ def _params(*semantics, vmem=None):
         dimension_semantics=semantics, vmem_limit_bytes=vmem))
 
 
-def _cost(q, plan, causal, matmuls, tensors):
+def _kv_group(g, rep):
+    """The KV column group of query column group ``g`` (at ``rep`` 1 the
+    index itself: the program text of a call without grouped heads)."""
+    return g if rep == 1 else g // rep
+
+
+def _group_walk(rep, at):
+    """``spec(shape, index)`` for a backward grid ``(b, g, *walk)`` whose
+    block indices are ``index(b, g, gk, *walk)``, ``g`` the query column
+    group and ``gk`` its KV group.  Grouped-query heads (``rep`` > 1) put
+    the KV groups on the grid's second axis and a group's query heads on
+    axis ``at``: ``(b, gk, ..., r, ...)``, ``g = gk * rep + r``."""
+    if rep == 1:
+        return lambda shape, index: pl.BlockSpec(
+            shape, lambda b, g, *walk: index(b, g, g, *walk))
+
+    def spec(shape, index):
+        def by_group(b, gk, *walk):
+            walk = list(walk)
+            return index(b, gk * rep + walk.pop(at - 2), gk, *walk)
+
+        return pl.BlockSpec(shape, by_group)
+
+    return spec
+
+
+def _cost(q, k, plan, causal, matmuls, tensors):
     """What a call costs, for XLA's scheduler: it decides what to run beside
     a kernel (the copies that bring the next operands) by this, and without
     it by the call's bytes, which the compact lse cut by half.  ``matmuls``
-    of ``2 * S * S * D`` FLOPs a head, ``tensors`` of q's size and the lse
-    through HBM."""
+    of ``2 * S * S * D`` FLOPs a head, ``tensors`` through HBM, half of
+    them of q's size and half of k's, and the lse."""
     b, sp, hw = q.shape
     heads = hw // (plan.width // max(plan.group, 1))
     square = b * heads * sp * sp // (2 if causal else 1)
@@ -779,13 +863,15 @@ def _cost(q, plan, causal, matmuls, tensors):
         square = b * heads * band_pairs(sp, plan.window)
     return pl.CostEstimate(
         flops=2 * matmuls * square * (hw // heads), transcendentals=square,
-        bytes_accessed=tensors * q.size * q.dtype.itemsize + 4 * b * heads * sp)
+        bytes_accessed=(tensors // 2 * (q.size + k.size) * q.dtype.itemsize
+                        + 4 * b * heads * sp))
 
 
 def _fwd_call(q, k, v, causal, s_valid, plan):
     b, sp, hw = q.shape
     block, span, w = plan.block, plan.span, plan.width
     heads, groups, n = max(plan.group, 1), hw // w, sp // block
+    rep = hw // k.shape[2]
     from jax.experimental.pallas import tpu as pltpu
 
     if plan.window:
@@ -794,15 +880,16 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
 
         def kv_index(b, g, i, j):
             return (b, jnp.clip(j, jnp.maximum(i - reach, 0) * block // span,
-                                (i * block) // span), g)
+                                (i * block) // span), _kv_group(g, rep))
     elif causal:
         # a span wholly above the diagonal is not walked: name the last
         # needed one again, so it is not loaded either
         def kv_index(b, g, i, j):
-            return (b, jnp.minimum(j, (i * block) // span), g)
+            return (b, jnp.minimum(j, (i * block) // span),
+                    _kv_group(g, rep))
     else:
         def kv_index(b, g, i, j):
-            return (b, j, g)
+            return (b, j, _kv_group(g, rep))
 
     itemsize = q.dtype.itemsize
     need = (4 * span * w * itemsize             # k, v, double-buffered
@@ -835,7 +922,7 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
             pltpu.VMEM((heads, block, LANES), jnp.float32),
             pltpu.VMEM((block, w), jnp.float32),
         ] if n > 1 else [],
-        cost_estimate=_cost(q, plan, causal, matmuls=2, tensors=4),
+        cost_estimate=_cost(q, k, plan, causal, matmuls=2, tensors=4),
         interpret=interpret_mode(),
         **_params("parallel", "parallel", "parallel", "arbitrary",
                   vmem=_vmem_limit(need)),
@@ -845,19 +932,33 @@ def _fwd_call(q, k, v, causal, s_valid, plan):
 
 def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
     """One-kernel backward: grid (batch, column group, k/v block), the q
-    side resident."""
+    side resident.  Grouped-query heads: grid (batch, KV column group, query
+    head of the group, k/v block), so that a KV head's query heads follow
+    one another and its dk and dv are summed in VMEM (``_bwd_kernel``)."""
     b, sp, hw = q.shape
     block, w = plan.block, plan.width
     heads, groups, n = max(plan.group, 1), hw // w, sp // block
+    rep = hw // k.shape[2]
     from jax.experimental.pallas import tpu as pltpu
 
-    head = pl.BlockSpec((1, sp, w), lambda b, g, j: (b, 0, g))
-    head_stat = pl.BlockSpec((1, heads, sp),
-                             lambda b, g, j: (b * groups + g, 0, 0))
-    owned = pl.BlockSpec((1, block, w), lambda b, g, j: (b, j, g))
+    spec = _group_walk(rep, at=2)
+    owned = spec((1, block, w), lambda b, g, gk, j: (b, j, gk))
+    if rep == 1:
+        grid, walks, kv_out = (b, groups, n), ("arbitrary",), owned
+    else:
+        grid, walks = (b, groups // rep, rep, n), ("arbitrary", "arbitrary")
+        # a block leaves when the next step names another: each does once,
+        # after the group's last query head has written the sums to it
+        kv_out = pl.BlockSpec((1, block, w), lambda b, gk, r, j: (
+            b, jnp.where(r == rep - 1, j, 0), gk))
+
+    head = spec((1, sp, w), lambda b, g, gk, j: (b, 0, g))
+    head_stat = spec((1, heads, sp),
+                     lambda b, g, gk, j: (b * groups + g, 0, 0))
     out = jax.ShapeDtypeStruct((b, sp, hw), q.dtype)
+    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
     itemsize = q.dtype.itemsize
-    need = (_bwd_resident_bytes(sp, w, itemsize, heads)
+    need = (_bwd_resident_bytes(sp, w, itemsize, heads, rep > 1)
             + 8 * block * w * itemsize          # k, v, dk, dv
             + 2 * block * w * 4                 # dk, dv accumulators
             # s, p, dp, ds of the tallest tile, and their low-precision casts
@@ -866,28 +967,29 @@ def _bwd_call(q, k, v, do, o, lse, causal, s_valid, plan):
         functools.partial(_bwd_kernel, causal=causal, pad=s_valid != sp,
                           s_valid=s_valid, block=block, sub=plan.sub,
                           rows=plan.rows, n=n, heads=heads,
-                          window=plan.window),
-        grid=(b, groups, n),
+                          window=plan.window, rep=rep),
+        grid=grid,
         in_specs=[head, owned, owned, head, head, head_stat],
-        out_specs=[head, owned, owned],
-        out_shape=[out, out, out],
+        out_specs=[head, kv_out, kv_out],
+        out_shape=[out, kv_shape, kv_shape],
         scratch_shapes=[pltpu.VMEM((heads, sp, LANES), jnp.float32),
                         pltpu.VMEM((block, w), jnp.float32),
                         pltpu.VMEM((block, w), jnp.float32)]
-        + [pltpu.VMEM((sp, w), jnp.float32)] * (n > 1),
-        cost_estimate=_cost(q, plan, causal, matmuls=5, tensors=8),
+        + [pltpu.VMEM((sp, w), jnp.float32)] * ((n > 1) + 2 * (rep > 1)),
+        cost_estimate=_cost(q, k, plan, causal, matmuls=5, tensors=8),
         interpret=interpret_mode(),
-        **_params("parallel", "parallel", "arbitrary",
-                  vmem=_vmem_limit(need)),
+        **_params("parallel", "parallel", *walks, vmem=_vmem_limit(need)),
     )(q, k, v, do, o, lse)
 
 
 def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
     """dq pass + dk/dv pass over a one-level grid: for lengths whose q side
-    does not fit VMEM (``Plan.resident_bwd`` false)."""
+    does not fit VMEM (``Plan.resident_bwd`` false).  Grouped-query heads:
+    the dk/dv pass walks a KV head's query heads too (``_dkv_kernel``)."""
     b, sp, hw = q.shape
     w = plan.width
     heads, groups = max(plan.group, 1), hw // w
+    rep = hw // k.shape[2]
     # its kernels read both statistics lane-replicated, a row per head
     lse = jnp.broadcast_to(lse.reshape(b * groups * heads, sp, 1),
                            (b * groups * heads, sp, LANES))
@@ -915,8 +1017,8 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
                 else jnp.clip(j, jnp.maximum(i - reach, 0), i))
 
     q_spec_i = pl.BlockSpec((1, bq, w), lambda b, g, i, j: (b, i, g))
-    k_spec_j = pl.BlockSpec((1, bk, w),
-                            lambda b, g, i, j: (b, near(i, j, False), g))
+    k_spec_j = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (
+        b, near(i, j, False), _kv_group(g, rep)))
     stat_i = pl.BlockSpec((heads, bq, LANES),
                           lambda b, g, i, j: (b * groups + g, i, 0))
 
@@ -931,23 +1033,30 @@ def _bwd_call_two_pass(q, k, v, do, o, lse, causal, s_valid, plan):
         **_params("parallel", "parallel", "parallel", "arbitrary"),
     )(q, k, v, do, lse, delta)
 
-    # dk/dv: grid's 3rd dim walks k tiles, 4th dim scans q tiles
-    q_spec_j = pl.BlockSpec((1, bq, w),
-                            lambda b, g, i, j: (b, near(i, j, True), g))
-    k_spec_i = pl.BlockSpec((1, bk, w), lambda b, g, i, j: (b, i, g))
-    stat_j = pl.BlockSpec((heads, bq, LANES),
-                          lambda b, g, i, j: (b * groups + g,
-                                              near(i, j, True), 0))
+    # dk/dv: grid's 3rd dim walks k tiles, the last scans q tiles; between
+    # them, under grouped-query heads, the KV head's query heads
+    spec = _group_walk(rep, at=3)
+    grid, walks = (b, groups, nk, nq), ("arbitrary",)
+    if rep > 1:
+        grid = (b, groups // rep, nk, rep, nq)
+        walks = ("arbitrary", "arbitrary")
+    q_spec_j = spec((1, bq, w),
+                    lambda b, g, gk, i, j: (b, near(i, j, True), g))
+    k_spec_i = spec((1, bk, w), lambda b, g, gk, i, j: (b, i, gk))
+    stat_j = spec((heads, bq, LANES),
+                  lambda b, g, gk, i, j: (b * groups + g,
+                                          near(i, j, True), 0))
+    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
     dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **static),
-        grid=(b, groups, nk, nq),
+        functools.partial(_dkv_kernel, rep=rep, **static),
+        grid=grid,
         in_specs=[q_spec_j, k_spec_i, k_spec_i, q_spec_j, stat_j, stat_j],
         out_specs=[k_spec_i, k_spec_i],
-        out_shape=[out, out],
+        out_shape=[kv_shape, kv_shape],
         scratch_shapes=[pltpu.VMEM((bk, w), jnp.float32),
                         pltpu.VMEM((bk, w), jnp.float32)],
         interpret=interpret_mode(),
-        **_params("parallel", "parallel", "parallel", "arbitrary"),
+        **_params("parallel", "parallel", "parallel", *walks),
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -957,6 +1066,19 @@ def kernel_name(plan):
     device trace and their counts in ``telemetry.kernel_paths()`` /
     ``kernel_passes()``: windowed calls apart from full ones."""
     return "flash_attention_window" if plan.window else "flash_attention"
+
+
+def copy_kv_heads(k, v, N, kernel):
+    """Grouped-query k and v ``[B, S, N_kv, D]`` copied out to the ``N``
+    query heads, for a call that cannot address a KV head by the query
+    head's group; counted under ``kernel + "_kv_heads"`` as ``copied_<query
+    heads a KV head>``.  The copies are ``attention_layout`` in a device
+    trace."""
+    from ...telemetry.trace import count_kernel_path
+
+    count_kernel_path(kernel + "_kv_heads", f"copied_{N // k.shape[2]}")
+    with jax.named_scope("attention_layout"):
+        return tuple(jnp.repeat(t, N // t.shape[2], axis=2) for t in (k, v))
 
 
 # ------------------------------------------------------------- public API
@@ -1012,6 +1134,14 @@ def mha(q, k, v, causal=True, scale=None, block=None, window=None):
     """Blocked multi-head attention: [B, S, N, D] q/k/v -> [B, S, N, D].
 
     Any S (padded to the 128 tile internally); D should be a multiple of 8.
+    Grouped-query heads: k and v may be ``[B, S, N_kv, D]`` with N a
+    multiple of N_kv, query head ``h`` on KV head ``h // (N // N_kv)``.
+    Where a head is a lane block of its own (``Plan.group`` 1) the kernels
+    read a query head's KV block where it lies and sum dk and dv over the
+    group in VMEM, so k, v, dk and dv exist at N_kv heads only; the other
+    layouts take a copy of k and v at N heads (``copy_kv_heads``).  Which
+    it was is counted under ``kernel_name(plan) + "_kv_heads"``:
+    ``grouped_<N // N_kv>`` or ``copied_<N // N_kv>``.
     Differentiable (custom VJP, FlashAttention-2 backward).  Tile sizes come
     from ``tile_plan``; ``block`` overrides the owner block only.
 
@@ -1037,15 +1167,25 @@ def mha(q, k, v, causal=True, scale=None, block=None, window=None):
         scale = float(D) ** -0.5
     if window is not None and (not causal or window < 1):
         raise ValueError("a window belongs to a causal call and is >= 1 row")
-    plan = tile_plan(S, D, q.dtype, block, N, window)
+    if k.shape != v.shape or N % k.shape[2] or (
+            k.shape[:2] + k.shape[3:] != (B, S, D)):
+        raise ValueError(f"k and v {k.shape}, {v.shape} are no KV heads of "
+                         f"q {q.shape}")
+    plan = tile_plan(S, D, q.dtype, block, N, window, kv_heads=k.shape[2])
     count_kernel_path(kernel_name(plan),
                       f"in_place_{plan.group}" if plan.group else "folded")
+    if k.shape[2] != N:
+        if plan.group == 1:
+            count_kernel_path(kernel_name(plan) + "_kv_heads",
+                              f"grouped_{N // k.shape[2]}")
+        else:
+            k, v = copy_kv_heads(k, v, N, kernel_name(plan))
     if plan.group:
         # a reshape of contiguous dimensions; whatever the compiler still
         # pays for it (it may hold a 4-D operand in another physical layout)
         # is ``attention_layout`` in a device trace, as the folds below are
         with jax.named_scope("attention_layout"):
-            q, k, v = (t.reshape(B, S, N * D) for t in (q, k, v))
+            q, k, v = (t.reshape(*t.shape[:2], -1) for t in (q, k, v))
         o = _mha(q, k, v, causal, float(scale), plan)
         with jax.named_scope("attention_layout"):
             return o.reshape(B, S, N, D)

@@ -27,6 +27,48 @@ def interpret_mode():
     return jax.default_backend() != "tpu"
 
 
+def _kernel_mesh():
+    """Where ``shard_kernel`` wraps a call: ``(mesh to map over, the
+    process-global mesh, its axes not yet manual)``; None where the call is
+    direct (no mesh installed, one device, or every axis already manual)."""
+    from ..parallel import topology as topo
+
+    mesh = topo._GLOBAL_MESH
+    if mesh is None or mesh.mesh.size == 1:
+        return None
+    use_mesh, manual = mesh.mesh, frozenset()
+    am = jax.sharding.get_abstract_mesh()
+    if not am.empty:
+        use_mesh, manual = am, frozenset(am.manual_axes)
+    auto = frozenset(mesh.mesh.axis_names) - manual
+    return (use_mesh, mesh, auto) if auto else None
+
+
+def kernel_spec(spec, shape):
+    """The ``PartitionSpec`` ``shard_kernel`` lays an operand of ``shape``
+    out by, given the mesh axes ``spec`` names per dim: of those, an axis is
+    used only if it is larger than 1, is not already manual in an enclosing
+    ``shard_map`` and divides the dim.  None where the call is direct."""
+    from jax.sharding import PartitionSpec
+
+    where = _kernel_mesh()
+    if where is None:
+        return None
+    _, mesh, auto = where
+    dims = []
+    for entry, size in zip(spec, shape):
+        axes = () if entry is None else (
+            (entry,) if isinstance(entry, str) else tuple(entry))
+        kept, n = [], 1
+        for a in axes:
+            if (a in auto and mesh.sizes[a] > 1
+                    and size % (n * mesh.sizes[a]) == 0):
+                kept.append(a)
+                n *= mesh.sizes[a]
+        dims.append(tuple(kept) if kept else None)
+    return PartitionSpec(*dims)
+
+
 def shard_kernel(fn, args, specs, out_like=0):
     """Call ``fn(*args)`` -- a function made of Pallas kernels -- under the
     process-global mesh; its one output is laid out like ``args[out_like]``.
@@ -36,42 +78,16 @@ def shard_kernel(fn, args, specs, out_like=0):
     call must sit in a ``shard_map`` that is manual over every mesh axis,
     where each device runs the kernel on its own shard.  ``specs`` gives,
     per operand and per dim, the mesh axes that dim is laid out over by the
-    model code's conventions (the ``topology.constrain`` call sites).  Of
-    those, an axis is used only if it is larger than 1, is not already
-    manual in an enclosing ``shard_map`` and divides the dim; over the other
+    model code's conventions (the ``topology.constrain`` call sites); which
+    of them an operand takes is ``kernel_spec``'s answer, and over the other
     axes the operands are replicated.  With no mesh installed, one device,
     or every axis already manual, the call is direct.
     """
-    from jax.sharding import PartitionSpec
-
-    from ..parallel import topology as topo
-
-    mesh = topo._GLOBAL_MESH
-    if mesh is None or mesh.mesh.size == 1:
+    where = _kernel_mesh()
+    if where is None:
         return fn(*args)
-    use_mesh, manual = mesh.mesh, frozenset()
-    am = jax.sharding.get_abstract_mesh()
-    if not am.empty:
-        use_mesh, manual = am, frozenset(am.manual_axes)
-    auto = frozenset(mesh.mesh.axis_names) - manual
-    if not auto:
-        return fn(*args)
-
-    def fit(spec, shape):
-        dims = []
-        for entry, size in zip(spec, shape):
-            axes = () if entry is None else (
-                (entry,) if isinstance(entry, str) else tuple(entry))
-            kept, n = [], 1
-            for a in axes:
-                if (a in auto and mesh.sizes[a] > 1
-                        and size % (n * mesh.sizes[a]) == 0):
-                    kept.append(a)
-                    n *= mesh.sizes[a]
-            dims.append(tuple(kept) if kept else None)
-        return PartitionSpec(*dims)
-
-    in_specs = tuple(fit(s, a.shape) for s, a in zip(specs, args))
+    use_mesh, _, auto = where
+    in_specs = tuple(kernel_spec(s, a.shape) for s, a in zip(specs, args))
     return jax.shard_map(
         fn, mesh=use_mesh, in_specs=in_specs, out_specs=in_specs[out_like],
         axis_names=auto, check_vma=False)(*args)

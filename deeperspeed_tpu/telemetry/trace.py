@@ -822,7 +822,9 @@ def count_kernel_path(kernel, path):
     """Count one traced call of ``kernel`` on ``path``, where a kernel picks
     between forms from its operands' shapes (flash attention: ``in_place_2``
     = two heads to a lane block of ``[B, S, N*D]``, ``in_place_1``,
-    ``folded``).  Counted when a program is traced, not when it runs."""
+    ``folded``; under ``<kernel>_kv_heads`` a call on grouped-query heads:
+    ``grouped_<rep>`` | ``copied_<rep>``).  Counted when a program is
+    traced, not when it runs."""
     paths = _KERNEL_PATHS.setdefault(kernel, {})
     paths[path] = paths.get(path, 0) + 1
 

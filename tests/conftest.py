@@ -100,3 +100,64 @@ def faulty_fs():
     inj.install()
     yield inj
     inj.uninstall()
+
+
+@pytest.fixture
+def kv_heads_go_to_the_kernel(monkeypatch):
+    """-> ``check(loss, params, batch, groups)`` for a grouped-query model's
+    ``loss(params, batch) -> (loss, aux)``: with the accelerator's kernels on
+    (interpret mode here) the loss and every gradient are the plain path's
+    (which is attention on GQA's copies of k and v), the gradient program
+    hands every flash kernel its k and v at their KV heads and holds no
+    value shaped like a copy of them, for each ``(query heads, KV heads,
+    head dim)`` of ``groups``; returns what the traced calls counted under
+    ``*_kv_heads`` (``telemetry.kernel_paths()``)."""
+    import collections
+
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deeperspeed_tpu import telemetry
+    from deeperspeed_tpu.accelerator import get_accelerator
+    from deeperspeed_tpu.analysis.graphcheck import _walk_eqns
+
+    def counted():
+        return {kernel: collections.Counter(paths)
+                for kernel, paths in telemetry.kernel_paths().items()
+                if kernel.endswith("_kv_heads")}
+
+    def check(loss, params, batch, groups):
+        both = jax.value_and_grad(loss, has_aux=True)
+        (plain, _), plain_grads = jax.jit(both)(params, batch)
+        monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
+                            lambda self: True)
+        # counted when a call is TRACED: nothing from jit's cache
+        jax.clear_caches()
+        before = counted()
+        (got, _), grads = jax.jit(both)(params, batch)
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: loss(p, batch)[0]))(params)
+        after = counted()
+        assert float(got) == pytest.approx(float(plain), rel=1e-5)
+        want = dict(jax.tree_util.tree_leaves_with_path(plain_grads))
+        for path, g in jax.tree_util.tree_leaves_with_path(grads):
+            scale = max(float(jnp.max(jnp.abs(want[path]))), 1e-8)
+            np.testing.assert_allclose(
+                np.asarray(g) / scale, np.asarray(want[path]) / scale,
+                rtol=0, atol=3e-4, err_msg=jax.tree_util.keystr(path))
+        eqns = [eqn for _, eqn in _walk_eqns(jaxpr)]
+        for heads, kv, d in groups:
+            copies = [eqn for eqn in eqns for out in eqn.outvars
+                      if getattr(out.aval, "shape", ())[2:]
+                      == (kv, heads // kv, d)]
+            assert not copies, copies
+            kernels = [[v.aval.shape[-1] for v in eqn.invars[:3]]
+                       for eqn in eqns if eqn.primitive.name == "pallas_call"
+                       and eqn.params["jaxpr"].debug_info.func_name in (
+                           "_fwd_kernel", "_bwd_kernel")
+                       and eqn.invars[0].aval.shape[-1] == heads * d]
+            assert kernels and all(
+                widths == [heads * d, kv * d, kv * d] for widths in kernels)
+        return {kernel: dict(paths - before.get(kernel, collections.Counter()))
+                for kernel, paths in after.items()}
+
+    return check

@@ -389,6 +389,31 @@ def test_every_attention_call_of_the_model_takes_the_kernel(monkeypatch):
     np.testing.assert_allclose(got, plain, rtol=2e-4, atol=2e-4)
 
 
+def test_k_and_v_reach_the_kernels_at_their_kv_heads(kv_heads_go_to_the_kernel):
+    """Heads of 128 (a head a lane block, as in the cell), the 3 query
+    heads held of a windowed layer and the 2 of a full one over the one KV
+    head held: a train step hands k and v to the kernels at the KV head,
+    groups of 3 and of 2 by the layer's kind, copies nothing and sums
+    nothing after the kernel, and the loss and gradients (the gate's, too)
+    are the plain path's."""
+    cfg = _cfg(STACKS["stage"], head_dim=128, hidden_size=128)
+    ids, labels = _ids(41, cfg, b=1, s=256)
+    model = runner.program_model(cfg, dict(TRAFFIC, seq_len=256))
+    held = model.config
+    groups = [(held.heads(SLIDING), held.kv_heads, 128),
+              (held.heads(FULL), held.kv_heads, 128)]
+    assert all(heads > kv for heads, kv, _ in groups)
+    counted = kv_heads_go_to_the_kernel(
+        model.loss_fn(), ref.init_params(cfg, 41),
+        {"input_ids": ids, "labels": labels}, groups)
+    assert {kernel: set(paths) for kernel, paths in counted.items()
+            if paths} == {
+        "flash_attention_window_kv_heads": {
+            f"grouped_{groups[0][0] // groups[0][1]}"},
+        "flash_attention_kv_heads": {
+            f"grouped_{groups[1][0] // groups[1][1]}"}}
+
+
 def test_a_wide_layers_walk_takes_the_grouped_matmul(monkeypatch):
     """The routed walk's form is chosen by the shapes: this tiny model's
     expert layers (top-3 of 64 chooses 4.7 % of the pairs, widths the

@@ -269,6 +269,25 @@ def test_every_windowed_call_of_the_model_takes_the_kernel(monkeypatch):
         lambda p: model.apply({"params": p}, ids)[0])(params).jaxpr) == 4
 
 
+def test_k_and_v_reach_the_kernels_at_their_kv_heads(kv_heads_go_to_the_kernel):
+    """Heads of 128 (a head a lane block, as in the cell): a train step's
+    windowed and full layers hand k and v to the kernel at the 2 KV heads
+    of their 4 query heads, nothing copies them under ``attention_layout``
+    or sums dk and dv after the kernel, and the loss and gradients are the
+    plain path's on the copies."""
+    cfg = _cfg(PERIOD, num_attention_heads=4, num_key_value_heads=2,
+               head_dim=128, hidden_size=128)
+    ids, labels = _ids(37, cfg, b=1, s=256)
+    model = runner.program_model(cfg, dict(TRAFFIC, seq_len=256))
+    counted = kv_heads_go_to_the_kernel(
+        model.loss_fn(), ref.init_params(cfg, 37),
+        {"input_ids": ids, "labels": labels}, [(4, 2, 128)])
+    assert {kernel: set(paths) for kernel, paths in counted.items()
+            if paths} == {
+        "flash_attention_window_kv_heads": {"grouped_2"},
+        "flash_attention_kv_heads": {"grouped_2"}}
+
+
 # ---------------------------------------------------------------- the set-up
 @pytest.fixture(scope="module")
 def lowered_gradient_programs():

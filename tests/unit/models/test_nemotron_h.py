@@ -263,3 +263,18 @@ def test_presets():
     with pytest.raises(ValueError):
         NemotronH(NemotronHConfig.tiny(pattern="MX")).init(
             jax.random.PRNGKey(0), jnp.zeros((1, 4), jnp.int32))
+
+
+def test_k_and_v_reach_the_kernel_at_their_kv_heads(kv_heads_go_to_the_kernel):
+    """The attention layer at heads of 128 (a head a lane block): its call
+    hands k and v to the kernel at the 2 KV heads of its 4 query heads,
+    copies nothing, and the loss and gradients are the plain path's."""
+    cfg = _cfg("*E", num_attention_heads=4, num_key_value_heads=2,
+               head_dim=128)
+    ids, labels = _ids(43, cfg, b=1, s=256)
+    model = runner.program_model(cfg, dict(TRAFFIC, seq_len=256))
+    counted = kv_heads_go_to_the_kernel(
+        model.loss_fn(), ref.init_params(cfg, 43),
+        {"input_ids": ids, "labels": labels}, [(4, 2, 128)])
+    assert {kernel: set(paths) for kernel, paths in counted.items()
+            if paths} == {"flash_attention_kv_heads": {"grouped_2"}}

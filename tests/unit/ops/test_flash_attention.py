@@ -276,19 +276,24 @@ def test_long_sequence_plans_in_place(changes, N, D, causal):
         q, k, v, 3e-5, f"{changes} N={N} D={D} causal={causal}")
 
 
-def _primitives(jaxpr):
-    """Names of every primitive of a jaxpr, those of nested jaxprs (a jit,
-    a custom VJP's forward) included; a kernel's body is not the program's
-    (the lse's 128 x 128 transposes in VMEM are no transpose in HBM)."""
+def _equations(jaxpr):
+    """Every equation of a jaxpr, those of nested jaxprs (a jit, a custom
+    VJP's forward) included; a kernel's body is not the program's (the
+    lse's 128 x 128 transposes in VMEM are no transpose in HBM)."""
     for eqn in jaxpr.eqns:
-        yield eqn.primitive.name
+        yield eqn
         if eqn.primitive.name == "pallas_call":
             continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _primitives(inner)
+                    yield from _equations(inner)
+
+
+def _primitives(jaxpr):
+    """Names of every primitive of a jaxpr (``_equations``)."""
+    return (eqn.primitive.name for eqn in _equations(jaxpr))
 
 
 @pytest.mark.parametrize("shape,group", list(zip(CELL_SHAPES, (2, 2, 1)))
@@ -426,3 +431,222 @@ def test_plan_splits_what_does_not_fit_vmem():
     for S in (1024, 2048, 8192):
         plan = tile_plan(S, 128, jnp.bfloat16)
         assert plan.span == S and plan.resident_bwd
+
+
+# ------------------------------------------------- grouped-query heads
+# query heads over KV heads: one KV head; the cells' (train-mellum2-ep4-8k
+# 32 / 4, train-laguna-s-ep32-8k 36 / 4 windowed and 24 / 4 full); a pair
+GROUPED = [(8, 1), (32, 4), (36, 4), (24, 4), (4, 2)]
+
+
+def _grouped_qkv(S, N, kv, B=1, D=128, dtype=jnp.float32, seed=0):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    return tuple(jax.random.normal(key, (B, S, n, D), dtype)
+                 for key, n in zip(ks, (N, kv, kv)))
+
+
+def _on_copies(fn):
+    """``fn`` on k and v repeated out to the query heads (GQA's copy)."""
+    return lambda q, k, v: fn(q, *(
+        jnp.repeat(t, q.shape[2] // t.shape[2], axis=2) for t in (k, v)))
+
+
+def _kv_heads_counted(kernel="flash_attention"):
+    from deeperspeed_tpu.telemetry import kernel_paths
+
+    return dict(kernel_paths().get(kernel + "_kv_heads", {}))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("N,kv", GROUPED)
+def test_grouped_query_heads_fwd_and_grads(N, kv, dtype):
+    """k and v at their KV heads: the output and dq, and dk and dv summed
+    over a KV head's query heads inside the backward kernel, against the
+    reference on the copies; a padded length of three owner blocks, two
+    batch rows where the heads are few."""
+    S, B = 300, 2 if N == 4 else 1
+    q, k, v = _grouped_qkv(S, N, kv, B=B, dtype=dtype)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    before = _kv_heads_counted()
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, causal=True),
+        _on_copies(lambda *a: _reference_attention(*map(f32, a), causal=True)),
+        q, k, v, 3e-5 if dtype == jnp.float32 else 2e-2,
+        f"{N} / {kv} {jnp.dtype(dtype).name}")
+    grads = _grads(lambda *a: mha(*a, causal=True), q, k, v)
+    assert [g.shape for g in grads] == [q.shape, k.shape, v.shape]
+    assert all(g.dtype == dtype for g in grads)
+    after = _kv_heads_counted()
+    label = f"grouped_{N // kv}"
+    assert after[label] > before.get(label, 0)
+    assert not any(name.startswith("copied") for name in after
+                   if after[name] != before.get(name, 0))
+
+
+@pytest.mark.parametrize("causal", [True, False])
+@pytest.mark.parametrize("changes", [
+    dict(resident_bwd=False),                       # two-pass backward
+    dict(block=256, sub=128, rows=128, span=256),   # forward over spans
+    dict(block=512, sub=256, rows=256),             # one block to the head
+], ids=["two_pass_bwd", "spans", "one_block"])
+def test_grouped_query_heads_on_plans_the_cells_do_not_take(changes, causal):
+    """Eight query heads over two KV heads through the two-pass backward
+    (its dk/dv pass walks a KV head's query heads between the k tile and
+    the q tiles), the forward over spans, and a head of one block, causal
+    and not, at a padded length."""
+    S, N, kv, D = 500, 8, 2, 128
+    plan = tile_plan(S, D, jnp.float32, N=N, kv_heads=kv)._replace(**changes)
+
+    def fn(q, k, v):
+        o = pallas_flash._mha(*(t.reshape(2, S, -1) for t in (q, k, v)),
+                              causal, float(D) ** -0.5, plan)
+        return o.reshape(2, S, N, D)
+
+    q, k, v = _grouped_qkv(S, N, kv, B=2)
+    _assert_fwd_and_grads(
+        fn, _on_copies(lambda *a: _reference_attention(*a, causal=causal)),
+        q, k, v, 3e-5, f"{changes} causal={causal}")
+
+
+# the text ``jax.jit(grad(mha)).lower(q, k, v).as_text()`` of a call whose k
+# and v have q's head count, at the commit before grouped-query heads went
+# into the kernel (33029de): sha256, by (shape, window).  A change to the
+# kernels that every call takes moves these, and re-records them.
+_TEXT_BEFORE_GROUPED_HEADS = {
+    ((2, 640, 4, 128), None):
+        "7b9b229c092b1bef",
+    ((2, 1000, 3, 64), None):
+        "1416ff64f6025f96",
+}
+
+
+@pytest.mark.parametrize("shape,window", list(_TEXT_BEFORE_GROUPED_HEADS))
+def test_a_call_without_grouped_heads_lowers_to_the_text_it_had(shape, window):
+    """``rep == 1`` is the call as it was: the program text of forward +
+    backward is the recorded one's, and the same whether k and v come as q
+    itself or as arrays of their own with q's head count; such a call
+    counts nothing under ``*_kv_heads``."""
+    import hashlib
+
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    before = _kv_heads_counted(), _kv_heads_counted("flash_attention_window")
+    grad = jax.grad(lambda *a: jnp.sum(
+        mha(*a, window=window).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(x, x, x).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        _TEXT_BEFORE_GROUPED_HEADS[shape, window]
+    assert before == (_kv_heads_counted(),
+                      _kv_heads_counted("flash_attention_window"))
+    # and no grouped call's structure: a grid of three axes in the backward
+    kernels = [eqn for eqn in _equations(jax.make_jaxpr(grad)(x, x, x).jaxpr)
+               if eqn.primitive.name == "pallas_call"]
+    assert sorted(len(e.params["grid_mapping"].grid) for e in kernels) == [3, 4]
+
+
+def test_grouped_heads_are_one_more_grid_axis_and_no_copy():
+    """The grouped call's program: k, v, dk and dv at the KV heads' width
+    in and out of the two kernels, the backward's grid (batch, KV head,
+    query head of the group, k/v block), nothing repeated before the
+    kernels and nothing summed after them."""
+    S, N, kv, D = 512, 8, 2, 128
+    q = jax.ShapeDtypeStruct((1, S, N, D), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((1, S, kv, D), jnp.bfloat16)
+    grad = jax.grad(lambda *a: jnp.sum(mha(*a, block=128).astype(
+        jnp.float32)), argnums=(0, 1, 2))
+    eqns = list(_equations(jax.make_jaxpr(grad)(q, k, k).jaxpr))
+    fwd, bwd = [e for e in eqns if e.primitive.name == "pallas_call"]
+    assert fwd.params["grid_mapping"].grid == (1, N, 4, 1)
+    assert bwd.params["grid_mapping"].grid == (1, kv, N // kv, 4)
+    assert [a.aval.shape for a in fwd.invars] == [
+        (1, S, N * D), (1, S, kv * D), (1, S, kv * D)]
+    assert [a.aval.shape for a in bwd.outvars] == [
+        (1, S, N * D), (1, S, kv * D), (1, S, kv * D)]
+    # the one sum is the loss's own; no value is [B, S, KV, group, D]
+    assert [e.primitive.name for e in eqns].count("reduce_sum") == 1
+    assert not any(getattr(a.aval, "shape", ())[2:] == (kv, N // kv, D)
+                   for e in eqns for a in e.outvars)
+
+
+@pytest.mark.parametrize("N,kv,D,path", [
+    (4, 2, 64, "in_place_2"),     # two heads of 64 to a lane block
+    (6, 2, 96, "folded"),         # heads that are no lane blocks
+    (3, 1, 64, "folded"),         # an odd head count at 64
+])
+@pytest.mark.parametrize("window", [None, 100])
+def test_layouts_that_keep_the_copy_of_k_and_v(N, kv, D, path, window):
+    """Heads packed two to a lane block, and the folded layout, cannot
+    address a KV head by the query head's group: ``mha`` copies k and v out
+    to the query heads itself, says so, and gives the reference's
+    numbers."""
+    kernel = "flash_attention_window" if window else "flash_attention"
+    q, k, v = _grouped_qkv(384, N, kv, D=D)
+    before = _kv_heads_counted(kernel)
+    _assert_fwd_and_grads(
+        lambda *a: mha(*a, window=window),
+        _on_copies(lambda *a: _reference_attention(*a, window=window)),
+        q, k, v, 3e-5, f"{N} / {kv} at {D}")
+    after = _kv_heads_counted(kernel)
+    label = f"copied_{N // kv}"
+    assert after[label] > before.get(label, 0)
+    assert after.get(f"grouped_{N // kv}", 0) == before.get(
+        f"grouped_{N // kv}", 0)
+    from deeperspeed_tpu.telemetry import kernel_paths
+    assert path in kernel_paths()[kernel]
+
+
+@pytest.mark.parametrize("tp,label", [(4, "copied_4"), (2, "grouped_4")])
+def test_kv_heads_under_a_sharded_head_axis(reset_mesh, tp, label):
+    """Eight query heads over two KV heads, the head axis laid out over
+    ``tp`` devices: over two each device holds a KV head and its four query
+    heads and the kernel addresses the group; over four the KV heads do not
+    divide, and ``dot_product_attention`` hands each device its query
+    heads' copies."""
+    from deeperspeed_tpu.ops.attention.core import dot_product_attention
+
+    reset_mesh.set_mesh(reset_mesh.MeshTopology(dp=8 // tp, tp=tp))
+    q, k, v = _grouped_qkv(256, 8, 2, B=8 // tp, seed=3)
+    before = _kv_heads_counted()
+    jax.clear_caches()
+    _assert_fwd_and_grads(
+        jax.jit(lambda *a: dot_product_attention(*a, use_pallas=True)),
+        _on_copies(lambda *a: _reference_attention(*a, causal=True)),
+        q, k, v, 3e-5, f"tp={tp}")
+    after = _kv_heads_counted()
+    assert {name for name in after
+            if after[name] != before.get(name, 0)} == {label}
+
+
+def test_k_and_v_that_are_no_kv_heads_are_refused():
+    q, k, v = _grouped_qkv(128, 6, 4)
+    with pytest.raises(ValueError):
+        mha(q, k, v)
+    with pytest.raises(ValueError):
+        mha(q, k[:, :64], v[:, :64])
+
+
+@pytest.mark.parametrize("shape,kv,window", [
+    ((4, 8192, 32, 128), 4, 1024), ((4, 8192, 32, 128), 4, None),
+    ((2, 8192, 36, 128), 4, 512), ((2, 8192, 24, 128), 4, None),
+], ids=["mellum_window", "mellum_full", "laguna_window", "laguna_full"])
+def test_grouped_backward_fits_vmem_at_the_cells_shapes(shape, kv, window):
+    """The one-kernel backward still holds a head's q side at the cells'
+    lengths with a KV head's fp32 dk and dv sums beside it (``tile_plan``
+    counts them), inside the budget and under the limit a call may state."""
+    B, S, N, D = shape
+    plan = tile_plan(S, D, jnp.bfloat16, N=N, window=window, kv_heads=kv)
+    assert plan.resident_bwd and plan.group == 1
+    assert plan == tile_plan(S, D, jnp.bfloat16, N=N, window=window)
+    held = pallas_flash._bwd_resident_bytes(S, D, 2, grouped=True)
+    assert held - pallas_flash._bwd_resident_bytes(S, D, 2) == 2 * S * D * 4
+    assert held <= pallas_flash._VMEM_BUDGET
+    # what ``_bwd_call`` states: the resident side, the k/v blocks in and
+    # out, the block's accumulators and the tallest tile's temporaries
+    need = (held + 8 * plan.block * D * 2 + 2 * plan.block * D * 4
+            + 5 * max(plan.rows, plan.sub) * plan.block * 4)
+    assert pallas_flash._vmem_limit(need) <= pallas_flash._VMEM_LIMIT
+    assert need * 5 // 4 <= pallas_flash._VMEM_LIMIT
+    # a length at which the sums are what no longer fits goes two-pass
+    assert tile_plan(12288, D, jnp.bfloat16, N=N).resident_bwd
+    assert not tile_plan(12288, D, jnp.bfloat16, N=N,
+                         kv_heads=kv).resident_bwd

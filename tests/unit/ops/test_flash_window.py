@@ -105,6 +105,63 @@ def test_window_512_on_gqa_copies_of_four_kv_heads(heads, two_pass):
                  q, k, v, 3e-5, f"heads={heads} two_pass={two_pass}")
 
 
+def _grouped(S, N, kv, D=128, dtype=jnp.float32):
+    q, _, _ = _qkv(S=S, N=N, D=D, dtype=dtype)
+    _, k, v = _qkv(S=S, N=kv, D=D, dtype=dtype, seed=1)
+    return q, k, v
+
+
+def _on_copies(fn):
+    """``fn`` on k and v repeated out to the query heads (GQA's copy)."""
+    return lambda q, k, v: fn(q, *(
+        jnp.repeat(t, q.shape[2] // t.shape[2], axis=2) for t in (k, v)))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("N,kv,window", [
+    (32, 4, 1024),      # train-mellum2-ep4-8k's windowed layers
+    (36, 4, 512),       # train-laguna-s-ep32-8k's
+    (24, 4, 300),       # a window that crosses a block
+    (8, 1, 512), (8, 1, 1024), (4, 2, 300), (4, 2, 1024),
+])
+def test_window_on_kv_heads_addressed_by_the_group(N, kv, window, dtype):
+    """A windowed call on k and v at their KV heads (a query head reads its
+    KV head's block where it lies; dk and dv leave the backward kernel
+    summed over the group): outputs and gradients against
+    ``_reference_attention`` on the copies, over five owner blocks of a
+    padded length, and counted ``grouped_<N / kv>``."""
+    S = 1200
+    q, k, v = _grouped(S, N, kv, dtype=dtype)
+    f32 = lambda t: t.astype(jnp.float32)  # noqa: E731
+    before = dict(telemetry.kernel_paths().get(
+        "flash_attention_window_kv_heads", {}))
+    _assert_same(
+        lambda *a: mha(*a, block=256, window=window),
+        _on_copies(lambda *a: _reference_attention(*map(f32, a),
+                                                   window=window)),
+        q, k, v, 3e-5 if dtype == jnp.float32 else 2e-2,
+        f"{N} / {kv} window={window} {jnp.dtype(dtype).name}")
+    after = telemetry.kernel_paths()["flash_attention_window_kv_heads"]
+    assert {name for name in after if after[name] != before.get(name, 0)} \
+        == {f"grouped_{N // kv}"}
+
+
+@pytest.mark.parametrize("window", [200, 512])
+def test_window_on_kv_heads_through_the_two_pass_backward(window):
+    S, N, kv, D = 1000, 6, 2, 128
+    plan = tile_plan(S, D, jnp.float32, block=256, N=N, window=window,
+                     kv_heads=kv)._replace(resident_bwd=False)
+
+    def fn(q, k, v):
+        o = pallas_flash._mha(*(t.reshape(1, S, -1) for t in (q, k, v)),
+                              True, float(D) ** -0.5, plan)
+        return o.reshape(1, S, N, D)
+
+    _assert_same(fn, _on_copies(lambda *a: _masked_plain(*a, window)),
+                 *_grouped(S, N, kv), 3e-5, f"two-pass window={window}")
+
+
 @pytest.mark.parametrize("changes", [
     dict(resident_bwd=False),                       # the two-pass backward
     dict(block=256, sub=128, rows=128, span=256),   # the forward over spans

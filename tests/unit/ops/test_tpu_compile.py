@@ -327,6 +327,56 @@ def test_flash_mha_window_fwd_bwd(one_chip, shape, window):
         "flash_attention_window" in n for n in names), names
 
 
+@pytest.mark.parametrize("shape,kv,window", [
+    # train-mellum2-ep4-8k: 32 query heads over the 4 KV heads held, a
+    # window of 1024 on three layers of four
+    ((4, 8192, 32, 128), 4, 1024), ((4, 8192, 32, 128), 4, None),
+    # train-laguna-s-ep32-8k: 36 windowed | 24 full query heads over 4
+    ((2, 8192, 36, 128), 4, 512), ((2, 8192, 24, 128), 4, None),
+    # the hybrid cell's one layer; one KV head; one block to the head
+    ((2, 8192, 8, 128), 2, None), ((2, 4096, 8, 128), 1, None),
+    ((2, 2048, 4, 128), 2, 300),
+])
+def test_flash_mha_grouped_query_fwd_bwd(one_chip, shape, kv, window):
+    """Grouped-query heads are the same two kernel calls: q, o, do and dq at
+    the query heads' width, k, v, dk and dv at the KV heads' and nowhere at
+    the query heads' (no copy before the kernels, no sum after them), with
+    the KV head's fp32 dk and dv sums counted in the limit the call
+    states."""
+    B, S, N, D = shape
+    q = _sds(shape, jnp.bfloat16, one_chip)
+    k = _sds((B, S, kv, D), jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(functools.partial(
+        pallas_flash.mha, window=window), 3), q, k, k)
+    plan = pallas_flash.tile_plan(S, D, jnp.bfloat16, N=N, window=window,
+                                  kv_heads=kv)
+    assert plan.resident_bwd and plan.group == 1
+    sp = -(-S // plan.block) * plan.block
+    assert pallas_flash._bwd_resident_bytes(
+        sp, D, 2, grouped=True) - pallas_flash._bwd_resident_bytes(
+            sp, D, 2) == 2 * sp * D * 4
+    fwd, bwd = sorted(_kernel_operand_shapes(text), key=len)
+    wide, thin = (B, sp, N * D), (B, sp, kv * D)
+    assert fwd == [wide, thin, thin], fwd                       # q, k, v
+    assert bwd[:5] == [wide, thin, thin, wide, wide], bwd       # ... do, o
+    # nothing is copied out to the query heads or summed back over a group
+    assert "[%d,%d,%d,%d,%d]" % (B, S, kv, N // kv, D) not in text
+
+
+def test_flash_mha_grouped_query_long_sequence_two_pass(one_chip):
+    """S = 16k over 4 KV heads: the two-pass backward's three calls, the
+    dk/dv pass walking a KV head's query heads."""
+    q = _sds((1, 16384, 8, 128), jnp.bfloat16, one_chip)
+    k = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
+    text = _compile(_sum_grad(functools.partial(
+        pallas_flash.mha, window=2048), 3), q, k, k)
+    assert not pallas_flash.tile_plan(16384, 128, jnp.bfloat16, N=8,
+                                      kv_heads=4).resident_bwd
+    calls = _kernel_operand_shapes(text)
+    assert len(calls) == 3
+    assert all((1, 16384, 4 * 128) in operands for operands in calls)
+
+
 def test_flash_mha_window_long_sequence_two_pass(one_chip):
     """S = 16k under a window: the two-pass backward's three calls."""
     q = _sds((1, 16384, 4, 128), jnp.bfloat16, one_chip)
@@ -365,6 +415,42 @@ def test_recomputed_mellum_takes_the_kernel_for_every_windowed_layer(
                                             backward=4 * 6)
     # the sorted slots' buffer of each pass is handed over unwritten
     assert passes["unwritten"] == dict(forward=4, recomputed=0, backward=4)
+
+
+def test_recomputed_laguna_hands_its_kernels_k_and_v_at_the_kv_heads(
+        one_chip, on_the_chip):
+    """``Laguna`` (a full layer with 4 query heads, two windowed ones with 6,
+    over 2 KV heads of 128, remat): a kernel call forward and one backward a
+    layer by its kind, none recomputed, as before the kernel addressed KV
+    heads by the query head's group; and in the compiled step no value is
+    a copy of k or v at the query heads (``[B, S, 2, 3 | 2, 128]``)."""
+    from deeperspeed_tpu.models.laguna import Laguna, LagunaConfig
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+
+    model = Laguna(LagunaConfig.tiny(
+        hidden_size=256, head_dim=128, sliding_window=128,
+        intermediate_size=256, moe_intermediate_size=128,
+        shared_expert_intermediate_size=128, max_seq_len=256,
+        ce_chunk_tokens=256, remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    ids = jnp.zeros((2, 256), jnp.int32)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(1), ids)))
+    text = _compile(jax.grad(lambda p, ids: loss(
+        p["params"], {"input_ids": ids, "labels": ids})[0]),
+        params, _sds(ids.shape, ids.dtype, one_chip))
+    passes = count_kernel_passes(text)
+    assert passes["flash_attention_window"] == dict(forward=2, recomputed=0,
+                                                    backward=2)
+    assert passes["flash_attention"] == dict(forward=1, recomputed=0,
+                                             backward=1)
+    assert "[2,256,2,3,128]" not in text and "[2,256,2,2,128]" not in text
+    calls = pallas_kernel_calls(text)
+    for scope, wide in (("flash_attention_window", 6), ("flash_attention", 4)):
+        assert all(operands[:3] == [(2, 256, wide * 128), (2, 256, 256),
+                                    (2, 256, 256)]
+                   for operands in calls[scope]), calls[scope]
 
 
 @pytest.mark.parametrize("shape", [

@@ -175,3 +175,23 @@ def test_gqa_cache_stored_at_kv_heads():
     pvars = paged.init(jax.random.PRNGKey(0), toks)
     pk = pvars["cache"]["layers_0"]["attention"]["paged_key"]
     assert pk.shape[2] == 2
+
+
+@pytest.mark.parametrize("preset,kernel", [
+    ("tiny", "flash_attention"), ("tiny_mistral", "flash_attention_window")])
+def test_training_hands_k_and_v_to_the_kernel_at_their_kv_heads(
+        kv_heads_go_to_the_kernel, preset, kernel):
+    """The training path at heads of 128 (a head a lane block): k and v go
+    to the flash kernel at the 2 KV heads of the 4 query heads, under
+    Mistral's window too, nothing copies them, and the loss and gradients
+    are the plain path's on the copies."""
+    model = Llama(getattr(LlamaConfig, preset)(hidden_size=512,
+                                               max_seq_len=256))
+    assert (model.config.head_dim, model.config.num_kv_heads) == (128, 2)
+    batch = model.example_batch(batch_size=1, seq_len=256)
+    params = model.init(jax.random.PRNGKey(3), batch["input_ids"])["params"]
+    loss = model.loss_fn()
+    counted = kv_heads_go_to_the_kernel(
+        lambda p, b: (loss(p, b), None), params, batch, [(4, 2, 128)])
+    assert {name: set(paths) for name, paths in counted.items()
+            if paths} == {kernel + "_kv_heads": {"grouped_2"}}

@@ -10,10 +10,20 @@ takes and returns ``[B, S, N, D]`` arrays in the layout the compiler gives a
 program's arguments, so ``around`` is an upper bound of what a model pays,
 where producers and consumers are fused; the kernel's time is the kernel's.
 
+A shape is ``BxSxNxD``, or ``BxSxN/KVxD`` for grouped-query heads: N query
+heads over KV heads of k and v.  Such a shape is timed twice: as the kernel
+takes it (``auto``: k and v at their KV heads, a query head reading its KV
+head's block where it lies, dk and dv summed over the group inside the
+backward kernel) and ``copied`` (k and v repeated out to N heads before the
+call and dk, dv summed back after it: the form every grouped-query model
+here had until PR 50, and the one ``mha`` still takes for layouts whose heads
+are no lane blocks of their own).  The copy and the sum are ``around``.
+
 ``--windows 0 1024`` times each shape under those causal windows too (0 is the
 full call; a windowed call's kernels are ``flash_attention_window`` events and
 its TFLOP/s credit the band's pairs only).  ``--parent DIR`` (a second checkout of this repo) times that checkout's
-``mha`` the same way, on the same chip in the same process; ``--plans``
+``mha`` the same way (on the copies, where the heads are grouped), on the
+same chip in the same process; ``--plans``
 adds ``block:sub:rows`` triples beside the plan ``tile_plan`` picks.
 
     python tools/profile_attn.py --shapes 8x2048x16x64 16x1024x12x64 \
@@ -90,14 +100,15 @@ def device_ms(fn, x, calls=4):
             "around_top": {k: round(v / calls / 1e6, 4) for k, v in top}}
 
 
-def _time(name, call, x, flops, iters):
-    fwd = jax.jit(lambda t: call(t, t, t))
-    fwdbwd = jax.jit(jax.grad(
-        lambda t: call(t, t, t).astype(jnp.float32).sum()))
+def _time(name, call, qkv, flops, iters):
+    """``call(q, k, v)`` forward, and forward + backward to all three."""
+    fwd = jax.jit(lambda t: (call(*t),) + t[1:])
+    fwdbwd = jax.jit(lambda t: jax.grad(
+        lambda *a: call(*a).astype(jnp.float32).sum(), argnums=(0, 1, 2))(*t))
     try:
         for mode, fn in (("fwd", fwd), ("fwdbwd", fwdbwd)):
-            wall = timed_inner(fn, x, iters=iters)
-            dev = device_ms(fn, x)
+            wall = timed_inner(fn, qkv, iters=iters)
+            dev = device_ms(fn, qkv)
             emit(f"{name}_{mode}", wall, **dev, tflops=round(
                 flops[mode] / (dev["kernel_ms"] * 1e-3) / 1e12, 2))
     except Exception as e:  # noqa: BLE001 -- a plan the compiler refuses
@@ -122,13 +133,25 @@ def main():
     parent = _parent_module(args.parent) if args.parent else None
     planned = pf.tile_plan
 
+    def copied(mha, **kw):
+        """``mha`` on k and v repeated out to the query heads."""
+        return lambda q, k, v: mha(q, *(
+            jnp.repeat(t, q.shape[2] // t.shape[2], axis=2) for t in (k, v)),
+            causal=causal, **kw)
+
     for shape in args.shapes:
-        B, S, N, D = (int(t) for t in shape.split("x"))
-        x = jax.random.normal(jax.random.PRNGKey(2), (B, S, N, D), dtype)
+        B, S, heads, D = shape.split("x")
+        B, S, D = int(B), int(S), int(D)
+        N, KV = (int(t) for t in (heads.split("/") * 2)[:2])
+        keys = jax.random.split(jax.random.PRNGKey(2), 3)
+        x = tuple(jax.random.normal(key, (B, S, n, D), dtype)
+                  for key, n in zip(keys, (N, KV, KV)))
         flops = {m: attn_flops(B, S, N, D, causal, mode=m)
                  for m in ("fwd", "fwdbwd")}
-        own = planned(S, D, dtype, N=N)
+        own = planned(S, D, dtype, N=N, kv_heads=KV)
         plans = [("auto", own)]
+        if KV != N:
+            plans.append(("copied", planned(S, D, dtype, N=N)))
         for text in args.plans:
             block, sub, rows = (int(t) for t in text.split(":"))
             if S % block == 0:
@@ -148,15 +171,19 @@ def main():
                 try:
                     _time(f"{shape}_{label}" + (f"_w{window}" if window
                                                 else ""),
+                          copied(pf.mha) if label == "copied" else
                           lambda q, k, v: pf.mha(q, k, v, causal=causal),
                           x, {m: f * share for m, f in flops.items()},
                           args.iters)
                 finally:
                     pf.tile_plan = planned
         if parent is not None:
-            _time(f"{shape}_parent",
-                  lambda q, k, v: parent.mha(q, k, v, causal=causal),
-                  x, flops, args.iters)
+            for window in args.windows:
+                share = (pf.band_pairs(S, window) / pf.band_pairs(S, None)
+                         if causal else 1.0)
+                _time(f"{shape}_parent" + (f"_w{window}" if window else ""),
+                      copied(parent.mha, window=window or None), x,
+                      {m: f * share for m, f in flops.items()}, args.iters)
 
 
 if __name__ == "__main__":
