@@ -36,8 +36,9 @@ absent heads would add is left out and nothing stands in for them.
 The same engine protocol as the other models (``loss_fn`` /
 ``example_batch`` / ``param_partition_rules`` / ``num_params`` /
 ``flops_per_token`` / ``no_cast_paths``).  Scopes: ``attention`` (the
-sublayer with its norm) with ``eva_pool`` (the summaries) and ``eva_attend``
-(from q, k, v and the summaries to the mixed output) inside, ``mlp``,
+sublayer with its norm) with ``eva_pool`` (the summaries; on a TPU a
+kernel pair under that name) and ``eva_attend`` (from q, k, v and the
+summaries to the mixed output; the kernel pair ``eva_attention``) inside, ``mlp``,
 ``embed``, ``head_ce``.  A step's counters: ``layer_applications``,
 ``eva_pairs_visited`` (the (row, key) pairs the attention calls compute, all
 heads and layers) beside ``eva_pairs_needed`` (what the equations need), and

@@ -9,18 +9,22 @@ position ``t`` lies in window ``w(t) = t // W`` and chunk ``c`` (positions
 * chunk summaries, by learned directions ``mu, phi`` in R^D:
   ``kb_c = sum_{j in c} softmax_{j in c}(mu . k_j) k_j``,
   ``vb_c = sum_{j in c} softmax_{j in c}(phi . k_j) v_j``
-  (``chunk_summaries``; the logits unscaled, float32);
+  (``chunk_summaries``; the logits unscaled; logits, weights and sums
+  float32);
 * row ``t`` attends, in ONE softmax at scale ``D^-1/2`` with float32
   statistics, over ``L_t = {j : w(j) = w(t), j <= t}`` (exact keys) and
   ``R_t = {c : window of c < w(t)}`` (the summaries of every earlier window:
   all of a window's or none, and none of the row's own)
   (``eva_attention``).
 
-On a TPU the second is the kernel pair of ``pallas_eva.py`` where the shapes
-are whole tiles; elsewhere, and for shapes the kernels do not take, a plain
-form a window block at a time (a window's ``[W, W + S / C]`` scores, never
-a sequence's).  The equations with what a published config leaves to
-assumption: ``benchmarks/reference/evabyte_ref.py``.
+On a TPU, where the shapes are whole tiles, each is a kernel pair: the
+summaries ``pallas_eva_pool.py`` (k and v read once where the projections
+left them), the attention ``pallas_eva.py``.  Elsewhere, and for shapes the
+kernels do not take, plain forms: the pooling on ``[B, S / C, C, N, D]``
+views, the attention a window block at a time (a window's
+``[W, W + S / C]`` scores, never a sequence's).  The equations with what a
+published config leaves to assumption:
+``benchmarks/reference/evabyte_ref.py``.
 """
 
 import functools
@@ -31,17 +35,35 @@ import jax.numpy as jnp
 from ...accelerator import get_accelerator
 from ...parallel.topology import BATCH_AXES, SP_AXIS, TP_AXIS
 from ..pallas_utils import shard_kernel
-from . import pallas_eva
+from . import pallas_eva, pallas_eva_pool
+
+# heads are independent (the directions are a head's own): each shard runs
+# its own batch rows and heads over the whole sequence
+_HEADS_SPEC = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
 
 
-def chunk_summaries(k, v, mu, phi, chunk):
+def chunk_summaries(k, v, mu, phi, chunk, use_pallas=None):
     """``k, v`` [B, S, N, D] (``k`` rotated), ``mu, phi`` [N, D] -> the
-    summaries ``(kb, vb)`` [B, S / chunk, N, D] in ``k``'s dtype.  The
-    pooling logits and weights are float32 on the vector unit (a float32
-    matmul would run in bfloat16)."""
+    summaries ``(kb, vb)`` [B, S / chunk, N, D] in ``k``'s dtype; the
+    pooling logits, weights and sums float32.  ``use_pallas`` as
+    ``eva_attention``'s: None takes the kernels on a TPU where the shapes
+    are whole tiles (``pallas_eva_pool.compiles_for_tpu``)."""
+    from ...telemetry.trace import count_kernel_path
+
     B, S, N, D = k.shape
     if S % chunk:
         raise ValueError(f"{S} rows are not whole chunks of {chunk}")
+    if use_pallas is None:
+        use_pallas = (get_accelerator().use_pallas_kernels()
+                      and k.dtype == v.dtype
+                      and pallas_eva_pool.compiles_for_tpu(S, chunk, D,
+                                                           k.dtype))
+    if use_pallas:
+        rows, heads = _HEADS_SPEC, _HEADS_SPEC[2:]
+        return shard_kernel(
+            functools.partial(pallas_eva_pool.pool, chunk=chunk),
+            (k, v, mu, phi), (rows, rows, heads, heads), out_like=(0, 0))
+    count_kernel_path(pallas_eva_pool.KERNEL_NAME, "plain")
     kc = k.reshape(B, S // chunk, chunk, N, D).astype(jnp.float32)
     vc = v.reshape(B, S // chunk, chunk, N, D).astype(jnp.float32)
 
@@ -118,10 +140,8 @@ def eva_attention(q, k, v, kb, vb, window, chunk, scale=None,
     if scale is None:
         scale = float(D) ** -0.5
     if _takes_kernel(S, window, chunk, D, use_pallas):
-        # heads are independent (the directions are a head's own): each
-        # shard runs its own batch rows and heads over the whole sequence
-        spec = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
         return shard_kernel(
             functools.partial(pallas_eva.eva_mha, window=window, chunk=chunk,
-                              scale=scale), (q, k, v, kb, vb), (spec,) * 5)
+                              scale=scale), (q, k, v, kb, vb),
+            (_HEADS_SPEC,) * 5)
     return _plain(q, k, v, kb, vb, window, chunk, scale)

@@ -5,7 +5,8 @@ summaries of every chunk of every EARLIER window, forward + backward.
 The equations are ``ops/attention/eva.py``'s.  Here ``W`` is the window in
 rows, ``P`` the summaries a window leaves (``W / chunk``), ``nW = S / W``.
 The kernels take q, k, v ``[B, S, N*D]`` as the projections hold them and
-the summaries ``[B, S / chunk, N*D]``, heads addressed as column groups of
+the summaries ``[B, S / chunk, N*D]`` (as ``pallas_eva_pool``'s kernels
+write them, under a scope of their own), heads addressed as column groups of
 ``D`` lanes (``pallas_flash``'s in-place layout at one head a lane block),
 and are built from ``pallas_flash``'s tiles:
 

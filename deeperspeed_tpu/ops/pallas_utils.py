@@ -71,7 +71,8 @@ def kernel_spec(spec, shape):
 
 def shard_kernel(fn, args, specs, out_like=0):
     """Call ``fn(*args)`` -- a function made of Pallas kernels -- under the
-    process-global mesh; its one output is laid out like ``args[out_like]``.
+    process-global mesh; its one output is laid out like ``args[out_like]``
+    (a tuple of outputs like the operands a tuple ``out_like`` names).
 
     GSPMD cannot partition a Mosaic kernel ("Mosaic kernels cannot be
     automatically partitioned"): under a mesh of more than one device the
@@ -88,8 +89,10 @@ def shard_kernel(fn, args, specs, out_like=0):
         return fn(*args)
     use_mesh, _, auto = where
     in_specs = tuple(kernel_spec(s, a.shape) for s, a in zip(specs, args))
+    out_specs = (tuple(in_specs[i] for i in out_like)
+                 if isinstance(out_like, tuple) else in_specs[out_like])
     return jax.shard_map(
-        fn, mesh=use_mesh, in_specs=in_specs, out_specs=in_specs[out_like],
+        fn, mesh=use_mesh, in_specs=in_specs, out_specs=out_specs,
         axis_names=auto, check_vma=False)(*args)
 
 

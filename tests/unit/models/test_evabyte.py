@@ -295,10 +295,11 @@ def test_the_stream_the_statistics_and_the_logits_stay_float32():
 # ----------------------------------------------------------------- the kernel
 def test_every_attention_call_of_the_model_takes_the_kernel(monkeypatch):
     """With the accelerator's kernels on and shapes the kernels tile, both
-    layers count an ``eva_attention`` call (interpret mode here) in a FRESH
-    trace, and the result is the plain path's."""
+    layers count an ``eva_attention`` call and an ``eva_pool`` call
+    (interpret mode here) in a FRESH trace, and the result is the plain
+    path's."""
     from deeperspeed_tpu.accelerator import get_accelerator
-    from deeperspeed_tpu.ops.attention import pallas_eva
+    from deeperspeed_tpu.ops.attention import pallas_eva, pallas_eva_pool
 
     cfg = dict(TINY, hidden_size=64, num_attention_heads=4)
     params, (ids, labels) = _params(13, cfg), _ids(13, b=1)
@@ -308,16 +309,60 @@ def test_every_attention_call_of_the_model_takes_the_kernel(monkeypatch):
                         lambda self: True)
     # this tiny head is no lane tile: say the shapes compile, as the cell's do
     monkeypatch.setattr(pallas_eva, "compiles_for_tpu", lambda *a: True)
+    monkeypatch.setattr(pallas_eva_pool, "compiles_for_tpu", lambda *a: True)
     # ``count_kernel_path`` counts when a call is TRACED
     jax.clear_caches()
-    before = dict(telemetry.kernel_paths().get("eva_attention", {}))
+    before = {name: dict(telemetry.kernel_paths().get(name, {}))
+              for name in ("eva_attention", "eva_pool")}
     got = model.logprobs(params, ids, labels)[0]
-    after = telemetry.kernel_paths()["eva_attention"]
-    assert after["in_place_1"] == before.get("in_place_1", 0) + 2
+    after = telemetry.kernel_paths()
+    for name, was in before.items():
+        assert after[name]["in_place_1"] == was.get("in_place_1", 0) + 2
+        assert after[name].get("plain", 0) == was.get("plain", 0)
     np.testing.assert_allclose(got, plain, rtol=2e-5, atol=2e-5)
     told = model.counters(1, S)
     assert told["eva_pairs_visited"] == 2 * 2 * (
         3 * 64 * 64 + 64 * 8 * 3)      # one row group a window: its square
+    monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
+                        lambda self: False)
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("body,bodies,calls_a_layer", [
+    ("_fwd_call", 2, 2), ("_bwd_call", 1, 1)])
+def test_the_pooling_kernels_are_lowered_once_for_all_the_layers(
+        monkeypatch, body, bodies, calls_a_layer):
+    """What a cell's set-up pays for the pooling kernels must not grow with
+    the layers (PERF.md section 6, PR 51: a kernel's body is straight-line
+    code over its block, and traced and lowered at every call site it took a
+    warm ``setup_s`` from 31 s to 70): the jitted forward and backward calls
+    are bodies of the recomputed model's lowered gradient program whose
+    number does not depend on its layers: the forward pass's and the
+    recomputed pass's, each called once a layer, and the backward's one."""
+    import re
+
+    from deeperspeed_tpu.accelerator import get_accelerator
+    from deeperspeed_tpu.ops.attention import pallas_eva, pallas_eva_pool
+
+    monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
+                        lambda self: True)
+    monkeypatch.setattr(pallas_eva, "compiles_for_tpu", lambda *a: True)
+    monkeypatch.setattr(pallas_eva_pool, "compiles_for_tpu", lambda *a: True)
+    jax.clear_caches()
+
+    def counted(layers):
+        model = EvaByte(EvaByteConfig.tiny(num_hidden_layers=layers,
+                                           remat=True, dtype=jnp.bfloat16))
+        loss, batch = model.loss_fn(), model.example_batch(1, S)
+        params = jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0), batch["input_ids"]))
+        text = jax.jit(jax.grad(lambda p, b: loss(p["params"], b)[0])).lower(
+            params, batch).as_text()
+        return (len(re.findall(rf"func\.func private @{body}(_\d+)?\(", text)),
+                len(re.findall(rf"call @{body}(_\d+)?\(", text)))
+
+    assert counted(2) == (bodies, 2 * calls_a_layer)
+    assert counted(4) == (bodies, 4 * calls_a_layer)
     monkeypatch.setattr(type(get_accelerator()), "use_pallas_kernels",
                         lambda self: False)
     jax.clear_caches()

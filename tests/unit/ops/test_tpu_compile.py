@@ -25,7 +25,8 @@ from jax.sharding import SingleDeviceSharding
 
 from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
-from deeperspeed_tpu.ops.attention import eva, paged, pallas_eva, pallas_flash
+from deeperspeed_tpu.ops.attention import (eva, paged, pallas_eva,
+                                           pallas_eva_pool, pallas_flash)
 from deeperspeed_tpu.ops.quantizer import fused as qfused
 from deeperspeed_tpu.ops.sampling import topk
 from deeperspeed_tpu.ops.transformer import normalize
@@ -33,7 +34,7 @@ from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
 _BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
-            pallas_gmm, pallas_eva)
+            pallas_gmm, pallas_eva, pallas_eva_pool)
 
 
 @pytest.fixture(scope="module")
@@ -459,47 +460,60 @@ def test_recomputed_laguna_hands_its_kernels_k_and_v_at_the_kv_heads(
 ])
 def test_eva_attention_fwd_bwd(one_chip, shape):
     """EVA attention at the cell's shape (eight windows of 2048 bytes, 1024
-    summaries of 16-byte chunks, heads of 128): the summaries are plain
-    ``jnp``, the attention exactly two kernel calls under the scope
-    ``eva_attention`` on the projections' own ``[B, S, N*D]`` and the
-    summaries' ``[B, S / 16, N*D]``; the backward reads the lse as the
-    forward wrote it, one float a row; and no buffer of the program holds a
-    score matrix of the sequence's length (the float32 scores ``[16, 16384,
-    2048 + 896]`` would be 3.1 GB, those inside the mask 1.5 GB)."""
+    summaries of 16-byte chunks, heads of 128): four kernel calls, the
+    summaries' pair under the scope ``eva_pool`` from the projections' own
+    ``[B, S, N*D]`` to ``[B, S / 16, N*D]`` and the attention's under
+    ``eva_attention`` on both; the backward reads the lse as the forward
+    wrote it, one float a row; no buffer of the program is a float32 copy of
+    k or v, and none holds a score matrix of the sequence's length (the
+    float32 scores ``[16, 16384, 2048 + 896]`` would be 3.1 GB, those
+    inside the mask 1.5 GB)."""
     B, S, N, D = shape
     W, C = 2048, 16
     q = _sds(shape, jnp.bfloat16, one_chip)
     mu = _sds((N, D), jnp.float32, one_chip)
 
     def attend(q, k, v, mu, phi):
-        kb, vb = eva.chunk_summaries(k, v, mu, phi, C)
-        return eva.eva_attention(q, k, v, kb, vb, W, C, use_pallas=True)
+        with jax.named_scope("layer"):  # as in a model: the kernels' scopes
+            # are then not the outermost, which jvp() would wrap
+            kb, vb = eva.chunk_summaries(k, v, mu, phi, C, use_pallas=True)
+            return eva.eva_attention(q, k, v, kb, vb, W, C, use_pallas=True)
 
     compiled = jax.jit(_sum_grad(attend, 5)).lower(q, q, q, mu, mu).compile()
     text = compiled.as_text()
-    calls = _kernel_operand_shapes(text)
-    assert len(calls) == 2
-    for operands in calls:
-        assert (B, S, N * D) in operands and (B, S // C, N * D) in operands
+    calls = pallas_kernel_calls(text)
+    assert {name: len(found) for name, found in calls.items()} == {
+        "eva_attention": 2, "eva_pool": 2}
+    rows, pooled = (B, S, N * D), (B, S // C, N * D)
+    for operands in calls["eva_attention"]:
+        assert rows in operands and pooled in operands
+    # the pooling's forward reads the direction matrices, k and v and writes
+    # what the attention reads; its backward reads the directions, k, v and
+    # the summaries' cotangents
+    matrix = (N, pallas_eva_pool.PARTS * D, 128)
+    assert sorted(calls["eva_pool"], key=len) == [
+        [matrix, rows, rows], [matrix, (2, N * D), rows, rows, pooled, pooled]]
+    entry = text[text.index("\nENTRY "):]
+    assert f"= f32[{B},{S}," not in entry, "a float32 buffer of k's size"
     # the backward reads the lse as the forward wrote it: one float a row
-    assert sum((B * N, 1, S) in operands for operands in calls) == 1
-    names = [line.split('op_name="')[1].split('"')[0]
-             for line in text.splitlines()
-             if 'custom_call_target="tpu_custom_call"' in line]
-    assert len(names) == 2 and all("eva_attention" in n for n in names), names
-    # every temporary of forward and backward TOGETHER (the summaries'
-    # float32 pooling among them) is under a third of the bytes of the
-    # float32 scores inside the mask alone
+    assert sum((B * N, 1, S) in operands
+               for operands in calls["eva_attention"]) == 1
+    # every temporary of forward and backward TOGETHER is under a third of
+    # the bytes of the float32 scores inside the mask alone
     scores = 4 * B * N * eva.pairs_needed(S, W, C)
     assert compiled.memory_analysis().temp_size_in_bytes < scores / 3
     assert pallas_eva.compiles_for_tpu(S, W, C, D)
+    assert pallas_eva_pool.compiles_for_tpu(S, C, D, jnp.bfloat16)
 
 
 def test_recomputed_evabyte_keeps_the_eva_kernels_residuals(one_chip,
                                                             on_the_chip):
     """``EvaByte`` (two layers, remat, a float32 stream): each layer's
-    attention is one ``eva_attention`` kernel call forward and one backward;
-    the remat wrap keeps the kernel's output and lse (nothing recomputed)."""
+    attention is one ``eva_attention`` kernel call forward and one backward,
+    the remat wrap keeps that kernel's output and lse (nothing recomputed);
+    its summaries are one ``eva_pool`` call forward, one recomputed (the wrap
+    does not keep them and the attention's backward reads them) and one
+    backward."""
     from deeperspeed_tpu.models.evabyte import EvaByte, EvaByteConfig
     from deeperspeed_tpu.telemetry import count_kernel_passes
 
@@ -518,6 +532,7 @@ def test_recomputed_evabyte_keeps_the_eva_kernels_residuals(one_chip,
         params, _sds(ids.shape, ids.dtype, one_chip)))
     assert passes["eva_attention"] == dict(forward=2, recomputed=0,
                                            backward=2)
+    assert passes["eva_pool"] == dict(forward=2, recomputed=2, backward=2)
     assert "flash_attention" not in passes
 
 
