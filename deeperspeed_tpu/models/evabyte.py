@@ -33,10 +33,10 @@ Heads are independent up to ``W_o``, so the shares' outputs of ``W_o`` add
 up to the whole layer's (``tests/unit/models/test_evabyte.py``); what the
 absent heads would add is left out and nothing stands in for them.
 
-The same engine protocol as the other models (``loss_fn`` /
-``example_batch`` / ``param_partition_rules`` / ``num_params`` /
-``flops_per_token`` / ``no_cast_paths``).  Scopes: ``attention`` (the
-sublayer with its norm) with ``eva_pool`` (the summaries; on a TPU a
+The stack, the skeleton of ``loss_fn`` and the rest of the engine protocol
+are ``models/decoder.py``'s; the eight-slice head (``head_loss``,
+``logprobs``) is this file's.  Scopes: ``attention`` (the sublayer with its
+norm) with ``eva_pool`` (the summaries; on a TPU a
 kernel pair under that name) and ``eva_attend`` (from q, k, v and the
 summaries to the mixed output; the kernel pair ``eva_attention``) inside, ``mlp``,
 ``embed``, ``head_ce``.  A step's counters: ``layer_applications``,
@@ -54,13 +54,16 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import eva
-from ..ops.attention.pallas_flash import SAVED_BY_REMAT
 from ..ops.transformer.cross_entropy import (multi_label_linear_cross_entropy,
                                              multi_label_logprobs)
 from ..ops.transformer.normalize import rms_norm
 from ..ops.transformer.rope import apply_rotary_pos_emb, rotary_tables
 from ..parallel.topology import BATCH_AXES
+from .decoder import Decoder, Stack, _dense
 from .gpt_neox import maybe_constrain
+
+
+EVA = "eva"
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -118,11 +121,6 @@ class EvaByteConfig:
         return EvaByteConfig(**dict(small, **kw))
 
 
-def _dense(width, cfg, name):
-    return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name,
-                    kernel_init=nn.initializers.normal(cfg.init_std))
-
-
 def _directions(key, shape, dtype=jnp.float32):
     """normal(0, 1) clipped to [-1, 1], times ``D^-1/2``."""
     return (jnp.clip(jax.random.normal(key, shape, dtype), -1.0, 1.0)
@@ -146,8 +144,8 @@ class EvaAttention(nn.Module):
         cfg = self.config
         B, S, _ = u.shape
         n, d = cfg.heads, cfg.head_dim
-        q, k, v = (_dense(n * d, cfg, name)(u).reshape(B, S, n, d)
-                   for name in ("q_proj", "k_proj", "v_proj"))
+        q, k, v = (_dense(n * d, cfg, name, cfg.init_std)(u).reshape(
+            B, S, n, d) for name in ("q_proj", "k_proj", "v_proj"))
         mu = self.param("adaptive_mu_k", _directions, (n, d), jnp.float32)
         phi = self.param("adaptive_phi", _directions, (n, d), jnp.float32)
         cos, sin = rotary_tables(jnp.arange(S)[None], d, cfg.rope_theta,
@@ -160,7 +158,7 @@ class EvaAttention(nn.Module):
                                     cfg.chunk_size)
         with jax.named_scope("attention_layout"):
             out = out.reshape(B, S, n * d)
-        return _dense(cfg.hidden_size, cfg, "o_proj")(out)
+        return _dense(cfg.hidden_size, cfg, "o_proj", cfg.init_std)(out)
 
 
 class EvaMLP(nn.Module):
@@ -169,16 +167,22 @@ class EvaMLP(nn.Module):
     @nn.compact
     def __call__(self, u):
         cfg = self.config
-        f = cfg.intermediate_size
-        gate = _dense(f, cfg, "gate_proj")(u)
-        up = _dense(f, cfg, "up_proj")(u)
-        return _dense(cfg.hidden_size, cfg, "down_proj")(nn.silu(gate) * up)
+        f, std = cfg.intermediate_size, cfg.init_std
+        gate = _dense(f, cfg, "gate_proj", std)(u)
+        up = _dense(f, cfg, "up_proj", std)(u)
+        return _dense(cfg.hidden_size, cfg, "down_proj", std)(
+            nn.silu(gate) * up)
 
 
 class EvaByteBlock(nn.Module):
-    """``h = x + Attn(N1(x))``, ``y = h + MLP(N2(h))`` on a float32 stream."""
+    """``h = x + Attn(N1(x))``, ``y = h + MLP(N2(h))`` on a float32 stream
+    -> (y, nothing: no layer routes)."""
+
+    #: every layer is of the one kind
+    KINDS = frozenset((EVA,))
 
     config: EvaByteConfig
+    kind: str = EVA
 
     @nn.compact
     def __call__(self, x):
@@ -194,7 +198,7 @@ class EvaByteBlock(nn.Module):
                            (cfg.hidden_size,), jnp.float32)
             u = unit_offset_norm(x, g, cfg.rms_norm_eps, cfg.dtype)
             x = x + EvaMLP(cfg, name="mlp")(u).astype(jnp.float32)
-        return maybe_constrain(x, (BATCH_AXES, "sp", None))
+        return maybe_constrain(x, (BATCH_AXES, "sp", None)), {}
 
 
 def byte_targets(labels, k):
@@ -209,46 +213,25 @@ def byte_targets(labels, k):
             jnp.broadcast_to(inside, picked.shape))
 
 
-class EvaByte(nn.Module):
-    """Causal byte LM: ids [B, S] -> the closing norm's output [B, S, H] in
-    the compute dtype (the head is applied by the chunked cross entropy)."""
+class EvaByte(Decoder):
+    """Causal byte LM: ids [B, S] -> (the closing norm's output [B, S, H] in
+    the compute dtype, nothing: the head is applied by the chunked cross
+    entropy and no layer routes)."""
+
+    block_cls = EvaByteBlock
+    final_norm = ("final_norm_weight", nn.initializers.zeros,
+                  unit_offset_norm)
+    example_len = property(lambda self: 2 * self.config.window_size)
 
     config: EvaByteConfig
 
-    @nn.compact
-    def __call__(self, input_ids, **_):
+    def stack(self):
         cfg = self.config
-        with jax.named_scope("embed"):
-            # the stream starts, and stays, float32
-            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
-                         embedding_init=nn.initializers.normal(cfg.init_std),
-                         name="embed_tokens")(input_ids)
-        block = EvaByteBlock
-        if cfg.remat:
-            # a recomputed layer keeps the attention kernel's own two
-            # residuals (its output and one float a row), as the dense
-            # models' do; its float32 input is the checkpoint
-            block = nn.remat(
-                block, policy=jax.checkpoint_policies.save_only_these_names(
-                    *SAVED_BY_REMAT))
-        for i in range(cfg.layers):
-            x = block(cfg, name=f"layers_{i}")(x)
-        with jax.named_scope("head_ce"):    # the head, from its norm on
-            g = self.param("final_norm_weight", nn.initializers.zeros,
-                           (cfg.hidden_size,), jnp.float32)
-            x = unit_offset_norm(x, g, cfg.rms_norm_eps, cfg.dtype)
-            self.param("lm_head_kernel", nn.initializers.normal(cfg.init_std),
-                       (cfg.hidden_size, cfg.num_pred_heads * cfg.vocab_size),
-                       jnp.float32)
-        return x
-
-    # ------------------------------------------------------------ engine API
-    def example_batch(self, batch_size=2, seq_len=None, seed=0):
-        cfg = self.config
-        seq = seq_len or min(cfg.max_seq_len, 2 * cfg.window_size)
-        toks = jax.random.randint(jax.random.PRNGKey(seed),
-                                  (batch_size, seq + 1), 0, cfg.vocab_size)
-        return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}
+        # the stream starts, and stays, float32 (``Stack``'s default): a
+        # recomputed layer's float32 input is the checkpoint
+        return Stack(kinds=(EVA,) * cfg.layers, rows=cfg.vocab_size,
+                     columns=cfg.num_pred_heads * cfg.vocab_size,
+                     norm_eps=cfg.rms_norm_eps, init_std=cfg.init_std)
 
     def counters(self, batch, seq):
         """What a step's attention calls compute and what they need, (row,
@@ -269,7 +252,7 @@ class EvaByte(nn.Module):
         ``i`` [B, S, K] float32, 0 where the target lies past the end;
         which exist [B, S, K])."""
         cfg = self.config
-        hidden = self.apply({"params": params}, input_ids)
+        hidden, _ = self.apply({"params": params}, input_ids)
         want, inside = byte_targets(labels, cfg.num_pred_heads)
         with jax.named_scope("head_ce"):
             ll = multi_label_logprobs(
@@ -277,33 +260,24 @@ class EvaByte(nn.Module):
                 want.reshape(-1, cfg.num_pred_heads), cfg.ce_chunk_tokens)
         return jnp.where(inside, ll.reshape(want.shape), 0.0), inside
 
-    def loss_fn(self):
+    @nn.nowrap
+    def head_loss(self, hidden, kernel, batch):
         """Mean cross entropy over every (position, slice) pair whose target
         lies inside the sequence (and under ``loss_mask`` [B, S], a mask on
-        the TARGET's position) -> (loss, the step's counters)."""
+        the TARGET's position) -> (loss, the chunks the head's walk took)."""
         cfg = self.config
-
-        def loss(params, batch, rng=None, **_):
-            ids, labels = batch["input_ids"], batch["labels"]
-            hidden = self.apply({"params": params}, ids)
-            with jax.named_scope("head_ce"):
-                want, inside = byte_targets(labels, cfg.num_pred_heads)
-                mask = inside.astype(jnp.float32)
-                if batch.get("loss_mask") is not None:
-                    mask = mask * byte_targets(
-                        batch["loss_mask"].astype(jnp.float32),
-                        cfg.num_pred_heads)[0]
-                weights = -mask / jnp.maximum(jnp.sum(mask), 1.0)
-                ce, chunks = multi_label_linear_cross_entropy(
-                    hidden.reshape(-1, cfg.hidden_size),
-                    params["lm_head_kernel"],
-                    want.reshape(-1, cfg.num_pred_heads),
-                    weights.reshape(-1, cfg.num_pred_heads),
-                    cfg.ce_chunk_tokens)
-            return ce, jax.lax.stop_gradient(dict(
-                self.counters(*ids.shape), head_chunks=chunks))
-
-        return loss
+        want, inside = byte_targets(batch["labels"], cfg.num_pred_heads)
+        mask = inside.astype(jnp.float32)
+        if batch.get("loss_mask") is not None:
+            mask = mask * byte_targets(
+                batch["loss_mask"].astype(jnp.float32),
+                cfg.num_pred_heads)[0]
+        weights = -mask / jnp.maximum(jnp.sum(mask), 1.0)
+        ce, chunks = multi_label_linear_cross_entropy(
+            hidden.reshape(-1, cfg.hidden_size), kernel,
+            want.reshape(-1, cfg.num_pred_heads),
+            weights.reshape(-1, cfg.num_pred_heads), cfg.ce_chunk_tokens)
+        return ce, {"head_chunks": chunks}
 
     def no_cast_paths(self):
         """Float32 under mixed precision: the embedding table (it feeds the

@@ -18,9 +18,9 @@ mechanisms Mellum has not:
   and the routed weights are scaled by ``moe_routed_scaling_factor``.
 
 The attention sublayer (into the flash kernel, window and full), ``Rope``,
-the routed walk (``MellumMoE`` over ``moe/dropless.py``), the chunked head +
-cross entropy and the engine protocol are Mellum's own code, imported.  The
-equations, and what the published ``config.json`` leaves to assumption, are
+the routed walk (``MellumMoE`` over ``moe/dropless.py``) and what ``Mellum``
+states of its stack (``models/decoder.py``) are Mellum's own code, imported.
+The equations, and what the published ``config.json`` leaves to assumption, are
 in ``benchmarks/reference/laguna_ref.py``.
 
 A chip's share is told: ``layers_held`` layers from ``first_layer_held``,
@@ -47,13 +47,13 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..moe import dropless
 from ..ops.attention.pallas_flash import band_pairs
 from ..ops.transformer.normalize import rms_norm
 from ..parallel.topology import BATCH_AXES
+from .decoder import _dense
 from .gpt_neox import maybe_constrain
 from .mellum import (FULL, SCOPE_OF, SLIDING, Mellum, MellumAttention,
-                     MellumMoE, Rope, _dense)
+                     MellumMoE, Rope)
 
 DENSE, SPARSE = "dense", "sparse"
 #: a full layer and three windowed ones, twelve times; layer 0's MLP dense
@@ -238,32 +238,23 @@ class LagunaBlock(nn.Module):
 
 class Laguna(Mellum):
     """Causal LM: tokens [B, S] -> (the closing norm's output [B, S, H],
-    each layer's counters and chosen-here mask).  The stack, the head, the
-    loss and the rest of the engine protocol are ``Mellum``'s."""
+    each sparse layer's counters and chosen-here mask)."""
 
     block_cls = LagunaBlock
 
     config: LagunaConfig
 
-    def _hidden(self, params, input_ids):
-        """The stack to the final norm -> (hidden [B, S, H], which held
-        experts each token chose in each SPARSE layer [sparse layers, B, S,
-        held], counters of what ran on the device)."""
+    def counters(self, batch, seq):
         kinds = self.config.kinds
-        hidden, told = self.apply({"params": params}, input_ids)
-        told = [t for t in told if t]
 
         def count(at, value):
             return jnp.int32(sum(1 for kind in kinds if kind[at] == value))
 
-        counters = {
-            "window_layer_applications": count(0, SLIDING),
-            "full_layer_applications": count(0, FULL),
-            "dense_mlp_layer_applications": count(1, DENSE),
-            "moe_layer_applications": count(1, SPARSE),
-            "shared_expert_layer_applications": count(1, SPARSE),
-            **dropless.load_counters([t["counters"] for t in told])}
-        return hidden, jnp.stack([t["chosen"] for t in told]), counters
+        return {"window_layer_applications": count(0, SLIDING),
+                "full_layer_applications": count(0, FULL),
+                "dense_mlp_layer_applications": count(1, DENSE),
+                "moe_layer_applications": count(1, SPARSE),
+                "shared_expert_layer_applications": count(1, SPARSE)}
 
     def param_partition_rules(self):
         """Megatron-style tp placement: attention by heads (the gate's

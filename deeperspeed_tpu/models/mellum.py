@@ -31,10 +31,9 @@ tables.  Experts are independent, so the shares' partial outputs add up to
 the whole layer's (router, norms and attention are every chip's alike and
 count once): ``tests/unit/models/test_mellum.py``.
 
-The same engine protocol as the other models (``loss_fn`` /
-``example_batch`` / ``param_partition_rules`` / ``num_params`` /
-``flops_per_token`` / ``no_cast_paths``).  Scopes: ``attention`` (a layer's
-attention sublayer with its norm) with ``attention_window`` or
+The stack, the routed layers' report, the head + loss and the rest of the
+engine protocol are ``models/decoder.py``'s.  Scopes: ``attention`` (a
+layer's attention sublayer with its norm) with ``attention_window`` or
 ``attention_full`` inside by kind, ``mlp`` with ``moe_route`` and
 ``moe_experts`` inside, ``embed``, ``head_ce``.
 """
@@ -49,13 +48,12 @@ from jax.sharding import PartitionSpec as P
 
 from ..moe import dropless
 from ..ops.attention.core import dot_product_attention
-from ..ops.attention.pallas_flash import SAVED_BY_REMAT, band_pairs
-from ..ops.transformer.cross_entropy import (chunked_linear_cross_entropy,
-                                             mean_linear_cross_entropy)
+from ..ops.attention.pallas_flash import band_pairs
 from ..ops.transformer.normalize import rms_norm
 from ..ops.transformer.rope import (apply_rotary_pos_emb, rotary_tables,
                                     yarn_inv_freq)
 from ..parallel.topology import BATCH_AXES
+from .decoder import Decoder, Stack, _dense
 from .gpt_neox import maybe_constrain
 
 SLIDING, FULL = "sliding_attention", "full_attention"
@@ -154,11 +152,6 @@ class MellumConfig:
             routed_experts_held=4, first_expert_held=4, max_seq_len=64,
             ce_chunk_tokens=48)
         return MellumConfig(**dict(small, **kw))
-
-
-def _dense(width, cfg, name):
-    return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
 
 
 class MellumAttention(nn.Module):
@@ -269,95 +262,28 @@ class MellumBlock(nn.Module):
                 {"counters": counters, "chosen": chosen})
 
 
-class Mellum(nn.Module):
+class Mellum(Decoder):
     """Causal LM: tokens [B, S] -> (the closing norm's output [B, S, H],
     each layer's counters and chosen-here mask)."""
 
-    #: the class of a layer, made with (configuration, an entry of the
-    #: configuration's ``kinds``); a sibling model names its own
+    #: a sibling model names its own
     block_cls = MellumBlock
+    #: and the grouped walk's plan: its sorts are made once a step
+    saved_by_remat = Decoder.saved_by_remat + (dropless.PLAN_SAVED_BY_REMAT,)
 
     config: MellumConfig
 
-    @nn.compact
-    def __call__(self, input_ids, **_):
+    def stack(self):
         cfg = self.config
-        if set(cfg.kinds) - self.block_cls.KINDS:
-            raise ValueError(f"layer_types {cfg.kinds!r}: "
-                             f"{sorted(self.block_cls.KINDS)}")
-        with jax.named_scope("embed"):
-            x = nn.Embed(cfg.vocab_rows, cfg.hidden_size, dtype=cfg.dtype,
-                         embedding_init=nn.initializers.normal(0.02),
-                         name="embed_tokens")(input_ids)
-        block = self.block_cls
-        if cfg.remat:
-            # a recomputed layer keeps the flash kernel's own two residuals
-            # (windowed calls name theirs alike), as the dense models' do,
-            # and the grouped walk's plan: its sorts are made once a step
-            block = nn.remat(
-                block,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *SAVED_BY_REMAT, dropless.PLAN_SAVED_BY_REMAT))
-        told = []
-        for i, kind in enumerate(cfg.kinds):
-            x, said = block(cfg, kind, name=f"layers_{i}")(x)
-            told.append(said)
-        with jax.named_scope("head_ce"):    # the head, from its norm on
-            scale = self.param("final_norm_scale", nn.initializers.ones,
-                               (cfg.hidden_size,), jnp.float32)
-            x = rms_norm(x, scale, eps=cfg.rms_norm_eps)
-            # the head's weights are applied by the chunked cross entropy
-            self.param("lm_head_kernel", nn.initializers.normal(0.02),
-                       (cfg.hidden_size, cfg.vocab_rows), jnp.float32)
-        return x, told
+        return Stack(kinds=cfg.kinds, rows=cfg.vocab_rows,
+                     columns=cfg.vocab_rows, norm_eps=cfg.rms_norm_eps,
+                     table_dtype=cfg.dtype)
 
-    # ------------------------------------------------------------ engine API
-    def example_batch(self, batch_size=2, seq_len=None, seed=0):
-        seq = seq_len or min(self.config.max_seq_len, 128)
-        toks = jax.random.randint(jax.random.PRNGKey(seed),
-                                  (batch_size, seq + 1), 0,
-                                  self.config.vocab_rows)
-        return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}
-
-    def _hidden(self, params, input_ids):
-        """The stack to the final norm -> (hidden [B, S, H], which held
-        experts each token chose in each layer [layers, B, S, held], counters
-        of what ran on the device)."""
-        cfg = self.config
-        hidden, told = self.apply({"params": params}, input_ids)
-        counters = {
-            "window_layer_applications": jnp.int32(cfg.kinds.count(SLIDING)),
-            "full_layer_applications": jnp.int32(cfg.kinds.count(FULL)),
-            "moe_layer_applications": jnp.int32(len(told)),
-            **dropless.load_counters([t["counters"] for t in told])}
-        return hidden, jnp.stack([t["chosen"] for t in told]), counters
-
-    def logprobs(self, params, input_ids, labels):
-        """The training path's forward, for a check that wants every token's
-        value -> (log-probability of ``labels`` [B, S] float32, the chosen
-        held experts and the counters of ``_hidden``)."""
-        cfg = self.config
-        hidden, chosen, counters = self._hidden(params, input_ids)
-        with jax.named_scope("head_ce"):
-            token_ll = chunked_linear_cross_entropy(
-                hidden.reshape(-1, cfg.hidden_size), params["lm_head_kernel"],
-                labels.reshape(-1), cfg.ce_chunk_tokens)
-        return token_ll.reshape(labels.shape), chosen, counters
-
-    def loss_fn(self):
-        """Mean next-token cross entropy -> (loss, the step's counters:
-        layer applications by kind and the expert layers' load)."""
-        cfg = self.config
-
-        def loss(params, batch, rng=None, **_):
-            hidden, _, counters = self._hidden(params, batch["input_ids"])
-            with jax.named_scope("head_ce"):
-                ce = mean_linear_cross_entropy(
-                    hidden, params["lm_head_kernel"], batch["labels"],
-                    batch.get("loss_mask"), cfg.ce_chunk_tokens)
-            return ce, jax.lax.stop_gradient(counters)
-
-        return loss
+    def counters(self, batch, seq):
+        kinds = self.config.kinds
+        return {"window_layer_applications": jnp.int32(kinds.count(SLIDING)),
+                "full_layer_applications": jnp.int32(kinds.count(FULL)),
+                "moe_layer_applications": jnp.int32(len(kinds))}
 
     def no_cast_paths(self):
         """Float32 under mixed precision: the embedding table (its gradient

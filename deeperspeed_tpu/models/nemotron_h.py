@@ -32,12 +32,11 @@ are independent, so the shares' partial mixer outputs add up to the whole
 layer's (the router, the latent projections and the shared expert are every
 chip's alike and count once): ``tests/unit/models/test_nemotron_h.py``.
 
-The same engine protocol as the other models (``loss_fn`` / ``example_batch``
-/ ``param_partition_rules`` / ``num_params`` / ``flops_per_token`` /
-``no_cast_paths``).  With layers of several kinds, parameters, FLOPs, remat
-and the named scopes all go by layer kind: ``ssm`` (with ``ssm_scan``
-inside), ``mlp`` (with ``moe_route``, ``moe_experts``, ``moe_shared``
-inside) and ``attention``.
+The stack, the routed layers' report, the head + loss and the rest of the
+engine protocol are ``models/decoder.py``'s.  With layers of several kinds,
+parameters, FLOPs and the named scopes all go by layer kind: ``ssm`` (with
+``ssm_scan`` inside), ``mlp`` (with ``moe_route``, ``moe_experts``,
+``moe_shared`` inside) and ``attention``.
 """
 
 import dataclasses
@@ -51,12 +50,10 @@ from jax.sharding import PartitionSpec as P
 
 from ..moe import dropless
 from ..ops.attention.core import dot_product_attention
-from ..ops.attention.pallas_flash import SAVED_BY_REMAT
 from ..ops.ssm import causal_depthwise_conv1d, gated_group_rms_norm, ssd_scan
-from ..ops.transformer.cross_entropy import (chunked_linear_cross_entropy,
-                                             mean_linear_cross_entropy)
 from ..ops.transformer.normalize import rms_norm
 from ..parallel.topology import BATCH_AXES
+from .decoder import Decoder, Stack, _dense
 from .gpt_neox import maybe_constrain
 
 SUPER_PATTERN = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -134,11 +131,6 @@ class NemotronHConfig:
             first_expert=4, experts_held=4,
             max_seq_len=64, ce_chunk_tokens=48)
         return NemotronHConfig(**dict(small, **kw))
-
-
-def _dense(width, cfg, name):
-    return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name,
-                    kernel_init=nn.initializers.normal(0.02))
 
 
 def _uniform(bound):
@@ -261,6 +253,9 @@ class NemotronHBlock(nn.Module):
     """``x + Mixer(RMSNorm(x))`` for one letter of the pattern -> (x, what
     an E layer's routed walk counted, else nothing)."""
 
+    #: the letters of ``NemotronHConfig.pattern``
+    KINDS = frozenset(KINDS)
+
     config: NemotronHConfig
     kind: str = "M"
 
@@ -273,8 +268,8 @@ class NemotronHBlock(nn.Module):
             scale = self.param("norm_scale", nn.initializers.ones,
                                (cfg.hidden_size,), jnp.float32)
             # in the stream's own type: the first layer reads the float32
-            # embedding (``NemotronH.__call__``), every later one the
-            # compute type
+            # embedding (``NemotronH.stack``), every later one the compute
+            # type
             u = rms_norm(x, scale, eps=cfg.norm_eps)
             if self.kind == "M":
                 y = MambaMixer(cfg, name="mixer")(u)
@@ -287,103 +282,35 @@ class NemotronHBlock(nn.Module):
         return maybe_constrain(x, (BATCH_AXES, "sp", None)), told
 
 
-class NemotronH(nn.Module):
+class NemotronH(Decoder):
     """Hybrid causal LM: tokens [B, S] -> (the closing norm's output
     [B, S, H], each E layer's counters and chosen-here mask)."""
 
+    block_cls = NemotronHBlock
+
     config: NemotronHConfig
 
-    @nn.compact
-    def __call__(self, input_ids, **_):
+    def stack(self):
         cfg = self.config
-        if set(cfg.pattern) - set(KINDS):
-            raise ValueError(f"pattern {cfg.pattern!r}: letters are M, E, *")
-        with jax.named_scope("embed"):
-            # the table's rows reach the first layer in float32 and the
-            # stream takes the compute type with that layer's output.  Where
-            # the first layer is an expert layer it routes on raw embeddings:
-            # every token of one id has the same scores, so a rounding that
-            # swaps an id's 22nd and 23rd expert moves ALL its tokens at once
-            # (a hot id is a tenth of a batch); float32 scores of a float32
-            # input keep the choice the float32 arithmetic's (PERF.md, PR 34)
-            x = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=jnp.float32,
-                         embedding_init=nn.initializers.normal(0.02),
-                         name="embed_tokens")(input_ids)
-        block = NemotronHBlock
-        if cfg.remat:
-            # a recomputed attention layer keeps the flash kernel's own two
-            # residuals, as the dense models' blocks do
-            block = nn.remat(
-                NemotronHBlock,
-                policy=jax.checkpoint_policies.save_only_these_names(
-                    *SAVED_BY_REMAT))
-        told = []
-        for i, kind in enumerate(cfg.pattern):
-            x, said = block(cfg, kind, name=f"layers_{i}")(x)
-            if said:
-                told.append(said)
-        with jax.named_scope("head_ce"):    # the head, from its norm on
-            scale = self.param("final_norm_scale", nn.initializers.ones,
-                               (cfg.hidden_size,), jnp.float32)
-            x = rms_norm(x.astype(cfg.dtype), scale, eps=cfg.norm_eps)
-            # the head's weights are applied by the chunked cross entropy
-            self.param("lm_head_kernel", nn.initializers.normal(0.02),
-                       (cfg.hidden_size, cfg.vocab_size), jnp.float32)
-        return x, told
+        # the table's rows reach the first layer in float32 (``Stack``'s
+        # default) and the stream takes the compute type with that layer's
+        # output.  Where the first layer is an expert layer it routes on
+        # raw embeddings: every token of one id has the same scores, so a
+        # rounding that swaps an id's 22nd and 23rd expert moves ALL its
+        # tokens at once (a hot id is a tenth of a batch); float32 scores of
+        # a float32 input keep the choice the float32 arithmetic's (PERF.md,
+        # PR 34)
+        return Stack(kinds=tuple(cfg.pattern), rows=cfg.vocab_size,
+                     columns=cfg.vocab_size, norm_eps=cfg.norm_eps)
 
-    # ------------------------------------------------------------ engine API
-    def example_batch(self, batch_size=2, seq_len=None, seed=0):
-        seq = seq_len or min(self.config.max_seq_len, 128)
-        toks = jax.random.randint(jax.random.PRNGKey(seed),
-                                  (batch_size, seq + 1), 0,
-                                  self.config.vocab_size)
-        return {"input_ids": toks[:, :-1], "labels": toks[:, 1:]}
-
-    def _hidden(self, params, input_ids):
-        """The stack to the final norm -> (hidden [B, S, H], which held
-        experts each token chose in each E layer [layers, B, S, held],
-        counters of what ran on the device)."""
+    def counters(self, batch, seq):
         cfg = self.config
-        hidden, told = self.apply({"params": params}, input_ids)
-        counters = {
-            "ssm_layer_applications": jnp.int32(cfg.layers("M")),
-            "attention_layer_applications": jnp.int32(cfg.layers("*")),
-            "moe_layer_applications": jnp.int32(len(told))}
-        if told:
-            counters.update(dropless.load_counters(
-                [t["counters"] for t in told]))
-            chosen = jnp.stack([t["chosen"] for t in told])
-        else:
-            chosen = jnp.zeros((0,) + input_ids.shape + (cfg.experts_held,),
-                               bool)
-        return hidden, chosen, counters
+        return {"ssm_layer_applications": jnp.int32(cfg.layers("M")),
+                "attention_layer_applications": jnp.int32(cfg.layers("*")),
+                "moe_layer_applications": jnp.int32(cfg.layers("E"))}
 
-    def logprobs(self, params, input_ids, labels):
-        """The training path's forward, for a check that wants every token's
-        value -> (log-probability of ``labels`` [B, S] float32, the chosen
-        held experts and the counters of ``_hidden``)."""
-        cfg = self.config
-        hidden, chosen, counters = self._hidden(params, input_ids)
-        with jax.named_scope("head_ce"):
-            token_ll = chunked_linear_cross_entropy(
-                hidden.reshape(-1, cfg.hidden_size), params["lm_head_kernel"],
-                labels.reshape(-1), cfg.ce_chunk_tokens)
-        return token_ll.reshape(labels.shape), chosen, counters
-
-    def loss_fn(self):
-        """Mean next-token cross entropy -> (loss, the step's counters:
-        layer applications by kind and the expert layers' load)."""
-        cfg = self.config
-
-        def loss(params, batch, rng=None, **_):
-            hidden, _, counters = self._hidden(params, batch["input_ids"])
-            with jax.named_scope("head_ce"):
-                ce = mean_linear_cross_entropy(
-                    hidden, params["lm_head_kernel"], batch["labels"],
-                    batch.get("loss_mask"), cfg.ce_chunk_tokens)
-            return ce, jax.lax.stop_gradient(counters)
-
-        return loss
+    def none_chosen(self, shape):
+        return jnp.zeros((0,) + shape + (self.config.experts_held,), bool)
 
     def no_cast_paths(self):
         """Float32 under mixed precision: the embedding table (its gradient

@@ -82,7 +82,7 @@ def test_logits_of_all_eight_slices_loss_and_gradients(seed):
     1e-5 of the gradient's norm."""
     model = runner.program_model(TINY, TRAFFIC)
     params, (ids, labels) = _params(seed), _ids(seed)
-    hidden = model.apply({"params": params}, ids)
+    hidden = model.apply({"params": params}, ids)[0]
     got = (hidden @ params["lm_head_kernel"]).reshape(2, S, 8, -1)
     for b in range(2):
         want = ref.logits(params, TINY, ids[b])
@@ -200,7 +200,7 @@ def test_the_shares_of_the_heads_add_up_to_the_whole_layer():
     cfg = dict(whole_cfg, attention_heads_held=2, first_head_held=2)
     share = ref.take_heads(params, whole_cfg, 2, 2)["layers_1"]
     got_y = EvaByteBlock(runner.program_model(cfg, TRAFFIC).config).apply(
-        {"params": share}, x[None])[0]
+        {"params": share}, x[None])[0][0]
     with jax.default_matmul_precision("highest"):
         ref_y = ref._layer(x, share, cfg, jnp.arange(S), "float32", "float32",
                            ())
@@ -272,12 +272,12 @@ def test_the_stream_the_statistics_and_the_logits_stay_float32():
     cfg = model.config
     params = _params(2)
     x = jnp.ones((1, S, 64), jnp.float32)
-    y = EvaByteBlock(cfg).apply({"params": params["layers_0"]}, x)
+    y = EvaByteBlock(cfg).apply({"params": params["layers_0"]}, x)[0]
     assert y.dtype == jnp.float32
     jaxpr = str(jax.make_jaxpr(lambda p, x: EvaByteBlock(cfg).apply(
-        {"params": p}, x))(params["layers_0"], x))
+        {"params": p}, x)[0])(params["layers_0"], x))
     assert "bf16[1,192,64]" in jaxpr and "f32[1,192,64]" in jaxpr
-    hidden = model.apply({"params": params}, jnp.zeros((1, S), jnp.int32))
+    hidden = model.apply({"params": params}, jnp.zeros((1, S), jnp.int32))[0]
     assert hidden.dtype == jnp.bfloat16
     import re
     keep = [re.compile(p) for p in model.no_cast_paths()]
