@@ -1,0 +1,53 @@
+"""Model FLOP/s utilisation of the whole training step of a model with
+learned sparse attention over a routed mixture, at the median step time, at
+the shares this chip holds.  FLOPs a token by
+``reference/keye_ref.flops_per_token``: 6 x the matmul weights a token
+passes (attention's four projections, the indexer's three, the router, a
+routed expert per slot -- the slots from the program's own counter
+``moe_slots_held``, the mean over the window's steps, kept in the run's
+record by the runner -- and the head), plus, a (row, key) pair: over the
+CHOSEN pairs the main attention's scores and values and the loss's second
+``q . k``, over the CAUSAL pairs the indexer's scores; times tokens per step
+over the median step, over chips x the published bf16 peak.  What the walk
+computes beside the chosen pairs, and recomputed operations, do not count.
+The layers and the pairs selected are checked against what the program
+counted on the device, and a dropped slot refuses the number: where the
+program has no such counters, or they say otherwise, there is no number."""
+
+from benchmarks import core
+from benchmarks.reference import keye_ref as ref
+
+
+def flops_per_token(cfg, seq_len, tokens_per_step, counters):
+    """-> FLOPs a token, or None where the counters disagree with the
+    configuration or a slot was dropped."""
+    depth = ref.layers_held(cfg)
+    if (counters.get("dsa_layer_applications") != depth
+            or counters.get("moe_layer_applications") != depth
+            or counters.get("moe_slots_dropped") != 0):
+        return None
+    rows = tokens_per_step / seq_len
+    if counters.get("dsa_pairs_selected") != depth * rows * ref.pairs(
+            cfg, seq_len)[0]:
+        return None
+    return ref.flops_per_token(
+        cfg, seq_len, counters["moe_slots_held"] / tokens_per_step)
+
+
+def compute(record, trace):
+    ready = record.get("step_ready_at")
+    cfg = record.get("model_config", {})
+    if not ready or len(ready) < 3 or "sa_config" not in cfg:
+        return None
+    counters = record.get("step_counters")
+    if not counters:
+        return None
+    tokens_per_step = record["tokens"] / record["attempted"]
+    per_token = flops_per_token(cfg, record["seq_len"], tokens_per_step,
+                                counters)
+    if per_token is None:
+        return None
+    peak = core.device_peaks(record["device_kind"])["bf16_flops_per_s"]
+    step_s = core.median([b - a for a, b in zip(ready[:-1], ready[1:])])
+    return core.mfu_pct(per_token, tokens_per_step / step_s, record["chips"],
+                        peak)

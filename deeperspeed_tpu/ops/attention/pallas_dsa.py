@@ -1,0 +1,485 @@
+"""Pallas kernels of learned sparse attention (DSA: a lightning indexer
+scores every earlier key, a row keeps its ``topk`` best, one softmax over
+those): the selection of a row block, the attention over the chosen forward
++ backward, and the head-averaged probabilities the indexer's loss imitates.
+The equations are ``ops/attention/dsa.py``'s.
+
+On this chip the selection is a MASK inside a causal walk and not a gather:
+a row's 2048 keys are its own, and gathering them costs 4.2 MB a row against
+the 0.5 GB a layer the dense walk streams at 16k (PERF.md section 4).  So
+the kernels are ``pallas_flash``'s shape, grouped-query addressing as PR 50
+left it (query column group ``h`` reads KV column group ``h // rep`` of
+``[B, S, N_kv*D]`` where it lies), with one more operand:
+
+* the selection, packed: int32 words ``[B, Sp, W]``, bit ``j`` of word
+  ``(t, c)`` says that row ``t`` chose column ``j * W + c``.  ``W`` is the
+  walk's column chunk (``sel_layout``: whole 128-lane tiles, at most 32
+  chunks to the padded length ``Sp``; 512 at 16k), so the tile of rows ``i``
+  against chunk ``j`` reads its rows' words WHOLE, lane for lane, and
+  shifts them by ``j``: no relayout, 34 MB a layer at 16k where int8 is 268.
+  A program holds its row block's words while it walks the chunks.
+* a count of chosen pairs per (row block, chunk) tile, prefetched to SMEM:
+  a tile in which no row chose anything is neither computed nor loaded (its
+  block index names the last needed block again).  Everything above the
+  diagonal goes that way; inside the triangle few tiles do while the
+  indexer is untrained (``dsa_tiles_skipped``).
+
+Kernels, each under the scope that names it in a device trace and in
+``telemetry.kernel_passes()``:
+
+* ``dsa_select``: a block of rows of the indexer's scores, resident in
+  VMEM -> its words.  The ``k``-th largest of a row by bisection on the
+  float32 bit pattern (32 counting passes over VMEM), equal scores by
+  position (a second bisection, over the column), exact.
+* ``dsa_attention``: forward (online softmax over the chosen of a tile,
+  ``o`` and one float a row out), and a two-pass backward: dq over (row
+  block, chunks), dk/dv over (chunk, row blocks, query heads), the KV
+  head's sums in VMEM.
+* ``dsa_head_probs``: ``mean_h softmax_{S_t}(q_h . k)`` of a chunk of rows
+  from the saved log-sum-exp, the heads summed in VMEM: float32
+  ``[B, rows, Sp]``, zero outside the chosen.
+"""
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+
+from ..pallas_utils import LANES, NEG_INF, interpret_mode
+from .pallas_flash import (_NN, _NT, _TN, _params, _rows_off_lanes,
+                           _rows_onto_lanes, _vmem_limit)
+
+#: the scopes the kernels run under
+ATTENTION, SELECT, HEAD_PROBS = "dsa_attention", "dsa_select", "dsa_head_probs"
+_INT_MIN = -(1 << 31)
+
+
+class SelLayout(NamedTuple):
+    """How a length's selection is packed and walked (rows)."""
+    chunk: int      # W: columns of a chunk = words of a row
+    chunks: int     # n <= 32: bits used of a word
+    padded: int     # Sp = n * W
+    rows: int       # bq: rows of a grid program's block (divides Sp)
+
+
+def sel_layout(S):
+    chunk = max(LANES, -(-S // (32 * LANES)) * LANES)
+    chunks = -(-S // chunk)
+    padded = chunks * chunk
+    rows = next(r for r in (512, 256, LANES) if padded % r == 0)
+    return SelLayout(chunk, chunks, padded, rows)
+
+
+def compiles_for_tpu(head_dim):
+    """Whether the TPU compiler takes the attention kernels: a head a whole
+    lane block (any length is padded to whole tiles)."""
+    return head_dim % LANES == 0
+
+
+# ---------------------------------------------------------------- selection
+def sortable(scores):
+    """float32 -> int32 with the same order (no NaN): the bit pattern, the
+    negatives' magnitude bits flipped."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return bits ^ ((bits >> 31) & 0x7FFFFFFF)
+
+
+def masked_key(scores, row0, col0, seq):
+    """A tile of scores (rows from ``row0``, columns from ``col0``) -> its
+    sortable keys, ``INT_MIN`` where the row does not see the column."""
+    t = row0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 0)
+    col = col0 + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    return jnp.where((col <= t) & (t < seq), sortable(scores), _INT_MIN)
+
+
+def select_rows(key, chunks, shape, row0, seq, topk):
+    """``key(c)``: the masked keys ``[rows, W]`` of a block of rows (from
+    ``row0``) against chunk ``c`` -> the block's words ``[rows, W]``: row
+    ``t`` keeps the ``min(t + 1, topk)`` largest of its columns ``s <= t``,
+    of equal scores the lower column first; a row at or past ``seq`` keeps
+    nothing.  Exact: the threshold is the k-th largest bit pattern, found
+    bit by bit by counting, and the equal scores are cut at the column
+    where their count is reached.  The same code on tiles in VMEM
+    (``_select_kernel``) and on slices of an array (``dsa.py``)."""
+    rows, w = shape
+    t = row0 + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    want = jnp.where(t < seq, jnp.minimum(t + 1, topk), 0).astype(jnp.float32)
+
+    def count(test):
+        """How many columns of each row pass ``test(key tile, chunk)``."""
+        hits = sum(jnp.where(test(key(c), c), 1.0, 0.0)
+                   for c in range(chunks))
+        return jnp.sum(hits, axis=1, keepdims=True)
+
+    def threshold(b, thr):
+        # bits 31 .. 0: adding 2**31 to INT_MIN wraps to 0
+        cand = thr + jnp.left_shift(jnp.int32(1), 31 - b)
+        return jnp.where(count(lambda k, c: k >= cand) >= want, cand, thr)
+
+    thr = jax.lax.fori_loop(0, 32, threshold,
+                            jnp.full((rows, 1), _INT_MIN, jnp.int32))
+    # of the equal ones the lowest columns, as many as are still short
+    short = want - count(lambda k, c: k > thr)
+    bits = max(1, (chunks * w - 1).bit_length())
+
+    def cut(b, at):
+        cand = at + jnp.left_shift(jnp.int32(1), bits - 1 - b)
+        before = count(lambda k, c: (k == thr) & (c * w + lane < cand))
+        return jnp.where(before < short, cand, at)
+
+    at = jax.lax.fori_loop(0, bits, cut, jnp.zeros((rows, 1), jnp.int32))
+    words = jnp.zeros(shape, jnp.int32)
+    for c in range(chunks):
+        k = key(c)
+        chosen = ((k > thr) | ((k == thr) & (c * w + lane <= at))) & (want > 0)
+        words = words | jnp.left_shift(chosen.astype(jnp.int32), c)
+    return words
+
+
+def unpack_rows(words, chunks):
+    """The words ``[..., rows, W]`` -> bool ``[..., rows, chunks * W]``."""
+    bits = (words[..., None, :] >> jnp.arange(chunks, dtype=jnp.int32)[
+        :, None]) & 1
+    return bits.reshape(*words.shape[:-2], words.shape[-2], -1).astype(bool)
+
+
+def _select_kernel(q_ref, k_ref, w_ref, words_ref, key_scr, *, rows, chunk,
+                   chunks, seq, topk, heads):
+    """A block of rows: its scores ``I[t, s] = sum_j w[t, j] relu(q_j[t] .
+    k[s])`` a chunk of columns at a time into VMEM as masked keys (the
+    chunks past the block's diagonal are seen by no row), then the
+    selection of the whole rows."""
+    i = pl.program_id(1)
+    row0 = i * rows
+    last = (row0 + rows - 1) // chunk       # the chunk the last row ends in
+
+    def scores(c, carry):
+        k = k_ref[0, pl.ds(pl.multiple_of(c * chunk, chunk), chunk), :]
+        acc = jnp.zeros((rows, chunk), jnp.float32)
+        for j in range(heads):
+            dots = jax.lax.dot_general(q_ref[0, j], k, _NT,
+                                       preferred_element_type=jnp.float32)
+            acc = acc + w_ref[0, :, j:j + 1] * jnp.maximum(dots, 0.0)
+        key_scr[c] = masked_key(acc, row0, c * chunk, seq)
+        return carry
+
+    key_scr[...] = jnp.full_like(key_scr, _INT_MIN)
+    jax.lax.fori_loop(0, last + 1, scores, 0)
+    words_ref[0] = select_rows(lambda c: key_scr[c], chunks, (rows, chunk),
+                               row0, seq, topk)
+
+
+def select_call(qi, ki, w, seq, topk, layout):
+    """The indexer's queries ``[B, H_I, Sp, D_I]``, its keys ``[B, Sp,
+    D_I]`` and weights ``[B, Sp, H_I]`` float32 -> words ``[B, Sp, W]``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, sp, d = qi.shape
+    # a block of rows whose keys for the whole length stay in VMEM
+    rows = min(LANES, layout.rows)
+    need = (6 * rows * sp * 4 + 2 * sp * d * ki.dtype.itemsize
+            + 4 * rows * heads * d * qi.dtype.itemsize)
+    with jax.named_scope(SELECT):
+        return pl.pallas_call(
+            functools.partial(_select_kernel, rows=rows, chunk=layout.chunk,
+                              chunks=layout.chunks, seq=seq, topk=topk,
+                              heads=heads),
+            grid=(b, sp // rows),
+            in_specs=[
+                pl.BlockSpec((1, heads, rows, d), lambda b, i: (b, 0, i, 0)),
+                pl.BlockSpec((1, sp, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, rows, heads), lambda b, i: (b, i, 0))],
+            out_specs=pl.BlockSpec((1, rows, layout.chunk),
+                                   lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct((b, sp, layout.chunk), jnp.int32),
+            scratch_shapes=[pltpu.VMEM((layout.chunks, rows, layout.chunk),
+                                       jnp.int32)],
+            interpret=interpret_mode(),
+            **_params("parallel", "parallel", vmem=_vmem_limit(need)),
+        )(qi, ki, w)
+
+
+# -------------------------------------------------- attention over the chosen
+def _chosen(words_ref, j):
+    """Which pairs of the tile (the block's rows, chunk ``j``) are chosen."""
+    return (words_ref[0] & jnp.left_shift(jnp.int32(1), j)) != 0
+
+
+def _tile_count(counts_ref, b, i, j, nq, n):
+    return counts_ref[(b * nq + i) * n + j]
+
+
+def _fwd_kernel(counts_ref, q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, nq, n):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
+    def _tile():
+        # q arrives pre-scaled.  A row that has met no chosen column yet
+        # holds exp(0) of its masked ones; its first chosen column's alpha
+        # is exp(NEG_INF - m) = 0 and wipes them (every real row chose one)
+        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
+                                preferred_element_type=jnp.float32)
+        s = jnp.where(_chosen(words_ref, j), s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
+            l_scr.shape)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v_ref.dtype), v_ref[0], _NN,
+            preferred_element_type=jnp.float32)
+
+    @pl.when(j == n - 1)
+    def _finalize():
+        l = l_scr[:, :1]
+        l = jnp.where(l == 0.0, 1.0, l)         # rows past the length
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+        lse_ref[0] = _rows_onto_lanes(m_scr[:, :1] + jnp.log(l))
+
+
+def _need(layout, d, itemsize, tiles):
+    """VMEM a program of the attention kernels holds: its blocks
+    double-buffered (rows of q-like operands, a chunk of k and v, the words)
+    and ``tiles`` float32 score-sized temporaries."""
+    bq, w = layout.rows, layout.chunk
+    return (2 * (4 * bq + 2 * w) * d * itemsize + 2 * bq * w * 4
+            + tiles * bq * w * 4 + 4 * bq * LANES * 4 + bq * d * 4)
+
+
+def _specs(layout, heads, rep, d):
+    """Block specs of the grids ``(b, h, i, j)`` whose programs own a row
+    block ``i`` of query head ``h`` and walk the chunks ``j``: a chunk past
+    the block's diagonal names the diagonal's again (never loaded)."""
+    bq, w = layout.rows, layout.chunk
+
+    def near(i, j):
+        return jnp.minimum(j, (i * bq + bq - 1) // w)
+
+    rows = pl.BlockSpec((1, bq, d), lambda b, h, i, j, *_: (b, i, h))
+    cols = pl.BlockSpec((1, w, d),
+                        lambda b, h, i, j, *_: (b, near(i, j), h // rep))
+    words = pl.BlockSpec((1, bq, w), lambda b, h, i, j, *_: (b, i, 0))
+    stat = pl.BlockSpec((1, 1, bq),
+                        lambda b, h, i, j, *_: (b * heads + h, 0, i))
+    return rows, cols, words, stat
+
+
+def fwd_call(q, k, v, words, counts, heads, layout):
+    """Pre-scaled q ``[B, Sp, N*D]``, k, v ``[B, Sp, N_kv*D]``, the words
+    and the tiles' counts ``[B * nq * n]`` -> (o, lse ``[B*N, 1, Sp]``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, sp, hw = q.shape
+    d = hw // heads
+    rep = hw // k.shape[2]
+    bq, n = layout.rows, layout.chunks
+    rows, cols, wspec, stat = _specs(layout, heads, rep, d)
+    pairs = b * heads * sp * sp // 2
+    return pl.pallas_call(
+        functools.partial(_fwd_kernel, nq=sp // bq, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, heads, sp // bq, n),
+            in_specs=[rows, cols, cols, wspec], out_specs=[rows, stat],
+            scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
+                            pltpu.VMEM((bq, LANES), jnp.float32),
+                            pltpu.VMEM((bq, d), jnp.float32)]),
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((b * heads, 1, sp), jnp.float32)],
+        cost_estimate=pl.CostEstimate(
+            flops=4 * pairs * d, transcendentals=pairs,
+            bytes_accessed=2 * q.size * q.dtype.itemsize),
+        interpret=interpret_mode(),
+        **_params("parallel", "parallel", "parallel", "arbitrary",
+                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 3))),
+    )(counts, q, k, v, words)
+
+
+def _probs(q_ref, k_ref, words_ref, lse, j):
+    """A tile's probabilities from the rows' saved log-sum-exp: zero where
+    the row did not choose the column."""
+    s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
+                            preferred_element_type=jnp.float32)
+    return jnp.where(_chosen(words_ref, j), jnp.exp(s - lse), 0.0)
+
+
+def _dq_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               words_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, nq, n):
+    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+
+    @pl.when(j == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        lse_scr[...] = _rows_off_lanes(lse_ref[0])
+        delta_scr[...] = _rows_off_lanes(delta_ref[0])
+
+    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
+    def _tile():
+        p = _probs(q_ref, k_ref, words_ref, lse_scr[:, :1], j)
+        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta_scr[:, :1])).astype(k_ref.dtype)
+        dq_scr[...] += jax.lax.dot_general(
+            ds, k_ref[0], _NN, preferred_element_type=jnp.float32)
+
+    @pl.when(j == n - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                words_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, nq, n, rep, d):
+    """Grid (b, chunk j, row block i, query head h): the words of tile
+    (i, j) are read once for all the heads, and the KV heads' dk and dv of
+    the chunk are summed in VMEM over the row blocks and the groups."""
+    b, j, i, h = (pl.program_id(a) for a in range(4))
+
+    @pl.when((i == 0) & (h == 0))
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
+    def _tile():
+        g = h // rep
+        lse = _rows_off_lanes(lse_ref[0])[:, :1]
+        delta = _rows_off_lanes(delta_ref[0])[:, :1]
+        q, do = q_ref[0], do_ref[0]
+        p = _probs(q_ref, k_ref, words_ref, lse, j)
+        dv_scr[g] += jax.lax.dot_general(p.astype(do.dtype), do, _TN,
+                                         preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v_ref[0], _NT,
+                                 preferred_element_type=jnp.float32)
+        ds = (p * (dp - delta)).astype(q.dtype)
+        dk_scr[g] += jax.lax.dot_general(ds, q, _TN,
+                                         preferred_element_type=jnp.float32)
+
+    @pl.when((i == nq - 1) & (h == pl.num_programs(3) - 1))
+    def _finalize():
+        for g in range(dk_scr.shape[0]):
+            cols = pl.ds(g * d, d)
+            dk_ref[0, :, cols] = dk_scr[g].astype(dk_ref.dtype)
+            dv_ref[0, :, cols] = dv_scr[g].astype(dv_ref.dtype)
+
+
+def bwd_call(q, k, v, do, lse, delta, words, counts, heads, layout):
+    """-> (dq of the pre-scaled q, dk, dv), in two passes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, sp, hw = q.shape
+    d = hw // heads
+    kv = k.shape[2] // d
+    rep = heads // kv
+    bq, w, n = layout.rows, layout.chunk, layout.chunks
+    nq = sp // bq
+    rows, cols, wspec, stat = _specs(layout, heads, rep, d)
+    dq = pl.pallas_call(
+        functools.partial(_dq_kernel, nq=nq, n=n),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, heads, nq, n),
+            in_specs=[rows, cols, cols, rows, stat, stat, wspec],
+            out_specs=rows,
+            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
+                            pltpu.VMEM((bq, LANES), jnp.float32),
+                            pltpu.VMEM((bq, LANES), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        interpret=interpret_mode(),
+        **_params("parallel", "parallel", "parallel", "arbitrary",
+                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5))),
+    )(counts, q, k, v, do, lse, delta, words)
+
+    def far(i, j):      # a row block before the chunk's first: never loaded
+        return jnp.maximum(i, (j * w) // bq)
+
+    rows_j = pl.BlockSpec((1, bq, d),
+                          lambda b, j, i, h, *_: (b, far(i, j), h))
+    cols_j = pl.BlockSpec((1, w, d), lambda b, j, i, h, *_: (b, j, h // rep))
+    words_j = pl.BlockSpec((1, bq, w),
+                           lambda b, j, i, h, *_: (b, far(i, j), 0))
+    stat_j = pl.BlockSpec(
+        (1, 1, bq), lambda b, j, i, h, *_: (b * heads + h, 0, far(i, j)))
+    whole = pl.BlockSpec((1, w, kv * d), lambda b, j, i, h, *_: (b, j, 0))
+    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
+    dk, dv = pl.pallas_call(
+        functools.partial(_dkv_kernel, nq=nq, n=n, rep=rep, d=d),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(b, n, nq, heads),
+            in_specs=[rows_j, cols_j, cols_j, rows_j, stat_j, stat_j,
+                      words_j],
+            out_specs=[whole, whole],
+            scratch_shapes=[pltpu.VMEM((kv, w, d), jnp.float32),
+                            pltpu.VMEM((kv, w, d), jnp.float32)]),
+        out_shape=[kv_shape, kv_shape],
+        interpret=interpret_mode(),
+        **_params("parallel", "parallel", "arbitrary", "arbitrary",
+                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5)
+                                   + 4 * kv * w * d * 4)),
+    )(counts, q, k, v, do, lse, delta, words)
+    return dq, dk, dv
+
+
+# ------------------------------------------------ head-averaged probabilities
+def _head_probs_kernel(counts_ref, at_ref, q_ref, k_ref, lse_ref, words_ref,
+                       out_ref, *, nq, n, heads):
+    """Grid (b, row block i of the chunk of rows, chunk j, head h): the
+    heads' probabilities of a tile summed where the output block lies."""
+    b, i, j, h = (pl.program_id(a) for a in range(4))
+
+    @pl.when(h == 0)
+    def _init():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(_tile_count(counts_ref, b, at_ref[0] + i, j, nq, n) > 0)
+    def _tile():
+        lse = _rows_off_lanes(lse_ref[0])[:, :1]
+        out_ref[0] += _probs(q_ref, k_ref, words_ref, lse, j) * (1.0 / heads)
+
+
+def head_probs_call(q, k, lse, words, counts, at, rows, heads, layout):
+    """``mean_h softmax_{S_t}(q_h . k)`` of the ``rows`` rows from row block
+    ``at`` (a traced int32 ``[1]``, in blocks of ``layout.rows``) ->
+    float32 ``[B, rows, Sp]``, zero where a row did not choose."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, sp, hw = q.shape
+    d = hw // heads
+    rep = hw // k.shape[2]
+    bq, w, n = layout.rows, layout.chunk, layout.chunks
+
+    def near(i, j, at):
+        return jnp.minimum(j, ((at[0] + i) * bq + bq - 1) // w)
+
+    with jax.named_scope(HEAD_PROBS):
+        return pl.pallas_call(
+            functools.partial(_head_probs_kernel, nq=sp // bq, n=n,
+                              heads=heads),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b, rows // bq, n, heads),
+                in_specs=[
+                    pl.BlockSpec((1, bq, d), lambda b, i, j, h, c, at: (
+                        b, at[0] + i, h)),
+                    pl.BlockSpec((1, w, d), lambda b, i, j, h, c, at: (
+                        b, near(i, j, at), h // rep)),
+                    pl.BlockSpec((1, 1, bq), lambda b, i, j, h, c, at: (
+                        b * heads + h, 0, at[0] + i)),
+                    pl.BlockSpec((1, bq, w), lambda b, i, j, h, c, at: (
+                        b, at[0] + i, 0))],
+                out_specs=pl.BlockSpec((1, bq, w),
+                                       lambda b, i, j, h, c, at: (b, i, j))),
+            out_shape=jax.ShapeDtypeStruct((b, rows, sp), jnp.float32),
+            interpret=interpret_mode(),
+            **_params("parallel", "parallel", "parallel", "arbitrary",
+                      vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5))),
+        )(counts, at, q, k, lse, words)

@@ -65,3 +65,26 @@ def apply_rotary_pos_emb(q, k, cos, sin):
     k_rot = k_rot * cos + _rotate_half(k_rot) * sin
     return (jnp.concatenate([q_rot, q_pass], -1),
             jnp.concatenate([k_rot, k_pass], -1))
+
+
+def mrope_tables(positions, sections, rot_dim, base=10000, dtype=jnp.float32):
+    """cos/sin tables [B, seq, 1, rot_dim] of multi-axis rotary: positions
+    [axes, B, seq] (temporal, height, width), and frequency pair ``i`` of the
+    ``rot_dim / 2`` turns by ``base^(-2i/rot_dim)`` times the position on
+    the axis whose section ``i`` falls in (``sections``: pairs an axis, in
+    order, summing to ``rot_dim / 2``).  Where the axes' positions coincide
+    (a text token's) these are ``rotary_tables``'s."""
+    if sum(sections) != rot_dim // 2 or len(sections) != positions.shape[0]:
+        raise ValueError(f"sections {tuple(sections)} are not the "
+                         f"{rot_dim // 2} pairs of {positions.shape[0]} axes")
+    inv_freq = 1.0 / (base ** (jnp.arange(0, rot_dim, 2, dtype=jnp.float32)
+                               / rot_dim))
+    axis_of = jnp.repeat(jnp.arange(len(sections)), jnp.asarray(sections),
+                         total_repeat_length=rot_dim // 2)
+    # [B, seq, pairs]: each pair reads its own axis's position
+    at = jnp.take(jnp.moveaxis(positions.astype(jnp.float32), 0, -1),
+                  axis_of, axis=-1)
+    freqs = at * inv_freq
+    emb = jnp.concatenate([freqs, freqs], axis=-1)
+    return (jnp.cos(emb)[..., None, :].astype(dtype),
+            jnp.sin(emb)[..., None, :].astype(dtype))

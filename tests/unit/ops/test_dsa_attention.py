@@ -1,0 +1,173 @@
+"""Learned sparse attention (``ops/attention/dsa.py`` over
+``pallas_dsa.py``) at small sizes on the CPU, the kernels in interpret mode
+and the plain forms alike: the selection is exact (``min(t + 1, topk)`` a
+row, ties to the lower position, equal to a stable sort's); the attention
+over the chosen and the indexer's loss, forward and backward, are a naive
+``[B, N, S, S]`` computation's; the packed selection's counts."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deeperspeed_tpu.ops.attention import dsa, pallas_dsa
+
+FORMS = pytest.mark.parametrize("use_pallas", [False, True],
+                                ids=["plain", "kernels"])
+
+
+def _operands(seq, seed=0, B=2, N=4, KV=2, D=16, HI=3, DI=8):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 6)
+    q = jax.random.normal(keys[0], (B, seq, N, D))
+    k = jax.random.normal(keys[1], (B, seq, KV, D))
+    v = jax.random.normal(keys[2], (B, seq, KV, D))
+    qi = jax.random.normal(keys[3], (B, seq, HI, DI))
+    ki = jax.random.normal(keys[4], (B, seq, DI))
+    w = jax.random.normal(keys[5], (B, seq, HI))
+    return qi, ki, w, q, k, v
+
+
+def _scores(qi, ki, w):
+    return jnp.einsum("bjrs,brj->brs", jnp.maximum(
+        jnp.einsum("brjd,bsd->bjrs", qi, ki), 0.0), w)
+
+
+def _sorted_selection(scores, topk):
+    """Each row's ``min(t + 1, topk)`` largest of ``s <= t`` by a stable
+    sort: of equal scores the lower position first."""
+    S = scores.shape[-1]
+    causal = jnp.tril(jnp.ones((S, S), bool))
+    order = jnp.argsort(jnp.where(causal, -scores, jnp.inf), axis=-1,
+                        stable=True)
+    rank = jnp.argsort(order, axis=-1)
+    return (rank < jnp.minimum(jnp.arange(S) + 1, topk)[None, :, None]) \
+        & causal
+
+
+def _naive(qi, ki, w, q, k, v, topk):
+    B, S, N, D = q.shape
+    scores = _scores(qi, ki, w)
+    chosen = _sorted_selection(scores, topk)
+    kr, vr = (jnp.repeat(t, N // k.shape[2], axis=2) for t in (k, v))
+    s = jnp.einsum("bqnd,bknd->bnqk", q, kr) * D ** -0.5
+    p = jax.nn.softmax(jnp.where(chosen[:, None], s, -jnp.inf), -1)
+    o = jnp.einsum("bnqk,bknd->bqnd", p, vr)
+    pbar = jax.lax.stop_gradient(p.mean(1))
+    log_pi = jax.nn.log_softmax(jnp.where(chosen, scores, -jnp.inf), -1)
+    seen = chosen & (pbar > 0)
+    kl = jnp.sum(jnp.where(seen, pbar * (jnp.log(jnp.where(seen, pbar, 1.0))
+                                         - jnp.where(seen, log_pi, 0.0)),
+                           0.0)) / (B * S)
+    return chosen, o, kl
+
+
+def _program(qi, ki, w, q, k, v, topk, use_pallas):
+    sel = dsa.dsa_select(qi, ki, w, topk, use_pallas=use_pallas)
+    o, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=use_pallas)
+    kl = dsa.dsa_indexer_loss(qi, ki, w, q, k, lse, sel,
+                              use_pallas=use_pallas)
+    return sel, o, kl
+
+
+@FORMS
+@pytest.mark.parametrize("seq,topk", [(96, 16), (300, 40)],
+                         ids=["one-chunk", "three-chunks"])
+def test_the_selection_is_exact_and_a_stable_sorts(seq, topk, use_pallas):
+    """Exactly ``min(t + 1, topk)`` a row, inside the causal triangle, the
+    same set as a stable sort of the scores gives; planted ties (three keys
+    alike, so three columns of every row score alike) go to the lower
+    position."""
+    qi, ki, w, *_ = _operands(seq, seed=seq)
+    ki = ki.at[:, 5].set(ki[:, 3]).at[:, 20].set(ki[:, 3])
+    sel = jax.jit(lambda *a: dsa.dsa_select(*a, topk, use_pallas=use_pallas))(
+        qi, ki, w)
+    chosen = np.asarray(sel.mask())
+    want = np.asarray(_sorted_selection(_scores(qi, ki, w), topk))
+    np.testing.assert_array_equal(chosen, want)
+    np.testing.assert_array_equal(
+        chosen.sum(-1), np.broadcast_to(
+            np.minimum(np.arange(seq) + 1, topk), chosen.shape[:2]))
+    assert not np.triu(chosen, 1).any()
+    expected = 2 * int(np.minimum(np.arange(seq) + 1, topk).sum())
+    assert int(sel.pairs_selected()) == expected
+    # the tie: wherever a row chose the later of two equal keys it chose
+    # the earlier one too
+    assert (chosen[:, :, 3] >= chosen[:, :, 5]).all()
+    assert (chosen[:, :, 5] >= chosen[:, :, 20]).all()
+    tied = chosen[:, 21:, 3] != chosen[:, 21:, 20]
+    assert tied.any(), "no row's cut fell between the tied keys"
+
+
+def test_the_selection_counts_its_tiles():
+    """A tile's count is the chosen pairs of its rows against its chunk;
+    nothing above the diagonal; a pass visits the tiles some row chose in."""
+    qi, ki, w, *_ = _operands(300, seed=2)
+    sel = dsa.dsa_select(qi, ki, w, 40, use_pallas=False)
+    lay = sel.layout
+    assert (lay.chunk, lay.chunks, lay.padded, lay.rows) == (128, 3, 384, 128)
+    counts = np.asarray(sel.counts).reshape(2, 3, 3)
+    chosen = np.zeros((2, 384, 384), bool)
+    chosen[:, :300, :300] = np.asarray(sel.mask())
+    want = chosen.reshape(2, 3, 128, 3, 128).sum((2, 4))
+    np.testing.assert_array_equal(counts, want)
+    assert (np.triu(counts, 1) == 0).all()
+    assert int(sel.pairs_visited()) == int((counts > 0).sum()) * 128 * 128
+    assert int(sel.tiles_skipped()) == int((np.tril(counts) == 0).sum()
+                                           - 2 * 3)
+    assert pallas_dsa.sel_layout(16384) == (512, 32, 16384, 512)
+
+
+@FORMS
+@pytest.mark.parametrize("seq,topk", [(96, 16), (300, 40), (2048, 100)],
+                         ids=["one-chunk", "three-chunks", "four-stretches"])
+def test_attention_and_loss_forward_and_backward_are_the_naive(seq, topk,
+                                                               use_pallas):
+    """The attention over the chosen and the indexer's loss, and the
+    gradient of a function of both with respect to all six operands, against
+    a ``[B, N, S, S]`` computation differentiated by ``jax``: float32, so
+    the tolerance is rounding's (sums in another order).  At 2,048 rows the
+    loss's pass walks its four stretches of row chunks, each against the
+    columns up to its own last row (``dsa.LOSS_STRETCHES``)."""
+    args = _operands(seq, seed=seq + 1, B=2 if seq < 2048 else 1)
+    if seq == 2048:
+        lay = pallas_dsa.sel_layout(seq)
+        assert (lay.padded // lay.rows) % dsa.LOSS_STRETCHES == 0
+
+    def objective(args, fn):
+        _, o, kl = fn(*args)
+        return jnp.sum(o * jnp.cos(o)) + 3.0 * kl
+
+    def program(*a):
+        return _program(*a, topk, use_pallas)
+
+    def naive(*a):
+        return _naive(*a, topk)
+
+    _, o, kl = jax.jit(program)(*args)
+    _, want_o, want_kl = naive(*args)
+    np.testing.assert_allclose(o, want_o, rtol=1e-5, atol=2e-6)
+    np.testing.assert_allclose(kl, want_kl, rtol=1e-5)
+    got = jax.jit(jax.grad(lambda a: objective(a, program)))(args)
+    want = jax.grad(lambda a: objective(a, naive))(args)
+    for name, a, b in zip("qi ki w q k v".split(), got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()),
+            err_msg=name)
+
+
+def test_the_loss_reaches_the_indexer_alone_and_the_output_it_not():
+    """The indexer's loss sends nothing to q, k (held constant), and the
+    attention's output nothing to the indexer's operands (the selection is
+    a constant of the backward pass)."""
+    args = _operands(96, seed=7)
+
+    def parts(args):
+        _, o, kl = _program(*args, 16, False)
+        return jnp.sum(o * o), kl
+
+    by_output = jax.grad(lambda a: parts(a)[0])(args)
+    by_loss = jax.grad(lambda a: parts(a)[1])(args)
+    for g in by_output[:3] + by_loss[3:]:
+        assert not np.asarray(g).any()
+    for g in by_output[3:] + by_loss[:3]:
+        assert np.asarray(g).any()

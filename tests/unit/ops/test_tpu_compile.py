@@ -25,8 +25,9 @@ from jax.sharding import SingleDeviceSharding
 
 from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
-from deeperspeed_tpu.ops.attention import (eva, paged, pallas_eva,
-                                           pallas_eva_pool, pallas_flash)
+from deeperspeed_tpu.ops.attention import (dsa, eva, paged, pallas_dsa,
+                                           pallas_eva, pallas_eva_pool,
+                                           pallas_flash)
 from deeperspeed_tpu.ops.quantizer import fused as qfused
 from deeperspeed_tpu.ops.sampling import topk
 from deeperspeed_tpu.ops.transformer import normalize
@@ -34,7 +35,7 @@ from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
 _BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
-            pallas_gmm, pallas_eva, pallas_eva_pool)
+            pallas_gmm, pallas_eva, pallas_eva_pool, pallas_dsa)
 
 
 @pytest.fixture(scope="module")
@@ -533,6 +534,78 @@ def test_recomputed_evabyte_keeps_the_eva_kernels_residuals(one_chip,
     assert passes["eva_attention"] == dict(forward=2, recomputed=0,
                                            backward=2)
     assert passes["eva_pool"] == dict(forward=2, recomputed=2, backward=2)
+    assert "flash_attention" not in passes
+
+
+def test_dsa_fwd_bwd_at_the_keye_cells_shape(one_chip):
+    """Learned sparse attention at train-keye-vl2-ep8-16k's shapes (one
+    sequence of 16,384, 32 query heads over 4 KV heads of 128, an indexer of
+    16 heads of 64, topk 2048), the selection, the attention forward and
+    backward and the indexer's loss with its gradients: k and v reach the
+    kernels at their KV heads, the selection as 34 MB of packed words, and
+    the program holds no ``[heads, S, S]`` array of the main attention (its
+    temporaries are a fraction of ONE head's float32 plane)."""
+    B, S, N, KV, D, HI, DI = 1, 16384, 32, 4, 128, 16, 64
+    bf = jnp.bfloat16
+
+    def fn(qi, ki, w, q, k, v):
+        def loss(qi, ki, w, q, k, v):
+            sel = dsa.dsa_select(qi, ki, w, 2048, use_pallas=True)
+            o, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=True)
+            return jnp.sum(o.astype(jnp.float32)) + dsa.dsa_indexer_loss(
+                qi, ki, w, q, k, lse, sel, use_pallas=True)
+        return jax.grad(loss, argnums=(0, 1, 2, 3, 4, 5))(qi, ki, w, q, k, v)
+
+    compiled = jax.jit(fn).lower(
+        _sds((B, S, HI, DI), bf, one_chip), _sds((B, S, DI), bf, one_chip),
+        _sds((B, S, HI), jnp.float32, one_chip),
+        _sds((B, S, N, D), bf, one_chip), _sds((B, S, KV, D), bf, one_chip),
+        _sds((B, S, KV, D), bf, one_chip)).compile()
+    text = compiled.as_text()
+    calls = pallas_kernel_calls(text)
+    assert set(calls) >= {"dsa_select", "dsa_attention", "dsa_head_probs"}
+    operands = [shape for call in calls["dsa_attention"] for shape in call]
+    assert (B, S, KV * D) in operands and (B, S, N * D) in operands
+    assert (B, S, 512) in operands              # the packed selection
+    assert (B, S, N * D) not in [s for call in calls["dsa_select"]
+                                 for s in call]
+    one_head_plane = S * S * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < one_head_plane
+    assert f"f32[{N},{S},{S}]" not in text and f"bf16[{N},{S},{S}]" not in text
+    assert f"f32[{B},{N},{S},{S}]" not in text
+
+
+def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):
+    """``Keye`` (two layers, remat): a layer's selection is one
+    ``dsa_select`` call, its attention one ``dsa_attention`` call forward
+    and two backward (dq; dk and dv), its loss's ``dsa_head_probs`` a call a
+    chunk of rows; the remat wrap keeps the selection, the attention's
+    output and log-sum-exp and the indexer's gradients, so nothing of them
+    is recomputed; and no dense flash call is made."""
+    from deeperspeed_tpu.models.keye import Keye, KeyeConfig
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+
+    model = Keye(KeyeConfig.tiny(
+        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=128,
+        mrope_section=(16, 24, 24), indexer_num_heads=4, indexer_head_dim=64,
+        topk=512, moe_intermediate_size=128, max_seq_len=2048,
+        ce_chunk_tokens=2048, remat=True, dtype=jnp.bfloat16,
+        use_pallas=True))
+    loss = model.loss_fn()
+    ids = jnp.zeros((1, 2048), jnp.int32)
+    params = jax.tree.map(
+        lambda x: _sds(x.shape, x.dtype, one_chip),
+        jax.eval_shape(lambda: model.init(jax.random.PRNGKey(1), ids)))
+    passes = count_kernel_passes(_compile(
+        jax.grad(lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0]),
+        params, _sds(ids.shape, ids.dtype, one_chip)))
+    assert passes["dsa_select"] == dict(forward=2, recomputed=0, backward=0)
+    assert passes["dsa_attention"] == dict(forward=2, recomputed=0,
+                                           backward=4)
+    chunks = 2048 // pallas_dsa.sel_layout(2048).rows
+    assert passes["dsa_head_probs"] == dict(forward=2 * chunks, recomputed=0,
+                                            backward=0)
     assert "flash_attention" not in passes
 
 

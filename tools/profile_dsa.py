@@ -1,0 +1,167 @@
+"""Time learned sparse attention's parts alone on the chip
+(``ops/attention/dsa.py`` over ``pallas_dsa.py``), at one shape: the
+selection (scores + each row's best), the attention over the chosen forward
+and forward + backward, the indexer's loss with its gradients.  One JSON
+line a part: ms a call of the whole program by the host's clock over
+``--calls`` calls and the device's busiest operations from a profiler
+session; with ``--check`` first, at ``--check-seq`` rows, the kernels' outputs
+beside the plain forms' (the selection exactly, the rest by the largest
+difference over the largest entry).
+
+    python tools/profile_dsa.py                 # the Keye cell's shape
+    python tools/profile_dsa.py --seq 8192 --rows 256 512
+"""
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+
+from deeperspeed_tpu.ops.attention import dsa, pallas_dsa
+from tools.profile_moe_walk import busiest
+
+
+def operands(args, seq):
+    B, N, KV, D = args.batch, args.heads, args.kv_heads, args.head_dim
+    HI, DI = args.indexer_heads, args.indexer_dim
+    keys = jax.random.split(jax.random.PRNGKey(0), 7)
+    dt = args.dtype
+    q = jax.random.normal(keys[0], (B, seq, N, D), dt)
+    k = jax.random.normal(keys[1], (B, seq, KV, D), dt)
+    v = jax.random.normal(keys[2], (B, seq, KV, D), dt)
+    qi = jax.random.normal(keys[3], (B, seq, HI, DI), dt)
+    ki = jax.random.normal(keys[4], (B, seq, DI), dt)
+    w = jax.random.normal(keys[5], (B, seq, HI)) * (HI * DI) ** -0.5
+    do = jax.random.normal(keys[6], (B, seq, N, D), dt)
+    return q, k, v, qi, ki, w, do
+
+
+def parts(topk, use):
+    def select(qi, ki, w):
+        return dsa.dsa_select(qi, ki, w, topk, use_pallas=use)
+
+    def sel_of(words, counts, seq):
+        return dsa.Selection(words, counts, pallas_dsa.sel_layout(seq), seq)
+
+    def attend(q, k, v, words, counts):
+        return dsa.dsa_attention(q, k, v, sel_of(words, counts, q.shape[1]),
+                                 use_pallas=use)
+
+    def attend_grad(q, k, v, words, counts, do):
+        def f(q, k, v):
+            o, _ = attend(q, k, v, words, counts)
+            return jnp.sum(o.astype(jnp.float32) * do.astype(jnp.float32))
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    def loss(qi, ki, w, q, k, lse, words, counts):
+        return jax.value_and_grad(
+            lambda qi, ki, w: dsa.dsa_indexer_loss(
+                qi, ki, w, q, k, lse, sel_of(words, counts, q.shape[1]),
+                use_pallas=use), argnums=(0, 1, 2))(qi, ki, w)
+
+    return select, attend, attend_grad, loss
+
+
+def timed(name, fn, args, calls, top):
+    fn = jax.jit(fn)
+    t0 = time.perf_counter()
+    out = jax.block_until_ready(fn(*args))
+    first = time.perf_counter() - t0
+    t0 = time.perf_counter()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    ms = 1e3 * (time.perf_counter() - t0) / calls
+    ops, _ = busiest(lambda: fn(*args), max(2, calls // 4), top)
+    print(json.dumps({"part": name, "ms_a_call": round(ms, 3),
+                      "first_call_s": round(first, 1), "busiest": ops}),
+          flush=True)
+    return out
+
+
+def rel(a, b):
+    a, b = (jnp.asarray(t, jnp.float32) for t in (a, b))
+    return float(jnp.max(jnp.abs(a - b)) / jnp.maximum(jnp.max(jnp.abs(b)),
+                                                        1e-30))
+
+
+def check(args):
+    seq, topk = args.check_seq, args.check_topk
+    q, k, v, qi, ki, w, do = operands(args, seq)
+    got, want = ({}, {})
+    for out, use in ((got, True), (want, False)):
+        select, attend, attend_grad, loss = (jax.jit(f) for f in parts(
+            topk, use))
+        sel = select(qi, ki, w)
+        out["words"] = sel.words
+        # both sides attend over the kernel's selection
+        words, counts = got["words"], got.setdefault("counts", sel.counts)
+        out["o"], out["lse"] = attend(q, k, v, words, counts)
+        out["dq"], out["dk"], out["dv"] = attend_grad(q, k, v, words, counts,
+                                                      do)
+        out["kl"], (out["dqi"], out["dki"], out["dw"]) = loss(
+            qi, ki, w, q, k, got["lse"], words, counts)
+    same = bool(jnp.all(got["words"] == want["words"]))
+    print(json.dumps({"check": "kernels beside the plain forms", "seq": seq,
+                      "topk": topk, "selection_equal": same,
+                      "pairs_selected": int(jnp.sum(got["counts"])),
+                      **{name: rel(got[name], want[name])
+                         for name in ("o", "dq", "dk", "dv", "kl", "dqi",
+                                      "dki", "dw")}}), flush=True)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--batch", type=int, default=1)
+    ap.add_argument("--seq", type=int, default=16384)
+    ap.add_argument("--heads", type=int, default=32)
+    ap.add_argument("--kv-heads", type=int, default=4)
+    ap.add_argument("--head-dim", type=int, default=128)
+    ap.add_argument("--indexer-heads", type=int, default=16)
+    ap.add_argument("--indexer-dim", type=int, default=64)
+    ap.add_argument("--topk", type=int, default=2048)
+    ap.add_argument("--dtype", default="bfloat16")
+    ap.add_argument("--calls", type=int, default=8)
+    ap.add_argument("--top", type=int, default=6)
+    ap.add_argument("--rows", nargs="+", default=["kept"],
+                    help="rows of the kernels' block, to sweep; 'kept' = "
+                    "the module's own")
+    ap.add_argument("--check", action="store_true")
+    ap.add_argument("--check-seq", type=int, default=2048)
+    ap.add_argument("--check-topk", type=int, default=256)
+    args = ap.parse_args(argv)
+    print(json.dumps({"device": jax.devices()[0].device_kind}), flush=True)
+    if args.check:
+        check(args)
+    q, k, v, qi, ki, w, do = operands(args, args.seq)
+    kept = pallas_dsa.sel_layout
+    for rows in args.rows:
+        # the sweep's layout is this tool's own: the module derives one block
+        if rows != "kept" and kept(args.seq).padded % int(rows):
+            raise SystemExit(f"{rows} rows do not divide the padded length")
+        pallas_dsa.sel_layout = kept if rows == "kept" else (
+            lambda S, r=int(rows): kept(S)._replace(rows=r))
+        select, attend, attend_grad, loss = parts(args.topk, True)
+        tag = f"rows={rows}"
+        sel = timed(f"select {tag}", select, (qi, ki, w), args.calls,
+                    args.top)
+        print(json.dumps({"pairs_selected": int(sel.pairs_selected()),
+                          "pairs_visited": int(sel.pairs_visited()),
+                          "tiles_skipped": int(sel.tiles_skipped())}))
+        _, lse = timed(f"attend forward {tag}", attend,
+                       (q, k, v, sel.words, sel.counts), args.calls, args.top)
+        timed(f"attend forward + backward {tag}", attend_grad,
+              (q, k, v, sel.words, sel.counts, do), args.calls, args.top)
+        timed(f"indexer loss + gradients {tag}", loss,
+              (qi, ki, w, q, k, lse, sel.words, sel.counts), args.calls,
+              args.top)
+
+
+if __name__ == "__main__":
+    sys.exit(main())
