@@ -30,12 +30,13 @@ attention exists where the kernels run: the kernels hold a tile, and the
 loss reads the head-averaged probabilities ``[rows, Sp]`` a chunk of rows at
 a time.  The indexer's plane exists a chunk of rows at a time too.
 
-On a TPU the selection (with the scores), the attention and the
-head-averaged probabilities are kernels (``pallas_dsa.py``); the rest of the
-loss is plain ``jnp`` by row chunks.  Elsewhere, and with ``use_pallas``
-False, plain forms (the attention's then holds ``[B, N, S, S]``: tests'
-sizes only).  The equations with what a published config leaves to
-assumption: ``benchmarks/reference/keye_ref.py``.
+On a TPU the selection (with the scores), the attention, the head-averaged
+probabilities and the loss with its gradients are kernels
+(``pallas_dsa.py``): of the indexer's plane a tile at a time exists, in
+VMEM.  Elsewhere, and with ``use_pallas`` False, plain forms (the
+attention's then holds ``[B, N, S, S]``, the loss's ``[B, H_I, rows,
+cols]`` a chunk of rows: tests' sizes only).  The equations with what a
+published config leaves to assumption: ``benchmarks/reference/keye_ref.py``.
 """
 
 import functools
@@ -286,16 +287,52 @@ def _head_probs_plain(qp, k, lse, chosen, at, rows, heads):
     return jnp.mean(p, axis=1)
 
 
-#: a pass of the loss walks the rows in this many stretches, each against
+#: the plain form of the loss's pass (the kernels end a block's walk at its
+#: diagonal themselves) walks the rows in this many stretches, each against
 #: the columns up to its own last row: what lies right of the diagonal is
 #: chosen by no row, and a scan's chunks must be one shape, so a stretch's
 #: chunks all see the stretch's columns (four: 62.5 % of the square)
 LOSS_STRETCHES = 4
 
 
+def _loss_pass_kernels(qi, ki, w, qp, k, lse, sel, heads, with_grads):
+    """``_loss_pass`` where the kernels run: one scan over the chunks of
+    ``layout.rows`` rows, ``dsa_head_probs`` then ``dsa_loss_grads`` (whose
+    walk ends at the chunk's diagonal: no stretches)."""
+    lay = sel.layout
+    B, sp, H, D = qi.shape
+    q_rows = jnp.swapaxes(qi, 1, 2)                     # [B, H, Sp, D]
+    q_cols = jnp.swapaxes(q_rows, 2, 3)
+    k_cols = jnp.swapaxes(ki.reshape(B, lay.chunks, lay.chunk, D), 2, 3)
+
+    def chunk(carry, i):
+        loss, dkt, dq, dw = carry
+        pbar = pallas_dsa.head_probs_call(
+            qp, k, lse, sel.words, sel.counts, i[None], lay.rows, heads, lay)
+        part, dq, dk, dw = pallas_dsa.loss_grads_call(
+            q_rows, q_cols, ki, k_cols, w, pbar, sel.words, sel.counts,
+            i[None], dq, dw, B * sel.seq, lay, with_grads)
+        return (loss + jnp.sum(part[:, :, 0, 0]), dkt + dk, dq, dw), ()
+
+    (loss, dkt, dq, dw), _ = jax.lax.scan(
+        chunk, (jnp.float32(0.0), jnp.zeros(k_cols.shape, jnp.float32),
+                jnp.zeros_like(q_rows), jnp.zeros_like(w)),
+        jnp.arange(sp // lay.rows, dtype=jnp.int32))
+    loss = loss / (B * sel.seq)
+    if not with_grads:
+        return loss, None
+    dki = jnp.swapaxes(dkt, 2, 3).reshape(ki.shape).astype(ki.dtype)
+    return loss, (jnp.swapaxes(dq, 1, 2), dki, dw)
+
+
 def _loss_pass(qi, ki, w, qp, k, lse, sel, heads, kernel, with_grads):
     """One pass over the rows, a chunk at a time -> (the loss, and with
-    ``with_grads`` its gradients to ``q^I, k^I, w``)."""
+    ``with_grads`` its gradients to ``q^I, k^I, w``).  The plain form below
+    is what the kernels are held to: its equations and its rounding points
+    (``through`` to the compute type once, every sum float32) are theirs."""
+    if kernel:
+        return _loss_pass_kernels(qi, ki, w, qp, k, lse, sel, heads,
+                                  with_grads)
     lay, seq = sel.layout, sel.seq
     B, sp = qi.shape[:2]
     rows = lay.rows
@@ -311,13 +348,8 @@ def _loss_pass(qi, ki, w, qp, k, lse, sel, heads, kernel, with_grads):
             loss, dki = carry
             i, q_c, w_c, words = args
             chosen = pallas_dsa.unpack_rows(words, lay.chunks)[..., :cols]
-            if kernel:
-                pbar = pallas_dsa.head_probs_call(
-                    qp, k, lse, sel.words, sel.counts, i[None], rows, heads,
-                    lay)[..., :cols]
-            else:
-                pbar = _head_probs_plain(qp, k[:, :cols], lse, chosen,
-                                         i * rows, rows, heads)
+            pbar = _head_probs_plain(qp, k[:, :cols], lse, chosen, i * rows,
+                                     rows, heads)
             dots = indexer_dots(q_c, keys)              # [B, H, rows, cols]
             acts = jnp.maximum(dots, 0.0)
             scores = jnp.einsum("bjrs,brj->brs", acts, w_c)
@@ -401,7 +433,8 @@ def dsa_indexer_loss(qi, ki, w, q, k, lse, sel, scale=None, use_pallas=None):
     B, S, N, D = q.shape
     scale = float(D) ** -0.5 if scale is None else float(scale)
     kernel = _takes_kernel(use_pallas, pallas_dsa.compiles_for_tpu(D))
-    count_kernel_path(pallas_dsa.HEAD_PROBS, "pallas" if kernel else "plain")
+    for name in (pallas_dsa.HEAD_PROBS, pallas_dsa.LOSS_GRADS):
+        count_kernel_path(name, "pallas" if kernel else "plain")
     sp = sel.layout.padded
     with jax.named_scope("dsa_indexer_loss"):
         q, k, lse = (jax.lax.stop_gradient(t) for t in (q, k, lse))

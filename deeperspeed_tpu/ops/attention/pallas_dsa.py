@@ -1,8 +1,8 @@
 """Pallas kernels of learned sparse attention (DSA: a lightning indexer
 scores every earlier key, a row keeps its ``topk`` best, one softmax over
 those): the selection of a row block, the attention over the chosen forward
-+ backward, and the head-averaged probabilities the indexer's loss imitates.
-The equations are ``ops/attention/dsa.py``'s.
++ backward, the head-averaged probabilities the indexer's loss imitates, and
+that loss with its gradients.  The equations are ``ops/attention/dsa.py``'s.
 
 On this chip the selection is a MASK inside a causal walk and not a gather:
 a row's 2048 keys are its own, and gathering them costs 4.2 MB a row against
@@ -38,6 +38,14 @@ Kernels, each under the scope that names it in a device trace and in
 * ``dsa_head_probs``: ``mean_h softmax_{S_t}(q_h . k)`` of a chunk of rows
   from the saved log-sum-exp, the heads summed in VMEM: float32
   ``[B, rows, Sp]``, zero outside the chosen.
+* ``dsa_loss_grads``: the indexer's loss of a chunk of rows against those
+  probabilities, with its gradients.  A block of rows (its own size,
+  ``loss_rows``: the loss shares nothing with the attention's block) walks
+  the chunks up to its diagonal twice: the scores into VMEM and the rows'
+  log-sum-exp over their chosen, then the KL's gradient and the three
+  products it feeds (``dw``, ``dq^I`` held for the block, ``dk^I`` summed
+  over the row blocks where the output block lies).  Nothing the size of
+  ``[H_I, rows, cols]`` leaves VMEM.
 """
 
 import functools
@@ -53,6 +61,7 @@ from .pallas_flash import (_NN, _NT, _TN, _params, _rows_off_lanes,
 
 #: the scopes the kernels run under
 ATTENTION, SELECT, HEAD_PROBS = "dsa_attention", "dsa_select", "dsa_head_probs"
+LOSS_GRADS = "dsa_loss_grads"
 _INT_MIN = -(1 << 31)
 
 
@@ -483,3 +492,211 @@ def head_probs_call(q, k, lse, words, counts, at, rows, heads, layout):
             **_params("parallel", "parallel", "parallel", "arbitrary",
                       vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5))),
         )(counts, at, q, k, lse, words)
+
+
+# ------------------------------------------- the indexer's loss and gradients
+def loss_rows(layout):
+    """Rows of a ``dsa_loss_grads`` program's block: the most whose float32
+    scores against the whole length take 8 MiB of VMEM (128 at 16k).  At
+    twice that the kernel alone is a sixth faster, but the step is not:
+    under its 48 MiB XLA no longer keeps the selection's words in VMEM for
+    the attention kernels, which lose more (PERF.md, PR 54)."""
+    fits = (r for r in (256, LANES)
+            if layout.rows % r == 0 and r * layout.padded * 4 <= 8 << 20)
+    return next(fits, LANES)
+
+
+def _onto_lanes(x):
+    """``[rows, W]`` -> ``[rows, 128]``: the lane blocks summed, so that a
+    row's sum costs one reduction across lanes a block of rows and not one a
+    tile."""
+    return sum(x[:, c:c + LANES] for c in range(0, x.shape[1], LANES))
+
+
+def _loss_grads_kernel(counts_ref, at_ref, q_ref, qt_ref, k_ref, kt_ref,
+                       w_ref, pbar_ref, words_ref, _dq, _dw, loss_ref, dq_ref,
+                       dkt_ref, dw_ref, sc_scr, m_scr, l_scr, loss_scr,
+                       dq_scr, dw_scr, *, nq, n, bq, rows, chunk, heads,
+                       total, grads):
+    """Grid (b, row block i of the chunk of rows, step t): the block walks
+    the chunks ``j = t mod n`` twice.  First the scores ``I[t, s] = sum_j
+    w_j relu(q_j . k)`` of a tile into VMEM and the rows' running maximum
+    and sum over their chosen; then, from the rows' log-sum-exp, the KL's
+    terms and its gradient to the scores, and a head at a time what that
+    sends to ``w`` (row sums), ``q^I`` (held for the block) and ``k^I``
+    (transposed, ``[D, W]`` a chunk: summed over the row blocks in the
+    output block, which stays where it is)."""
+    b, i, t = (pl.program_id(a) for a in range(3))
+    j = jax.lax.rem(t, n)
+    row0 = at_ref[0] * bq + i * rows
+    live = (j <= (row0 + rows - 1) // chunk) & (
+        _tile_count(counts_ref, b, row0 // bq, j, nq, n) > 0)
+
+    @pl.when(t == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        for scr in (l_scr, loss_scr, dq_scr, dw_scr):
+            scr[...] = jnp.zeros_like(scr)
+
+    @pl.when((t == 0) & (i == 0))
+    def _init_dk():
+        dkt_ref[...] = jnp.zeros_like(dkt_ref)
+
+    def dots(h):
+        return jax.lax.dot_general(q_ref[0, h], kt_ref[0, 0], _NN,
+                                   preferred_element_type=jnp.float32)
+
+    @pl.when((t < n) & live)
+    def _scores():
+        # the heads unrolled: a loop of 4 heads a body reads 17.7 ms a layer
+        # where this reads 15.6, one of 1 head 23.3 (PERF.md, PR 54)
+        acc = jnp.zeros((rows, chunk), jnp.float32)
+        for h in range(heads):
+            acc = acc + w_ref[0, :, h:h + 1] * jnp.maximum(dots(h), 0.0)
+        sc_scr[j] = acc
+        chosen = _chosen(words_ref, j)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(
+            jnp.where(chosen, acc, NEG_INF), axis=1, keepdims=True))
+        p = jnp.where(chosen, jnp.exp(acc - m_new), 0.0)
+        l_scr[...] = jnp.broadcast_to(
+            l_scr[:, :1] * jnp.exp(m_prev - m_new)
+            + jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
+        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+
+    @pl.when(t == n)
+    def _stat():
+        # a row that chose nothing (past the length) has 0, and gives zeros
+        l = l_scr[...]
+        m_scr[...] = jnp.where(
+            l > 0.0, m_scr[...] + jnp.log(jnp.where(l > 0.0, l, 1.0)), 0.0)
+
+    @pl.when((t >= n) & live)
+    def _grads():
+        logp = sc_scr[j] - m_scr[:, :1]
+        pbar = pbar_ref[0]
+        seen = pbar > 0.0
+        loss_scr[...] += _onto_lanes(jnp.where(
+            seen, pbar * (jnp.log(jnp.where(seen, pbar, 1.0)) - logp), 0.0))
+        if not grads:
+            return
+        ds = (jnp.where(_chosen(words_ref, j), jnp.exp(logp), 0.0)
+              - pbar) / total
+        dkt = jnp.zeros(dkt_ref.shape[2:], jnp.float32)
+        for h in range(heads):
+            d = dots(h)
+            dw_scr[h] += _onto_lanes(ds * jnp.maximum(d, 0.0))
+            # rounded once to the compute type, for both products
+            through = jnp.where(d > 0.0, ds * w_ref[0, :, h:h + 1],
+                                0.0).astype(q_ref.dtype)
+            dq_scr[h] += jax.lax.dot_general(
+                through, k_ref[0], _NN, preferred_element_type=jnp.float32)
+            dkt = dkt + jax.lax.dot_general(
+                qt_ref[0, h], through, _NN,
+                preferred_element_type=jnp.float32)
+        dkt_ref[0, j] += dkt
+
+    @pl.when(t == 2 * n - 1)
+    def _finalize():
+        loss_ref[0, 0] = jnp.broadcast_to(
+            jnp.sum(jnp.sum(loss_scr[...], axis=1, keepdims=True), axis=0,
+                    keepdims=True), loss_ref.shape[2:])
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, heads), 1)
+        dw = jnp.zeros((rows, heads), jnp.float32)
+        for h in range(heads):
+            dw = jnp.where(lane == h, jnp.sum(dw_scr[h], axis=1,
+                                              keepdims=True), dw)
+        dw_ref[0] = dw
+
+
+# Behind a ``jax.jit`` of its own, as ``pallas_eva_pool.py``'s calls: the
+# kernel's body is long straight-line code (two loops over the heads), and
+# lowered at every layer's call site it cost a process 19 s of set-up
+# (PERF.md, PR 54); a model's layers are one trace and one lowered body.
+@functools.partial(jax.jit, static_argnames=("total", "layout", "grads"))
+def loss_grads_call(qi, qt, ki, kt, w, pbar, words, counts, at, dq, dw,
+                    total, layout, grads=True):
+    """The indexer's loss of the rows ``pbar [B, R, Sp]`` (float32,
+    ``head_probs_call``'s) describes, from row block ``at`` (a traced int32
+    ``[1]``, in blocks of ``layout.rows``), against ``q^I [B, H_I, Sp,
+    D_I]``, ``k^I [B, Sp, D_I]`` (and both transposed, so that every product
+    is a plain one: ``[B, H_I, D_I, Sp]``, and a chunk at a time ``[B, n,
+    D_I, W]``) and ``w [B, Sp, H_I]`` float32 -> (the KL's terms summed,
+    one float a row block in ``[B, R / rows, 8, 128]`` at ``[..., 0, 0]``;
+    ``dq`` and ``dw``, the whole length's (``q^I``'s and ``w``'s shapes and
+    types), with these rows' gradients written where they lie (in place: a
+    scan that STACKED a call's outputs had XLA fuse the stack into the
+    call, under its own 16 MiB of scoped VMEM and not the call's);
+    ``dk^I`` of these rows alone, float32, in ``kt``'s layout), the
+    gradients already over ``total`` rows.  Without ``grads`` the three are
+    not made (zeros)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, heads, sp, d = qi.shape
+    size = pbar.shape[1]
+    bq, w_, n = layout.rows, layout.chunk, layout.chunks
+    rows = loss_rows(layout)
+
+    def near(i, t, at):
+        return jnp.minimum(t % n, (at[0] * bq + i * rows + rows - 1) // w_)
+
+    def block(i, at):           # the kernel's blocks divide the layout's
+        return at[0] * (bq // rows) + i
+
+    item = qi.dtype.itemsize
+    need = (n * rows * w_ * 4 + 2 * n * d * w_ * 4          # scores, dk^I
+            + 4 * heads * rows * LANES * (item + 2)          # q^I, dq^I, sums
+            + 2 * heads * d * rows * item + 12 * rows * w_ * 4)
+    with jax.named_scope(LOSS_GRADS):
+        return pl.pallas_call(
+            functools.partial(_loss_grads_kernel, nq=sp // bq, n=n, bq=bq,
+                              rows=rows, chunk=w_, heads=heads,
+                              total=float(total), grads=grads),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(b, size // rows, 2 * n),
+                in_specs=[
+                    pl.BlockSpec((1, heads, rows, d), lambda b, i, t, c, at: (
+                        b, 0, block(i, at), 0)),
+                    pl.BlockSpec((1, heads, d, rows), lambda b, i, t, c, at: (
+                        b, 0, 0, block(i, at))),
+                    pl.BlockSpec((1, w_, d), lambda b, i, t, c, at: (
+                        b, near(i, t, at), 0)),
+                    pl.BlockSpec((1, 1, d, w_), lambda b, i, t, c, at: (
+                        b, near(i, t, at), 0, 0)),
+                    pl.BlockSpec((1, rows, heads), lambda b, i, t, c, at: (
+                        b, block(i, at), 0)),
+                    # read on the second walk alone
+                    pl.BlockSpec((1, rows, w_), lambda b, i, t, c, at: (
+                        b, i, jnp.where(t < n, 0, near(i, t, at)))),
+                    pl.BlockSpec((1, rows, w_), lambda b, i, t, c, at: (
+                        b, block(i, at), 0)),
+                    pl.BlockSpec(memory_space=pl.ANY),
+                    pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=[
+                    pl.BlockSpec((1, 1, 8, LANES),
+                                 lambda b, i, t, c, at: (b, i, 0, 0)),
+                    pl.BlockSpec((1, heads, rows, d), lambda b, i, t, c, at: (
+                        b, 0, block(i, at), 0)),
+                    pl.BlockSpec((1, n, d, w_),
+                                 lambda b, i, t, c, at: (b, 0, 0, 0)),
+                    pl.BlockSpec((1, rows, heads), lambda b, i, t, c, at: (
+                        b, block(i, at), 0))],
+                scratch_shapes=[
+                    pltpu.VMEM((n, rows, w_), jnp.float32),
+                    pltpu.VMEM((rows, LANES), jnp.float32),
+                    pltpu.VMEM((rows, LANES), jnp.float32),
+                    pltpu.VMEM((rows, LANES), jnp.float32),
+                    pltpu.VMEM((heads, rows, d), jnp.float32),
+                    pltpu.VMEM((heads, rows, LANES), jnp.float32)]),
+            out_shape=[
+                jax.ShapeDtypeStruct((b, size // rows, 8, LANES),
+                                     jnp.float32),
+                jax.ShapeDtypeStruct(dq.shape, dq.dtype),
+                jax.ShapeDtypeStruct((b, n, d, w_), jnp.float32),
+                jax.ShapeDtypeStruct(dw.shape, dw.dtype)],
+            input_output_aliases={9: 1, 10: 3},
+            interpret=interpret_mode(),
+            **_params("parallel", "arbitrary", "arbitrary",
+                      vmem=_vmem_limit(need)),
+        )(counts, at, qi, qt, ki, kt, w, pbar, words, dq, dw)

@@ -282,6 +282,8 @@ def test_a_recomputed_layer_keeps_what_is_made_once_a_step():
     assert traced("dsa_attention", "grouped_2") >= 2
     assert traced("dsa_select", "pallas") >= 2
     assert traced("dsa_head_probs", "pallas") >= 2
+    assert traced("dsa_loss_grads", "pallas") >= 2
+    assert dsa.GRADS_SAVED in dsa.SAVED_BY_REMAT
     for name in dsa.SAVED_BY_REMAT:
         assert f"name={name}" in text, name
 

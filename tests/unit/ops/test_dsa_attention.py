@@ -171,3 +171,98 @@ def test_the_loss_reaches_the_indexer_alone_and_the_output_it_not():
         assert not np.asarray(g).any()
     for g in by_output[3:] + by_loss[:3]:
         assert np.asarray(g).any()
+
+
+def _loss_and_grads(qi, ki, w, q, k, lse, sel, use_pallas):
+    """The indexer's loss with its gradients to ``q^I, k^I, w``."""
+    return jax.jit(jax.value_and_grad(
+        lambda qi, ki, w: dsa.dsa_indexer_loss(qi, ki, w, q, k, lse, sel,
+                                               use_pallas=use_pallas),
+        argnums=(0, 1, 2)))(qi, ki, w)
+
+
+def test_the_loss_kernel_is_the_plain_form_in_bfloat16():
+    """2,048 rows of two sequences in bfloat16: several of the kernel's row
+    blocks and both batch rows' walks sum into ``dk^I``.  Both forms round
+    where ``_loss_pass`` rounds, so what separates them is the order of the
+    float32 sums and the casts at the end (the flash tests' bf16
+    tolerance)."""
+    args = [t.astype(jnp.bfloat16) for t in _operands(2048, seed=11)]
+    qi, ki, w, q, k, v = args
+    w = w.astype(jnp.float32)
+    sel = dsa.dsa_select(qi, ki, w, 100, use_pallas=False)
+    assert sel.layout.padded // pallas_dsa.loss_rows(sel.layout) > 4
+    _, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=False)
+    got, want = (_loss_and_grads(qi, ki, w, q, k, lse, sel, use)
+                 for use in (True, False))
+    np.testing.assert_allclose(got[0], want[0], rtol=2e-2)
+    for name, a, b in zip(("dq^I", "dk^I", "dw"), got[1], want[1]):
+        assert a.dtype == b.dtype, name
+        b = np.asarray(b, np.float32)
+        np.testing.assert_allclose(np.asarray(a, np.float32), b, rtol=2e-2,
+                                   atol=2e-2 * np.abs(b).max(), err_msg=name)
+
+
+@FORMS
+def test_padded_rows_and_unchosen_columns_get_exactly_zero(use_pallas):
+    """300 rows pad to 384, and the first 40 rows have fewer than ``topk``
+    earlier keys: the loss's pass gives the rows past the length and the
+    columns no row chose exactly zero (no ``exp`` of a masked score leaks),
+    and every chosen column something."""
+    qi, ki, w, q, k, v = _operands(300, seed=13)
+    sel = dsa.dsa_select(qi, ki, w, 40, use_pallas=False)
+    _, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=False)
+    sp = sel.layout.padded
+    qp = dsa._pad_rows((q * q.shape[-1] ** -0.5).reshape(2, 300, -1), sp)
+    kp = dsa._pad_rows(k.reshape(2, 300, -1), sp)
+    padded = [dsa._pad_rows(t, sp) for t in (qi, ki, w)]
+    loss, (dq, dk, dw) = jax.jit(
+        lambda *a: dsa._loss_pass(*a, sel, q.shape[2], use_pallas, True))(
+            *padded, qp, kp, lse)
+    assert np.isfinite(float(loss))
+    for name, g in (("dq^I", dq), ("dk^I", dk), ("dw", dw)):
+        g = np.asarray(g)
+        assert g.shape[1] == sp and not g[:, 300:].any(), name
+        assert np.isfinite(g).all(), name
+    unchosen = ~np.asarray(sel.mask()).any(axis=1)          # [B, S] columns
+    assert unchosen.any() and not unchosen[:, :40].any()
+    assert not np.asarray(dk)[:, :300][unchosen].any()
+    assert np.asarray(dk)[:, :300][~unchosen].any(axis=-1).all()
+    # (row 0 chose its one key: both distributions are 1 there, no gradient)
+    assert np.asarray(dq)[:, 1:300].any(axis=(2, 3)).mean() > 0.95
+
+
+def _packed(mask, lay):
+    """bool ``[B, Sp, Sp]`` -> a ``Selection``'s words and counts."""
+    B, sp, _ = mask.shape
+    by_chunk = mask.reshape(B, sp, lay.chunks, lay.chunk).astype(jnp.int32)
+    words = jnp.sum(by_chunk << jnp.arange(lay.chunks)[:, None], axis=2)
+    counts = mask.reshape(B, sp // lay.rows, lay.rows, lay.chunks,
+                          lay.chunk).sum((2, 4), dtype=jnp.int32)
+    return words.astype(jnp.int32), counts.reshape(-1)
+
+
+def test_an_empty_tile_inside_the_triangle_is_walked_past():
+    """A selection made by hand whose last block of rows chose nothing in
+    the first chunk (a tile inside the triangle with a count of zero): the
+    kernels neither compute nor miss it, and the loss and its gradients are
+    the plain form's."""
+    seq = 384
+    qi, ki, w, q, k, v = _operands(seq, seed=17)
+    lay = pallas_dsa.sel_layout(seq)
+    assert (lay.chunk, lay.chunks, lay.rows) == (128, 3, 128)
+    t = np.arange(seq)
+    mask = (t[None, :] <= t[:, None]) & (t[None, :] % 3 != 1)
+    mask[256:, :128] = False
+    mask = jnp.asarray(np.broadcast_to(mask, (2, seq, seq)))
+    sel = dsa.Selection(*_packed(mask, lay), lay, seq)
+    np.testing.assert_array_equal(sel.mask(), mask)
+    assert int(sel.tiles_skipped()) == 2
+    _, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=False)
+    got, want = (_loss_and_grads(qi, ki, w, q, k, lse, sel, use)
+                 for use in (True, False))
+    np.testing.assert_allclose(got[0], want[0], rtol=1e-5)
+    for name, a, b in zip(("dq^I", "dk^I", "dw"), got[1], want[1]):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()),
+            err_msg=name)

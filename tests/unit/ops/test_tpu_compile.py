@@ -563,7 +563,13 @@ def test_dsa_fwd_bwd_at_the_keye_cells_shape(one_chip):
         _sds((B, S, KV, D), bf, one_chip)).compile()
     text = compiled.as_text()
     calls = pallas_kernel_calls(text)
-    assert set(calls) >= {"dsa_select", "dsa_attention", "dsa_head_probs"}
+    assert set(calls) >= {"dsa_select", "dsa_attention", "dsa_head_probs",
+                          "dsa_loss_grads"}
+    # the loss kernel reads a chunk of rows' probabilities and writes its
+    # rows of the whole length's dq^I; its block of rows is its own
+    loss_operands = [s for call in calls["dsa_loss_grads"] for s in call]
+    assert (B, 512, S) in loss_operands and (B, HI, S, DI) in loss_operands
+    assert pallas_dsa.loss_rows(pallas_dsa.sel_layout(S)) == 128
     operands = [shape for call in calls["dsa_attention"] for shape in call]
     assert (B, S, KV * D) in operands and (B, S, N * D) in operands
     assert (B, S, 512) in operands              # the packed selection
@@ -578,10 +584,11 @@ def test_dsa_fwd_bwd_at_the_keye_cells_shape(one_chip):
 def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):
     """``Keye`` (two layers, remat): a layer's selection is one
     ``dsa_select`` call, its attention one ``dsa_attention`` call forward
-    and two backward (dq; dk and dv), its loss's ``dsa_head_probs`` a call a
-    chunk of rows; the remat wrap keeps the selection, the attention's
-    output and log-sum-exp and the indexer's gradients, so nothing of them
-    is recomputed; and no dense flash call is made."""
+    and two backward (dq; dk and dv), its loss's ``dsa_head_probs`` and
+    ``dsa_loss_grads`` a call each a chunk of rows; the remat wrap keeps the
+    selection, the attention's output and log-sum-exp and the indexer's
+    gradients, so nothing of them is recomputed; and no dense flash call is
+    made."""
     from deeperspeed_tpu.models.keye import Keye, KeyeConfig
     from deeperspeed_tpu.telemetry import count_kernel_passes
 
@@ -604,8 +611,9 @@ def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):
     assert passes["dsa_attention"] == dict(forward=2, recomputed=0,
                                            backward=4)
     chunks = 2048 // pallas_dsa.sel_layout(2048).rows
-    assert passes["dsa_head_probs"] == dict(forward=2 * chunks, recomputed=0,
-                                            backward=0)
+    for kernel in ("dsa_head_probs", "dsa_loss_grads"):
+        assert passes[kernel] == dict(forward=2 * chunks, recomputed=0,
+                                      backward=0), kernel
     assert "flash_attention" not in passes
 
 

@@ -1,15 +1,17 @@
 """Time learned sparse attention's parts alone on the chip
 (``ops/attention/dsa.py`` over ``pallas_dsa.py``), at one shape: the
 selection (scores + each row's best), the attention over the chosen forward
-and forward + backward, the indexer's loss with its gradients.  One JSON
-line a part: ms a call of the whole program by the host's clock over
-``--calls`` calls and the device's busiest operations from a profiler
-session; with ``--check`` first, at ``--check-seq`` rows, the kernels' outputs
-beside the plain forms' (the selection exactly, the rest by the largest
-difference over the largest entry).
+and forward + backward, the indexer's loss with its gradients (the kernels
+``dsa_head_probs`` + ``dsa_loss_grads``, and beside them the plain form the
+CPU runs).  One JSON line a part: ms a call of the whole program by the
+host's clock over ``--calls`` calls and the device's busiest operations from
+a profiler session; with ``--check`` first, at ``--check-seq`` rows, the
+kernels' outputs beside the plain forms' (the selection exactly, the rest by
+the largest difference over the largest entry).
 
     python tools/profile_dsa.py                 # the Keye cell's shape
     python tools/profile_dsa.py --seq 8192 --rows 256 512
+    python tools/profile_dsa.py --loss-rows 128 256 512     # the loss's own
 """
 
 import argparse
@@ -132,6 +134,9 @@ def main(argv=None):
     ap.add_argument("--rows", nargs="+", default=["kept"],
                     help="rows of the kernels' block, to sweep; 'kept' = "
                     "the module's own")
+    ap.add_argument("--loss-rows", nargs="+", default=["kept"],
+                    help="rows of the loss kernel's block, to sweep (they "
+                    "divide the other kernels' block)")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--check-seq", type=int, default=2048)
     ap.add_argument("--check-topk", type=int, default=256)
@@ -147,7 +152,7 @@ def main(argv=None):
             raise SystemExit(f"{rows} rows do not divide the padded length")
         pallas_dsa.sel_layout = kept if rows == "kept" else (
             lambda S, r=int(rows): kept(S)._replace(rows=r))
-        select, attend, attend_grad, loss = parts(args.topk, True)
+        select, attend, attend_grad, _ = parts(args.topk, True)
         tag = f"rows={rows}"
         sel = timed(f"select {tag}", select, (qi, ki, w), args.calls,
                     args.top)
@@ -158,9 +163,20 @@ def main(argv=None):
                        (q, k, v, sel.words, sel.counts), args.calls, args.top)
         timed(f"attend forward + backward {tag}", attend_grad,
               (q, k, v, sel.words, sel.counts, do), args.calls, args.top)
-        timed(f"indexer loss + gradients {tag}", loss,
-              (qi, ki, w, q, k, lse, sel.words, sel.counts), args.calls,
-              args.top)
+        loss_args = (qi, ki, w, q, k, lse, sel.words, sel.counts)
+        own = pallas_dsa.loss_rows
+        for block in args.loss_rows:
+            # the loss kernel's block is its own, swept apart from the others'
+            pallas_dsa.loss_rows = own if block == "kept" else (
+                lambda layout, r=int(block): r)
+            pallas_dsa.loss_grads_call.clear_cache()    # traced at a block
+            timed(f"indexer loss + gradients {tag} loss_rows={block}",
+                  parts(args.topk, True)[3], loss_args, args.calls, args.top)
+        pallas_dsa.loss_rows = own
+        pallas_dsa.loss_grads_call.clear_cache()
+    timed("indexer loss + gradients, the plain form",
+          parts(args.topk, False)[3], loss_args, max(2, args.calls // 4),
+          args.top)
 
 
 if __name__ == "__main__":
