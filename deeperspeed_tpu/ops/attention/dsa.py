@@ -33,7 +33,12 @@ a time.  The indexer's plane exists a chunk of rows at a time too.
 On a TPU the selection (with the scores), the attention, the head-averaged
 probabilities and the loss with its gradients are kernels
 (``pallas_dsa.py``): of the indexer's plane a tile at a time exists, in
-VMEM.  Elsewhere, and with ``use_pallas`` False, plain forms (the
+VMEM.  The attention's three are tiled on two levels (a grid program owns a
+wide block of rows, of columns in dk/dv, and walks the selection's tiles in
+its body; ``pallas_dsa.attend_plan`` sizes the blocks from the shapes, and
+``telemetry.kernel_paths()["dsa_attention_walk"]`` says which walk a call
+got: ``resident`` | ``span``); ``SelLayout.rows`` stays the granularity of
+the counts, of the loss's scan and of ``dsa_head_probs``.  Elsewhere, and with ``use_pallas`` False, plain forms (the
 attention's then holds ``[B, N, S, S]``, the loss's ``[B, H_I, rows,
 cols]`` a chunk of rows: tests' sizes only).  The equations with what a
 published config leaves to assumption: ``benchmarks/reference/keye_ref.py``.
@@ -213,22 +218,23 @@ def _attend_plain(q, k, v, sel, scale):
     return o, _lse_layout(lse, sel.layout.padded)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
-def _attend(q, k, v, words, counts, heads, scale, layout):
-    return _attend_fwd(q, k, v, words, counts, heads, scale, layout)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+def _attend(q, k, v, words, counts, heads, scale, layout, plan):
+    return _attend_fwd(q, k, v, words, counts, heads, scale, layout, plan)[0]
 
 
-def _attend_fwd(q, k, v, words, counts, heads, scale, layout):
+def _attend_fwd(q, k, v, words, counts, heads, scale, layout, plan):
     with jax.named_scope(pallas_dsa.ATTENTION):
         # pre-scaled once, as ``pallas_flash`` does; dq is post-scaled
         qp = q * jnp.asarray(scale, q.dtype)
-        o, lse = pallas_dsa.fwd_call(qp, k, v, words, counts, heads, layout)
+        o, lse = pallas_dsa.fwd_call(qp, k, v, words, counts, heads, layout,
+                                     plan)
         o, lse = (checkpoint_name(t, name)
                   for t, name in zip((o, lse), _FLASH_SAVED))
         return (o, lse), (qp, k, v, words, counts, o, lse)
 
 
-def _attend_bwd(heads, scale, layout, res, cot):
+def _attend_bwd(heads, scale, layout, plan, res, cot):
     qp, k, v, words, counts, o, lse = res
     do, _ = cot         # the statistics go to the indexer's loss, detached
     with jax.named_scope(pallas_dsa.ATTENTION):
@@ -237,7 +243,7 @@ def _attend_bwd(heads, scale, layout, res, cot):
                         .reshape(b, sp, heads, hw // heads), axis=-1)
         delta = jnp.swapaxes(delta, 1, 2).reshape(b * heads, 1, sp)
         dq, dk, dv = pallas_dsa.bwd_call(qp, k, v, do, lse, delta, words,
-                                         counts, heads, layout)
+                                         counts, heads, layout, plan)
         return dq * jnp.asarray(scale, dq.dtype), dk, dv, None, None
 
 
@@ -257,6 +263,10 @@ def dsa_attention(q, k, v, sel, scale=None, use_pallas=None):
     rep = N // k.shape[2]
     count_kernel_path(pallas_dsa.ATTENTION,
                       f"grouped_{rep}" if kernel else "plain")
+    # the kernels' own blocks, from the shapes alone; which walk they gave
+    plan = pallas_dsa.attend_plan(sel.layout, D, rep, q.dtype)
+    count_kernel_path(pallas_dsa.ATTENTION + "_walk",
+                      plan.walk if kernel else "plain")
     with jax.named_scope("dsa_attend"):
         if not kernel:
             o, lse = _attend_plain(q, k, v, sel, scale)
@@ -264,7 +274,8 @@ def dsa_attention(q, k, v, sel, scale=None, use_pallas=None):
         sp = sel.layout.padded
         with jax.named_scope("attention_layout"):
             q, k, v = (_pad_rows(t.reshape(B, S, -1), sp) for t in (q, k, v))
-        o, lse = _attend(q, k, v, sel.words, sel.counts, N, scale, sel.layout)
+        o, lse = _attend(q, k, v, sel.words, sel.counts, N, scale, sel.layout,
+                         plan)
         with jax.named_scope("attention_layout"):
             o = o[:, :S].reshape(B, S, N, D)
         return o, jax.lax.stop_gradient(lse)

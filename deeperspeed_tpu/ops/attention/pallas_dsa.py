@@ -18,11 +18,41 @@ left it (query column group ``h`` reads KV column group ``h // rep`` of
   against chunk ``j`` reads its rows' words WHOLE, lane for lane, and
   shifts them by ``j``: no relayout, 34 MB a layer at 16k where int8 is 268.
   A program holds its row block's words while it walks the chunks.
-* a count of chosen pairs per (row block, chunk) tile, prefetched to SMEM:
-  a tile in which no row chose anything is neither computed nor loaded (its
-  block index names the last needed block again).  Everything above the
-  diagonal goes that way; inside the triangle few tiles do while the
-  indexer is untrained (``dsa_tiles_skipped``).
+* a count of chosen pairs per (group of ``SelLayout.rows`` rows, chunk)
+  tile, prefetched to SMEM: a tile in which no row chose anything is not
+  computed.  Everything above the diagonal goes that way; inside the
+  triangle few tiles do while the indexer is untrained
+  (``dsa_tiles_skipped``).
+
+Two-level tiling, in ``pallas_flash``'s words, for the three
+``dsa_attention`` kernels: the block the grid *loads* and the tile a kernel
+*computes* are separate sizes.
+
+* A grid program owns one ``block`` of rows of a few query heads of a
+  group side by side (forward and dq; ``attend_plan``, from the shapes
+  alone: 512 rows of four heads at 16k and eight heads a KV head, 2,048 of
+  one head without groups) with the block's words ``[block, W]`` in VMEM,
+  and has k and v of its KV head resident: the whole padded length where
+  that fits (to 16k at D = 128: the grid runs ``(batch, KV head, row block,
+  query heads of the group)``, so k and v change with the KV head alone and
+  the words with the row block), a wide ``span`` of it, on one more grid
+  axis, where it does not.  The dk/dv pass owns a ``cols``-wide block of
+  columns of one KV head and steps over the row blocks and the group's
+  query heads, its float32 sums in VMEM.
+* The tile stays the selection's: ``SelLayout.rows`` rows against one chunk
+  of ``W`` columns, computed where its count is above zero.  A program
+  walks the tiles of its block with two loops in the kernel body
+  (``_walk_tiles``), so a step of the walk costs no grid step, DMA or
+  pipeline stage, and the statistics change layout once a block and head.
+  A tile's chosen pairs are unpacked once for the program's heads, whose
+  chains are independent: one's products run under another's lane work.
+* The edge: a wide block meets the diagonal a group of ``SelLayout.rows``
+  rows at a time (each group's walk ends at the chunk its own diagonal
+  crosses), so the pairs a pass computes are ``dsa_pairs_visited`` whatever
+  the block.
+
+``dsa_select``, ``dsa_head_probs`` and ``dsa_loss_grads`` keep one-level
+grids of their own sizes.
 
 Kernels, each under the scope that names it in a device trace and in
 ``telemetry.kernel_passes()``:
@@ -32,9 +62,9 @@ Kernels, each under the scope that names it in a device trace and in
   float32 bit pattern (32 counting passes over VMEM), equal scores by
   position (a second bisection, over the column), exact.
 * ``dsa_attention``: forward (online softmax over the chosen of a tile,
-  ``o`` and one float a row out), and a two-pass backward: dq over (row
-  block, chunks), dk/dv over (chunk, row blocks, query heads), the KV
-  head's sums in VMEM.
+  ``o`` and one float a row out), and a two-pass backward: dq over the
+  forward's grid and walk, dk/dv over (KV head, column block, row blocks,
+  the group's query heads), the KV head's sums in VMEM.
 * ``dsa_head_probs``: ``mean_h softmax_{S_t}(q_h . k)`` of a chunk of rows
   from the saved log-sum-exp, the heads summed in VMEM: float32
   ``[B, rows, Sp]``, zero outside the chosen.
@@ -56,8 +86,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..pallas_utils import LANES, NEG_INF, interpret_mode
-from .pallas_flash import (_NN, _NT, _TN, _params, _rows_off_lanes,
-                           _rows_onto_lanes, _vmem_limit)
+from .pallas_flash import (_NN, _NT, _TN, _VMEM_BUDGET, _ds, _params,
+                           _rows_off_lanes, _rows_onto_lanes, _vmem_limit,
+                           _walk)
 
 #: the scopes the kernels run under
 ATTENTION, SELECT, HEAD_PROBS = "dsa_attention", "dsa_select", "dsa_head_probs"
@@ -212,51 +243,406 @@ def select_call(qi, ki, w, seq, topk, layout):
 
 
 # -------------------------------------------------- attention over the chosen
+def _picked(words_ref, rows, j):
+    """Which pairs of the tile (``rows`` of the block, chunk ``j``) are
+    chosen."""
+    return (words_ref[0, rows, :] & jnp.left_shift(jnp.int32(1), j)) != 0
+
+
 def _chosen(words_ref, j):
-    """Which pairs of the tile (the block's rows, chunk ``j``) are chosen."""
-    return (words_ref[0] & jnp.left_shift(jnp.int32(1), j)) != 0
+    """``_picked`` of the block's every row."""
+    return _picked(words_ref, slice(None), j)
 
 
 def _tile_count(counts_ref, b, i, j, nq, n):
     return counts_ref[(b * nq + i) * n + j]
 
 
-def _fwd_kernel(counts_ref, q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref,
-                m_scr, l_scr, acc_scr, *, nq, n):
-    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
+class AttendPlan(NamedTuple):
+    """Sizes of one ``dsa_attention`` call's three kernels, in rows of the
+    padded length: what a grid program LOADS.  What it computes at a time is
+    the selection's tile, ``SelLayout.rows`` x ``SelLayout.chunk``."""
+    block: int      # q rows a program owns: whole groups of SelLayout.rows
+    span: int       # k/v rows resident in the forward and dq: whole chunks
+    cols: int       # k/v rows a dk/dv program owns: whole chunks
+    heads: int      # query heads of a group side by side in a program
+    walk: str       # "resident": the span is the KV head's whole length
 
-    @pl.when(j == 0)
+
+# the most rows a program's heads own together and the widest block of
+# columns; the most VMEM the heads' float32 scores of a tile and a block's
+# words ``[block, W]``, double-buffered, may take
+_ATTEND_ROWS = 2048
+_ATTEND_COLS = 4096
+_TILES_BUDGET = 4 << 20
+_WORDS_BUDGET = 16 << 20
+
+
+def attend_plan(layout, d, rep, dtype, block=None, span=None, cols=None,
+                heads=None):
+    """The attention kernels' own sizes, from what a call can see: the
+    selection's layout (the padded length, the tile), the head's width, the
+    query heads a KV head and the operands' type.  ``block``, ``span``,
+    ``cols`` and ``heads`` override (tests, ``tools/profile_dsa.py``'s
+    sweep).  Measured at the Keye cell's shape (BENCH_KERNELS.md, PR 55).
+
+    * ``heads``: four query heads of a group to a program, or two, where
+      the group divides so and their score tiles take ``_TILES_BUDGET``
+      together: a tile's chosen pairs are unpacked once for them all, and
+      the heads' chains (a product, the softmax's lane work, a product) are
+      independent, so one's products run under another's lane work;
+    * ``block``: the most whole row groups that divide the length, up to
+      ``_ATTEND_ROWS`` rows of the program's heads together and
+      ``_WORDS_BUDGET`` of words (one block to the head where the length
+      is shorter; the tile's own rows where nothing wider divides it);
+    * ``span``: the KV head's whole length where k and v of it,
+      double-buffered, take half of ``pallas_flash``'s budget (to 16k at
+      D = 128 in bfloat16: the resident walk then holds ONE buffer of each,
+      they change with the KV head alone), else the most whole chunks that
+      do;
+    * ``cols``: whole chunks up to ``_ATTEND_COLS`` columns."""
+    sub, w, n, sp = layout.rows, layout.chunk, layout.chunks, layout.padded
+    item = jnp.dtype(dtype).itemsize
+    if heads is None:
+        heads = next(h for h in (4, 2, 1) if rep % h == 0 and (
+            h == 1 or 4 * h * sub * w <= _TILES_BUDGET))
+    if block is None:
+        nq = sp // sub
+        block = sub * next(
+            m for m in range(nq, 0, -1) if nq % m == 0 and (m == 1 or (
+                heads * m * sub <= _ATTEND_ROWS
+                and 8 * m * sub * w <= _WORDS_BUDGET)))
+    if span is None:
+        span = w * next(c for c in range(n, 0, -1) if n % c == 0
+                        and (4 * c * w * d * item <= _VMEM_BUDGET // 2
+                             or c == 1))
+    if cols is None:
+        cols = w * next(c for c in range(n, 0, -1) if n % c == 0
+                        and (c * w <= _ATTEND_COLS or c == 1))
+    return AttendPlan(block, span, cols, heads,
+                      "resident" if span == sp else "span")
+
+
+def _walk_tiles(counts_ref, b, i, c0, cn, tile, *, block, sub, w, nq, n):
+    """The walk inside a program's body: ``tile(rows, cols, j)`` for every
+    tile -- a group of ``sub`` rows of row block ``i`` against chunk ``j``
+    of the ``cn`` chunks from ``c0`` that the program holds -- which some
+    row chose in (its count, from SMEM).  A group ends at the chunk its own
+    diagonal crosses: nothing above the diagonal is computed at the tile's
+    granularity, however wide the block.  ``rows`` and ``cols`` are the
+    tile's ranges within the program's blocks."""
+    def group(s):
+        ig = i * (block // sub) + s
+        rows = _ds(s * sub, sub)
+
+        def chunk(j):
+            @pl.when(_tile_count(counts_ref, b, ig, j, nq, n) > 0)
+            def _live():
+                tile(rows, _ds((j - c0) * w, w), j)
+
+        _walk(c0, jnp.minimum(c0 + cn, (ig * sub + sub - 1) // w + 1), chunk)
+
+    _walk(0, block // sub, group)
+
+
+def _fwd_kernel(counts_ref, q_ref, k_ref, v_ref, words_ref, o_ref, lse_ref,
+                m_scr, l_scr, acc_scr, *, geometry, cps):
+    """Grid (b, KV head, row block i, query heads of the group, span): the
+    block's online softmax over the chosen of its tiles in the span, the
+    program's heads one after the other a tile."""
+    b, i, kj = pl.program_id(0), pl.program_id(2), pl.program_id(4)
+    heads, d = m_scr.shape[0], k_ref.shape[2]
+
+    @pl.when(kj == 0)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
-    def _tile():
-        # q arrives pre-scaled.  A row that has met no chosen column yet
-        # holds exp(0) of its masked ones; its first chosen column's alpha
-        # is exp(NEG_INF - m) = 0 and wipes them (every real row chose one)
-        s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
-                                preferred_element_type=jnp.float32)
-        s = jnp.where(_chosen(words_ref, j), s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[...] = jnp.broadcast_to(
-            l_scr[:, :1] * alpha + jnp.sum(p, axis=1, keepdims=True),
-            l_scr.shape)
-        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0], _NN,
-            preferred_element_type=jnp.float32)
+    def tile(rows, cols, j):
+        picked = _picked(words_ref, rows, j)
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        for h in range(heads):
+            lanes = pl.ds(h * d, d)
+            # q arrives pre-scaled.  A row that has met no chosen column
+            # yet holds exp(0) of its masked ones; its first chosen column's
+            # alpha is exp(NEG_INF - m) = 0 and wipes them (every real row
+            # chose one)
+            s = jnp.where(picked, jax.lax.dot_general(
+                q_ref[0, rows, lanes], k, _NT,
+                preferred_element_type=jnp.float32), NEG_INF)
+            m_prev = m_scr[h, rows, :1]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new)
+            m_scr[h, rows, :] = jnp.broadcast_to(m_new, (s.shape[0], LANES))
+            # a row's sum stays spread over the lanes until the block leaves
+            l_scr[h, rows, :] = l_scr[h, rows, :] * alpha + _onto_lanes(p)
+            acc_scr[rows, lanes] = (
+                acc_scr[rows, lanes] * alpha + jax.lax.dot_general(
+                    p.astype(v.dtype), v, _NN,
+                    preferred_element_type=jnp.float32))
 
-    @pl.when(j == n - 1)
+    _walk_tiles(counts_ref, b, i, kj * cps, cps, tile, **geometry)
+
+    @pl.when(kj == pl.num_programs(4) - 1)
     def _finalize():
-        l = l_scr[:, :1]
-        l = jnp.where(l == 0.0, 1.0, l)         # rows past the length
-        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = _rows_onto_lanes(m_scr[:, :1] + jnp.log(l))
+        for h in range(heads):
+            lanes = pl.ds(h * d, d)
+            l = jnp.sum(l_scr[h], axis=1, keepdims=True)
+            l = jnp.where(l == 0.0, 1.0, l)         # rows past the length
+            o_ref[0, :, lanes] = (acc_scr[:, lanes] / l).astype(o_ref.dtype)
+            lse_ref[h] = _rows_onto_lanes(m_scr[h, :, :1] + jnp.log(l))
+
+
+def _tile_probs(q, k, picked, lse):
+    """A tile's probabilities from the rows' saved log-sum-exp: zero where
+    the row did not choose the column."""
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+    return jnp.where(picked, jnp.exp(s - lse), 0.0)
+
+
+def _statistics_off_lanes(lse_ref, delta_ref, lse_scr, delta_scr):
+    """The heads' log-sum-exp and ``delta`` as the tiles read them, once a
+    block and head."""
+    for h in range(lse_scr.shape[0]):
+        lse_scr[h] = _rows_off_lanes(lse_ref[h])
+        delta_scr[h] = _rows_off_lanes(delta_ref[h])
+
+
+def _dq_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+               words_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, geometry,
+               cps):
+    """The forward's grid and walk: dq of the block, summed over the
+    spans."""
+    b, i, kj = pl.program_id(0), pl.program_id(2), pl.program_id(4)
+    d = k_ref.shape[2]
+
+    @pl.when(kj == 0)
+    def _init():
+        dq_scr[...] = jnp.zeros_like(dq_scr)
+        _statistics_off_lanes(lse_ref, delta_ref, lse_scr, delta_scr)
+
+    def tile(rows, cols, j):
+        picked = _picked(words_ref, rows, j)
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        for h in range(lse_scr.shape[0]):
+            lanes = pl.ds(h * d, d)
+            p = _tile_probs(q_ref[0, rows, lanes], k, picked,
+                            lse_scr[h, rows, :1])
+            dp = jax.lax.dot_general(do_ref[0, rows, lanes], v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_scr[h, rows, :1])).astype(k.dtype)
+            dq_scr[rows, lanes] += jax.lax.dot_general(
+                ds, k, _NN, preferred_element_type=jnp.float32)
+
+    _walk_tiles(counts_ref, b, i, kj * cps, cps, tile, **geometry)
+
+    @pl.when(kj == pl.num_programs(4) - 1)
+    def _finalize():
+        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
+
+
+def _dkv_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                words_ref, dk_ref, dv_ref, dk_scr, dv_scr, lse_scr,
+                delta_scr, *, geometry, cw):
+    """Grid (b, KV head, column block jb, row block i, query heads of the
+    group): a step walks the tiles of row block ``i`` against the ``cw``
+    chunks the program owns, and the KV head's dk and dv of those chunks
+    are summed in VMEM over the row blocks and the group."""
+    b, jb, i, r = (pl.program_id(a) for a in (0, 2, 3, 4))
+    block, w, d = geometry["block"], geometry["w"], k_ref.shape[2]
+
+    @pl.when((i == 0) & (r == 0))
+    def _init():
+        dk_scr[...] = jnp.zeros_like(dk_scr)
+        dv_scr[...] = jnp.zeros_like(dv_scr)
+
+    def tile(rows, cols, j):
+        picked = _picked(words_ref, rows, j)
+        k, v = k_ref[0, cols, :], v_ref[0, cols, :]
+        dk = dv = 0.0
+        for h in range(lse_scr.shape[0]):
+            lanes = pl.ds(h * d, d)
+            q, do = q_ref[0, rows, lanes], do_ref[0, rows, lanes]
+            p = _tile_probs(q, k, picked, lse_scr[h, rows, :1])
+            dv = dv + jax.lax.dot_general(
+                p.astype(do.dtype), do, _TN,
+                preferred_element_type=jnp.float32)
+            dp = jax.lax.dot_general(do, v, _NT,
+                                     preferred_element_type=jnp.float32)
+            ds = (p * (dp - delta_scr[h, rows, :1])).astype(q.dtype)
+            dk = dk + jax.lax.dot_general(
+                ds, q, _TN, preferred_element_type=jnp.float32)
+        dv_scr[cols, :] += dv
+        dk_scr[cols, :] += dk
+
+    @pl.when((i * block + block - 1) // w >= jb * cw)   # not wholly above
+    def _step():
+        _statistics_off_lanes(lse_ref, delta_ref, lse_scr, delta_scr)
+        _walk_tiles(counts_ref, b, i, jb * cw, cw, tile, **geometry)
+
+    @pl.when((i == pl.num_programs(3) - 1) & (r == pl.num_programs(4) - 1))
+    def _finalize():
+        dk_ref[0] = dk_scr[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
+
+
+def _geometry(layout, plan):
+    return dict(block=plan.block, sub=layout.rows, w=layout.chunk,
+                nq=layout.padded // layout.rows, n=layout.chunks)
+
+
+def _row_walk_specs(layout, plan, heads, rep, d):
+    """Block specs of the grid ``(b, g, i, r, kj)`` of the forward and dq: a
+    program owns row block ``i`` of the ``plan.heads`` query heads from
+    ``g * rep + r * plan.heads`` and has span ``kj`` of KV head ``g``
+    resident.  The group's heads follow one another over a row block, so
+    its words are fetched once for them all, and k and v change with the KV
+    head alone where the span is the whole length (one buffer each then).
+    A span past the block's diagonal names the diagonal's again (never
+    loaded)."""
+    block, span, hp = plan.block, plan.span, plan.heads
+
+    def near(i, kj):
+        return jnp.minimum(kj, (i * block + block - 1) // span)
+
+    once = dict(pipeline_mode=pl.Buffered(1)) if plan.walk == "resident" \
+        else {}
+    rows = pl.BlockSpec(
+        (1, block, hp * d),
+        lambda b, g, i, r, kj, *_: (b, i, g * (rep // hp) + r))
+    cols = pl.BlockSpec((1, span, d),
+                        lambda b, g, i, r, kj, *_: (b, near(i, kj), g), **once)
+    words = pl.BlockSpec((1, block, layout.chunk),
+                         lambda b, g, i, r, kj, *_: (b, i, 0))
+    stat = pl.BlockSpec(
+        (hp, 1, block),
+        lambda b, g, i, r, kj, *_: ((b * heads + g * rep) // hp + r, 0, i))
+    return rows, cols, words, stat
+
+
+def _attend_need(layout, plan, d, itemsize, rowlike, tiles):
+    """VMEM a forward or dq program holds: ``rowlike`` blocks of q's shape
+    and the words double-buffered, k and v of a span (once where resident),
+    three ``[block, 128]`` float32 scratches' worth a head and ``tiles``
+    float32 temporaries of a tile."""
+    block, w, hp = plan.block, layout.chunk, plan.heads
+    kv = (2 if plan.walk == "resident" else 4) * plan.span * d * itemsize
+    return (2 * rowlike * hp * block * d * itemsize + kv + 2 * block * w * 4
+            + 3 * hp * block * LANES * 4 + tiles * layout.rows * w * 4)
+
+
+# Each call is behind a ``jax.jit`` of its own, as ``loss_grads_call``: a
+# model's layers share one trace and one lowered body a kernel.
+@functools.partial(jax.jit, static_argnames=("heads", "layout", "plan"))
+def fwd_call(q, k, v, words, counts, heads, layout, plan):
+    """Pre-scaled q ``[B, Sp, N*D]``, k, v ``[B, Sp, N_kv*D]``, the words
+    and the tiles' counts ``[B * nq * n]`` -> (o, lse ``[B*N, 1, Sp]``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, sp, hw = q.shape
+    d = hw // heads
+    rep = hw // k.shape[2]
+    block, hp = plan.block, plan.heads
+    rows, cols, wspec, stat = _row_walk_specs(layout, plan, heads, rep, d)
+    pairs = b * heads * sp * sp // 2
+    statistic = pltpu.VMEM((hp, block, LANES), jnp.float32)
+    with jax.named_scope(ATTENTION):
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, geometry=_geometry(layout, plan),
+                              cps=plan.span // layout.chunk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, heads // rep, sp // block, rep // hp,
+                      sp // plan.span),
+                in_specs=[rows, cols, cols, wspec], out_specs=[rows, stat],
+                scratch_shapes=[statistic, statistic,
+                                pltpu.VMEM((block, hp * d), jnp.float32)]),
+            out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                       jax.ShapeDtypeStruct((b * heads, 1, sp), jnp.float32)],
+            cost_estimate=pl.CostEstimate(
+                flops=4 * pairs * d, transcendentals=pairs,
+                bytes_accessed=2 * q.size * q.dtype.itemsize),
+            interpret=interpret_mode(),
+            **_params("parallel", "parallel", "parallel", "parallel",
+                      "arbitrary", vmem=_vmem_limit(_attend_need(
+                          layout, plan, d, q.dtype.itemsize, 2, 3))),
+        )(counts, q, k, v, words)
+
+
+@functools.partial(jax.jit, static_argnames=("heads", "layout", "plan"))
+def bwd_call(q, k, v, do, lse, delta, words, counts, heads, layout, plan):
+    """-> (dq of the pre-scaled q, dk, dv), in two passes."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, sp, hw = q.shape
+    d = hw // heads
+    kv = k.shape[2] // d
+    rep = heads // kv
+    block, size, w, hp = plan.block, plan.cols, layout.chunk, plan.heads
+    item = q.dtype.itemsize
+    geometry = _geometry(layout, plan)
+    statistic = pltpu.VMEM((hp, block, LANES), jnp.float32)
+    rows, cols, wspec, stat = _row_walk_specs(layout, plan, heads, rep, d)
+    with jax.named_scope(ATTENTION):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, geometry=geometry,
+                              cps=plan.span // w),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, kv, sp // block, rep // hp, sp // plan.span),
+                in_specs=[rows, cols, cols, rows, stat, stat, wspec],
+                out_specs=rows,
+                scratch_shapes=[pltpu.VMEM((block, hp * d), jnp.float32),
+                                statistic, statistic]),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=interpret_mode(),
+            **_params("parallel", "parallel", "parallel", "parallel",
+                      "arbitrary", vmem=_vmem_limit(_attend_need(
+                          layout, plan, d, item, 3, 3))),
+        )(counts, q, k, v, do, lse, delta, words)
+
+    def far(i, jb):     # a row block before the columns' first: never loaded
+        return jnp.maximum(i, (jb * size) // block)
+
+    rows_j = pl.BlockSpec(
+        (1, block, hp * d),
+        lambda b, g, jb, i, r, *_: (b, far(i, jb), g * (rep // hp) + r))
+    cols_j = pl.BlockSpec((1, size, d), lambda b, g, jb, i, r, *_: (b, jb, g))
+    words_j = pl.BlockSpec((1, block, w),
+                           lambda b, g, jb, i, r, *_: (b, far(i, jb), 0))
+    stat_j = pl.BlockSpec(
+        (hp, 1, block), lambda b, g, jb, i, r, *_: (
+            (b * heads + g * rep) // hp + r, 0, far(i, jb)))
+    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
+    need = (4 * (hp * block + 2 * size) * d * item + 2 * block * w * 4
+            + 2 * size * d * 4 + 2 * hp * block * LANES * 4
+            + 5 * layout.rows * w * 4)
+    with jax.named_scope(ATTENTION):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, geometry=geometry, cw=size // w),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, kv, sp // size, sp // block, rep // hp),
+                in_specs=[rows_j, cols_j, cols_j, rows_j, stat_j, stat_j,
+                          words_j],
+                out_specs=[cols_j, cols_j],
+                scratch_shapes=[pltpu.VMEM((size, d), jnp.float32),
+                                pltpu.VMEM((size, d), jnp.float32),
+                                statistic, statistic]),
+            out_shape=[kv_shape, kv_shape],
+            interpret=interpret_mode(),
+            **_params("parallel", "parallel", "parallel", "arbitrary",
+                      "arbitrary", vmem=_vmem_limit(need)),
+        )(counts, q, k, v, do, lse, delta, words)
+    return dq, dk, dv
+
+
+# ------------------------------------------------ head-averaged probabilities
+def _probs(q_ref, k_ref, words_ref, lse, j):
+    """``_tile_probs`` of a program whose blocks are the tile."""
+    return _tile_probs(q_ref[0], k_ref[0], _chosen(words_ref, j), lse)
 
 
 def _need(layout, d, itemsize, tiles):
@@ -268,178 +654,6 @@ def _need(layout, d, itemsize, tiles):
             + tiles * bq * w * 4 + 4 * bq * LANES * 4 + bq * d * 4)
 
 
-def _specs(layout, heads, rep, d):
-    """Block specs of the grids ``(b, h, i, j)`` whose programs own a row
-    block ``i`` of query head ``h`` and walk the chunks ``j``: a chunk past
-    the block's diagonal names the diagonal's again (never loaded)."""
-    bq, w = layout.rows, layout.chunk
-
-    def near(i, j):
-        return jnp.minimum(j, (i * bq + bq - 1) // w)
-
-    rows = pl.BlockSpec((1, bq, d), lambda b, h, i, j, *_: (b, i, h))
-    cols = pl.BlockSpec((1, w, d),
-                        lambda b, h, i, j, *_: (b, near(i, j), h // rep))
-    words = pl.BlockSpec((1, bq, w), lambda b, h, i, j, *_: (b, i, 0))
-    stat = pl.BlockSpec((1, 1, bq),
-                        lambda b, h, i, j, *_: (b * heads + h, 0, i))
-    return rows, cols, words, stat
-
-
-def fwd_call(q, k, v, words, counts, heads, layout):
-    """Pre-scaled q ``[B, Sp, N*D]``, k, v ``[B, Sp, N_kv*D]``, the words
-    and the tiles' counts ``[B * nq * n]`` -> (o, lse ``[B*N, 1, Sp]``)."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, sp, hw = q.shape
-    d = hw // heads
-    rep = hw // k.shape[2]
-    bq, n = layout.rows, layout.chunks
-    rows, cols, wspec, stat = _specs(layout, heads, rep, d)
-    pairs = b * heads * sp * sp // 2
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, nq=sp // bq, n=n),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, heads, sp // bq, n),
-            in_specs=[rows, cols, cols, wspec], out_specs=[rows, stat],
-            scratch_shapes=[pltpu.VMEM((bq, LANES), jnp.float32),
-                            pltpu.VMEM((bq, LANES), jnp.float32),
-                            pltpu.VMEM((bq, d), jnp.float32)]),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((b * heads, 1, sp), jnp.float32)],
-        cost_estimate=pl.CostEstimate(
-            flops=4 * pairs * d, transcendentals=pairs,
-            bytes_accessed=2 * q.size * q.dtype.itemsize),
-        interpret=interpret_mode(),
-        **_params("parallel", "parallel", "parallel", "arbitrary",
-                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 3))),
-    )(counts, q, k, v, words)
-
-
-def _probs(q_ref, k_ref, words_ref, lse, j):
-    """A tile's probabilities from the rows' saved log-sum-exp: zero where
-    the row did not choose the column."""
-    s = jax.lax.dot_general(q_ref[0], k_ref[0], _NT,
-                            preferred_element_type=jnp.float32)
-    return jnp.where(_chosen(words_ref, j), jnp.exp(s - lse), 0.0)
-
-
-def _dq_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-               words_ref, dq_ref, dq_scr, lse_scr, delta_scr, *, nq, n):
-    b, i, j = pl.program_id(0), pl.program_id(2), pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        dq_scr[...] = jnp.zeros_like(dq_scr)
-        lse_scr[...] = _rows_off_lanes(lse_ref[0])
-        delta_scr[...] = _rows_off_lanes(delta_ref[0])
-
-    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
-    def _tile():
-        p = _probs(q_ref, k_ref, words_ref, lse_scr[:, :1], j)
-        dp = jax.lax.dot_general(do_ref[0], v_ref[0], _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta_scr[:, :1])).astype(k_ref.dtype)
-        dq_scr[...] += jax.lax.dot_general(
-            ds, k_ref[0], _NN, preferred_element_type=jnp.float32)
-
-    @pl.when(j == n - 1)
-    def _finalize():
-        dq_ref[0] = dq_scr[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(counts_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                words_ref, dk_ref, dv_ref, dk_scr, dv_scr, *, nq, n, rep, d):
-    """Grid (b, chunk j, row block i, query head h): the words of tile
-    (i, j) are read once for all the heads, and the KV heads' dk and dv of
-    the chunk are summed in VMEM over the row blocks and the groups."""
-    b, j, i, h = (pl.program_id(a) for a in range(4))
-
-    @pl.when((i == 0) & (h == 0))
-    def _init():
-        dk_scr[...] = jnp.zeros_like(dk_scr)
-        dv_scr[...] = jnp.zeros_like(dv_scr)
-
-    @pl.when(_tile_count(counts_ref, b, i, j, nq, n) > 0)
-    def _tile():
-        g = h // rep
-        lse = _rows_off_lanes(lse_ref[0])[:, :1]
-        delta = _rows_off_lanes(delta_ref[0])[:, :1]
-        q, do = q_ref[0], do_ref[0]
-        p = _probs(q_ref, k_ref, words_ref, lse, j)
-        dv_scr[g] += jax.lax.dot_general(p.astype(do.dtype), do, _TN,
-                                         preferred_element_type=jnp.float32)
-        dp = jax.lax.dot_general(do, v_ref[0], _NT,
-                                 preferred_element_type=jnp.float32)
-        ds = (p * (dp - delta)).astype(q.dtype)
-        dk_scr[g] += jax.lax.dot_general(ds, q, _TN,
-                                         preferred_element_type=jnp.float32)
-
-    @pl.when((i == nq - 1) & (h == pl.num_programs(3) - 1))
-    def _finalize():
-        for g in range(dk_scr.shape[0]):
-            cols = pl.ds(g * d, d)
-            dk_ref[0, :, cols] = dk_scr[g].astype(dk_ref.dtype)
-            dv_ref[0, :, cols] = dv_scr[g].astype(dv_ref.dtype)
-
-
-def bwd_call(q, k, v, do, lse, delta, words, counts, heads, layout):
-    """-> (dq of the pre-scaled q, dk, dv), in two passes."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, sp, hw = q.shape
-    d = hw // heads
-    kv = k.shape[2] // d
-    rep = heads // kv
-    bq, w, n = layout.rows, layout.chunk, layout.chunks
-    nq = sp // bq
-    rows, cols, wspec, stat = _specs(layout, heads, rep, d)
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, nq=nq, n=n),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, heads, nq, n),
-            in_specs=[rows, cols, cols, rows, stat, stat, wspec],
-            out_specs=rows,
-            scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32),
-                            pltpu.VMEM((bq, LANES), jnp.float32),
-                            pltpu.VMEM((bq, LANES), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret_mode(),
-        **_params("parallel", "parallel", "parallel", "arbitrary",
-                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5))),
-    )(counts, q, k, v, do, lse, delta, words)
-
-    def far(i, j):      # a row block before the chunk's first: never loaded
-        return jnp.maximum(i, (j * w) // bq)
-
-    rows_j = pl.BlockSpec((1, bq, d),
-                          lambda b, j, i, h, *_: (b, far(i, j), h))
-    cols_j = pl.BlockSpec((1, w, d), lambda b, j, i, h, *_: (b, j, h // rep))
-    words_j = pl.BlockSpec((1, bq, w),
-                           lambda b, j, i, h, *_: (b, far(i, j), 0))
-    stat_j = pl.BlockSpec(
-        (1, 1, bq), lambda b, j, i, h, *_: (b * heads + h, 0, far(i, j)))
-    whole = pl.BlockSpec((1, w, kv * d), lambda b, j, i, h, *_: (b, j, 0))
-    kv_shape = jax.ShapeDtypeStruct(k.shape, k.dtype)
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, nq=nq, n=n, rep=rep, d=d),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, n, nq, heads),
-            in_specs=[rows_j, cols_j, cols_j, rows_j, stat_j, stat_j,
-                      words_j],
-            out_specs=[whole, whole],
-            scratch_shapes=[pltpu.VMEM((kv, w, d), jnp.float32),
-                            pltpu.VMEM((kv, w, d), jnp.float32)]),
-        out_shape=[kv_shape, kv_shape],
-        interpret=interpret_mode(),
-        **_params("parallel", "parallel", "arbitrary", "arbitrary",
-                  vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5)
-                                   + 4 * kv * w * d * 4)),
-    )(counts, q, k, v, do, lse, delta, words)
-    return dq, dk, dv
-
-
-# ------------------------------------------------ head-averaged probabilities
 def _head_probs_kernel(counts_ref, at_ref, q_ref, k_ref, lse_ref, words_ref,
                        out_ref, *, nq, n, heads):
     """Grid (b, row block i of the chunk of rows, chunk j, head h): the

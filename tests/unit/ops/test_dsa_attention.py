@@ -242,22 +242,101 @@ def _packed(mask, lay):
     return words.astype(jnp.int32), counts.reshape(-1)
 
 
-def test_an_empty_tile_inside_the_triangle_is_walked_past():
-    """A selection made by hand whose last block of rows chose nothing in
-    the first chunk (a tile inside the triangle with a count of zero): the
-    kernels neither compute nor miss it, and the loss and its gradients are
-    the plain form's."""
-    seq = 384
+def _planned(monkeypatch, **sizes):
+    """The attention kernels' plan with ``sizes`` in place of what the
+    shapes give (blocks of several row groups at a test's length)."""
+    kept = pallas_dsa.attend_plan
+    monkeypatch.setattr(pallas_dsa, "attend_plan",
+                        lambda lay, d, rep, dtype: kept(lay, d, rep, dtype,
+                                                        **sizes))
+
+
+def _attention_grads(q, k, v, sel, use_pallas):
+    """``o`` and the gradients of a function of it to q, k, v."""
+    def objective(q, k, v):
+        o, _ = dsa.dsa_attention(q, k, v, sel, use_pallas=use_pallas)
+        return jnp.sum(o * jnp.cos(o)), o
+
+    (_, o), grads = jax.jit(jax.value_and_grad(
+        objective, argnums=(0, 1, 2), has_aux=True))(q, k, v)
+    return (o,) + grads
+
+
+@pytest.mark.parametrize("seq,heads,kv_heads,sizes,walk", [
+    (1152, 8, 1, dict(block=384, cols=384), "resident"),
+    (1152, 2, 2, dict(block=384, cols=384), "resident"),
+    (1152, 4, 2, dict(block=384, span=384, cols=128), "span"),
+    (1152, 2, 1, dict(block=128, cols=1152), "resident"),
+    (384, 4, 2, {}, "resident"),
+    (300, 4, 2, {}, "resident"),
+], ids=["blocks-of-groups-rep8", "blocks-of-groups-rep1", "spans",
+        "the-tiles-own-rows", "one-block", "padded"])
+def test_the_kernels_walk_is_the_naive_attention(monkeypatch, seq, heads,
+                                                 kv_heads, sizes, walk):
+    """The three kernels where a grid program owns a block of several row
+    groups and walks the tiles in its body: several blocks to the head
+    (under a group of eight query heads, and with none), k and v a span at
+    a time, one block to the head, a padded length; o, dq, dk and dv are a
+    ``[B, N, S, S]`` computation's, differentiated by ``jax``."""
+    from deeperspeed_tpu import telemetry
+
+    _planned(monkeypatch, **sizes)
+    qi, ki, w, q, k, v = _operands(seq, seed=seq + heads, B=1, N=heads,
+                                   KV=kv_heads)
+    topk = 40
+    sel = dsa.dsa_select(qi, ki, w, topk, use_pallas=False)
+    plan = pallas_dsa.attend_plan(sel.layout, q.shape[-1],
+                                  heads // kv_heads, q.dtype)
+    assert plan.walk == walk and plan.block % sel.layout.rows == 0
+    assert (seq == 1152) == (plan.block < sel.layout.padded)
+    assert plan.heads == min(4, heads // kv_heads)
+    before = telemetry.kernel_paths().get("dsa_attention_walk", {}).get(
+        walk, 0)
+    got = _attention_grads(q, k, v, sel, True)
+    assert telemetry.kernel_paths()["dsa_attention_walk"][walk] > before
+
+    def naive(q, k, v):
+        o = _naive(qi, ki, w, q, k, v, topk)[1]
+        return jnp.sum(o * jnp.cos(o)), o
+
+    (_, want_o), want = jax.value_and_grad(naive, argnums=(0, 1, 2),
+                                           has_aux=True)(q, k, v)
+    for name, a, b in zip("o dq dk dv".split(), got, (want_o,) + want):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()),
+            err_msg=name)
+
+
+@pytest.mark.parametrize("seq,sizes,hole,skipped", [
+    (384, {}, (slice(256, None), slice(0, 128)), 1),
+    (1152, dict(block=384, cols=384), (slice(768, None), slice(0, 384)), 9),
+    (1152, dict(block=384, cols=384), (slice(512, 640), slice(128, 256)), 1),
+], ids=["a-tile-of-the-one-block", "a-whole-block", "a-tile-inside-a-block"])
+def test_an_empty_tile_inside_the_triangle_is_walked_past(monkeypatch, seq,
+                                                          sizes, hole,
+                                                          skipped):
+    """A selection made by hand in which some rows chose nothing in some
+    chunks (tiles inside the triangle with a count of zero: one tile of the
+    length's one block, every tile of a whole block of rows against a block
+    of columns, one tile in the middle of a wide block): the kernels
+    neither compute nor miss them; the attention and its gradients, and the
+    loss and its gradients, are the plain forms'."""
+    _planned(monkeypatch, **sizes)
     qi, ki, w, q, k, v = _operands(seq, seed=17)
     lay = pallas_dsa.sel_layout(seq)
-    assert (lay.chunk, lay.chunks, lay.rows) == (128, 3, 128)
+    assert (lay.chunk, lay.chunks, lay.rows) == (128, seq // 128, 128)
     t = np.arange(seq)
     mask = (t[None, :] <= t[:, None]) & (t[None, :] % 3 != 1)
-    mask[256:, :128] = False
+    mask[hole] = False
     mask = jnp.asarray(np.broadcast_to(mask, (2, seq, seq)))
     sel = dsa.Selection(*_packed(mask, lay), lay, seq)
     np.testing.assert_array_equal(sel.mask(), mask)
-    assert int(sel.tiles_skipped()) == 2
+    assert int(sel.tiles_skipped()) == 2 * skipped
+    got, want = (_attention_grads(q, k, v, sel, use) for use in (True, False))
+    for name, a, b in zip("o dq dk dv".split(), got, want):
+        np.testing.assert_allclose(
+            a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()),
+            err_msg=name)
     _, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=False)
     got, want = (_loss_and_grads(qi, ki, w, q, k, lse, sel, use)
                  for use in (True, False))
@@ -266,3 +345,88 @@ def test_an_empty_tile_inside_the_triangle_is_walked_past():
         np.testing.assert_allclose(
             a, b, rtol=1e-4, atol=1e-5 * float(jnp.abs(b).max()),
             err_msg=name)
+
+
+def test_the_attentions_padded_rows_and_unchosen_columns_get_exactly_zero():
+    """300 rows pad to 384, one block of three row groups: whatever the
+    padded rows of ``do`` hold, the rows past the length get a dq of exactly
+    zero and send nothing to dk and dv, and a column no row chose gets
+    exactly zero of both (no ``exp`` of a masked score leaks); every chosen
+    column gets something."""
+    qi, ki, w, q, k, v = _operands(300, seed=13)
+    sel = dsa.dsa_select(qi, ki, w, 40, use_pallas=False)
+    lay = sel.layout
+    B, S, N, D = q.shape
+    plan = pallas_dsa.attend_plan(lay, D, 2, q.dtype)
+    assert (plan.block, lay.rows, lay.padded) == (384, 128, 384)
+    qp, kp, vp = (dsa._pad_rows(t.reshape(B, S, -1), lay.padded)
+                  for t in (q, k, v))
+    _, res = dsa._attend_fwd(qp, kp, vp, sel.words, sel.counts, N,
+                             D ** -0.5, lay, plan)
+    do = jax.random.normal(jax.random.PRNGKey(5), qp.shape)
+    given = [dsa._attend_bwd(N, D ** -0.5, lay, plan, res, (cot, None))[:3]
+             for cot in (do, do.at[:, S:].set(0.0))]
+    for name, a, b in zip(("dq", "dk", "dv"), *given):
+        a = np.asarray(a)
+        assert np.isfinite(a).all(), name
+        assert not a[:, S:].any(), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    unchosen = ~np.asarray(sel.mask()).any(axis=1)          # [B, S] columns
+    assert unchosen.any()
+    for name, g in zip(("dk", "dv"), given[0][1:]):
+        g = np.asarray(g).reshape(B, lay.padded, -1, D)[:, :S]
+        assert not g[unchosen].any(), name
+        assert g[~unchosen].any(axis=(-1, -2)).all(), name
+
+
+def test_the_attentions_plan_is_its_own_and_a_function_of_shapes_alone():
+    """The attention kernels' blocks come from the selection's layout, the
+    head's width and the type, and move nothing else: ``SelLayout.rows``,
+    the loss's block and ``dsa_head_probs``' grid are what they were, and
+    ``dsa_attention`` takes no size."""
+    import inspect
+
+    lay, bf = pallas_dsa.sel_layout, jnp.bfloat16
+
+    def plan(seq, d=128, rep=8, dtype=bf):
+        return pallas_dsa.attend_plan(lay(seq), d, rep, dtype)
+
+    assert lay(16384) == (512, 32, 16384, 512)
+    assert pallas_dsa.loss_rows(lay(16384)) == 128
+    # the cell: four heads of the group side by side on a block of a row
+    # group each (2,048 rows together), the KV head's whole k and v resident
+    assert plan(16384) == (512, 16384, 4096, 4, "resident")
+    assert plan(16384) == plan(16384, dtype="bfloat16")
+    assert plan(16384, rep=2) == (1024, 16384, 4096, 2, "resident")
+    assert plan(16384, rep=1) == plan(16384, rep=3) == (
+        2048, 16384, 4096, 1, "resident")
+    # twice the length, or float32 at the cell's: a span of k and v
+    assert plan(32768) == (1024, 16384, 4096, 2, "span")
+    assert plan(16384, dtype=jnp.float32) == (512, 8192, 4096, 4, "span")
+    # 128k: a tile is 512 x 4096: one head, a block of rows one group (its
+    # words), a block of columns one chunk
+    assert plan(131072) == (512, 16384, 4096, 1, "span")
+    # one block to the head; the tile's own rows where nothing wider divides
+    assert plan(300, d=16, rep=2, dtype=jnp.float32) == (
+        384, 384, 384, 2, "resident")
+    assert plan(1024) == (512, 1024, 1024, 4, "resident")
+    assert lay(2176).rows == 128
+    assert plan(2176) == (128, 2176, 2176, 4, "resident")
+    assert list(inspect.signature(dsa.dsa_attention).parameters) == [
+        "q", "k", "v", "sel", "scale", "use_pallas"]
+    # ``dsa_head_probs`` is called a chunk of ``SelLayout.rows`` rows, its
+    # grid (b, row blocks of the chunk, chunks, heads)
+    B, S, N, KV, D = 1, 16384, 32, 4, 128
+    layout = lay(S)
+    f32 = jnp.float32
+    jaxpr = jax.make_jaxpr(
+        lambda *a: pallas_dsa.head_probs_call(*a, layout.rows, N, layout))(
+            jax.ShapeDtypeStruct((B, S, N * D), bf),
+            jax.ShapeDtypeStruct((B, S, KV * D), bf),
+            jax.ShapeDtypeStruct((B * N, 1, S), f32),
+            jax.ShapeDtypeStruct((B, S, layout.chunk), jnp.int32),
+            jax.ShapeDtypeStruct((B * 32 * 32,), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32))
+    call, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
+    assert tuple(call.params["grid_mapping"].grid) == (1, 1, 32, 32)
+    assert call.outvars[0].aval.shape == (B, 512, S)

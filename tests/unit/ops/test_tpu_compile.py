@@ -581,6 +581,51 @@ def test_dsa_fwd_bwd_at_the_keye_cells_shape(one_chip):
     assert f"f32[{B},{N},{S},{S}]" not in text
 
 
+@pytest.mark.parametrize("kernel", ["forward", "dq", "dk/dv"])
+@pytest.mark.parametrize("S,heads,kv_heads,walk", [
+    (16384, 32, 4, "resident"),     # train-keye-vl2-ep8-16k's layer
+    (32768, 8, 1, "span"),          # twice the length: k and v by spans
+], ids=["keye-16k", "32k"])
+def test_dsa_attention_kernel_at_its_plan(one_chip, S, heads, kv_heads, walk,
+                                          kernel):
+    """Each of the three ``dsa_attention`` kernels alone at the blocks
+    ``pallas_dsa.attend_plan`` gives the shape: a block of rows of several
+    query heads of a group (of columns in dk/dv) a grid program, the tiles
+    of ``SelLayout.rows`` rows walked in the body, k and v of a KV head
+    resident at 16k (a scoped-VMEM limit of 32 MiB and no more) and a span
+    of them at 32k."""
+    import re
+
+    B, D, bf = 1, 128, jnp.bfloat16
+    lay = pallas_dsa.sel_layout(S)
+    plan = pallas_dsa.attend_plan(lay, D, heads // kv_heads, bf)
+    assert lay.rows == 512 and plan == (
+        (512, 16384, 4096, 4, walk) if S == 16384
+        else (1024, 16384, 4096, 2, walk))
+    q = _sds((B, S, heads * D), bf, one_chip)
+    k = _sds((B, S, kv_heads * D), bf, one_chip)
+    stat = _sds((B * heads, 1, S), jnp.float32, one_chip)
+    words = _sds((B, S, lay.chunk), jnp.int32, one_chip)
+    counts = _sds((B * (S // lay.rows) * lay.chunks,), jnp.int32, one_chip)
+    if kernel == "forward":
+        text = _compile(
+            lambda *a: pallas_dsa.fwd_call(*a, heads, lay, plan),
+            q, k, k, words, counts)
+    else:
+        part = slice(0, 1) if kernel == "dq" else slice(1, 3)
+        text = _compile(
+            lambda *a: pallas_dsa.bwd_call(*a, heads, lay, plan)[part],
+            q, k, k, q, stat, stat, words, counts)
+    calls = pallas_kernel_calls(text)
+    assert list(calls) == ["dsa_attention"] and len(calls["dsa_attention"]) == 1
+    # what the call states and what Mosaic laid out: at 32 MiB XLA still
+    # keeps the selection's words in VMEM around the kernels (PERF.md, PR 54)
+    call, = (line for line in text.splitlines() if "tpu_custom_call" in line)
+    stated, used = (int(size) for size in re.findall(
+        r'"memory_space":"1","offset":"0","size":"(\d+)"', call))
+    assert used <= stated <= (32 if walk == "resident" else 64) << 20
+
+
 def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):
     """``Keye`` (two layers, remat): a layer's selection is one
     ``dsa_select`` call, its attention one ``dsa_attention`` call forward

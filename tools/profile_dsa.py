@@ -1,16 +1,18 @@
 """Time learned sparse attention's parts alone on the chip
 (``ops/attention/dsa.py`` over ``pallas_dsa.py``), at one shape: the
-selection (scores + each row's best), the attention over the chosen forward
-and forward + backward, the indexer's loss with its gradients (the kernels
+selection (scores + each row's best), the attention's three kernels each
+alone (forward, dq, dk/dv) and through ``dsa_attention`` forward and
+forward + backward, the indexer's loss with its gradients (the kernels
 ``dsa_head_probs`` + ``dsa_loss_grads``, and beside them the plain form the
 CPU runs).  One JSON line a part: ms a call of the whole program by the
 host's clock over ``--calls`` calls and the device's busiest operations from
-a profiler session; with ``--check`` first, at ``--check-seq`` rows, the
-kernels' outputs beside the plain forms' (the selection exactly, the rest by
-the largest difference over the largest entry).
+a profiler session, the attention's with the plan they ran
+(``pallas_dsa.attend_plan``); with ``--check`` first, at ``--check-seq``
+rows, the kernels' outputs beside the plain forms' (the selection exactly,
+the rest by the largest difference over the largest entry).
 
     python tools/profile_dsa.py                 # the Keye cell's shape
-    python tools/profile_dsa.py --seq 8192 --rows 256 512
+    python tools/profile_dsa.py --seq 8192 --rows 512 1024 --cols 1024
     python tools/profile_dsa.py --loss-rows 128 256 512     # the loss's own
 """
 
@@ -70,7 +72,7 @@ def parts(topk, use):
     return select, attend, attend_grad, loss
 
 
-def timed(name, fn, args, calls, top):
+def timed(name, fn, args, calls, top, **told):
     fn = jax.jit(fn)
     t0 = time.perf_counter()
     out = jax.block_until_ready(fn(*args))
@@ -81,10 +83,37 @@ def timed(name, fn, args, calls, top):
     jax.block_until_ready(out)
     ms = 1e3 * (time.perf_counter() - t0) / calls
     ops, _ = busiest(lambda: fn(*args), max(2, calls // 4), top)
-    print(json.dumps({"part": name, "ms_a_call": round(ms, 3),
+    print(json.dumps({"part": name, "ms_a_call": round(ms, 3), **told,
                       "first_call_s": round(first, 1), "busiest": ops}),
           flush=True)
     return out
+
+
+def attention_kernels(args, q, k, v, do, sel, lay, plan, tag):
+    """The three ``dsa_attention`` kernels each alone on ``dsa.py``'s
+    operands (a call behind ``bwd_call`` whose result is not used is not
+    run) -> the rows' log-sum-exp."""
+    B, S, N, D = q.shape
+    told = dict(plan=plan._asdict())
+
+    def flat(t):
+        return dsa._pad_rows(t.reshape(B, S, -1), lay.padded)
+
+    qp, k, v, do = (flat(t) for t in (q * jnp.asarray(D ** -0.5, q.dtype),
+                                      k, v, do))
+    o, lse = timed(
+        f"dsa_attention forward {tag}",
+        lambda *a: pallas_dsa.fwd_call(*a, N, lay, plan),
+        (qp, k, v, sel.words, sel.counts), args.calls, args.top, **told)
+    delta = jnp.swapaxes(jnp.sum(
+        (do.astype(jnp.float32) * o.astype(jnp.float32)).reshape(
+            B, lay.padded, N, D), axis=-1), 1, 2).reshape(B * N, 1, -1)
+    operands = (qp, k, v, do, lse, delta, sel.words, sel.counts)
+    for name, part in (("dq", slice(0, 1)), ("dk/dv", slice(1, 3))):
+        timed(f"dsa_attention {name} {tag}",
+              lambda *a, part=part: pallas_dsa.bwd_call(*a, N, lay, plan)[part],
+              operands, args.calls, args.top, **told)
+    return lse
 
 
 def rel(a, b):
@@ -132,11 +161,20 @@ def main(argv=None):
     ap.add_argument("--calls", type=int, default=8)
     ap.add_argument("--top", type=int, default=6)
     ap.add_argument("--rows", nargs="+", default=["kept"],
-                    help="rows of the kernels' block, to sweep; 'kept' = "
-                    "the module's own")
+                    help="rows of the attention kernels' own block "
+                    "(pallas_dsa.attend_plan), to sweep; 'kept' = the "
+                    "module's own")
+    ap.add_argument("--cols", type=int, default=None,
+                    help="columns of a dk/dv program's block in the sweep")
+    ap.add_argument("--side-by-side", type=int, default=None,
+                    help="query heads of a group side by side in a program")
+    ap.add_argument("--span", type=int, default=None,
+                    help="rows of k and v resident in the forward and dq")
     ap.add_argument("--loss-rows", nargs="+", default=["kept"],
                     help="rows of the loss kernel's block, to sweep (they "
                     "divide the other kernels' block)")
+    ap.add_argument("--no-loss", action="store_true",
+                    help="the selection and the attention alone")
     ap.add_argument("--check", action="store_true")
     ap.add_argument("--check-seq", type=int, default=2048)
     ap.add_argument("--check-topk", type=int, default=256)
@@ -145,35 +183,42 @@ def main(argv=None):
     if args.check:
         check(args)
     q, k, v, qi, ki, w, do = operands(args, args.seq)
-    kept = pallas_dsa.sel_layout
+    select, attend, attend_grad, loss = parts(args.topk, True)
+    sel = timed("select", select, (qi, ki, w), args.calls, args.top)
+    print(json.dumps({"pairs_selected": int(sel.pairs_selected()),
+                      "pairs_visited": int(sel.pairs_visited()),
+                      "tiles_skipped": int(sel.tiles_skipped())}))
+    kept, lay = pallas_dsa.attend_plan, pallas_dsa.sel_layout(args.seq)
     for rows in args.rows:
-        # the sweep's layout is this tool's own: the module derives one block
-        if rows != "kept" and kept(args.seq).padded % int(rows):
-            raise SystemExit(f"{rows} rows do not divide the padded length")
-        pallas_dsa.sel_layout = kept if rows == "kept" else (
-            lambda S, r=int(rows): kept(S)._replace(rows=r))
-        select, attend, attend_grad, _ = parts(args.topk, True)
+        # the sweep's plan is this tool's own: the module derives one
+        if rows != "kept" and (int(rows) % lay.rows or lay.padded % int(rows)):
+            raise SystemExit(f"a block of {rows} rows is no whole groups of "
+                             f"{lay.rows} rows that divide {lay.padded}")
+        plan = kept(lay, args.head_dim, args.heads // args.kv_heads,
+                    args.dtype, None if rows == "kept" else int(rows),
+                    args.span, args.cols, args.side_by_side)
+        pallas_dsa.attend_plan = lambda *a, plan=plan: plan
+        _, attend, attend_grad, _ = parts(args.topk, True)  # traced at a plan
         tag = f"rows={rows}"
-        sel = timed(f"select {tag}", select, (qi, ki, w), args.calls,
-                    args.top)
-        print(json.dumps({"pairs_selected": int(sel.pairs_selected()),
-                          "pairs_visited": int(sel.pairs_visited()),
-                          "tiles_skipped": int(sel.tiles_skipped())}))
-        _, lse = timed(f"attend forward {tag}", attend,
-                       (q, k, v, sel.words, sel.counts), args.calls, args.top)
-        timed(f"attend forward + backward {tag}", attend_grad,
-              (q, k, v, sel.words, sel.counts, do), args.calls, args.top)
-        loss_args = (qi, ki, w, q, k, lse, sel.words, sel.counts)
-        own = pallas_dsa.loss_rows
-        for block in args.loss_rows:
-            # the loss kernel's block is its own, swept apart from the others'
-            pallas_dsa.loss_rows = own if block == "kept" else (
-                lambda layout, r=int(block): r)
-            pallas_dsa.loss_grads_call.clear_cache()    # traced at a block
-            timed(f"indexer loss + gradients {tag} loss_rows={block}",
-                  parts(args.topk, True)[3], loss_args, args.calls, args.top)
-        pallas_dsa.loss_rows = own
-        pallas_dsa.loss_grads_call.clear_cache()
+        lse = attention_kernels(args, q, k, v, do, sel, lay, plan, tag)
+        for name, fn, more in ((f"attend forward {tag}", attend, ()), (
+                f"attend forward + backward {tag}", attend_grad, (do,))):
+            timed(name, fn, (q, k, v, sel.words, sel.counts) + more,
+                  args.calls, args.top, plan=plan._asdict())
+    pallas_dsa.attend_plan = kept
+    if args.no_loss:
+        return
+    loss_args = (qi, ki, w, q, k, lse, sel.words, sel.counts)
+    own = pallas_dsa.loss_rows
+    for block in args.loss_rows:
+        # the loss kernel's block is its own
+        pallas_dsa.loss_rows = own if block == "kept" else (
+            lambda layout, r=int(block): r)
+        pallas_dsa.loss_grads_call.clear_cache()        # traced at a block
+        timed(f"indexer loss + gradients loss_rows={block}", loss, loss_args,
+              args.calls, args.top)
+    pallas_dsa.loss_rows = own
+    pallas_dsa.loss_grads_call.clear_cache()
     timed("indexer loss + gradients, the plain form",
           parts(args.topk, False)[3], loss_args, max(2, args.calls // 4),
           args.top)
