@@ -1,5 +1,6 @@
 """One decoder stack for the models whose training path is all they have
-(``nemotron_h.py``, ``mellum.py`` with ``laguna.py``, ``evabyte.py``).  Four
+(``nemotron_h.py``, ``mellum.py`` with ``laguna.py`` and ``keye.py``,
+``evabyte.py``, ``zaya.py``).  Four
 decisions are made here and nowhere else: how a stack of layers is run and
 recomputed, what a layer reports and how a step's counters are made of it,
 how the head and the loss are called, and what a model offers the engine by
@@ -13,10 +14,14 @@ with ``hidden_size``, ``max_seq_len``, ``ce_chunk_tokens``, ``dtype``,
   on its class: what a layer may be.  ``said`` is ``{}`` of a layer that
   routes nothing, else ``{"counters": what the routed walk counted
   (``dropless.dropless_moe``), "chosen": which held experts each token chose
-  [B, S, held]}``;
+  [B, S, held]}``.  A block whose layers hand a value on beside the stream
+  (a router's state) takes and returns it too, ``Block(...)(x, carried) ->
+  (x, said, carried)``: the first layer held is called with ``x`` alone;
 * a subclass of ``Decoder`` that states seven values: ``block_cls``; in
   ``stack()`` the ``kinds`` of its layers in order, the table's ``rows``,
-  ``table_dtype`` and ``init_std`` and the head's ``columns``;
+  ``table_dtype`` and ``init_std`` and the head's ``columns``, or
+  ``tied_head``: the head is the table's transpose, one leaf, whose gradient
+  is the sum of its two uses;
   ``saved_by_remat``; ``final_norm`` (with ``norm_eps`` in ``stack()``);
 * its counts: ``counters(batch, seq)``, its ``*_layer_applications`` of a
   step on that many tokens (the benchmark's exact checks go by these names),
@@ -52,6 +57,7 @@ class Stack(NamedTuple):
     norm_eps: float             # the closing norm's
     table_dtype: Any = jnp.float32
     init_std: float = 0.02      # of both tables
+    tied_head: bool = False     # the head is ``embed_tokens``'s transpose
 
 
 def _dense(width, cfg, name, std=0.02):
@@ -67,7 +73,8 @@ def cast_rms_norm(x, scale, eps, dtype):
 class Decoder(nn.Module):
     """Causal LM: tokens [B, S] -> (the closing norm's output [B, S, H],
     what each layer that routed said).  ``lm_head_kernel`` is declared here
-    and applied by the chunked cross entropy."""
+    (unless the stack ties the head to the table) and applied by the chunked
+    cross entropy."""
 
     #: the class of a layer, made with (configuration, a kind of ``KINDS``)
     block_cls = None
@@ -101,16 +108,18 @@ class Decoder(nn.Module):
             block = nn.remat(
                 block, policy=jax.checkpoint_policies.save_only_these_names(
                     *self.saved_by_remat))
-        told = []
+        told, carried = [], ()  # what a layer hands the next beside ``x``
         for i, kind in enumerate(stack.kinds):
-            x, said = block(cfg, kind, name=f"layers_{i}")(x)
+            x, said, *carried = block(cfg, kind, name=f"layers_{i}")(
+                x, *carried)
             if said:
                 told.append(said)
         with jax.named_scope("head_ce"):    # the head, from its norm on
             x = norm(x, self.param(weight, weight_init, (cfg.hidden_size,),
                                    jnp.float32), stack.norm_eps, cfg.dtype)
-            self.param("lm_head_kernel", init,
-                       (cfg.hidden_size, stack.columns), jnp.float32)
+            if not stack.tied_head:
+                self.param("lm_head_kernel", init,
+                           (cfg.hidden_size, stack.columns), jnp.float32)
         return x, told
 
     # ------------------------------------------------------------ engine API
@@ -127,6 +136,14 @@ class Decoder(nn.Module):
         load = (dropless.load_counters([t["counters"] for t in told])
                 if told else {})
         return {**self.counters(*shape), **load}
+
+    @nn.nowrap
+    def head_kernel(self, params):
+        """The head's matrix [H, columns]: a leaf of its own, or the table's
+        transpose where the stack ties them."""
+        if self.stack().tied_head:
+            return params["embed_tokens"]["embedding"].T
+        return params["lm_head_kernel"]
 
     @nn.nowrap
     def head_loss(self, hidden, kernel, batch):
@@ -148,7 +165,7 @@ class Decoder(nn.Module):
                   else self.none_chosen(input_ids.shape))
         with jax.named_scope("head_ce"):
             token_ll = chunked_linear_cross_entropy(
-                hidden.reshape(-1, cfg.hidden_size), params["lm_head_kernel"],
+                hidden.reshape(-1, cfg.hidden_size), self.head_kernel(params),
                 labels.reshape(-1), cfg.ce_chunk_tokens)
         return token_ll.reshape(labels.shape), chosen, counters
 
@@ -161,7 +178,7 @@ class Decoder(nn.Module):
             hidden, told = self.apply({"params": params}, ids)
             counters = self._report(told, ids.shape)
             with jax.named_scope("head_ce"):
-                ce, more = self.head_loss(hidden, params["lm_head_kernel"],
+                ce, more = self.head_loss(hidden, self.head_kernel(params),
                                           batch)
             return ce, jax.lax.stop_gradient({**counters, **more})
 

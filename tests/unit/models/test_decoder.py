@@ -190,3 +190,134 @@ def test_the_parameter_tree_keeps_its_names(model, layers, norm):
     assert params["lm_head_kernel"].shape == (cfg.hidden_size, columns)
     assert params["lm_head_kernel"].dtype == params[norm].dtype == jnp.float32
     assert params[norm].shape == (cfg.hidden_size,)
+
+
+# ------------------------------ a value carried from layer to layer, a tied head
+class CarryingBlock(nn.Module):
+    """A layer that hands the next a running mean of what it read, beside
+    the stream: the first layer held is called with the stream alone."""
+    KINDS = frozenset((MLP,))
+
+    config: ToyConfig
+    kind: str = MLP
+
+    @nn.compact
+    def __call__(self, x, carried=None):
+        cfg = self.config
+        H = x.shape[-1]
+        scale = self.param("norm_scale", nn.initializers.ones, (H,),
+                           jnp.float32)
+        u = rms_norm(x, scale, eps=1e-6)
+        state = _dense(8, cfg, "state")(u)
+        if carried is not None:
+            mix = self.param("mix", nn.initializers.constant(0.5), (8,))
+            state = state + mix * carried
+        y = _dense(H, cfg, "down")(nn.silu(_dense(2 * H, cfg, "up")(u))
+                                   ) + _dense(H, cfg, "read")(state)
+        return x + y, {}, state
+
+
+class Carrying(Toy):
+    block_cls = CarryingBlock
+    tied = False
+
+    def stack(self):
+        return super().stack()._replace(tied_head=self.tied)
+
+    def num_params(self):
+        shapes = jax.eval_shape(lambda: self.init(
+            jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))
+        return sum(x.size for x in jax.tree_util.tree_leaves(shapes))
+
+
+class Tied(Carrying):
+    tied = True
+
+
+def test_a_value_carried_from_layer_to_layer_crosses_the_remat_wrap():
+    """The same loss and gradients with and without remat, the mix of the
+    first layer absent (it is handed nothing) and every later layer's
+    gradient alive: the carried value is differentiated through."""
+    kinds = (MLP,) * 3
+    plain, recomputed = (Carrying(ToyConfig(kinds=kinds, remat=r))
+                         for r in (False, True))
+    batch = plain.example_batch(2, 40)
+    params = _params(plain, batch)
+    assert "mix" not in params["layers_0"]
+    assert "mix" in params["layers_1"] and "mix" in params["layers_2"]
+    want, got = (jax.value_and_grad(m.loss_fn(), has_aux=True)(params, batch)
+                 for m in (plain, recomputed))
+    assert float(got[0][0]) == pytest.approx(float(want[0][0]), rel=1e-6)
+    for (path, g), w in zip(jax.tree_util.tree_leaves_with_path(got[1]),
+                            jax.tree_util.tree_leaves(want[1])):
+        np.testing.assert_allclose(g, w, rtol=1e-5, atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+    assert np.asarray(got[1]["layers_2"]["mix"]).any()
+    # layer 0's state reaches the loss through layers 1 AND 2 alone once
+    # its own read-out is cut: the chain of carried values
+    cut = jax.tree_util.tree_map(lambda x: x, params)
+    cut["layers_0"]["read"]["kernel"] = jnp.zeros_like(
+        cut["layers_0"]["read"]["kernel"])
+    through = jax.grad(lambda p: plain.loss_fn()(p, batch)[0])(cut)
+    assert np.asarray(through["layers_0"]["state"]["kernel"]).any()
+
+
+def test_a_stack_that_ties_its_head_has_one_table():
+    """No ``lm_head_kernel``; the table's gradient is the sum of its use as
+    the embedding and its use as the head; the table counts once."""
+    kinds = (MLP,) * 2
+    tied, untied = (cls(ToyConfig(kinds=kinds)) for cls in (Tied, Carrying))
+    batch = tied.example_batch(2, 40)
+    params = _params(tied, batch)
+    assert "lm_head_kernel" not in params
+    assert "lm_head_kernel" in _params(untied, batch)
+    assert untied.num_params() - tied.num_params() == 64 * 32
+    table = params["embed_tokens"]["embedding"]
+    np.testing.assert_array_equal(tied.head_kernel(params), table.T)
+
+    def loss(embedding, head):
+        hidden, _ = tied.apply({"params": dict(
+            params, embed_tokens={"embedding": embedding})}, batch["input_ids"])
+        return tied.head_loss(hidden, head.T, batch)[0]
+
+    as_embedding, as_head = jax.grad(loss, argnums=(0, 1))(table, table)
+    whole = jax.grad(lambda p: tied.loss_fn()(p, batch)[0])(params)[
+        "embed_tokens"]["embedding"]
+    assert np.asarray(as_embedding).any() and np.asarray(as_head).any()
+    np.testing.assert_allclose(as_embedding + as_head, whole, rtol=1e-5,
+                               atol=1e-8)
+    # the per-token log-probabilities go through the same table
+    lp, _, _ = _no_routing(tied).logprobs(params, batch["input_ids"],
+                                          batch["labels"])
+    assert float(-lp.mean()) == pytest.approx(
+        float(tied.loss_fn()(params, batch)[0]), rel=1e-5)
+
+
+def _no_routing(model):
+    """The model, saying what ``chosen`` is where no layer routed."""
+    class Quiet(type(model)):
+        def none_chosen(self, shape):
+            return jnp.zeros((0,) + shape + (0,), bool)
+    return Quiet(model.config)
+
+
+def test_a_stack_that_does_neither_builds_the_tree_it_built_before():
+    """Same leaves, same shapes, ``lm_head_kernel`` among them: the toy's
+    tree by hand, as it was before a stack could carry or tie."""
+    model = Toy(ToyConfig(kinds=(MLP, ROUTED)))
+    assert model.stack().tied_head is False
+    tree = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32)))["params"]
+    shapes = {jax.tree_util.keystr(p): v.shape
+              for p, v in jax.tree_util.tree_leaves_with_path(tree)}
+    assert shapes == {
+        "['embed_tokens']['embedding']": (64, 32),
+        "['final_norm_scale']": (32,),
+        "['lm_head_kernel']": (32, 64),
+        "['layers_0']['norm_scale']": (32,),
+        "['layers_0']['up']['kernel']": (32, 64),
+        "['layers_0']['down']['kernel']": (64, 32),
+        "['layers_1']['norm_scale']": (32,),
+        "['layers_1']['router_kernel']": (32, 4),
+        "['layers_1']['experts_up_proj']": (4, 32, 64),
+        "['layers_1']['experts_down_proj']": (4, 64, 32)}

@@ -338,6 +338,8 @@ def test_flash_mha_window_fwd_bwd(one_chip, shape, window):
     # the hybrid cell's one layer; one KV head; one block to the head
     ((2, 8192, 8, 128), 2, None), ((2, 4096, 8, 128), 1, None),
     ((2, 2048, 4, 128), 2, 300),
+    # train-zaya1-8b-ep2-8k: the latent's 8 query heads over 2 KV heads
+    ((4, 8192, 8, 128), 2, None),
 ])
 def test_flash_mha_grouped_query_fwd_bwd(one_chip, shape, kv, window):
     """Grouped-query heads are the same two kernel calls: q, o, do and dq at
@@ -662,12 +664,40 @@ def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):
     assert "flash_attention" not in passes
 
 
-@pytest.mark.parametrize("tokens,latent,inner,held,gated", [
-    (32768, 2304, 896, 16, True),    # train-mellum2-ep4-8k's layer
-    (16384, 2304, 896, 16, True),    # the same at micro-batch 2
-    (16384, 3072, 1024, 8, True),    # train-laguna-s-ep32-8k's layer
+def test_recomputed_zaya_keeps_the_flash_residuals_and_the_walks_plan(
+        one_chip, on_the_chip):
+    """``Zaya`` (three layers, remat, the head tied to the table): a layer's
+    attention in the latent is a ``flash_attention`` call forward and one
+    backward with k and v at the 2 KV heads, a layer's top-1 walk the
+    grouped form's two matmuls forward and six backward; the recomputed
+    layer keeps the kernel's residuals and the walk's plan and runs neither
+    again."""
+    from deeperspeed_tpu.models.zaya import Zaya, ZayaConfig
+
+    model = Zaya(ZayaConfig.tiny(
+        hidden_size=256, num_heads=4, num_kv_heads=2, head_dim=128,
+        moe_intermediate_size=128, router_hidden_size=128, max_seq_len=256,
+        ce_chunk_tokens=256, remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    passes = _model_gradient_passes(
+        model, lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
+    assert passes["flash_attention"] == dict(forward=3, recomputed=0,
+                                             backward=3)
+    assert passes["grouped_matmul"] == dict(forward=3 * 2, recomputed=0,
+                                            backward=3 * 6)
+    assert passes["unwritten"] == dict(forward=3, recomputed=0, backward=3)
+
+
+@pytest.mark.parametrize("tokens,latent,inner,held,gated,most", [
+    (32768, 2304, 896, 16, True, 8),    # train-mellum2-ep4-8k's layer
+    (16384, 2304, 896, 16, True, 8),    # the same at micro-batch 2
+    (16384, 3072, 1024, 8, True, 8),    # train-laguna-s-ep32-8k's layer
+    # train-zaya1-8b-ep2-8k's: experts as wide as the stream, a slot a token
+    (32768, 2048, 2048, 8, True, 1),
 ])
-def test_grouped_walk_fwd_bwd(one_chip, tokens, latent, inner, held, gated):
+def test_grouped_walk_fwd_bwd(one_chip, tokens, latent, inner, held, gated,
+                              most):
     """The routed walk's grouped form at a cell's shapes, forward and
     backward: two grouped matmuls into a buffer handed over unwritten, then
     those again, two transposed and two outer products into float32
@@ -680,7 +710,7 @@ def test_grouped_walk_fwd_bwd(one_chip, tokens, latent, inner, held, gated):
     def fn(x, held_w, w_in, w_out, is_chosen):
         return dropless.routed_experts(
             x, held_w, is_chosen, w_in, w_out, activation,
-            dropless.ROWS_PER_GROUPED_CHUNK, True, min(8, held))[0]
+            dropless.ROWS_PER_GROUPED_CHUNK, True, most)[0]
 
     shapes = (_sds((tokens, latent), bf16, one_chip),
               _sds((tokens, held), jnp.float32, one_chip),
