@@ -15,9 +15,11 @@ with a tied table.
   convolution whose channels mix inside each head (``ops/ssm.py``); the
   mean of a query head and its KV head from BEFORE the convolutions is
   added back; every head is divided by its RMS, k times a learned
-  temperature a KV head, rotary on half a head (``cca_mix``: everything
-  between the projections and the flash kernel, which is called with
-  grouped-query heads as it is for every other model).
+  temperature a KV head, rotary on half a head (``ops/attention/cca.py``'s
+  ``cca_mix``: everything between the projections and the flash kernel,
+  one kernel pair on a TPU where the shapes are whole tiles; the flash
+  kernel is called with grouped-query heads as it is for every other
+  model).
 * The router.  ``rho = u W_D + b_D`` in 256; ``rho += gamma * rho_prev``,
   the previous layer's state (the decoder stack hands it on beside the
   stream); an RMSNorm and a three-matrix GELU MLP give the 16 logits; the
@@ -55,10 +57,9 @@ from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from ..moe import dropless
+from ..ops.attention.cca import cca_mix
 from ..ops.attention.core import dot_product_attention
-from ..ops.ssm import causal_depthwise_conv1d, causal_headwise_conv1d
 from ..ops.transformer.normalize import rms_norm
-from ..ops.transformer.rope import _rotate_half, rotary_tables
 from ..parallel.topology import BATCH_AXES
 from .decoder import Decoder, Stack, _dense
 from .gpt_neox import maybe_constrain
@@ -150,55 +151,6 @@ def fold(r, f, scale, bias):
     scale, bias = scale.astype(jnp.float32), bias.astype(jnp.float32)
     return (scale[0] * (r.astype(jnp.float32) + bias[0])
             + scale[1] * (f.astype(jnp.float32) + bias[1])).astype(r.dtype)
-
-
-def _a_step_later(x):
-    """``y[:, t] = x[:, t - 1]``, zeros before the sequence."""
-    return jnp.pad(x, ((0, 0), (1, 0), (0, 0)))[:, :-1]
-
-
-def cca_mix(qt, kt, v, taps, taps_bias, head_kernel, head_bias, temperature,
-            *, heads, kv_heads, rotary_dim, rope_theta, eps):
-    """Everything between CCA's projections and the attention kernel, on
-    ``[B, S, heads x d]`` as the projections leave it and the kernel reads
-    it (a head is a run of whole lane blocks: nothing is laid out anew):
-    ``qt`` [B, S, n_q d], ``kt`` and ``v`` [B, S, n_kv d] -> (q, k, v) of the
-    same shapes.  The value shift, the two convolutions on the packed ``[qt |
-    kt]``, the q-k mean from before them, unit-RMS heads, k's temperature,
-    rotary on the first ``rotary_dim`` of a head; float32 from the
-    convolutions on."""
-    dtype, f32 = qt.dtype, jnp.float32
-    group = heads // kv_heads
-    own, previous = jnp.split(v, 2, axis=-1)
-    v = jnp.concatenate([own, _a_step_later(previous)], axis=-1)
-    z = causal_depthwise_conv1d(jnp.concatenate([qt, kt], axis=-1), taps,
-                                taps_bias)
-    z = causal_headwise_conv1d(z, head_kernel, head_bias)
-    # a head at a time, as slices of the last axis (``ops/ssm.py``'s group
-    # norm says why not a reshape)
-    z = jnp.split(z.astype(f32), heads + kv_heads, axis=-1)
-    q_before = jnp.split(qt.astype(f32), heads, axis=-1)
-    k_before = jnp.split(kt.astype(f32), kv_heads, axis=-1)
-    m_q = [(q_before[j] + k_before[j // group]) / 2 for j in range(heads)]
-    m_k = [sum(m_q[i * group:(i + 1) * group]) / group
-           for i in range(kv_heads)]
-    cos, sin = rotary_tables(jnp.arange(qt.shape[1]), rotary_dim, rope_theta)
-    cos, sin = cos[:, 0], sin[:, 0]                      # [S, rotary_dim]
-
-    def head(x, scale=None):
-        x = x * jax.lax.rsqrt(jnp.mean(jnp.square(x), -1, keepdims=True)
-                              + eps)
-        if scale is not None:
-            x = x * scale
-        turn, rest = x[..., :rotary_dim], x[..., rotary_dim:]
-        return jnp.concatenate([turn * cos + _rotate_half(turn) * sin, rest],
-                               axis=-1)
-
-    q = [head(z[j] + m_q[j]) for j in range(heads)]
-    k = [head(z[heads + i] + m_k[i], temperature[i].astype(f32))
-         for i in range(kv_heads)]
-    return (jnp.concatenate(q, axis=-1).astype(dtype),
-            jnp.concatenate(k, axis=-1).astype(dtype), v)
 
 
 class ZayaAttention(nn.Module):

@@ -25,9 +25,9 @@ from jax.sharding import SingleDeviceSharding
 
 from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
-from deeperspeed_tpu.ops.attention import (dsa, eva, paged, pallas_dsa,
-                                           pallas_eva, pallas_eva_pool,
-                                           pallas_flash)
+from deeperspeed_tpu.ops.attention import (cca, dsa, eva, paged, pallas_cca,
+                                           pallas_dsa, pallas_eva,
+                                           pallas_eva_pool, pallas_flash)
 from deeperspeed_tpu.ops.quantizer import fused as qfused
 from deeperspeed_tpu.ops.sampling import topk
 from deeperspeed_tpu.ops.transformer import normalize
@@ -35,7 +35,7 @@ from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
 _BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
-            pallas_gmm, pallas_eva, pallas_eva_pool, pallas_dsa)
+            pallas_gmm, pallas_eva, pallas_eva_pool, pallas_dsa, pallas_cca)
 
 
 @pytest.fixture(scope="module")
@@ -687,6 +687,53 @@ def test_recomputed_zaya_keeps_the_flash_residuals_and_the_walks_plan(
     assert passes["grouped_matmul"] == dict(forward=3 * 2, recomputed=0,
                                             backward=3 * 6)
     assert passes["unwritten"] == dict(forward=3, recomputed=0, backward=3)
+    # the latent's mixing is the kernel pair (the row block follows a
+    # sequence of 256 rows), run again in the recomputed layer: q, k, v are
+    # not kept by name
+    assert passes["cca_mix"] == dict(forward=3, recomputed=3, backward=3)
+
+
+def test_cca_mix_pair_at_the_zaya_cells_shape(one_chip):
+    """CCA's mixing at ``train-zaya1-8b-ep2-8k``'s shape (4 x 8192 rows, 8
+    query and 2 KV heads of 128, bfloat16), forward + backward in all eight
+    operands: two kernel calls under the scope ``cca_mix`` that read the
+    projections' ``[B, S, n d]`` where it lies and write q, k, v (and their
+    gradients) in the same layout; the backward's residuals are the streams
+    and the parameters, and no buffer of the program is a float32 copy of a
+    stream."""
+    B, S, heads, kv_heads, d = 4, 8192, 8, 2, 128
+    c, bf16, f32 = (heads + kv_heads) * d, jnp.bfloat16, jnp.float32
+    wide, thin = (B, S, heads * d), (B, S, kv_heads * d)
+    shapes = [_sds(shape, dtype, one_chip) for shape, dtype in (
+        (wide, bf16), (thin, bf16), (thin, bf16), ((2, c), f32), ((c,), f32),
+        ((2, heads + kv_heads, d, d), f32), ((c,), f32), ((kv_heads,), f32))]
+
+    def mix(*operands):
+        with jax.named_scope("layer"):
+            return cca.cca_mix(
+                *operands, heads=heads, kv_heads=kv_heads, rotary_dim=d // 2,
+                rope_theta=5e6, eps=1e-5, use_pallas=True)
+
+    def weighted(*operands):    # cotangents that are not a constant
+        return sum(jnp.sum(out.astype(f32) * out.astype(f32))
+                   for out in mix(*operands))
+
+    forward = pallas_kernel_calls(_compile(mix, *shapes))
+    assert list(forward) == ["cca_mix"] and len(forward["cca_mix"]) == 1
+    assert forward["cca_mix"][0][:3] == [wide, thin, thin]
+    compiled = jax.jit(jax.grad(weighted, argnums=tuple(range(8)))).lower(
+        *shapes).compile()
+    text = compiled.as_text()
+    calls = pallas_kernel_calls(text)
+    assert {name: len(found) for name, found in calls.items()} == {
+        "cca_mix": 2}
+    backward = max(calls["cca_mix"], key=len)
+    # the streams and their halo views, then the three cotangents
+    assert backward[:7] == [wide, thin, wide, thin, wide, thin, thin]
+    entry = text[text.index("\nENTRY "):]
+    assert f"= f32[{B},{S}," not in entry, "a float32 buffer of a stream's size"
+    assert pallas_cca.compiles_for_tpu(S, d, d // 2)
+    assert pallas_cca.mix_rows(S) == pallas_cca.ROWS
 
 
 @pytest.mark.parametrize("tokens,latent,inner,held,gated,most", [
