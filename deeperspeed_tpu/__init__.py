@@ -16,6 +16,10 @@ Public API shape follows the reference (`deepspeed/__init__.py:64,246,269`):
     loss = engine.train_batch(batch)
 """
 
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # ``setup/import`` runs from here
+
 __version__ = "0.1.0"
 __git_branch__ = "main"
 
@@ -27,6 +31,10 @@ from .runtime.initialize import initialize, add_config_arguments  # noqa: F401
 from .runtime.pipe.module import PipelineModule, LayerSpec, TiedLayerSpec  # noqa: F401
 from .parallel.topology import ProcessTopology, PipeModelDataParallelTopology  # noqa: F401
 from .utils import logging as _logging  # noqa: F401
+from .telemetry.trace import keep_setup_span as _keep_setup_span
+
+# the whole of this import, for ``telemetry.setup_timeline()``
+_keep_setup_span("setup/import", _IMPORT_T0, _time.perf_counter())
 
 
 def init_distributed(dist_backend=None, **kwargs):

@@ -28,9 +28,10 @@ from ..accelerator import get_accelerator
 from ..monitor.monitor import MonitorMaster
 from ..parallel import topology as topo
 from ..telemetry.trace import (TraceSessionWatch, compile_stats,
+                               describe_time_to_first_step,
                                publish_kernel_passes, publish_step_counters,
                                publish_step_scopes, span, step_scopes,
-                               step_span)
+                               step_span, time_to_first_step)
 from ..utils.logging import log_dist, logger
 from ..utils.timer import (
     BACKWARD_GLOBAL_TIMER,
@@ -294,10 +295,13 @@ class DeeperSpeedEngine:
             self._lr_fn = lambda step: jnp.asarray(base_lr, jnp.float32)
         self.lr_scheduler = self._lr_fn
 
-        # ---- materialize train state
-        self.state = self._build_state()
-        self._state_shardings = self._shardings_like_state()
-        self._spill_opt()
+        # ---- materialize train state: the one stretch of this method that
+        # takes 0.5 s or more on the chip (PERF.md section 5), so the one
+        # child of ``setup/initialize``
+        with span("setup/initialize/state"):
+            self.state = self._build_state()
+            self._state_shardings = self._shardings_like_state()
+            self._spill_opt()
 
         # ---- data-efficiency stack (curriculum / random-LTD / PLD /
         # eigenvalue), reference ``engine.py:551-570,1809-1821``.  Must
@@ -377,6 +381,9 @@ class DeeperSpeedEngine:
         self._comm_footprint = None  # trace-time collective wire footprint
         self._tele_captured = False
         self._trace_watch = TraceSessionWatch()
+        #: ``telemetry.trace.time_to_first_step`` at the end of the first
+        #: ``train_batch`` that compiled nothing (logged there, once)
+        self.time_to_first_step = None
 
         # ---- resilience: preemption handlers + loss sentinel (PR 3)
         from .resilience import build_resilience
@@ -1334,12 +1341,19 @@ class DeeperSpeedEngine:
         publish = self._trace_watch.ended()
         with step_span("train/step", self.global_steps, "train_step",
                        profiled=self._trace_watch.profiled) as step:
-            return self._train_step_phases(step, data, capture, publish)
+            loss = self._train_step_phases(step, data, capture, publish)
+        if self.time_to_first_step is None and not step.record["compiled"]:
+            # once: the first step that compiled nothing has closed
+            self.time_to_first_step = time_to_first_step(step.record["t1"])
+            log_dist(describe_time_to_first_step(self.time_to_first_step),
+                     ranks=[0])
+        return loss
 
     def _run_step(self, step, dispatch, publish, fn, *args):
         """Call a step program inside its ``train/dispatch`` span, which
         gets ``compiled=1`` if the call compiled anything, as the step's
-        record does."""
+        record does, with ``compile``: what the compile was made of, from
+        the span's start to here (``compile_stats().between``)."""
         if publish:
             self._publish_scopes(fn, *args)
         compiled = compile_stats().programs
@@ -1347,6 +1361,11 @@ class DeeperSpeedEngine:
         if compile_stats().programs != compiled:
             dispatch.set(compiled=1)
             step.record["compiled"] = True
+            found = compile_stats().between(dispatch.entered,
+                                            time.perf_counter())
+            kept = step.record.get("compile")
+            step.record["compile"] = found if kept is None else {
+                key: kept[key] + value for key, value in found.items()}
         return out
 
     def _publish_scopes(self, fn, *args):
@@ -1851,6 +1870,17 @@ class DeeperSpeedEngine:
     def load_checkpoint(self, load_dir, tag=None, load_module_strict=True,
                         load_optimizer_states=True, load_lr_scheduler_states=True,
                         load_module_only=False, custom_load_fn=None):
+        """Restore from ``load_dir`` -> (path, client state), the whole of
+        it one ``setup/load_checkpoint`` span: a restart's time to first
+        step has it beside ``setup/initialize``."""
+        with span("setup/load_checkpoint"):
+            return self._load_checkpoint(
+                load_dir, tag=tag,
+                load_optimizer_states=load_optimizer_states,
+                load_module_only=load_module_only)
+
+    def _load_checkpoint(self, load_dir, tag, load_optimizer_states,
+                         load_module_only):
         # universal (per-parameter slice) checkpoints load through their own
         # path into any topology (reference ``engine.py:800``
         # ``load_universal_checkpoint``)

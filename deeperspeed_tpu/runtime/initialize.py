@@ -10,6 +10,7 @@ import argparse
 
 from .config import DeeperSpeedConfig
 from .engine import DeeperSpeedEngine
+from ..telemetry.trace import span
 from ..utils.logging import log_dist
 
 
@@ -28,43 +29,44 @@ def initialize(
     loss_fn=None,
     config_params=None,
 ):
-    assert model is not None, "deeperspeed_tpu.initialize requires a model"
-    if config is None:
-        config = config_params
-    if config is None and args is not None and hasattr(args, "deepspeed_config"):
-        config = args.deepspeed_config
-    assert config is not None, "no config: pass config= or args.deepspeed_config"
+    with span("setup/initialize"):
+        assert model is not None, "deeperspeed_tpu.initialize requires a model"
+        if config is None:
+            config = config_params
+        if config is None and args is not None and hasattr(args, "deepspeed_config"):
+            config = args.deepspeed_config
+        assert config is not None, "no config: pass config= or args.deepspeed_config"
 
-    _apply_overlap_xla_flags(config)
-    model = _apply_moe_quantized_alltoall(model, config)
+        _apply_overlap_xla_flags(config)
+        model = _apply_moe_quantized_alltoall(model, config)
 
-    from .pipe.module import PipelineModule
+        from .pipe.module import PipelineModule
 
-    if isinstance(model, PipelineModule) or hasattr(model, "stage_forward"):
-        engine = _build_pipeline_engine(
-            model, config, optimizer=optimizer,
-            model_parameters=model_parameters, training_data=training_data,
-            lr_scheduler=lr_scheduler, mesh=mesh, loss_fn=loss_fn,
-            collate_fn=collate_fn,
-        )
-    elif _hybrid_enabled(config):
-        # reference engine selection: hybrid config -> DeepSpeedHybridEngine
-        # (``deepspeed/__init__.py:156-196``)
-        from .hybrid_engine import DeeperSpeedHybridEngine
+        if isinstance(model, PipelineModule) or hasattr(model, "stage_forward"):
+            engine = _build_pipeline_engine(
+                model, config, optimizer=optimizer,
+                model_parameters=model_parameters, training_data=training_data,
+                lr_scheduler=lr_scheduler, mesh=mesh, loss_fn=loss_fn,
+                collate_fn=collate_fn,
+            )
+        elif _hybrid_enabled(config):
+            # reference engine selection: hybrid config -> DeepSpeedHybridEngine
+            # (``deepspeed/__init__.py:156-196``)
+            from .hybrid_engine import DeeperSpeedHybridEngine
 
-        engine = DeeperSpeedHybridEngine(
-            model=model, config=config, optimizer=optimizer,
-            model_parameters=model_parameters, training_data=training_data,
-            lr_scheduler=lr_scheduler, mesh=mesh, loss_fn=loss_fn,
-            collate_fn=collate_fn,
-        )
-    else:
-        engine = DeeperSpeedEngine(
-            model=model, config=config, optimizer=optimizer,
-            model_parameters=model_parameters, training_data=training_data,
-            lr_scheduler=lr_scheduler, mesh=mesh, mpu=mpu, loss_fn=loss_fn,
-            collate_fn=collate_fn,
-        )
+            engine = DeeperSpeedHybridEngine(
+                model=model, config=config, optimizer=optimizer,
+                model_parameters=model_parameters, training_data=training_data,
+                lr_scheduler=lr_scheduler, mesh=mesh, loss_fn=loss_fn,
+                collate_fn=collate_fn,
+            )
+        else:
+            engine = DeeperSpeedEngine(
+                model=model, config=config, optimizer=optimizer,
+                model_parameters=model_parameters, training_data=training_data,
+                lr_scheduler=lr_scheduler, mesh=mesh, mpu=mpu, loss_fn=loss_fn,
+                collate_fn=collate_fn,
+            )
     log_dist("initialize() complete", ranks=[0])
     return engine, engine.optimizer, engine.training_dataloader, engine.lr_scheduler
 

@@ -22,9 +22,12 @@ Four pieces (see README "Observability"):
   primitive every live span goes through (a ``dst:`` event on the
   ``jax.profiler`` timeline, and a ring record when the tracer is on), and
   what is kept whether or not a tracer or a profiler is on:
-  :func:`compile_stats`, :func:`step_scopes`, :func:`step_timeline` (one
+  :func:`compile_stats` (with the compile phases by interval),
+  :func:`step_scopes`, :func:`step_timeline` (one
   record a train step: its clocks, its host phases by wall time, and the
   model's counters), :func:`step_counters` (the newest record's),
+  :func:`setup_timeline` (the ``setup/*`` spans and the compile phases:
+  the time to the first step by phase),
   :func:`kernel_paths` and :func:`kernel_passes`;
 * :mod:`aggregate` -- mergeable registry snapshots + the pool-side
   :class:`MetricsAggregator` (counters sum, histograms merge bucket-wise,
@@ -45,8 +48,8 @@ from .registry import (LATENCY_BUCKETS_S, CounterChannel, HistogramChannel,
 from .slo import SLOAlert, SLOBurnEvaluator
 from .trace import (FlightRecorder, Span, TraceContext, Tracer, compile_stats,
                     count_kernel_passes, get_tracer, kernel_passes,
-                    kernel_paths, set_tracer, slo_percentiles, span,
-                    step_counters, step_scopes, step_timeline,
+                    kernel_paths, set_tracer, setup_timeline, slo_percentiles,
+                    span, step_counters, step_scopes, step_timeline,
                     tracer_from_config)
 from .watchdog import StallWatchdog
 from .wire import plain_wire_bytes, q_bytes, quantized_variant, wire_bytes
@@ -59,6 +62,7 @@ __all__ = [
     "Tracer", "TraceContext", "Span", "FlightRecorder", "get_tracer",
     "set_tracer", "tracer_from_config", "slo_percentiles", "span",
     "compile_stats", "step_scopes", "step_counters", "step_timeline",
+    "setup_timeline",
     "kernel_paths",
     "kernel_passes", "count_kernel_passes",
     "StallWatchdog", "step_cost", "compiled_cost",

@@ -44,8 +44,15 @@ on the step's thread adds its wall time to it, and the model's counters of
 that step go with it (:func:`step_counters` is the
 newest record's).  Its ``step`` is the ``step_num`` the step's annotation
 carries, so a device trace joins it.
+
+Set-up has the same, beside it (:func:`setup_timeline`): the ``setup/*``
+spans closed outside any step (``setup/import``, ``setup/initialize`` and
+its children, ``setup/load_checkpoint``) and the compile phases
+:func:`compile_stats` keeps by interval (trace, lower, backend compile,
+cache load), so that the time to the first step can be told by phase.
 """
 
+import functools
 import json
 import os
 import re
@@ -64,6 +71,8 @@ from .registry import JsonlSink, _is_rank0, get_registry
 #: every program span is ``dst:<layer>/<phase>`` on the profiler's timeline
 #: (a harness's own are ``bench:<span>``)
 SPAN_PREFIX = "dst:"
+#: a span of this layer, closed outside a step, is kept (``setup_timeline``)
+SETUP_PREFIX = "setup/"
 
 
 class _ThreadState(threading.local):
@@ -139,10 +148,14 @@ class _SpanScope:
     another thread lands in no record.  No CPU clock is read here: on the
     chip machine's host such a read is a system call of 6-18 us and the
     clock ticks every 10 ms, so a phase of a few ms cannot be told from
-    its neighbours (PERF.md section 6, PR 42); the step reads them, twice."""
+    its neighbours (PERF.md section 6, PR 42); the step reads them, twice.
+
+    Outside a step a span named ``setup/...`` is kept whole, with the
+    ``setup/`` span it lies in (:func:`setup_timeline`); any other span
+    outside a step is kept nowhere but in the ring."""
 
     __slots__ = ("_tracer", "name", "attrs", "_ids", "_annotation", "span",
-                 "_step", "_entered")
+                 "_step", "entered", "_setup")
 
     def __init__(self, tracer, name, trace_id, parent_id, attrs):
         self._tracer = tracer
@@ -157,7 +170,11 @@ class _SpanScope:
         self._annotation.__enter__()
         self._step = _THREAD.step
         if self._step is not None:
-            self._entered = time.perf_counter()
+            #: ``time.perf_counter()`` where a span inside a step opened
+            self.entered = time.perf_counter()
+        else:
+            self._setup = (_SETUP_TIMELINE.open(self.name, self.attrs)
+                           if self.name.startswith(SETUP_PREFIX) else None)
         if self._tracer.enabled:
             trace_id, parent_id = self._ids
             stack = _open_spans()
@@ -183,16 +200,20 @@ class _SpanScope:
         self._annotation.set_metadata(**attrs)
         if self.span is not None:
             self.span.attrs.update(attrs)
+        if self._step is None and self._setup is not None:
+            self._setup.update(attrs)
 
     def __exit__(self, exc_type, exc, tb):
         if self._step is not None:
-            wall = time.perf_counter() - self._entered
+            wall = time.perf_counter() - self.entered
             phase = self._step["phases"].get(self.name)
             if phase is None:
                 self._step["phases"][self.name] = [wall, 1]
             else:
                 phase[0] += wall
                 phase[1] += 1
+        elif self._setup is not None:
+            _SETUP_TIMELINE.close(self._setup)
         if self.span is not None:
             if exc_type is not None:
                 self.span.attrs["error"] = exc_type.__name__
@@ -573,49 +594,188 @@ def tenant_percentiles(records, quantiles=(0.5, 0.95, 0.99)):
 
 
 # ------------------------------------------------------------ compile stats
+def _merged(intervals, t0=None, t1=None):
+    """``intervals`` clipped to ``[t0, t1]`` as disjoint ones, in order."""
+    out = []
+    for a, b in sorted(intervals):
+        a = a if t0 is None else max(a, t0)
+        b = b if t1 is None else min(b, t1)
+        if b <= a:
+            continue
+        if out and a <= out[-1][1]:
+            out[-1][1] = max(out[-1][1], b)
+        else:
+            out.append([a, b])
+    return out
+
+
+def _overlap(xs, ys):
+    """Seconds that two lists of disjoint ordered intervals share."""
+    shared, i, j = 0.0, 0, 0
+    while i < len(xs) and j < len(ys):
+        shared += max(0.0, min(xs[i][1], ys[j][1]) - max(xs[i][0], ys[j][0]))
+        if xs[i][1] <= ys[j][1]:
+            i += 1
+        else:
+            j += 1
+    return shared
+
+
+class _CompilePhase:
+    """One kind of compile work: how often, the sum of its seconds, its
+    intervals on ``time.perf_counter()`` and ``{fun_name: [count, s]}``."""
+
+    __slots__ = ("count", "seconds", "intervals", "by_name")
+
+    def __init__(self, keep):
+        self.count, self.seconds = 0, 0.0
+        self.intervals = deque(maxlen=keep)
+        self.by_name = {}
+
+
 class _CompileStats:
     """What this process compiled, from ``jax.monitoring``: programs handed
     to the backend compiler, persistent-cache hits (programs loaded instead)
     and misses (programs compiled and written to it), with the instant
-    (``time.perf_counter``) and the seconds of each compile and load."""
+    (``time.perf_counter``) and the seconds of each compile and load.
+
+    And where compiling's time went, by interval: every jaxpr trace, every
+    lowering to MLIR, every backend compile and every load from the cache
+    (``phases``, a :class:`_CompilePhase` each).  Traces nest (a jitted call
+    inside a traced function fires inside the outer one's interval) and jax
+    wraps the cache's lookup in its backend-compile event, so sums of
+    durations count seconds twice; :meth:`seconds` gives unions.  A listener
+    is a few appends: no lock, nothing read from a device."""
 
     KEEP = 4096   # instants kept; the counts go on
+    #: intervals kept a kind: a set-up traces 16 thousand functions
+    KEEP_INTERVALS = 1 << 16
+    #: kind -> the ``jax.monitoring`` event it is read from
+    EVENTS = {
+        "trace": "/jax/core/compile/jaxpr_trace_duration",
+        "lower": "/jax/core/compile/jaxpr_to_mlir_module_duration",
+        "backend_compile": "/jax/core/compile/backend_compile_duration",
+        "cache_load": "/jax/compilation_cache/cache_retrieval_time_sec",
+    }
 
     def __init__(self):
         self.programs = self.cache_hits = self.cache_misses = 0
         self.compiles = deque(maxlen=self.KEEP)      # (done at, seconds)
         self.cache_loads = deque(maxlen=self.KEEP)   # (done at, seconds)
+        self.hits_at = deque(maxlen=self.KEEP)       # instants of the hits
+        self.misses_at = deque(maxlen=self.KEEP)     # ... and of the misses
+        self.phases = {kind: _CompilePhase(self.KEEP_INTERVALS)
+                       for kind in self.EVENTS}
+        self._kind_of = {event: kind for kind, event in self.EVENTS.items()}
+        #: the kinds whose interval is ``[now - seconds, now]`` of a
+        #: duration: the cache's load has no other, and all of them where
+        #: jax has no time-span listener (:meth:`listen`)
+        self._by_duration = {"cache_load"}
+
+    def listen(self, monitoring):
+        """Register with ``jax.monitoring``: the time spans
+        (``record_event_time_span(event, start, end, fun_name=...)``, which
+        jax 0.9.0 fires beside every duration of ``log_elapsed_time``) where
+        it has them, the durations alone where not (no names then)."""
+        monitoring.register_event_listener(self.on_event)
+        monitoring.register_event_duration_secs_listener(self.on_duration)
+        register = getattr(monitoring, "register_event_time_span_listener",
+                           None)
+        if register is not None:
+            register(self.on_time_span)
+        else:
+            self._by_duration = set(self.EVENTS)
 
     def on_event(self, event, **_kw):
         if event == "/jax/compilation_cache/cache_hits":
             self.cache_hits += 1
+            self.hits_at.append(time.perf_counter())
         elif event == "/jax/compilation_cache/cache_misses":
             self.cache_misses += 1
+            self.misses_at.append(time.perf_counter())
 
-    def on_duration(self, event, seconds, **_kw):
-        if event.endswith("backend_compile_duration"):
+    def on_duration(self, event, seconds, **kw):
+        kind = self._kind_of.get(event)
+        if kind in self._by_duration:
+            now = time.perf_counter()
+            self._keep(kind, now - float(seconds), now, kw.get("fun_name"))
+
+    def on_time_span(self, event, start, end, **kw):
+        kind = self._kind_of.get(event)
+        if kind is not None and kind not in self._by_duration:
+            # the events come on time.time(): one conversion a callback
+            shift = time.perf_counter() - time.time()
+            self._keep(kind, start + shift, end + shift, kw.get("fun_name"))
+
+    def _keep(self, kind, t0, t1, fun_name):
+        phase = self.phases[kind]
+        phase.count += 1
+        phase.seconds += t1 - t0
+        phase.intervals.append((t0, t1))
+        if fun_name is not None:
+            named = phase.by_name.get(fun_name)
+            if named is None:
+                phase.by_name[fun_name] = [1, t1 - t0]
+            else:
+                named[0] += 1
+                named[1] += t1 - t0
+        if kind == "backend_compile":
             self.programs += 1
-            kept = self.compiles
-        elif event.endswith("cache_retrieval_time_sec"):
-            kept = self.cache_loads
+            self.compiles.append((t1, t1 - t0))
+        elif kind == "cache_load":
+            self.cache_loads.append((t1, t1 - t0))
         else:
             return
-        kept.append((time.perf_counter(), float(seconds)))
         tracer = get_tracer()
         if tracer.enabled:
-            tracer.record_span("compile", "compile", dur_s=float(seconds),
-                               from_cache=kept is self.cache_loads)
+            tracer.record_span("compile", "compile", dur_s=t1 - t0,
+                               from_cache=kind == "cache_load", kind=kind,
+                               fun_name=fun_name)
+
+    def seconds(self, kind, t0=None, t1=None):
+        """The UNION of a kind's intervals (``trace``, ``lower``,
+        ``backend_compile``, ``cache_load``) clipped to ``[t0, t1]`` on
+        ``time.perf_counter()``: nested and repeated work counts once, so a
+        phase never exceeds the wall time that holds it.  ``backend_compile``
+        is that union less the cache loads inside it (jax's event wraps the
+        cache's lookup): the key's hashing, a true compile, the write."""
+        union = _merged(self.phases[kind].intervals, t0, t1)
+        total = sum((b - a for a, b in union), 0.0)
+        if kind == "backend_compile":
+            total -= _overlap(union, _merged(
+                self.phases["cache_load"].intervals, t0, t1))
+        return total
+
+    def between(self, t0, t1):
+        """What was compiled inside ``[t0, t1]``: the four unions as
+        ``<kind>_s`` and the programs, cache hits and misses counted there
+        (a step record's ``compile``)."""
+        found = {kind + "_s": self.seconds(kind, t0, t1)
+                 for kind in self.EVENTS}
+        for name, instants in (("programs", (at for at, _ in self.compiles)),
+                               ("cache_hits", self.hits_at),
+                               ("cache_misses", self.misses_at)):
+            found[name] = sum(1 for at in instants if t0 <= at <= t1)
+        return found
+
+    def slowest(self, kind, n=10):
+        """``[[fun_name, count, seconds]]`` of the ``n`` names with most
+        seconds of a kind (sums by name: a name traced inside itself counts
+        twice here, not in :meth:`seconds`)."""
+        ranked = sorted(self.phases[kind].by_name.items(),
+                        key=lambda item: -item[1][1])
+        return [[name, count, seconds] for name, (count, seconds)
+                in ranked[:n]]
 
 
 _COMPILE_STATS = _CompileStats()
-jax.monitoring.register_event_listener(_COMPILE_STATS.on_event)
-jax.monitoring.register_event_duration_secs_listener(
-    _COMPILE_STATS.on_duration)
+_COMPILE_STATS.listen(jax.monitoring)
 
 
 def compile_stats():
     """The process's compile counters, live (see :class:`_CompileStats`):
-    read ``.programs`` before and after a call to know whether it compiled."""
+    read ``.programs`` before and after a call to know whether it compiled,
+    ``.seconds(kind, t0, t1)`` for the wall time a compile phase took."""
     return _COMPILE_STATS
 
 
@@ -813,6 +973,160 @@ def step_counters(read=True):
     if read:
         newest = _read_counters(newest)
     return {r["program"]: dict(r["counters"]) for r in newest}
+
+
+# ---------------------------------------------------------- set-up timeline
+class _SetupTimeline:
+    """The ``setup/*`` spans closed outside a step, oldest first (the last
+    ``KEEP``), each with the ``setup/`` span it lay in on its thread.  Like
+    the step timeline: no lock, a ``deque.append`` is atomic."""
+
+    KEEP = _StepTimeline.KEEP
+
+    def __init__(self):
+        self.spans = deque(maxlen=self.KEEP)
+
+    def open(self, name, attrs):
+        """A span's record, open on this thread until :meth:`close`."""
+        try:
+            stack = _THREAD.setup
+        except AttributeError:
+            stack = _THREAD.setup = []
+        record = dict(attrs, name=name, t0=None, t1=None,
+                      parent=stack[-1]["name"] if stack else None)
+        stack.append(record)
+        record["t0"] = time.perf_counter()
+        return record
+
+    def close(self, record):
+        record["t1"] = time.perf_counter()
+        _THREAD.setup.pop()           # spans of one thread close in order
+        self.spans.append(record)
+
+    def keep(self, name, t0, t1, **attrs):
+        """An interval that was over before a span could be opened."""
+        self.spans.append(dict(attrs, name=name, t0=t0, t1=t1, parent=None))
+
+
+@functools.lru_cache(maxsize=None)
+def _process_t0():
+    """The process's start on ``time.perf_counter()``, from its start time
+    in ``/proc/self/stat`` (clock ticks since the boot) against the boot
+    clock now; None where that cannot be had.  Read once."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return time.perf_counter() - age
+
+
+_SETUP_TIMELINE = _SetupTimeline()
+
+
+def keep_setup_span(name, t0, t1, **attrs):
+    """Keep ``[t0, t1]`` (``time.perf_counter()``) as the ``setup/`` span
+    ``name``: for what ends before :func:`span` can be had, the package's own
+    import (``deeperspeed_tpu/__init__.py``)."""
+    _SETUP_TIMELINE.keep(name, t0, t1, **attrs)
+
+
+def setup_timeline():
+    """Set-up as the program timed it, the step timeline's sibling: always
+    on, copies, nothing read from a device.
+
+    * ``process_t0``: the process's start on ``time.perf_counter()`` (None
+      where ``/proc/self/stat`` cannot tell);
+    * ``spans``: the ``setup/*`` spans closed so far outside any step, oldest
+      first (the last 1,024): ``{"name", "t0", "t1", "parent", **attributes}``
+      with ``parent`` the name of the ``setup/`` span it lay in
+      (``setup/import``; ``setup/initialize`` and the engine's stretches
+      under it; ``setup/load_checkpoint``);
+    * ``compile``: ``{kind: {"count", "seconds", "wall_s"}}`` of the compile
+      phases (:class:`_CompileStats`): how many, the SUM of their durations,
+      and the union ``compile_stats().seconds(kind)``;
+    * ``slowest``: ``{kind: [[fun_name, count, seconds]]}``, the ten names
+      with most seconds of each kind.
+
+    The first steps are not here: they are records of
+    :func:`step_timeline`, and one whose dispatch compiled has ``compile``."""
+    stats = _COMPILE_STATS
+    return {
+        "process_t0": _process_t0(),
+        "spans": [dict(s) for s in _SETUP_TIMELINE.spans],
+        "compile": {kind: {"count": phase.count, "seconds": phase.seconds,
+                           "wall_s": stats.seconds(kind)}
+                    for kind, phase in stats.phases.items()},
+        "slowest": {kind: stats.slowest(kind) for kind in stats.phases},
+    }
+
+
+def time_to_first_step(t_end):
+    """From the process's start to ``t_end`` (``time.perf_counter()``, the
+    end of the first step that compiled nothing) by phase, in seconds:
+    ``before_import_s`` (the interpreter's start, whatever was imported
+    first), ``import_s`` (``setup/import``), ``initialize_s`` with
+    ``initialize`` (``{child: s}`` of the newest ``setup/initialize``),
+    ``compiled_steps`` and ``compiled_steps_s`` (the step records since then
+    whose dispatch compiled) with ``compile`` (the sum of those records'
+    own), and ``other_s``, what is left of ``total_s``: the caller's own
+    work before and between, and the step that compiled nothing."""
+    spans = [s for s in _SETUP_TIMELINE.spans if s["t1"] <= t_end]
+    imported = [s for s in spans if s["name"] == "setup/import"]
+    initialized = [s for s in spans if s["name"] == "setup/initialize"]
+    start = _process_t0()
+    if start is None:
+        start = min([s["t0"] for s in spans], default=t_end)
+    told = {"total_s": t_end - start, "before_import_s": 0.0,
+            "import_s": 0.0, "initialize_s": 0.0, "initialize": {}}
+    if imported:
+        told["before_import_s"] = imported[0]["t0"] - start
+        told["import_s"] = imported[0]["t1"] - imported[0]["t0"]
+    since = start
+    if initialized:
+        whole = initialized[-1]
+        since = whole["t1"]
+        told["initialize_s"] = whole["t1"] - whole["t0"]
+        told["initialize"] = {
+            s["name"].rsplit("/", 1)[1]: s["t1"] - s["t0"] for s in spans
+            if s["parent"] == whole["name"] and whole["t0"] <= s["t0"]
+            and s["t1"] <= whole["t1"]}
+    compiled = [r for r in _STEP_TIMELINE.records if "compile" in r
+                and since <= r["t0"] and r["t1"] <= t_end]
+    told["compiled_steps"] = len(compiled)
+    told["compiled_steps_s"] = sum(r["t1"] - r["t0"] for r in compiled)
+    told["compile"] = {key: sum(r["compile"][key] for r in compiled)
+                       for key in (compiled[0]["compile"] if compiled
+                                   else ())}
+    told["other_s"] = told["total_s"] - sum(
+        told[k] for k in ("before_import_s", "import_s", "initialize_s",
+                          "compiled_steps_s"))
+    return told
+
+
+def describe_time_to_first_step(told):
+    """:func:`time_to_first_step`'s answer as the operator's one line."""
+    parts = [f"before import {told['before_import_s']:.1f}",
+             f"import {told['import_s']:.1f}"]
+    children = ", ".join(f"{name} {s:.1f}"
+                         for name, s in told["initialize"].items())
+    parts.append(f"initialize {told['initialize_s']:.1f}"
+                 + (f" ({children})" if children else ""))
+    steps = (f"{told['compiled_steps']} compiled step"
+             f"{'' if told['compiled_steps'] == 1 else 's'} "
+             f"{told['compiled_steps_s']:.1f}")
+    c = told["compile"]
+    if c:
+        steps += (f" (trace {c['trace_s']:.1f}, lower {c['lower_s']:.1f}, "
+                  f"cache load {c['cache_load_s']:.1f}, compile "
+                  f"{c['backend_compile_s']:.1f}; programs {c['programs']}, "
+                  f"from the cache {c['cache_hits']}, written to it "
+                  f"{c['cache_misses']})")
+    parts += [steps, f"other {told['other_s']:.1f}"]
+    return (f"time to first step {told['total_s']:.1f} s: "
+            + " | ".join(parts))
 
 
 _KERNEL_PATHS = {}

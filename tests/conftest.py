@@ -58,10 +58,28 @@ PINNED_TO_A_LISTS_END = {
         "configuration, as a model_config PR must; the line should read [7]",
 }
 
+#: a test of the benchmark's with a clause from when set-up had no inside:
+#: test_bench_manifest.py:131 asserts `m["moves"] != "setup_s"` of every
+#: per-layer entry (PR 26), and PR 58 appended the eight that time set-up from
+#: the inside.  The file is the benchmark's; until a `benchmark` PR drops the
+#: clause both of its cases are expected to fail at that line, and
+#: test_bench_setup_readers.py::test_the_manifests_test_holds_but_for_its_clause_on_setup
+#: asserts every other clause of it on both manifests.
+SETUP_HAD_NO_INSIDE = {
+    "tests/unit/benchmarks/test_bench_manifest.py::"
+    "test_layer_metrics_have_readers_and_move_what_their_cells_report"
+    f"[{case}]":
+        "PR 26 wrote `m['moves'] != 'setup_s'` when nothing timed set-up "
+        "from the inside; PR 58's eight `setup.*` entries move it; the "
+        "clause should go"
+    for case in ("as committed", "after a later PR's entries")
+}
+
 
 def pytest_collection_modifyitems(config, items):
     for item in items:
-        why = PINNED_TO_A_LISTS_END.get(item.nodeid)
+        why = (PINNED_TO_A_LISTS_END.get(item.nodeid)
+               or SETUP_HAD_NO_INSIDE.get(item.nodeid))
         if why:
             item.add_marker(pytest.mark.xfail(
                 reason=why, raises=AssertionError, strict=False))
