@@ -38,10 +38,12 @@ wide block of rows, of columns in dk/dv, and walks the selection's tiles in
 its body; ``pallas_dsa.attend_plan`` sizes the blocks from the shapes, and
 ``telemetry.kernel_paths()["dsa_attention_walk"]`` says which walk a call
 got: ``resident`` | ``span``); ``SelLayout.rows`` stays the granularity of
-the counts, of the loss's scan and of ``dsa_head_probs``.  Elsewhere, and with ``use_pallas`` False, plain forms (the
-attention's then holds ``[B, N, S, S]``, the loss's ``[B, H_I, rows,
-cols]`` a chunk of rows: tests' sizes only).  The equations with what a
-published config leaves to assumption: ``benchmarks/reference/keye_ref.py``.
+the counts, of the loss's scan and of ``dsa_head_probs``' calls (which walk
+the heads in the kernel's body, ``pallas_dsa.head_probs_plan``).
+Elsewhere, and with ``use_pallas`` False, plain forms (the attention's then
+holds ``[B, N, S, S]``, the loss's ``[B, H_I, rows, cols]`` a chunk of rows:
+tests' sizes only).  The equations with what a published config leaves to
+assumption: ``benchmarks/reference/keye_ref.py``.
 """
 
 import functools
@@ -316,10 +318,13 @@ def _loss_pass_kernels(qi, ki, w, qp, k, lse, sel, heads, with_grads):
     q_cols = jnp.swapaxes(q_rows, 2, 3)
     k_cols = jnp.swapaxes(ki.reshape(B, lay.chunks, lay.chunk, D), 2, 3)
 
+    plan = pallas_dsa.head_probs_plan(lay, qp.shape[2] // k.shape[2])
+
     def chunk(carry, i):
         loss, dkt, dq, dw = carry
         pbar = pallas_dsa.head_probs_call(
-            qp, k, lse, sel.words, sel.counts, i[None], lay.rows, heads, lay)
+            qp, k, lse, sel.words, sel.counts, i[None], lay.rows, heads, lay,
+            plan)
         part, dq, dk, dw = pallas_dsa.loss_grads_call(
             q_rows, q_cols, ki, k_cols, w, pbar, sel.words, sel.counts,
             i[None], dq, dw, B * sel.seq, lay, with_grads)

@@ -51,8 +51,14 @@ Two-level tiling, in ``pallas_flash``'s words, for the three
   crosses), so the pairs a pass computes are ``dsa_pairs_visited`` whatever
   the block.
 
-``dsa_select``, ``dsa_head_probs`` and ``dsa_loss_grads`` keep one-level
-grids of their own sizes.
+``dsa_head_probs`` is tiled the same way with the HEADS walked in the body
+(``head_probs_plan``): a grid program owns an output tile, the call's row
+block of q at every head's columns stays resident for the whole call and a
+chunk of k at the KV heads serves them all; a few heads of a group run side
+by side, the rest in a loop, their probabilities are summed in float32 and
+masked, scaled and stored once a tile, and their log-sum-exp leave the lanes
+once a call.  ``dsa_select`` and ``dsa_loss_grads`` keep one-level grids of
+their own sizes.
 
 Kernels, each under the scope that names it in a device trace and in
 ``telemetry.kernel_passes()``:
@@ -67,7 +73,8 @@ Kernels, each under the scope that names it in a device trace and in
   the group's query heads), the KV head's sums in VMEM.
 * ``dsa_head_probs``: ``mean_h softmax_{S_t}(q_h . k)`` of a chunk of rows
   from the saved log-sum-exp, the heads summed in VMEM: float32
-  ``[B, rows, Sp]``, zero outside the chosen.
+  ``[B, rows, Sp]``, zero outside the chosen (a tile above the diagonal or
+  of count 0 is written as zeros).
 * ``dsa_loss_grads``: the indexer's loss of a chunk of rows against those
   probabilities, with its gradients.  A block of rows (its own size,
   ``loss_rows``: the loss shares nothing with the attention's block) walks
@@ -278,6 +285,14 @@ _TILES_BUDGET = 4 << 20
 _WORDS_BUDGET = 16 << 20
 
 
+def _side_by_side(layout, rep):
+    """Query heads of a group side by side in a program: four, or two,
+    where the group divides so and their float32 score tiles take
+    ``_TILES_BUDGET`` together."""
+    return next(h for h in (4, 2, 1) if rep % h == 0 and (
+        h == 1 or 4 * h * layout.rows * layout.chunk <= _TILES_BUDGET))
+
+
 def attend_plan(layout, d, rep, dtype, block=None, span=None, cols=None,
                 heads=None):
     """The attention kernels' own sizes, from what a call can see: the
@@ -304,8 +319,7 @@ def attend_plan(layout, d, rep, dtype, block=None, span=None, cols=None,
     sub, w, n, sp = layout.rows, layout.chunk, layout.chunks, layout.padded
     item = jnp.dtype(dtype).itemsize
     if heads is None:
-        heads = next(h for h in (4, 2, 1) if rep % h == 0 and (
-            h == 1 or 4 * h * sub * w <= _TILES_BUDGET))
+        heads = _side_by_side(layout, rep)
     if block is None:
         nq = sp // sub
         block = sub * next(
@@ -640,37 +654,71 @@ def bwd_call(q, k, v, do, lse, delta, words, counts, heads, layout, plan):
 
 
 # ------------------------------------------------ head-averaged probabilities
-def _probs(q_ref, k_ref, words_ref, lse, j):
-    """``_tile_probs`` of a program whose blocks are the tile."""
-    return _tile_probs(q_ref[0], k_ref[0], _chosen(words_ref, j), lse)
+class ProbsPlan(NamedTuple):
+    """Sizes of one ``dsa_head_probs`` call.  A grid program owns, and
+    computes at a time, the selection's tile of the output,
+    ``SelLayout.rows`` x ``SelLayout.chunk``."""
+    heads: int      # query heads of a group side by side in the body
 
 
-def _need(layout, d, itemsize, tiles):
-    """VMEM a program of the attention kernels holds: its blocks
-    double-buffered (rows of q-like operands, a chunk of k and v, the words)
-    and ``tiles`` float32 score-sized temporaries."""
-    bq, w = layout.rows, layout.chunk
-    return (2 * (4 * bq + 2 * w) * d * itemsize + 2 * bq * w * 4
-            + tiles * bq * w * 4 + 4 * bq * LANES * 4 + bq * d * 4)
+def head_probs_plan(layout, rep, heads=None):
+    """``dsa_head_probs``' own sizes, from what a call can see: the
+    selection's layout (the tile) and the query heads a KV head.  ``heads``
+    overrides (tests, ``tools/profile_dsa.py``'s sweep).  ``attend_plan``'s
+    rule for the heads side by side: their chains (a product, an ``exp``)
+    are independent, and their probabilities are summed before they meet
+    the output block.  Measured at the Keye cell's shape: BENCH_KERNELS.md,
+    PR 60."""
+    return ProbsPlan(_side_by_side(layout, rep) if heads is None else heads)
 
 
 def _head_probs_kernel(counts_ref, at_ref, q_ref, k_ref, lse_ref, words_ref,
-                       out_ref, *, nq, n, heads):
-    """Grid (b, row block i of the chunk of rows, chunk j, head h): the
-    heads' probabilities of a tile summed where the output block lies."""
-    b, i, j, h = (pl.program_id(a) for a in range(4))
+                       out_ref, lse_scr, *, nq, n, d, rep, hp, heads):
+    """Grid (b, row block i of the call's rows, chunk j): the program walks
+    the query heads in its body, ``hp`` of a KV head's group side by side,
+    and stores the mean of their probabilities of the tile over the chosen
+    pairs.  A tile no row chose in (all above the diagonal) is zeros."""
+    b, i, j = (pl.program_id(a) for a in range(3))
+    bq = q_ref.shape[1]
 
-    @pl.when(h == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    @pl.when(j == 0)
+    def _statistics():
+        # once a row block: head ``h`` of a side-by-side set in lane ``h``
+        lane = jax.lax.broadcasted_iota(jnp.int32, (bq, LANES), 1)
+
+        def side(s):
+            slab = jnp.zeros((bq, LANES), jnp.float32)
+            for h in range(hp):
+                slab = jnp.where(lane == h,
+                                 _rows_off_lanes(lse_ref[s * hp + h]), slab)
+            lse_scr[s] = slab
+
+        _walk(0, heads // hp, side)
+
+    out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(_tile_count(counts_ref, b, at_ref[0] + i, j, nq, n) > 0)
     def _tile():
-        lse = _rows_off_lanes(lse_ref[0])[:, :1]
-        out_ref[0] += _probs(q_ref, k_ref, words_ref, lse, j) * (1.0 / heads)
+        def side(s):
+            k = k_ref[0, :, _ds(s // (rep // hp) * d, d)]
+            q = q_ref[0, :, _ds(s * hp * d, hp * d)]
+            stat = lse_scr[s]
+            # exp of a pair no row chose may be anything: masked below
+            out_ref[0] += sum(jnp.exp(jax.lax.dot_general(
+                q[:, h * d:(h + 1) * d], k, _NT,
+                preferred_element_type=jnp.float32) - stat[:, h:h + 1])
+                for h in range(hp))
+
+        _walk(0, heads // hp, side)
+        out_ref[0] = jnp.where(_chosen(words_ref, j),
+                               out_ref[0] * (1.0 / heads), 0.0)
 
 
-def head_probs_call(q, k, lse, words, counts, at, rows, heads, layout):
+# Behind a ``jax.jit`` of its own, as the attention's calls: the scan's body
+# and every layer share one trace and one lowered kernel body.
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "heads", "layout", "plan"))
+def head_probs_call(q, k, lse, words, counts, at, rows, heads, layout, plan):
     """``mean_h softmax_{S_t}(q_h . k)`` of the ``rows`` rows from row block
     ``at`` (a traced int32 ``[1]``, in blocks of ``layout.rows``) ->
     float32 ``[B, rows, Sp]``, zero where a row did not choose."""
@@ -678,33 +726,42 @@ def head_probs_call(q, k, lse, words, counts, at, rows, heads, layout):
 
     b, sp, hw = q.shape
     d = hw // heads
-    rep = hw // k.shape[2]
+    kw = k.shape[2]
     bq, w, n = layout.rows, layout.chunk, layout.chunks
+    hp, item = plan.heads, q.dtype.itemsize
 
-    def near(i, j, at):
+    def near(i, j, at):         # a chunk past the diagonal's: never loaded
         return jnp.minimum(j, ((at[0] + i) * bq + bq - 1) // w)
 
+    # q (one buffer: it changes with the row block alone), k, the heads'
+    # log-sum-exp (a sublane tile each) and off the lanes, words and output,
+    # the heads' float32 tiles
+    need = (bq * hw * item + 2 * w * kw * item + 2 * heads * 8 * bq * 4
+            + (heads // hp) * bq * LANES * 4 + 4 * bq * w * 4
+            + (hp + 2) * bq * w * 4)
     with jax.named_scope(HEAD_PROBS):
         return pl.pallas_call(
-            functools.partial(_head_probs_kernel, nq=sp // bq, n=n,
-                              heads=heads),
+            functools.partial(_head_probs_kernel, nq=sp // bq, n=n, d=d,
+                              rep=hw // kw, hp=hp, heads=heads),
             grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=2, grid=(b, rows // bq, n, heads),
+                num_scalar_prefetch=2, grid=(b, rows // bq, n),
                 in_specs=[
-                    pl.BlockSpec((1, bq, d), lambda b, i, j, h, c, at: (
-                        b, at[0] + i, h)),
-                    pl.BlockSpec((1, w, d), lambda b, i, j, h, c, at: (
-                        b, near(i, j, at), h // rep)),
-                    pl.BlockSpec((1, 1, bq), lambda b, i, j, h, c, at: (
-                        b * heads + h, 0, at[0] + i)),
-                    pl.BlockSpec((1, bq, w), lambda b, i, j, h, c, at: (
+                    pl.BlockSpec((1, bq, hw), lambda b, i, j, c, at: (
+                        b, at[0] + i, 0), pipeline_mode=pl.Buffered(1)),
+                    pl.BlockSpec((1, w, kw), lambda b, i, j, c, at: (
+                        b, near(i, j, at), 0)),
+                    pl.BlockSpec((heads, 1, bq), lambda b, i, j, c, at: (
+                        b, 0, at[0] + i)),
+                    pl.BlockSpec((1, bq, w), lambda b, i, j, c, at: (
                         b, at[0] + i, 0))],
                 out_specs=pl.BlockSpec((1, bq, w),
-                                       lambda b, i, j, h, c, at: (b, i, j))),
+                                       lambda b, i, j, c, at: (b, i, j)),
+                scratch_shapes=[pltpu.VMEM((heads // hp, bq, LANES),
+                                           jnp.float32)]),
             out_shape=jax.ShapeDtypeStruct((b, rows, sp), jnp.float32),
             interpret=interpret_mode(),
-            **_params("parallel", "parallel", "parallel", "arbitrary",
-                      vmem=_vmem_limit(_need(layout, d, q.dtype.itemsize, 5))),
+            **_params("parallel", "parallel", "arbitrary",
+                      vmem=_vmem_limit(need)),
         )(counts, at, q, k, lse, words)
 
 

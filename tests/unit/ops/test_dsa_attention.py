@@ -382,8 +382,8 @@ def test_the_attentions_padded_rows_and_unchosen_columns_get_exactly_zero():
 def test_the_attentions_plan_is_its_own_and_a_function_of_shapes_alone():
     """The attention kernels' blocks come from the selection's layout, the
     head's width and the type, and move nothing else: ``SelLayout.rows``,
-    the loss's block and ``dsa_head_probs``' grid are what they were, and
-    ``dsa_attention`` takes no size."""
+    the loss's block and the rows of a ``dsa_head_probs`` call are what they
+    were, and ``dsa_attention`` takes no size."""
     import inspect
 
     lay, bf = pallas_dsa.sel_layout, jnp.bfloat16
@@ -414,19 +414,106 @@ def test_the_attentions_plan_is_its_own_and_a_function_of_shapes_alone():
     assert plan(2176) == (128, 2176, 2176, 4, "resident")
     assert list(inspect.signature(dsa.dsa_attention).parameters) == [
         "q", "k", "v", "sel", "scale", "use_pallas"]
-    # ``dsa_head_probs`` is called a chunk of ``SelLayout.rows`` rows, its
-    # grid (b, row blocks of the chunk, chunks, heads)
+    # ``dsa_head_probs`` is called a chunk of ``SelLayout.rows`` rows: a
+    # grid program an output tile, the heads walked in its body (no grid
+    # step a head), behind a jit of its own
     B, S, N, KV, D = 1, 16384, 32, 4, 128
     layout = lay(S)
     f32 = jnp.float32
+    probs = pallas_dsa.head_probs_plan(layout, N // KV)
     jaxpr = jax.make_jaxpr(
-        lambda *a: pallas_dsa.head_probs_call(*a, layout.rows, N, layout))(
+        lambda *a: pallas_dsa.head_probs_call(*a, layout.rows, N, layout,
+                                              probs))(
             jax.ShapeDtypeStruct((B, S, N * D), bf),
             jax.ShapeDtypeStruct((B, S, KV * D), bf),
             jax.ShapeDtypeStruct((B * N, 1, S), f32),
             jax.ShapeDtypeStruct((B, S, layout.chunk), jnp.int32),
             jax.ShapeDtypeStruct((B * 32 * 32,), jnp.int32),
             jax.ShapeDtypeStruct((1,), jnp.int32))
-    call, = (e for e in jaxpr.eqns if e.primitive.name == "pallas_call")
-    assert tuple(call.params["grid_mapping"].grid) == (1, 1, 32, 32)
+    jitted, = jaxpr.eqns
+    assert jitted.params["name"] == "head_probs_call"
+    call, = (e for e in jitted.params["jaxpr"].eqns
+             if e.primitive.name == "pallas_call")
+    assert tuple(call.params["grid_mapping"].grid) == (1, 1, 32)
     assert call.outvars[0].aval.shape == (B, 512, S)
+
+
+def test_head_probs_plan_is_its_own_and_a_function_of_shapes_alone():
+    """``dsa_head_probs``' one size, the heads side by side in a program's
+    body, comes from the selection's tile and the query heads a KV head by
+    the attention's rule (a program owns the tile: no other size);
+    ``dsa_indexer_loss`` takes no size."""
+    import inspect
+
+    lay = pallas_dsa.sel_layout
+
+    def plan(seq, rep=8, **over):
+        return pallas_dsa.head_probs_plan(lay(seq), rep, **over)
+
+    # the cell: four heads of a group's eight side by side, as the attention
+    assert plan(16384) == (4,) == pallas_dsa.attend_plan(
+        lay(16384), 128, 8, jnp.bfloat16)[3:4]
+    assert plan(16384, rep=2) == plan(16384, rep=6) == (2,)
+    assert plan(16384, rep=1) == plan(16384, rep=3) == (1,)
+    # twice the length: a tile is 512 x 1,024, two heads' take the budget
+    assert plan(32768) == (2,) and plan(131072) == (1,)
+    assert plan(300, rep=2) == (2,) and plan(2176) == (4,)
+    assert plan(16384, heads=8) == (8,)
+    assert list(inspect.signature(dsa.dsa_indexer_loss).parameters) == [
+        "qi", "ki", "w", "q", "k", "lse", "sel", "scale", "use_pallas"]
+
+
+@pytest.mark.parametrize("seq,heads,kv_heads,blocks,over,side_by_side", [
+    (1152, 8, 1, 1, None, 4),
+    (1152, 4, 2, 1, None, 2),
+    (1152, 2, 2, 3, None, 1),
+    (1152, 8, 2, 1, 1, 1),
+    (300, 4, 2, 1, None, 2),
+], ids=["groups-of-8", "groups-of-2", "no-groups-rows-of-3",
+        "a-head-at-a-time", "padded"])
+def test_head_probs_kernel_is_the_plain_form(seq, heads, kv_heads, blocks,
+                                             over, side_by_side):
+    """``dsa_head_probs`` (interpret mode) against ``_head_probs_plain`` a
+    call of every row block, the first (its one tile at the diagonal) to the
+    last (tiles far under it): the heads of a KV group side by side and in
+    the body's loop, a head at a time over two groups, a call of several row
+    blocks, a padded length.  The selection is made by hand with a tile of
+    count 0 inside the triangle, and a key no row chose scores so high that
+    its ``exp`` overflows: the output is exactly zero wherever a row did not
+    choose (above the diagonal, in the empty tile, past the length) and
+    finite everywhere."""
+    qi, ki, w, q, k, v = _operands(seq, seed=seq + heads, B=2, N=heads,
+                                   KV=kv_heads)
+    B, S, N, D = q.shape
+    lay = pallas_dsa.sel_layout(seq)
+    sp, rows = lay.padded, blocks * lay.rows
+    t = np.arange(sp)
+    mask = (t[None, :] <= t[:, None]) & (t[None, :] % 3 != 1) \
+        & (t[:, None] < seq)
+    if seq == 1152:
+        mask[512:640, 128:256] = False
+    mask = jnp.asarray(np.broadcast_to(mask, (B, sp, sp)))
+    words, counts = _packed(mask, lay)
+    sel = dsa.Selection(words, counts, lay, seq)
+    assert int(sel.tiles_skipped()) == B * (seq == 1152)
+    _, lse = dsa.dsa_attention(q, k, v, sel, use_pallas=False)
+    k = k.at[:, 4].multiply(1e3)            # 4 % 3 == 1: chosen by no row
+    qp = dsa._pad_rows((q * D ** -0.5).reshape(B, S, -1), sp)
+    kp = dsa._pad_rows(k.reshape(B, S, -1), sp)
+    plan = pallas_dsa.head_probs_plan(lay, N // kv_heads, over)
+    assert plan == (side_by_side,)
+    overflowed = False
+    for at in range(0, sp // lay.rows, blocks):
+        chosen = mask[:, at * lay.rows:at * lay.rows + rows]
+        got = np.asarray(pallas_dsa.head_probs_call(
+            qp, kp, lse, words, counts, jnp.array([at], jnp.int32), rows, N,
+            lay, plan))
+        assert got.shape == (B, rows, sp) and np.isfinite(got).all()
+        assert not got[~np.asarray(chosen)].any()
+        want = dsa._head_probs_plain(qp, kp, lse, chosen, at * lay.rows,
+                                     rows, N)
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-7)
+        s = jnp.einsum("brd,bd->br", qp[:, at * lay.rows:at * lay.rows + rows,
+                                        :D], kp[:, 4, :D])
+        overflowed |= bool(jnp.any(s - lse[0, 0, at * lay.rows] > 100.0))
+    assert overflowed, "no exp of an unchosen pair overflowed"

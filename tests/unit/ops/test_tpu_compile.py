@@ -84,6 +84,17 @@ def _kernel_operand_shapes(text):
             for call in calls]
 
 
+def _scoped_vmem(text):
+    """Of compiled text with ONE kernel call: the scoped VMEM the call
+    states and what Mosaic laid out, in bytes."""
+    import re
+
+    call, = (line for line in text.splitlines() if "tpu_custom_call" in line)
+    stated, used = (int(size) for size in re.findall(
+        r'"memory_space":"1","offset":"0","size":"(\d+)"', call))
+    return stated, used
+
+
 def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
@@ -596,8 +607,6 @@ def test_dsa_attention_kernel_at_its_plan(one_chip, S, heads, kv_heads, walk,
     of ``SelLayout.rows`` rows walked in the body, k and v of a KV head
     resident at 16k (a scoped-VMEM limit of 32 MiB and no more) and a span
     of them at 32k."""
-    import re
-
     B, D, bf = 1, 128, jnp.bfloat16
     lay = pallas_dsa.sel_layout(S)
     plan = pallas_dsa.attend_plan(lay, D, heads // kv_heads, bf)
@@ -622,10 +631,41 @@ def test_dsa_attention_kernel_at_its_plan(one_chip, S, heads, kv_heads, walk,
     assert list(calls) == ["dsa_attention"] and len(calls["dsa_attention"]) == 1
     # what the call states and what Mosaic laid out: at 32 MiB XLA still
     # keeps the selection's words in VMEM around the kernels (PERF.md, PR 54)
-    call, = (line for line in text.splitlines() if "tpu_custom_call" in line)
-    stated, used = (int(size) for size in re.findall(
-        r'"memory_space":"1","offset":"0","size":"(\d+)"', call))
+    stated, used = _scoped_vmem(text)
     assert used <= stated <= (32 if walk == "resident" else 64) << 20
+
+
+@pytest.mark.parametrize("S,heads,kv_heads,dtype,side_by_side", [
+    (16384, 32, 4, jnp.bfloat16, 4),        # train-keye-vl2-ep8-16k's layer
+    (16384, 32, 4, jnp.float32, 4),
+    (32768, 8, 1, jnp.bfloat16, 2),         # a tile of 512 x 1,024
+], ids=["keye-16k", "float32", "32k"])
+def test_dsa_head_probs_kernel_at_its_plan(one_chip, S, heads, kv_heads,
+                                           dtype, side_by_side):
+    """``dsa_head_probs`` alone at the sizes ``pallas_dsa.head_probs_plan``
+    gives the shape: a grid program an output tile, every head's columns of
+    the row block of q resident (a dynamic lane-block slice a set of heads
+    in the body's loop), one call of one kernel behind its jit, under a
+    scoped-VMEM limit of 32 MiB and no more (at 48 XLA stops keeping the
+    selection's words in VMEM for the attention's kernels)."""
+    B, D = 1, 128
+    lay = pallas_dsa.sel_layout(S)
+    plan = pallas_dsa.head_probs_plan(lay, heads // kv_heads)
+    assert plan == (side_by_side,)
+    text = _compile(
+        lambda *a: pallas_dsa.head_probs_call(*a, lay.rows, heads, lay, plan),
+        _sds((B, S, heads * D), dtype, one_chip),
+        _sds((B, S, kv_heads * D), dtype, one_chip),
+        _sds((B * heads, 1, S), jnp.float32, one_chip),
+        _sds((B, S, lay.chunk), jnp.int32, one_chip),
+        _sds((B * (S // lay.rows) * lay.chunks,), jnp.int32, one_chip),
+        _sds((1,), jnp.int32, one_chip))
+    calls = pallas_kernel_calls(text)
+    assert list(calls) == ["dsa_head_probs"]
+    assert len(calls["dsa_head_probs"]) == 1
+    assert f"f32[{B},{lay.rows},{S}]" in text
+    stated, used = _scoped_vmem(text)
+    assert used <= stated <= 32 << 20
 
 
 def test_recomputed_keye_keeps_what_is_made_once_a_step(one_chip):

@@ -5,15 +5,19 @@ alone (forward, dq, dk/dv) and through ``dsa_attention`` forward and
 forward + backward, the indexer's loss with its gradients (the kernels
 ``dsa_head_probs`` + ``dsa_loss_grads``, and beside them the plain form the
 CPU runs).  One JSON line a part: ms a call of the whole program by the
-host's clock over ``--calls`` calls and the device's busiest operations from
-a profiler session, the attention's with the plan they ran
-(``pallas_dsa.attend_plan``); with ``--check`` first, at ``--check-seq``
+host's clock over ``--calls`` calls, the device's busiest operations from a
+profiler session and under ``kernels_ms`` each kernel's device ms a call
+alone with their sum (the loss's lines: ``dsa_head_probs`` beside
+``dsa_loss_grads``), the attention's with the plan they ran
+(``pallas_dsa.attend_plan``), the loss's with ``dsa_head_probs``'
+(``pallas_dsa.head_probs_plan``); with ``--check`` first, at ``--check-seq``
 rows, the kernels' outputs beside the plain forms' (the selection exactly,
 the rest by the largest difference over the largest entry).
 
     python tools/profile_dsa.py                 # the Keye cell's shape
     python tools/profile_dsa.py --seq 8192 --rows 512 1024 --cols 1024
     python tools/profile_dsa.py --loss-rows 128 256 512     # the loss's own
+    python tools/profile_dsa.py --probs-heads 1 2 4 8       # dsa_head_probs'
 """
 
 import argparse
@@ -29,6 +33,9 @@ import jax.numpy as jnp
 
 from deeperspeed_tpu.ops.attention import dsa, pallas_dsa
 from tools.profile_moe_walk import busiest
+
+KERNELS = (pallas_dsa.SELECT, pallas_dsa.ATTENTION, pallas_dsa.HEAD_PROBS,
+           pallas_dsa.LOSS_GRADS)
 
 
 def operands(args, seq):
@@ -82,10 +89,16 @@ def timed(name, fn, args, calls, top, **told):
         out = fn(*args)
     jax.block_until_ready(out)
     ms = 1e3 * (time.perf_counter() - t0) / calls
-    ops, _ = busiest(lambda: fn(*args), max(2, calls // 4), top)
+    ops, _ = busiest(lambda: fn(*args), max(2, calls // 4), None)
+    # an instruction is named after its innermost scope: ``<kernel>.<n>``
+    kernels = {k: round(sum(op["ms"] for op in ops
+                            if op["op"].split(".")[0] == k), 3)
+               for k in KERNELS}
     print(json.dumps({"part": name, "ms_a_call": round(ms, 3), **told,
-                      "first_call_s": round(first, 1), "busiest": ops}),
-          flush=True)
+                      "first_call_s": round(first, 1),
+                      "kernels_ms": {k: v for k, v in kernels.items() if v},
+                      "kernels_sum_ms": round(sum(kernels.values()), 3),
+                      "busiest": ops[:top]}), flush=True)
     return out
 
 
@@ -173,6 +186,10 @@ def main(argv=None):
     ap.add_argument("--loss-rows", nargs="+", default=["kept"],
                     help="rows of the loss kernel's block, to sweep (they "
                     "divide the other kernels' block)")
+    ap.add_argument("--probs-heads", nargs="+", type=int, default=[],
+                    help="query heads side by side in dsa_head_probs' body "
+                    "(pallas_dsa.head_probs_plan), to sweep after the "
+                    "module's own")
     ap.add_argument("--no-loss", action="store_true",
                     help="the selection and the attention alone")
     ap.add_argument("--check", action="store_true")
@@ -183,7 +200,7 @@ def main(argv=None):
     if args.check:
         check(args)
     q, k, v, qi, ki, w, do = operands(args, args.seq)
-    select, attend, attend_grad, loss = parts(args.topk, True)
+    select, attend, attend_grad, _ = parts(args.topk, True)
     sel = timed("select", select, (qi, ki, w), args.calls, args.top)
     print(json.dumps({"pairs_selected": int(sel.pairs_selected()),
                       "pairs_visited": int(sel.pairs_visited()),
@@ -215,10 +232,19 @@ def main(argv=None):
         pallas_dsa.loss_rows = own if block == "kept" else (
             lambda layout, r=int(block): r)
         pallas_dsa.loss_grads_call.clear_cache()        # traced at a block
-        timed(f"indexer loss + gradients loss_rows={block}", loss, loss_args,
+        timed(f"indexer loss + gradients loss_rows={block}",
+              parts(args.topk, True)[3], loss_args,     # traced at a block
               args.calls, args.top)
     pallas_dsa.loss_rows = own
     pallas_dsa.loss_grads_call.clear_cache()
+    own_plan = pallas_dsa.head_probs_plan
+    for side in args.probs_heads:
+        plan = own_plan(lay, args.heads // args.kv_heads, side)
+        pallas_dsa.head_probs_plan = lambda *a, plan=plan: plan
+        timed(f"indexer loss + gradients probs_heads={side}",
+              parts(args.topk, True)[3], loss_args,     # traced at a plan
+              args.calls, args.top, probs_plan=plan._asdict())
+    pallas_dsa.head_probs_plan = own_plan
     timed("indexer loss + gradients, the plain form",
           parts(args.topk, False)[3], loss_args, max(2, args.calls // 4),
           args.top)
