@@ -14,7 +14,9 @@ with ``hidden_size``, ``max_seq_len``, ``ce_chunk_tokens``, ``dtype``,
   on its class: what a layer may be.  ``said`` is ``{}`` of a layer that
   routes nothing, else ``{"counters": what the routed walk counted
   (``dropless.dropless_moe``), "chosen": which held experts each token chose
-  [B, S, held]}``.  A block whose layers hand a value on beside the stream
+  [B, S, held]}``, and where the layer has a term of its own in the loss (a
+  mixture's balance term) ``"loss"``: a scalar ``loss_fn`` adds to the
+  head's.  A block whose layers hand a value on beside the stream
   (a router's state) takes and returns it too, ``Block(...)(x, carried) ->
   (x, said, carried)``: the first layer held is called with ``x`` alone;
 * a subclass of ``Decoder`` that states seven values: ``block_cls``; in
@@ -63,6 +65,22 @@ class Stack(NamedTuple):
 def _dense(width, cfg, name, std=0.02):
     return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name,
                     kernel_init=nn.initializers.normal(std))
+
+
+class GatedMLP(nn.Module):
+    """``W_down (silu(W_gate u) * W_up u)`` at ``width``: a dense layer's
+    MLP and a sparse layer's shared expert (``laguna.py``,
+    ``moonlight.py``)."""
+
+    config: Any
+    width: int
+
+    @nn.compact
+    def __call__(self, u):
+        cfg = self.config
+        hidden = (jax.nn.silu(_dense(self.width, cfg, "gate_proj")(u))
+                  * _dense(self.width, cfg, "up_proj")(u))
+        return _dense(cfg.hidden_size, cfg, "down_proj")(hidden)
 
 
 def cast_rms_norm(x, scale, eps, dtype):
@@ -180,6 +198,9 @@ class Decoder(nn.Module):
             with jax.named_scope("head_ce"):
                 ce, more = self.head_loss(hidden, self.head_kernel(params),
                                           batch)
+            for said in told:       # a layer's own term, where it has one
+                if "loss" in said:
+                    ce = ce + said["loss"]
             return ce, jax.lax.stop_gradient({**counters, **more})
 
         return loss

@@ -50,7 +50,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops.attention.pallas_flash import band_pairs
 from ..ops.transformer.normalize import rms_norm
 from ..parallel.topology import BATCH_AXES
-from .decoder import _dense
+from .decoder import GatedMLP
 from .gpt_neox import maybe_constrain
 from .mellum import (FULL, SCOPE_OF, SLIDING, Mellum, MellumAttention,
                      MellumMoE, Rope)
@@ -176,21 +176,6 @@ class LagunaConfig:
             routed_experts_held=4, first_expert_held=4, max_seq_len=64,
             ce_chunk_tokens=48)
         return LagunaConfig(**dict(small, **kw))
-
-
-class GatedMLP(nn.Module):
-    """``W_down (silu(W_gate u) * W_up u)`` at ``width``: the dense layer's
-    MLP and a sparse layer's shared expert."""
-
-    config: LagunaConfig
-    width: int
-
-    @nn.compact
-    def __call__(self, u):
-        cfg = self.config
-        hidden = (jax.nn.silu(_dense(self.width, cfg, "gate_proj")(u))
-                  * _dense(self.width, cfg, "up_proj")(u))
-        return _dense(cfg.hidden_size, cfg, "down_proj")(hidden)
 
 
 class LagunaBlock(nn.Module):

@@ -734,3 +734,23 @@ def dropless_moe(x, logits, w_in, w_out, *, k, first_expert, experts_held,
                                    activation, rows, grouped,
                                    min(k, experts_held))
     return out, counters, is_chosen
+
+
+def sequence_balance(logits, chosen, seqs):
+    """DeepSeek-V3's sequence-wise balance term (its section 2.1.2, before
+    the coefficient ``alpha``), a reduction over what the router computed:
+    ``logits`` [T, E] float32 of ``seqs`` sequences of equal length one
+    after the other and ``chosen`` [T, k], the experts each token took ->
+    the mean over the sequences of ``sum_i f_i P_i`` over ALL E experts,
+    with ``s' = sigmoid(logits) / sum_j sigmoid(logits)_j``, ``P_i`` the
+    sequence's mean of ``s'[t, i]`` and ``f_i = E / (k T_seq) x`` the
+    sequence's tokens that took expert i (a count: no gradient)."""
+    tokens, experts = logits.shape
+    k, length = chosen.shape[-1], tokens // seqs
+    scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+    share = scores / jnp.sum(scores, axis=-1, keepdims=True)
+    took = jnp.sum(chosen[..., None] == jnp.arange(experts), axis=1)
+    per_seq = lambda t: jnp.sum(  # noqa: E731
+        t.reshape(seqs, length, experts).astype(jnp.float32), axis=1)
+    f = jax.lax.stop_gradient(per_seq(took)) * (experts / (k * length))
+    return jnp.mean(jnp.sum(f * per_seq(share) / length, axis=-1))

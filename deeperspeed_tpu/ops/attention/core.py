@@ -95,3 +95,52 @@ def dot_product_attention(q, k, v, mask=None, causal=True, scale=None, dropout_r
 
 
 causal_attention = functools.partial(dot_product_attention, causal=True)
+
+
+def _reference_latent_attention(q_nope, q_rope, k_nope, k_rope, v, scale=None):
+    """jnp reference path of ``latent_attention``: the rotary key broadcast
+    over the heads by the einsum, the [S, S] scores materialised."""
+    seq = q_nope.shape[1]
+    if scale is None:
+        scale = float(q_nope.shape[-1] + q_rope.shape[-1]) ** -0.5
+    logits = (jnp.einsum("bqnd,bknd->bnqk", q_nope, k_nope,
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("bqnd,bkd->bnqk", q_rope, k_rope,
+                           preferred_element_type=jnp.float32)) * scale
+    logits = jnp.where(jnp.tril(jnp.ones((seq, seq), bool))[None, None],
+                       logits, jnp.finfo(jnp.float32).min)
+    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+    return jnp.einsum("bnqk,bknd->bqnd", probs, v)
+
+
+def latent_attention(q_nope, q_rope, k_nope, k_rope, v, scale=None,
+                     use_pallas=None):
+    """Causal attention whose score is the sum of two products (latent
+    attention's training half): ``softmax((q_nope . k_nope + q_rope .
+    k_rope) * scale) v`` with ``q_nope``, ``k_nope`` [B, S, N, d_nope],
+    ``q_rope`` [B, S, N, d_rope], ``k_rope`` [B, S, d_rope] -- ONE rotary
+    key, shared by the heads and copied to none -- and ``v`` [B, S, N, d_v]
+    -> [B, S, N, d_v]; ``scale`` defaults to ``(d_nope + d_rope) ** -0.5``.
+    The flash kernel of ``pallas_flash_mla`` where it takes the shapes, the
+    plain path elsewhere."""
+    if use_pallas is None:
+        use_pallas = get_accelerator().use_pallas_kernels()
+    if use_pallas:
+        from .flash import flash_attention_supported, flash_latent_attention
+
+        if flash_attention_supported(q_nope.shape, q_nope.dtype,
+                                     rope_dim=q_rope.shape[-1],
+                                     v_dim=v.shape[-1]):
+            heads = (BATCH_AXES, None, (SP_AXIS, TP_AXIS), None)
+            return shard_kernel(
+                functools.partial(flash_latent_attention, scale=scale),
+                (q_nope, q_rope, k_nope, k_rope, v),
+                (heads, heads, heads, (BATCH_AXES, None, None), heads),
+                out_like=4)
+    from ...telemetry.trace import count_kernel_path
+
+    # which form a traced call took: ``in_place_<heads to a rotary lane
+    # block>`` (the kernel's own count) or this
+    count_kernel_path("flash_attention_mla", "plain")
+    return _reference_latent_attention(q_nope, q_rope, k_nope, k_rope, v,
+                                       scale=scale)

@@ -27,7 +27,8 @@ from deeperspeed_tpu.ops import pallas_gmm, pallas_ssd, pallas_utils, ssm
 from deeperspeed_tpu.ops.attention import core as attn_core
 from deeperspeed_tpu.ops.attention import (cca, dsa, eva, paged, pallas_cca,
                                            pallas_dsa, pallas_eva,
-                                           pallas_eva_pool, pallas_flash)
+                                           pallas_eva_pool, pallas_flash,
+                                           pallas_flash_mla)
 from deeperspeed_tpu.ops.quantizer import fused as qfused
 from deeperspeed_tpu.ops.sampling import topk
 from deeperspeed_tpu.ops.transformer import normalize
@@ -35,7 +36,8 @@ from deeperspeed_tpu.parallel import topology as topo_mod
 from deeperspeed_tpu.telemetry.hlo_cost import pallas_kernel_calls
 
 _BY_NAME = (pallas_utils, pallas_flash, paged, qfused, topk, pallas_ssd,
-            pallas_gmm, pallas_eva, pallas_eva_pool, pallas_dsa, pallas_cca)
+            pallas_gmm, pallas_eva, pallas_eva_pool, pallas_dsa, pallas_cca,
+            pallas_flash_mla)
 
 
 @pytest.fixture(scope="module")
@@ -774,6 +776,73 @@ def test_cca_mix_pair_at_the_zaya_cells_shape(one_chip):
     assert f"= f32[{B},{S}," not in entry, "a float32 buffer of a stream's size"
     assert pallas_cca.compiles_for_tpu(S, d, d // 2)
     assert pallas_cca.mix_rows(S) == pallas_cca.ROWS
+
+
+def test_flash_mla_fwd_bwd_at_the_moonlight_cells_shape(one_chip):
+    """Latent attention at ``train-moonlight-16b-ep8-8k``'s shape (4 x 8192
+    rows, 16 heads of 128 + 64 | 128, bfloat16), forward + backward in all
+    five operands: one kernel call each under the scope
+    ``flash_attention_mla`` whose operands are the projections' outputs as
+    they stand.  The rotary key goes in ONCE, ``[B, S, 64]`` (never ``[B, S,
+    16 x 64]``), its gradient comes out at that shape, and no operand is
+    3,072 wide: no value padded to the score's 192."""
+    B, S, N, dn, dr, dv = 4, 8192, 16, 128, 64, 128
+    bf16 = jnp.bfloat16
+    shapes = [_sds(shape, bf16, one_chip) for shape in (
+        (B, S, N, dn), (B, S, N, dr), (B, S, N, dn), (B, S, dr),
+        (B, S, N, dv))]
+    assert pallas_flash_mla.supported((B, S, N, dn), dr, dv, bf16)
+    nope, rope, key = (B, S, N * dn), (B, S, N * dr), (B, S, dr)
+
+    def mla(*operands):
+        with jax.named_scope("layer"):
+            return pallas_flash_mla.mla(*operands)
+
+    forward = pallas_kernel_calls(_compile(mla, *shapes))
+    assert list(forward) == ["flash_attention_mla"]
+    assert forward["flash_attention_mla"] == [[nope, rope, nope, key, nope]]
+    text = _compile(_sum_grad(mla, 5), *shapes)
+    calls = pallas_kernel_calls(text)["flash_attention_mla"]
+    assert len(calls) == 2
+    backward = max(calls, key=len)
+    # the forward's five, then do, o and the lse
+    assert backward[:7] == [nope, rope, nope, key, nope, nope, nope]
+    assert backward[7] == (B * N, 1, S)
+    flat = [shape for call in calls for shape in call]
+    assert (B, S, N * (dn + dr)) not in flat, "a value or key padded to 192"
+    assert sum(shape == key for shape in flat) == 2, "the ONE rotary key"
+    # the key's gradient leaves the kernel at one head
+    assert f"bf16[{B},{S},{dr}]" in text
+    from deeperspeed_tpu.telemetry import count_kernel_passes
+
+    assert count_kernel_passes(text)["flash_attention_mla"] == dict(
+        forward=1, recomputed=0, backward=1)
+
+
+def test_recomputed_moonlight_keeps_the_kernels_residuals_and_the_walks_plan(
+        one_chip, on_the_chip):
+    """``Moonlight`` (a dense and two sparse layers, remat, heads of 128 +
+    64 | 128): a layer's attention is one ``flash_attention_mla`` call
+    forward and one backward, a sparse layer's top-3 walk the grouped form's
+    two matmuls forward and six backward; the recomputed layer keeps the
+    kernel's residuals and the walk's plan and runs neither again; no plain
+    ``flash_attention`` is in the step."""
+    from deeperspeed_tpu.models.moonlight import Moonlight, MoonlightConfig
+
+    model = Moonlight(MoonlightConfig.tiny(
+        hidden_size=256, num_attention_heads=2, kv_lora_rank=128,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        intermediate_size=256, moe_intermediate_size=128, max_seq_len=256,
+        ce_chunk_tokens=256, remat=True, dtype=jnp.bfloat16))
+    loss = model.loss_fn()
+    passes = _model_gradient_passes(
+        model, lambda p, ids: loss(
+            p["params"], {"input_ids": ids, "labels": ids})[0], one_chip)
+    assert passes["flash_attention_mla"] == dict(forward=3, recomputed=0,
+                                                 backward=3)
+    assert passes["grouped_matmul"] == dict(forward=2 * 2, recomputed=0,
+                                            backward=2 * 6)
+    assert "flash_attention" not in passes
 
 
 @pytest.mark.parametrize("tokens,latent,inner,held,gated,most", [
